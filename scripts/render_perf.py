@@ -70,9 +70,21 @@ def _programs_cell(leg: dict) -> str:
     return f"{cell}, {td} tree" if td else cell
 
 
+def _provenance(sidecar: dict) -> str:
+    """What the record can speak for, from its own `backend` field: a
+    run on the CPU mesh yields counts (dispatches, compiles, bytes,
+    collective launches) and parity, never device times."""
+    backend = sidecar.get("backend", "unknown")
+    if backend == "cpu":
+        return ("**CPU run — counts and parity only; device times not "
+                "measured.** Every time below is XLA:CPU (or the Pallas "
+                "interpreter) against pandas/sklearn on the same host.")
+    return f"**Backend `{backend}`** (as recorded by the run)."
+
+
 def render(sidecar: dict) -> str:
     legs = sidecar["legs"]
-    lines = []
+    lines = [_provenance(sidecar), ""]
     lines.append(f"Suite wall-clock **{sidecar['value']}s vs "
                  f"{sidecar['baseline_seconds_measured_host']}s** for the "
                  f"same legs run the single-node pandas/sklearn way "
@@ -84,20 +96,18 @@ def render(sidecar: dict) -> str:
                  f"per-pass leg time is in `bench_legs.json`).")
     lines.append("")
     lines.append("| leg | framework | host | speedup | per-pass s | "
-                 "host measured | MFU | programs (new/distinct, tree "
+                 "host measured | programs (new/distinct, tree "
                  "dispatches) | engine Δ (h2d/d2h, cache) |")
-    lines.append("|---|---|---|---|---|---|---|---|---|")
+    lines.append("|---|---|---|---|---|---|---|---|")
     for k in sorted(legs):
         v = legs[k]
         label = LEG_LABELS.get(k, k)
-        mfu = v.get("mfu_pct")
-        mfu_s = f"{mfu}%" if mfu is not None else "—"
         sp = v.get("speedup_vs_host")
         lines.append(
             f"| {label} | {v['seconds']}s | "
             f"{v.get('host_baseline_seconds', '—')}s | "
             f"{sp}× | {v.get('seconds_per_pass')} | "
-            f"{v.get('host_measured', '?')} | {mfu_s} | "
+            f"{v.get('host_measured', '?')} | "
             f"{_programs_cell(v)} | "
             f"{_engine_cell(v.get('engine_counters'))} |")
     lines.append("")

@@ -10,52 +10,30 @@ MLOps glue (tracking/registry/feature store/AutoML) — single-process Python
 driver, no JVM, native C++ for host-side hot ops.
 """
 
-import os as _os
-
-
-def _enable_persistent_compile_cache() -> None:
-    """Point XLA's persistent compilation cache at a repo-local directory so
-    a fresh process reuses every program compiled by an earlier one (SURVEY
-    §7 hard-part #6: compile+first-exec dominated r2's bench wall-clock).
-    Owned by `parallel.dispatch.ensure_compile_cache` (conf knob
-    `sml.compile.cacheDir`); opt out with SML_TPU_COMPILE_CACHE=0."""
-    # import OUTSIDE the guard: a broken dispatch module must fail the
-    # package import loudly, not silently disable compile caching
-    from .parallel.dispatch import ensure_compile_cache
-    try:
-        ensure_compile_cache()
-    except Exception:
-        pass  # compile caching is best-effort
-
-
-_enable_persistent_compile_cache()
-
 
 def _require_pandas_cow() -> None:
     """The frame layer's shallow-copy memoization (`toPandas` caching,
     `pdf.copy(deep=False)` views) is only mutation-safe under pandas
-    copy-on-write. pandas>=3 has CoW always-on; on 2.x we enable the mode
-    explicitly — a deliberate PROCESS-GLOBAL flip (it is pandas 3.x
-    semantics, and the frame layer deep-copies defensively if someone
-    turns it back off) — and anything older is refused (ADVICE r3: an
-    in-place mutation of a returned frame must never corrupt a cached
-    parent)."""
+    copy-on-write, which pandas>=3 has always on; anything older is
+    refused (an in-place mutation of a returned frame must never corrupt
+    a cached parent)."""
     import pandas as pd
-    major = int(pd.__version__.split(".")[0])
-    if major >= 3:
-        return
-    if major < 2:  # 1.5's experimental CoW is incomplete: refuse outright
+    if int(pd.__version__.split(".")[0]) < 3:
         raise ImportError(
-            f"sml_tpu requires pandas>=2.0 (found {pd.__version__})")
-    try:
-        pd.options.mode.copy_on_write = True
-    except (AttributeError, KeyError):
-        raise ImportError(
-            f"sml_tpu requires pandas>=2.0 with copy-on-write "
-            f"(found {pd.__version__})")
+            f"sml_tpu requires pandas>=3 (found {pd.__version__})")
 
 
 _require_pandas_cow()
+
+# XLA's persistent compilation cache: a fresh process reuses every program
+# compiled by an earlier one. Placed by `JAX_COMPILATION_CACHE_DIR` when
+# set, else `sml.compile.cacheDir`, else `<checkout>/.jax_cache`
+# (`parallel.dispatch.ensure_compile_cache`). A failure to set it up fails
+# the import: a run that silently recompiles everything is not the run
+# that was asked for.
+from .parallel.dispatch import ensure_compile_cache as _ensure_compile_cache
+
+_ensure_compile_cache()
 
 from .conf import GLOBAL_CONF
 from .frame import DataFrame, Row, TpuSession, functions, get_session

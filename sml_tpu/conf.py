@@ -87,9 +87,10 @@ _register("sml.tree.kernel", "auto", str,
           "sml_tpu/native/hist_kernel.py Pallas kernels (bin-accumulate "
           "straight from the compact bin cache, in-register gain scan; "
           "runs in interpret mode on non-TPU backends — the tier-1 "
-          "bit-parity testing story); 'auto' = pallas on real TPU only, "
-          "xla everywhere else. Unavailable pallas falls back to xla and "
-          "counts kernel.fallback. See docs/KERNELS.md")
+          "bit-parity testing story) and RAISES where they cannot launch; "
+          "'auto' = xla everywhere today: neither fit kernel compiles "
+          "for TPU v5e as written (native/hist_kernel.AUTO_ON_TPU), and "
+          "auto never emulates off-TPU. See docs/KERNELS.md")
 _register("sml.tree.kernelBlockRows", 4096, int,
           "Row-block size of the pallas bin-accumulate kernel's grid on "
           "hardware (bounds the VMEM one-hot tile to ~blockRows*F*bins "
@@ -124,10 +125,11 @@ _register("sml.tree.roundsPerDispatch", 0, int,
           "with the input buffer DONATED between chunks — bounds compile "
           "time for very deep ensembles without per-round host transfers")
 _register("sml.compile.cacheDir", "", str,
-          "Persistent XLA compilation-cache directory. Empty = the "
-          "repo-local .jax_cache default (or JAX_COMPILATION_CACHE_DIR / "
-          "SML_TPU_COMPILE_CACHE when set); applied at import and "
-          "re-applied whenever this key is set "
+          "Persistent XLA compilation-cache directory, used only where "
+          "JAX_COMPILATION_CACHE_DIR is NOT set (a cache placed from "
+          "outside wins and the code sets no other). Empty = the fixed "
+          "<checkout>/.jax_cache; applied at import and re-applied "
+          "whenever this key is set "
           "(parallel.dispatch.ensure_compile_cache)")
 _register("sml.split.sortMemoBytes", 1 << 30, int,
           "Byte bound for randomSplit's pre-split sort memo (each entry "
@@ -213,15 +215,16 @@ _register("sml.infer.kernel", "auto", str,
           "(level-order SoA node tables resident in VMEM, depth-unrolled "
           "predicated descent, leaf sums accumulated in-register; runs "
           "in interpret mode on non-TPU backends — the tier-1 bit-parity "
-          "testing story); 'auto' = pallas on real TPU only, xla "
-          "everywhere else. Unavailable pallas falls back to xla and "
-          "counts infer.kernel.fallback. See docs/KERNELS.md")
+          "testing story) and RAISES where it cannot launch; 'auto' = "
+          "pallas on a TPU mesh (compiled; a failing toolchain probe "
+          "there counts infer.kernel.fallback), xla everywhere else. "
+          "See docs/KERNELS.md")
 _register("sml.infer.kernelBlockRows", 2048, int,
           "Row-block size of the pallas traversal kernel's grid on "
           "hardware (bounds the VMEM per-level one-hot tile to "
           "~blockRows*(n_nodes+F) elements; the actual block is the "
-          "largest divisor of the per-chip padded rows at or under "
-          "this). The hand-set default the --kernelbench autotuner "
+          "largest 32-row-aligned divisor of the per-chip padded rows at "
+          "or under this). The hand-set default the --kernelbench autotuner "
           "exists to beat: a tuned spec from the prewarm manifest "
           "overrides this per (model shape, batch width) when "
           "sml.infer.autotune is on. Interpret mode always runs ONE "

@@ -62,7 +62,7 @@ _register("sml.fleet.minReplicas", 1, int,
 _register("sml.fleet.maxReplicas", 4, int,
           "Fleet ceiling: the autoscaler never adds past this many "
           "replicas — each replica pins a warm scorer and a standing "
-          "queue, and the device tunnel is shared no matter how many "
+          "queue, and the device lane is shared no matter how many "
           "batchers feed it")
 _register("sml.fleet.scaleUpOccupancy", 0.75, float,
           "Autoscaler scale-up band: mean fleet queue occupancy "

@@ -12,7 +12,7 @@ Both properties are one wrapper deep:
 - pressure: the replica owns a `QueuePressure(parent=DEVICE_QUEUE)`
   and hands it to its endpoint's `MicroBatcher`, so admissions feed
   BOTH the per-replica signal the router reads and the process-wide
-  dispatcher signal (`parallel/dispatch.py` — the device tunnel is
+  dispatcher signal (`parallel/dispatch.py` — the device lane is
   shared no matter how many batchers feed it);
 - killability: `_ReplicaEndpoint` checks the replica's poison flag on
   every device/host scoring call. `poison()` (a simulated crash — the

@@ -66,11 +66,9 @@ def drop_split_cache_for(token) -> None:
 
 
 def _group_handout(g: pd.DataFrame) -> pd.DataFrame:
-    """The frame a user fn receives: shallow under CoW (writes can't reach
-    the cached split), deep when someone disabled CoW on an older pandas."""
-    if int(pd.__version__.split(".")[0]) < 3 \
-            and pd.options.mode.copy_on_write is not True:
-        return g.copy(deep=True)
+    """The frame a user fn receives: shallow under pandas' copy-on-write
+    (always on in pandas>=3, which the package requires), so writes can't
+    reach the cached split."""
     return g.copy(deep=False)
 
 

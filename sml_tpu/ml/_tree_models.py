@@ -594,8 +594,9 @@ def fused_reg_stats_from_matrix(spec, X: np.ndarray, lab: np.ndarray,
     """The fused traverse+metric device pass over a raw feature matrix:
     bins (content-memoized), routes, and — on the device route — returns
     the five regression sufficient statistics from ONE program dispatch
-    (D2H is five scalars). Returns None on the host route or any surprise;
-    callers then take the ordinary predict+stats path. Shared by the bare
+    (D2H is five scalars). Returns None on the host route or a shape it
+    screens out; callers then take the ordinary predict+stats path. An
+    error from the device program itself propagates. Shared by the bare
     tree-model hook and the fused-pipeline hook."""
     if spec.mode != "regression":
         return None
@@ -646,10 +647,12 @@ class _TreeEvalHook(RegStatsHook):
     materializing a prediction column (host traversal or a 3.2MB/800k-row
     D2H) and re-uploading pred/label for the stats pass."""
 
-    def _compute(self, raw, lab, label_col: str):
-        model = self._tail
-        X = extract_features(raw, model.getOrDefault("featuresCol"))
-        return fused_reg_stats_from_matrix(model._spec, X, lab,
+    def _features(self, raw):
+        return extract_features(
+            raw, self._tail.getOrDefault("featuresCol")), None
+
+    def _stats(self, X, lab):
+        return fused_reg_stats_from_matrix(self._tail._spec, X, lab,
                                            link=self._link)
 
 

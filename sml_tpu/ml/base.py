@@ -207,7 +207,10 @@ def _attach_fused_features(cur, fitted_transforms, est, raw_pdf):
                            (X, keep, raw_pdf)}
         return cur
     except Exception:
-        return cur  # any surprise: the generic per-stage path is correct
+        # host-only feature-chain compilation (no device program runs in
+        # this block): on any surprise the generic per-stage path is
+        # correct, and raises whatever is really wrong
+        return cur
 
 
 class Pipeline(Estimator):
@@ -256,9 +259,10 @@ class Pipeline(Estimator):
             # whole-chain fused fit (featurizer.try_fast_fit): the standard
             # prep chain fits from the raw pandas and the estimator reads a
             # one-pass assembled block — nothing else materializes. Only
-            # the CHAIN COMPILATION is guarded (any surprise falls back to
-            # the always-correct generic path); the estimator fit runs
-            # unguarded so its real errors propagate.
+            # the host-side CHAIN COMPILATION is guarded (any surprise
+            # falls back to the always-correct generic path); the
+            # estimator fit — the device work — runs unguarded so its
+            # real errors propagate.
             from .featurizer import try_fast_fit
             try:
                 fast = try_fast_fit(stages, raw_pdf, make_frame)
@@ -324,11 +328,12 @@ class RegStatsHook:
     (prediction_col, label_col) stats cache, the predictionCol/parent/
     label guards, the strict label conversion (a non-numeric label column
     must raise on the materialize path and DECLINE here, never silently
-    coerce to NaN), and the decline-on-any-surprise contract — so the
-    producers cannot drift apart. Subclasses implement `_compute(raw,
-    lab, label_col)` and may override `_label_ok`. Returning None always
-    means: the evaluator takes the ordinary materialize path, so results
-    never depend on the hook firing."""
+    coerce to NaN), and the split between the host step that may decline
+    (`_features`) and the device step whose errors propagate (`_stats`)
+    — so the producers cannot drift apart. Subclasses implement those
+    two and may override `_label_ok`. Returning None always means: the
+    evaluator takes the ordinary materialize path, so results never
+    depend on the hook firing."""
 
     # names only — resolved via getattr(np, name) host-side and
     # getattr(jnp, name) in the device program, so the two sides cannot
@@ -364,30 +369,47 @@ class RegStatsHook:
     def _label_ok(self, label_col: str) -> bool:
         return True
 
-    def _compute(self, raw, lab, label_col: str):
+    def _features(self, raw):
+        """HOST step: the (X, keep) feature block for the raw parent
+        pandas (`keep` is the featurizer's row-drop mask or None)."""
+        raise NotImplementedError
+
+    def _stats(self, X, lab):
+        """DEVICE step: the five statistics from the feature block, or
+        None when the route or the shape declines."""
         raise NotImplementedError
 
     def reg_stats(self, prediction_col: str, label_col: str):
         cached = self._stats_cache.get((prediction_col, label_col))
         if cached is not None:
             return cached  # rmse-then-mae-then-r2 costs one predict, not 3
+        if self._tail.getOrDefault("predictionCol") != prediction_col:
+            return None
+        if not hasattr(self._parent, "toPandas"):
+            return None
+        raw = self._parent.toPandas()
+        if label_col not in raw.columns or len(raw) == 0:
+            return None
+        if not self._label_ok(label_col):
+            return None
         try:
-            if self._tail.getOrDefault("predictionCol") != prediction_col:
-                return None
-            if not hasattr(self._parent, "toPandas"):
-                return None
-            raw = self._parent.toPandas()
-            if label_col not in raw.columns or len(raw) == 0:
-                return None
-            if not self._label_ok(label_col):
-                return None
             lab = np.asarray(raw[label_col], dtype=np.float64)
-            stats = self._compute(raw, lab, label_col)
-            if stats is not None:
-                self._stats_cache[(prediction_col, label_col)] = stats
-            return stats
-        except Exception:
-            return None  # any surprise: the materialize path is correct
+            X, keep = self._features(raw)
+        except (KeyError, TypeError, ValueError):
+            # host-side prep surprise (non-numeric label, a column the
+            # compiled chain assumed raw): decline — the materialize
+            # path converts strictly and raises what is really wrong
+            return None
+        if keep is not None:
+            lab = lab[keep]
+        # the device dispatch runs OUTSIDE any guard: an error raised by
+        # a compiled program or by the compiler reaches the caller
+        # instead of being absorbed by a slower path that still prints
+        # the right metric
+        stats = self._stats(X, lab)
+        if stats is not None:
+            self._stats_cache[(prediction_col, label_col)] = stats
+        return stats
 
 
 class _ScorerEvalHook(RegStatsHook):
@@ -409,10 +431,10 @@ class _ScorerEvalHook(RegStatsHook):
         from .featurizer import produced_columns
         return label_col not in produced_columns(self._prep_stages)
 
-    def _compute(self, raw, lab, label_col: str):
-        X, keep = self._feat.transform_with_mask(raw)
-        if keep is not None:
-            lab = lab[keep]
+    def _features(self, raw):
+        return self._feat.transform_with_mask(raw)
+
+    def _stats(self, X, lab):
         spec = getattr(self._tail, "_spec", None)
         if spec is not None and hasattr(spec, "trees"):
             # tree tail: the whole traverse+metric fuses into one device
@@ -509,19 +531,12 @@ class PipelineModel(Model):
         exactly; falls back to the generic path whenever the shape doesn't
         fit. Mirrors Spark's lazy whole-stage codegen philosophy
         (`SML/ML 00b - Spark Review.py:45`) on the host side."""
-        import os as _os
-        debug = _os.environ.get("SML_FUSED_DEBUG") == "1"
-        try:
-            if not hasattr(df, "toPandas") or getattr(df, "isStreaming", False):
-                return None
-            plan = self._fast_plan()
-            if plan is None:
-                return None
-            feat, scorer, assembler, tail = plan
-        except Exception:
-            if debug:
-                raise
+        if not hasattr(df, "toPandas") or getattr(df, "isStreaming", False):
             return None
+        plan = self._fast_plan()
+        if plan is None:
+            return None
+        feat, scorer, assembler, tail = plan
         from ..frame.dataframe import DataFrame as _DF, _split_rows
         from .linalg import vector_series
         out_col = assembler.getOrDefault("outputCol")
@@ -531,7 +546,12 @@ class PipelineModel(Model):
             import pandas as pd
             raw = parent.toPandas()
             n_parts = len(parent._materialize())
-            X, keep, cols = feat.transform_with_columns(raw)
+            try:
+                X, keep, cols = feat.transform_with_columns(raw)
+            except (KeyError, TypeError, ValueError):
+                # host-side surprise in the compiled chain (odd dtype, a
+                # column it assumed raw): the generic chain handles it
+                return None
             if cols is None:
                 return None  # un-recoverable interim: caller falls back
             base = raw if keep is None else \
@@ -553,22 +573,19 @@ class PipelineModel(Model):
         # LAZY: the pass runs at first materialization, like every other
         # frame op — so an evaluator pushdown (`_fused_eval` hook below) on
         # a transform that is only ever evaluated never assembles the
-        # output frame at all. A mid-pass surprise (odd dtype, unseen
-        # interim shape) falls back to the generic per-stage chain INSIDE
-        # compute(), so laziness never changes what a consumer sees.
+        # output frame at all. Only the HOST featurize step may decline
+        # (inside compute()) and fall back to the generic per-stage
+        # chain; the scorer's device dispatch runs unguarded, so an error
+        # from a compiled program or the compiler reaches the consumer.
         from ..utils.profiler import PROFILER
         stages = self.stages
 
         def compute_or_fallback():
-            try:
-                with PROFILER.span("fused_transform",
-                                   rows=None, stages=len(stages)):
-                    parts = compute()
-                if parts is not None:
-                    return parts
-            except Exception:
-                if debug:
-                    raise
+            with PROFILER.span("fused_transform",
+                               rows=None, stages=len(stages)):
+                parts = compute()
+            if parts is not None:
+                return parts
             cur = parent
             for s in stages:
                 cur = s.transform(cur)

@@ -8,14 +8,13 @@
 // for finite x, bin 0 for any non-finite value (tree_impl.make_bins).
 //
 // Built on demand by native/build.py (g++ -O3); callers fall back to the
-// NumPy implementation when no compiler is available.
+// NumPy implementation when no compiler is available. Only the two entry
+// points have C linkage (a template cannot).
 
 #include <cstdint>
 #include <cmath>
 #include <thread>
 #include <vector>
-
-extern "C" {
 
 // One column: edges must be ascending; out[i] = #edges < x strictly left.
 static void bin_column(const double* col, int64_t n, const float* edges,
@@ -68,6 +67,8 @@ static void bin_matrix_impl(const T* X, int64_t n, int32_t F,
     }
     for (auto& t : pool) t.join();
 }
+
+extern "C" {
 
 void bin_matrix(const double* X, int64_t n, int32_t F, const float* edges,
                 const int32_t* n_edges, int32_t max_edges,

@@ -32,9 +32,9 @@ under `pallas_call(interpret=True)` with a SINGLE row block, so the traced
 kernel body is op-for-op the XLA path's math (same one-hot, same
 `dot_general` dimension numbers, same cumsum/argmax) evaluated by the same
 backend — fit outputs are BIT-IDENTICAL to the XLA path, which
-tests/test_hist_kernel.py asserts. On hardware the row-block grid bounds
-VMEM instead; cross-block f32 accumulation order then differs from one
-big dot by float associativity only (see docs/KERNELS.md).
+tests/test_hist_kernel.py asserts. The row-block grid is what would bound
+VMEM on hardware, but neither body compiles for the chip as written
+(`AUTO_ON_TPU` below; docs/KERNELS.md "State on the chip").
 
 Every `pl.pallas_call` in the package must live in `sml_tpu/native/` —
 graftlint's `dispatch-bypass` rule flags raw kernel launches anywhere
@@ -45,24 +45,36 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
-import numpy as np
-
 from ..utils.profiler import PROFILER
 
-_avail: Dict[str, bool] = {}
+#: `sml.tree.kernel=auto` does NOT select these kernels on a TPU mesh:
+#: neither body compiles for v5e with jax 0.9.0 / libtpu 0.0.34 (PR 21,
+#: docs/KERNELS.md "State on the chip"). `hist_accumulate`: "Mosaic
+#: failed to compile TPU kernel: infer-vector-layout: unsupported shape
+#: cast ... tpu.reshape (vector<4096xi1>) -> vector<4096x1xi1>" (the 1-D
+#: row operands). `split_scan`: "Unimplemented primitive in Pallas TPU
+#: lowering for KernelType.TC: cumsum". The two share one switch, so
+#: `auto` resolves to the XLA build on TPU; an explicit 'pallas' there
+#: raises the compiler's message.
+AUTO_ON_TPU = False
+
+#: minor-dimension tile of every VMEM array: what a VMEM guard must pad to
+LANES = 128
+
+#: interpret flag -> None (a launch worked) | the error text it raised
+_avail: Dict[bool, Optional[str]] = {}
 
 
-def available() -> bool:
-    """Whether the Pallas toolchain can run a kernel in this process —
-    probed ONCE with a tiny interpret-mode launch (import and
-    interpret-machinery failures land here, so callers get a clean
-    yes/no instead of a mid-trace exception). This does NOT prove every
-    SHAPE lowers on real hardware — per-spec VMEM limits are guarded
-    statically by `tree_impl._kernel_for` instead. The fallback ladder
-    (`tree_impl._kernel_choice`) turns a False into the XLA path plus a
-    `kernel.fallback` count."""
-    hit = _avail.get("ok")
-    if hit is None:
+def probe(interpret: bool) -> Optional[str]:
+    """Whether the Pallas toolchain can launch a kernel in this process,
+    probed ONCE per mode with a tiny kernel: `interpret=True` on non-TPU
+    backends, a Mosaic COMPILE and run on a TPU mesh. Returns None when
+    the launch worked, else the error it raised, so callers can raise
+    the compiler's own message (`resolve_mode`). This proves the
+    toolchain, not that every kernel body lowers at every shape: a body
+    that cannot compile fails at its own first launch, and nothing
+    catches that."""
+    if interpret not in _avail:
         try:
             import jax
             import jax.numpy as jnp
@@ -73,14 +85,43 @@ def available() -> bool:
 
             out = pl.pallas_call(
                 _probe,
-                out_shape=jax.ShapeDtypeStruct((1, 2), jnp.float32),
-                interpret=True,
-            )(jnp.ones((1, 2), jnp.float32))
-            hit = bool(np.asarray(out)[0, 0] == 2.0)
-        except Exception:
-            hit = False
-        _avail["ok"] = hit
-    return hit
+                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+                interpret=interpret,
+            )(jnp.ones((8, 128), jnp.float32))
+            _avail[interpret] = None if float(out[0, 0]) == 2.0 \
+                else "probe kernel returned a wrong value"
+        except Exception as e:  # noqa: BLE001 — reported, never swallowed
+            _avail[interpret] = f"{type(e).__name__}: {e}"
+    return _avail[interpret]
+
+
+def resolve_mode(key: str, mode, platform: str,
+                 auto_on_tpu: bool) -> Tuple[str, bool]:
+    """(kernel, fell_back) for the value `mode` of the kernel switch
+    `key` (`sml.tree.kernel` / `sml.infer.kernel`) on a mesh of
+    `platform` — the ONE resolution both switches share
+    (docs/KERNELS.md). 'xla' short-circuits. 'auto' selects pallas only
+    on a TPU mesh AND only while the switch's kernels are recorded as
+    compiling there (`auto_on_tpu`) — otherwise xla is the resolver's
+    answer for the platform, not a fallback; a TPU whose toolchain probe
+    then fails is the one fallback (the caller counts it). An explicit
+    'pallas' is a demand: interpret mode off-TPU, a compiled launch on
+    TPU, and a toolchain that cannot launch raises its own error. Any
+    other value raises (a typo must not silently land on either path)."""
+    mode = str(mode).strip().lower()
+    if mode not in ("auto", "pallas", "xla"):
+        raise ValueError(
+            f"{key} must be one of auto/pallas/xla, got {mode!r}")
+    on_tpu = platform == "tpu"
+    if mode == "xla" or (mode == "auto" and not (on_tpu and auto_on_tpu)):
+        return "xla", False
+    err = probe(interpret=not on_tpu)
+    if err is None:
+        return "pallas", False
+    if mode == "pallas":
+        raise RuntimeError(f"{key}=pallas but a Pallas kernel cannot "
+                           f"launch on this {platform} mesh: {err}")
+    return "xla", True
 
 
 def _block_plan(n: int, interpret: bool,
@@ -107,22 +148,6 @@ def _block_plan(n: int, interpret: bool,
     while n % k:
         k += 1
     return k, n // k
-
-
-def _tpu_compiler_params():
-    """Sequential-grid compiler params for the accumulating kernel (grid
-    steps revisit the same output block, so the grid must not be declared
-    parallel). Version-tolerant: absent/renamed param classes degrade to
-    None (the compiler default) rather than failing the launch."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        cls = getattr(pltpu, "CompilerParams", None) \
-            or getattr(pltpu, "TPUCompilerParams", None)
-        if cls is None:
-            return None
-        return cls(dimension_semantics=("arbitrary",))
-    except Exception:
-        return None
 
 
 def hist_accumulate(binned, lid, grad, hess, weight, *, n_bins: int,
@@ -176,9 +201,10 @@ def hist_accumulate(binned, lid, grad, hess, weight, *, n_bins: int,
 
     kwargs = {}
     if not interpret:
-        params = _tpu_compiler_params()
-        if params is not None:
-            kwargs["compiler_params"] = params
+        from jax.experimental.pallas import tpu as pltpu
+        # grid steps revisit the one output block: the grid is sequential
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
     PROFILER.count("kernel.pallas_launch")
     if interpret:
         PROFILER.count("kernel.interpret")

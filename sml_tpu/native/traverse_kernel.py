@@ -23,14 +23,21 @@ Boosting" (arXiv:1706.08359) — for a block of rows at a time:
   leaves the kernel. The per-level one-hots and the `(T, rows)` margin
   stack never touch HBM.
 
-The kernel body is op-for-op `ml/inference._forest_margin`'s math (same
-one-hot where-sums — gather-free and exact in f32, see that docstring
-for why — same select, same reductions). The traversal has NO cross-row
-operation, so row blocking cannot change any output bit: interpret mode
-(non-TPU backends, single block) and compiled mode (row-block grid) are
-both BIT-IDENTICAL to the XLA path, which tests/test_traverse_kernel.py
-asserts across DT/RF/xgboost, uint8/uint16 bin matrices, NaN rows, and
-the logistic finalize.
+The kernel body is `ml/inference._forest_margin`'s math (same one-hot
+where-sums — gather-free and exact in f32, see that docstring for why —
+same select), written to the chip's tiling: every value is 2-D with rows
+on sublanes (`(block, 1)` node ids, `(block, width)` one-hot tiles — a
+1-D vector or a 1-D iota does not lower through Mosaic), trees run as a
+`fori_loop` that reads one `(1, n_nodes)` table row per step, and the
+weighted leaf sum accumulates tree by tree. The traversal has NO
+cross-row operation, so row blocking cannot change any output bit; each
+per-row where-sum has exactly one nonzero term, so only the order of the
+T-term weighted tree sum can differ from the XLA path (sequential here,
+XLA-determined there). Interpret mode (non-TPU backends, single block)
+is bit-identical to the XLA path on CPU, which
+tests/test_traverse_kernel.py asserts across DT/RF/xgboost, uint8/uint16
+bin matrices, NaN rows, and the logistic finalize; the compiled kernel's
+agreement on TPU is checked by `chip_smoke.py` (docs/KERNELS.md).
 
 Every `pl.pallas_call` in the package must live in `sml_tpu/native/`,
 and every *invocation* of `forest_traverse` must come from the
@@ -46,47 +53,65 @@ from typing import Optional, Tuple
 import numpy as np
 
 from ..utils.profiler import PROFILER
-from .hist_kernel import _tpu_compiler_params, available  # noqa: F401
+from .hist_kernel import LANES as _LANES
 
-#: compiled-path VMEM budget per grid step (the per-level one-hot tiles
-#: plus the resident node tables; ~16 MB VMEM/core shared with operands)
-TRAVERSE_VMEM_BUDGET = 8 << 20
+#: `sml.infer.kernel=auto` selects this kernel on a TPU mesh: it compiles
+#: for v5e (jax 0.9.0 / libtpu 0.0.34) at the course shapes and agrees
+#: with the XLA traversal there (PR 21 chip run, docs/KERNELS.md)
+AUTO_ON_TPU = True
+
+#: compiled-path VMEM budget per grid step. Mosaic's scoped limit on v5e
+#: is 16 MiB; `traverse_vmem_bytes` is within ~7% of what the compiler
+#: reported at the course shapes, so 12 MiB leaves the rest as margin
+TRAVERSE_VMEM_BUDGET = 12 << 20
+
+_SUBLANES = 32  # row-block multiple that suits every bin dtype (uint8)
+
+
+def _lane_pad(k: int) -> int:
+    return -(-int(k) // _LANES) * _LANES
 
 
 def traverse_vmem_bytes(block_rows: int, n_trees: int, n_nodes: int,
                         n_feat: int) -> int:
-    """Per-grid-step VMEM estimate of the compiled traversal: the f32
-    per-level node one-hot and leaf one-hot tiles (`block·n_nodes` each),
-    the feature-select tile (`block·F`), the in-register per-tree margin
-    stack (`T·block`), the widened bin tile (`block·F`), and the resident
-    SoA node tables (three `(T, n_nodes)` lanes). The guard in
-    `ml/inference.py` demotes oversized (block_rows × trees) specs with
-    this estimate instead of failing to lower mid-trace (block_rows=0 =
-    the block-independent node-table term alone)."""
+    """Per-grid-step VMEM estimate of the compiled traversal, counting
+    the 128-lane padding of every minor dimension: per row, the
+    `(block, width)` node one-hot and its where-select, the widened bin
+    tile and its feature select, and the `(block, 1)` vectors (node,
+    looked-up feature/bin, row bin, accumulator), each of which occupies
+    a full lane tile; plus the resident SoA node tables (three
+    `(T, n_nodes)` lanes). The guard in `ml/inference.py` demotes
+    oversized (block_rows × trees) specs with this estimate instead of
+    failing to compile mid-trace (block_rows=0 = the block-independent
+    node-table term alone)."""
     blk = max(int(block_rows), 0)
-    return int(4 * blk * (2 * n_nodes + 2 * n_feat + n_trees)
-               + 12 * n_trees * n_nodes)
+    per_row = 4 * (2 * _lane_pad(n_nodes) + 2 * _lane_pad(n_feat)
+                   + 4 * _LANES)
+    tables = 12 * (-(-int(n_trees) // 8) * 8) * _lane_pad(n_nodes)
+    return int(blk * per_row + tables)
 
 
 def max_block_rows(n_trees: int, n_nodes: int, n_feat: int) -> int:
     """Largest row block whose per-grid-step estimate fits
-    `TRAVERSE_VMEM_BUDGET`, or 0 when even a minimal 8-row block cannot
-    (the resident node tables alone bust the budget — the spec must
-    demote to XLA). THE single source of the guard's arithmetic: the
+    `TRAVERSE_VMEM_BUDGET`, or 0 when even one 32-row tile cannot (the
+    resident node tables alone bust the budget — the spec must demote
+    to XLA). THE single source of the guard's arithmetic: the
     resolver in `ml/inference.py` clamps/demotes through this, so the
     budget math cannot drift from the `traverse_vmem_bytes` estimate."""
     fixed = traverse_vmem_bytes(0, n_trees, n_nodes, n_feat)
     per_row = traverse_vmem_bytes(1, n_trees, n_nodes, n_feat) - fixed
     blk = (TRAVERSE_VMEM_BUDGET - fixed) // max(per_row, 1)
-    return int(blk) if blk >= 8 else 0
+    return int(blk) if blk >= _SUBLANES else 0
 
 
 def _block_plan(n: int, interpret: bool,
                 block_rows: Optional[int]) -> Tuple[int, int]:
     """(grid steps, rows per block). Interpret mode uses ONE block (no
     VMEM to bound; fewer traced ops). Compiled mode picks the largest
-    divisor of `n` at or under the target so every grid step sees a full
-    block — rows are bucket-padded by staging, so divisors are dense.
+    divisor of `n` at or under the target that is a multiple of 32 rows
+    (the sublane tile of a uint8 bin block; a block narrower than the
+    array must be tile-aligned), so every grid step sees a full block —
+    rows are bucket-padded by staging, so aligned divisors are dense.
     Unlike the fit kernel's plan this never changes results: the
     traversal has no cross-row reduction, so blocking is pure VMEM
     scheduling.
@@ -97,13 +122,15 @@ def _block_plan(n: int, interpret: bool,
     TRACE time and must never consult live conf — a read here would be
     burned into the executable and silently diverge from the keyed
     value. None/0 means no blocking: one full block."""
-    if interpret or not block_rows:
+    if interpret or not block_rows or n <= int(block_rows):
         return 1, n
-    target = max(1, min(int(block_rows), n))
-    k = -(-n // target)
-    while n % k:
-        k += 1
-    return k, n // k
+    for k in range(-(-n // int(block_rows)), n // _SUBLANES + 1):
+        if n % k == 0 and (n // k) % _SUBLANES == 0:
+            return k, n // k
+    raise ValueError(
+        f"no {_SUBLANES}-row-aligned block of at most {block_rows} rows "
+        f"divides the {n} rows on this chip; stage rows through "
+        f"`mesh.bucket_rows` or score with sml.infer.kernel=xla")
 
 
 def forest_traverse(binned, sf, sb, lv, weights, *, depth: int,
@@ -116,7 +143,7 @@ def forest_traverse(binned, sf, sb, lv, weights, *, depth: int,
     on wide-bin models); `sf`/`sb`/`lv` are the level-order SoA node
     tables (`(T, n_nodes)`, `_EnsembleSpec.stacked()` layout) and
     `weights` the `(T,)` per-tree weights. Equivalent XLA-path
-    computation, which the kernel body reproduces op-for-op per block:
+    computation, whose where-sums the kernel body reproduces per block:
     `ml/inference._forest_margin(binned, sf, sb, lv, weights, depth)`.
 
     The mask multiply, the base offset, and every psum of the fused
@@ -132,47 +159,55 @@ def forest_traverse(binned, sf, sb, lv, weights, *, depth: int,
     nblk, blk = _block_plan(n, interpret, block_rows)
 
     def kernel(b_ref, sf_ref, sb_ref, lv_ref, w_ref, out_ref):
-        # the XLA path's exact ops on one row block (_forest_margin):
-        # one-hot masked where-SUMs, exact in f32 — no gathers, no MXU
-        # bf16 operand truncation
-        binned_f = b_ref[...].astype(jnp.float32)
-        fio = jnp.arange(F, dtype=jnp.float32)
+        # _forest_margin's where-SUMs on one row block, exact in f32 —
+        # no gathers, no MXU bf16 operand truncation. Everything stays
+        # 2-D (rows on sublanes) and every iota is 2-D: Mosaic lowers
+        # neither 1-D vectors reshaped to columns nor 1-D iota. The bin
+        # block widens through int32 (no uint8 -> f32 cast on the chip).
+        binned_f = b_ref[...].astype(jnp.int32).astype(jnp.float32)
+        fio = jax.lax.broadcasted_iota(jnp.int32, (1, F), 1) \
+            .astype(jnp.float32)
+        nio = jax.lax.broadcasted_iota(jnp.int32, (1, n_nodes), 1)
 
-        def one_tree(f, s, v):
+        def one_tree(t, acc):
+            f = sf_ref[pl.ds(t, 1), :]                     # (1, n_nodes)
             fpos = jnp.maximum(f, 0).astype(jnp.float32)
             internal = f >= 0
-            s_f = s.astype(jnp.float32)
-            node = jnp.zeros((blk,), dtype=jnp.int32)
+            s_f = sb_ref[pl.ds(t, 1), :].astype(jnp.float32)
+            v = lv_ref[pl.ds(t, 1), :].astype(jnp.float32)
+            node = jnp.zeros((blk, 1), dtype=jnp.int32)
             for lvl in range(depth):
                 width = min(2 ** (lvl + 1) - 1, n_nodes)
-                iota = jnp.arange(width, dtype=jnp.int32)
-                oh = node[:, None] == iota[None, :]
-                fa = jnp.sum(jnp.where(oh, fpos[None, :width], 0.0), axis=1)
-                ba = jnp.sum(jnp.where(oh, s_f[None, :width], 0.0), axis=1)
-                isin = jnp.any(oh & internal[None, :width], axis=1)
-                xbin = jnp.sum(jnp.where(fio[None, :] == fa[:, None],
-                                         binned_f, 0.0), axis=1)
+                oh = node == nio[:, :width]                # (blk, width)
+                fa = jnp.sum(jnp.where(oh, fpos[:, :width], 0.0),
+                             axis=1, keepdims=True)
+                ba = jnp.sum(jnp.where(oh, s_f[:, :width], 0.0),
+                             axis=1, keepdims=True)
+                isin = jnp.sum(
+                    jnp.where(oh & internal[:, :width], 1.0, 0.0),
+                    axis=1, keepdims=True) > 0.0
+                xbin = jnp.sum(jnp.where(fio == fa, binned_f, 0.0),
+                               axis=1, keepdims=True)
                 child = 2 * node + 1 + (xbin > ba).astype(jnp.int32)
                 node = jnp.where(isin, child, node)
-            leaf_oh = (node[:, None]
-                       == jnp.arange(n_nodes, dtype=jnp.int32)[None, :])
-            return jnp.sum(jnp.where(leaf_oh,
-                                     v.astype(jnp.float32)[None, :], 0.0),
-                           axis=1)
+            leaf = jnp.sum(jnp.where(node == nio, v, 0.0),
+                           axis=1, keepdims=True)
+            return acc + w_ref[pl.ds(t, 1), :].astype(jnp.float32) * leaf
 
-        per_tree = jax.vmap(one_tree)(sf_ref[...], sb_ref[...], lv_ref[...])
-        out_ref[...] = jnp.sum(
-            w_ref[...].astype(jnp.float32)[:, None] * per_tree, axis=0)
+        out_ref[...] = jax.lax.fori_loop(
+            0, T, one_tree, jnp.zeros((blk, 1), jnp.float32))
 
     kwargs = {}
     if not interpret:
-        params = _tpu_compiler_params()
-        if params is not None:
-            kwargs["compiler_params"] = params
+        from jax.experimental.pallas import tpu as pltpu
+        # no grid step revisits an output block: row blocks are
+        # independent
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",))
     PROFILER.count("kernel.pallas_launch")
     if interpret:
         PROFILER.count("kernel.interpret")
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(nblk,),
         in_specs=[
@@ -180,10 +215,11 @@ def forest_traverse(binned, sf, sb, lv, weights, *, depth: int,
             pl.BlockSpec((T, n_nodes), lambda i: (0, 0)),
             pl.BlockSpec((T, n_nodes), lambda i: (0, 0)),
             pl.BlockSpec((T, n_nodes), lambda i: (0, 0)),
-            pl.BlockSpec((T,), lambda i: (0,)),
+            pl.BlockSpec((T, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(binned, sf, sb, lv, weights)
+    )(binned, sf, sb, lv, weights.reshape(T, 1))
+    return out[:, 0]

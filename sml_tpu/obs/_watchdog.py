@@ -3,8 +3,8 @@ progress.
 
 The recorder and the audit describe operations that FINISHED; the
 failure mode the 10M-row data plane and the multi-replica serving tier
-hit first is the one that never does — a dispatch wedged behind a dead
-tunnel, a micro-batch flush stuck on a future nobody will set, a
+hit first is the one that never does — a dispatch wedged behind a lost
+device, a micro-batch flush stuck on a future nobody will set, a
 cross-host collective waiting for a process that crashed. This module is
 the in-flight half of the story:
 
@@ -56,7 +56,7 @@ _register("sml.obs.stallFactor", 8.0, float,
 _register("sml.obs.stallMillis", 5000, int,
           "Stall watchdog floor (ms): no ticket is flagged before this "
           "much elapsed time regardless of its prediction — the minimum "
-          "credible hang on a tunneled backend")
+          "credible hang of a device dispatch")
 
 #: stack-snapshot bound: frames per thread kept in a stall event (the
 #: ring and the sink both carry the args verbatim)

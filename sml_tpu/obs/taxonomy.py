@@ -57,14 +57,19 @@ COUNTERS = {
     # kernel.pallas_launch / kernel.interpret are TRACE-TIME statics
     # (counted once per program trace, like collective.*: launches per
     # execution = the count × executions); kernel.fallback counts fits
-    # that requested pallas but degraded to the XLA path — bench_diff
-    # treats any growth as a regression
+    # where `auto` wanted pallas on a TPU whose toolchain probe failed,
+    # or where the static VMEM guard demoted the spec — bench_diff
+    # treats any growth as a regression (an explicit 'pallas' that
+    # cannot run raises; it is never counted)
     "kernel.*",
+    # host-side C++ libraries (native/build.py): a library that could not
+    # be built or loaded, so its callers run the NumPy implementation
+    "native.build_failed",
     # fused traversal kernel on the SCORING path (native/traverse_kernel
     # + ml/inference.py resolution): infer.kernel.pallas / infer.kernel.xla
     # count spec resolutions landing on each path; infer.kernel.fallback
-    # counts dispatches that requested (or were tuned to) pallas but
-    # demoted to XLA — obs/regress.py flags any growth, like
+    # counts dispatches that `auto` (or a tuned spec) wanted on pallas
+    # but that demoted to XLA — obs/regress.py flags any growth, like
     # kernel.fallback; infer.kernel.autotune_s accumulates --kernelbench
     # sweep seconds (the cost the persisted manifest spec amortizes away)
     "infer.kernel.*",

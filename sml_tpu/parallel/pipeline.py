@@ -16,7 +16,7 @@ drain lands a `<family>.dispatch` / `<family>.drain` recorder event
 (`infer.*` for inference, `ingest.*` for the chunk plane) — the
 i+1-dispatches-before-i-drains event order IS the pipelining proof the
 tests assert — and every in-flight item holds a stall-watchdog ticket
-(`obs._watchdog`), so a wedged H2D transfer or dead tunnel is flagged
+(`obs._watchdog`), so a wedged H2D transfer or lost device is flagged
 with stacks instead of hanging silently.
 
 With the recorder disabled the instrumentation costs one attribute load

@@ -207,12 +207,14 @@ def test_fused_tpe_trial_history_parity(reg_fdf, profiled, fused_debug):
 
 
 def test_mapinpandas_small_leg_binds_host_mesh(spark, monkeypatch):
-    """The ml12 satellite: on a tunneled backend, a small pandas-fn leg's
-    WorkHint prices host, and the UDF body runs under the host mesh — a
-    device-capable body stops paying a tunnel round-trip per batch."""
+    """The ml12 satellite: on a device the dispatcher prices (one this
+    process does not own, slow link), a small pandas-fn leg's WorkHint
+    prices host, and the UDF body runs under the host mesh — a
+    device-capable body stops paying a dispatch round trip per batch."""
     from sml_tpu.parallel import dispatch, mesh as meshlib
 
     monkeypatch.setattr(dispatch, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "_locally_attached", lambda: False)
     cal = dispatch._Calibration()
     cal._done = True
     cal.rt_fixed = 0.15
@@ -234,7 +236,7 @@ def test_mapinpandas_small_leg_binds_host_mesh(spark, monkeypatch):
 
 
 def test_mapinpandas_cpu_backend_unchanged(spark):
-    """No tunnel -> no binding: the active (virtual device) mesh stays in
+    """CPU backend -> no binding: the active (virtual device) mesh stays in
     force, so CPU-mesh tests and pinned-mesh flows see zero change."""
     from sml_tpu.parallel import dispatch, mesh as meshlib
 

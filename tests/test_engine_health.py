@@ -292,12 +292,34 @@ def _run_diff(*args):
         capture_output=True, text=True, timeout=120, cwd=REPO)
 
 
-def test_bench_diff_self_compare_committed_artifacts():
-    """Satellite/acceptance: the committed BENCH_r01.json and the
-    committed sidecar each self-compare to ZERO findings, exit 0 — and
-    the gate runs jax-free (it is a tier-1 CI test)."""
-    for artifact in ("BENCH_r01.json", "bench_legs.json"):
-        proc = _run_diff(os.path.join(REPO, artifact), "--json")
+def _driver_record() -> dict:
+    """A document in the driver's record shape (`{n, cmd, rc, tail,
+    parsed}`: the captured end of a `python bench.py` run plus its parsed
+    headline), built inline with made-up walls — the format
+    `obs/regress.py` must keep reading, without keeping any particular
+    run of it in the tree."""
+    legs = {"ml02_lr": 4.0, "ml06_dt": 2.5, "ml07_rf": 2.5,
+            "ml11_xgb": 3.5, "ml12_mapinpandas": 0.4,
+            "ml13_applyinpandas": 0.05}
+    headline = {"metric": "ml02-ml13 suite wall-clock (synthetic record)",
+                "value": round(sum(legs.values()), 3), "unit": "seconds",
+                "vs_baseline": 2.0}
+    tail = "devices: [synthetic]\nwarmup (incl. compiles): 100.0s\n" \
+        + "".join(f"  {k:<24s}{v:>6.2f}s\n" for k, v in legs.items()) \
+        + "  rmse_xgb                   65.000\n" \
+        + json.dumps(headline) + "\n"
+    return {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": tail,
+            "parsed": headline}
+
+
+def test_bench_diff_self_compare_committed_artifacts(tmp_path):
+    """Satellite/acceptance: a driver-shaped record and the committed
+    sidecar each self-compare to ZERO findings, exit 0 — and the gate
+    runs jax-free (it is a tier-1 CI test)."""
+    record = tmp_path / "driver_record.json"
+    record.write_text(json.dumps(_driver_record()))
+    for artifact in (str(record), os.path.join(REPO, "bench_legs.json")):
+        proc = _run_diff(artifact, "--json")
         assert proc.returncode == 0, proc.stdout + proc.stderr
         result = json.loads(proc.stdout)
         assert result["ok"] is True
@@ -325,18 +347,18 @@ def test_bench_diff_flags_injected_sidecar_regression(tmp_path):
 
 
 def test_bench_diff_flags_injected_bench_record_regression(tmp_path):
-    """The BENCH_r0x driver-record format is diffable too: a 30% slower
-    leg in the tail flags."""
-    with open(os.path.join(REPO, "BENCH_r01.json")) as f:
-        doc = json.load(f)
+    """The driver-record format is diffable too: a 30% slower leg in
+    the tail flags."""
+    base = tmp_path / "base.json"
+    base.write_text(json.dumps(_driver_record()))
+    doc = _driver_record()
     doc["tail"] = re.sub(
         r"ml11_xgb(\s+)([0-9.]+)s",
         lambda m: f"ml11_xgb{m.group(1)}{float(m.group(2)) * 1.3:.2f}s",
         doc["tail"])
     cand = tmp_path / "cand.json"
     cand.write_text(json.dumps(doc))
-    proc = _run_diff(os.path.join(REPO, "BENCH_r01.json"), str(cand),
-                     "--json")
+    proc = _run_diff(str(base), str(cand), "--json")
     assert proc.returncode == 1, proc.stdout
     result = json.loads(proc.stdout)
     assert any(f["key"] == "ml11_xgb" and f["kind"] == "leg-wall"

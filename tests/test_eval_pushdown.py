@@ -198,3 +198,77 @@ def test_link_pushdown_on_bare_tree_transform(spark):
     truth = float(np.sqrt(np.mean(
         (np.exp(pp["prediction"]) - pp["price"]) ** 2)))
     assert abs(rmse - truth) < 1e-6 * max(truth, 1.0)
+
+
+# ----------------------------------- device errors are not absorbed (PR 21)
+class _DeviceBoom(RuntimeError):
+    """Stands in for an error raised by a compiled program or the
+    compiler (an XlaRuntimeError / MosaicError on the chip)."""
+
+
+def _tree_pipeline(spark):
+    df = _frame(spark, with_nan_label=False)
+    model = Pipeline(stages=[
+        StringIndexer(inputCols=["cat"], outputCols=["cat_idx"]),
+        VectorAssembler(inputCols=["cat_idx", "x1", "x2"],
+                        outputCol="features"),
+        RandomForestRegressor(labelCol="label", numTrees=3, maxDepth=3,
+                              seed=1),
+    ]).fit(df)
+    return df, model
+
+
+def test_device_error_propagates_out_of_reg_stats(spark, monkeypatch):
+    """An exception raised by the device program inside the evaluator
+    pushdown reaches the caller: before PR 21 `reg_stats` caught it and
+    the materialize path produced the right metric from another route."""
+    from sml_tpu.ml import _staging
+    df, model = _tree_pipeline(spark)
+    lazy = model.transform(df)
+    assert getattr(lazy, "_fused_eval", None) is not None
+
+    def boom(*a, **k):
+        raise _DeviceBoom("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(_staging, "run_data_parallel", boom)
+    with pytest.raises(_DeviceBoom):
+        lazy._fused_eval.reg_stats("prediction", "label")
+    with pytest.raises(_DeviceBoom):
+        RegressionEvaluator(labelCol="label").evaluate(model.transform(df))
+
+
+def test_device_error_propagates_out_of_fused_transform(spark, monkeypatch):
+    """The lazy fused transform's `compute_or_fallback` may fall back to
+    the per-stage chain on a HOST featurize surprise only; a scorer
+    (device dispatch) error must surface, not be replaced by a slower
+    path that prints the same predictions."""
+    from sml_tpu.ml.inference import DeviceScorer
+    df, model = _tree_pipeline(spark)
+
+    def boom(self, X):
+        raise _DeviceBoom("RESOURCE_EXHAUSTED: out of vmem")
+
+    monkeypatch.setattr(DeviceScorer, "score_block", boom)
+    lazy = model.transform(df)
+    with pytest.raises(_DeviceBoom):
+        lazy.toPandas()
+
+
+def test_host_featurize_surprise_still_falls_back(spark, monkeypatch):
+    """The other half of the contract: a host-side surprise in the
+    compiled feature chain declines the pushdown and falls back to the
+    generic per-stage transform, which serves the same predictions."""
+    from sml_tpu.ml.featurizer import CompiledFeaturizer
+    df, model = _tree_pipeline(spark)
+    want = model.transform(df).toPandas()["prediction"].to_numpy()
+
+    def surprise(self, raw):
+        raise KeyError("a column the compiled chain assumed raw")
+
+    monkeypatch.setattr(CompiledFeaturizer, "transform_with_mask", surprise)
+    monkeypatch.setattr(CompiledFeaturizer, "transform_with_columns",
+                        surprise)
+    lazy = model.transform(df)
+    assert lazy._fused_eval.reg_stats("prediction", "label") is None
+    np.testing.assert_allclose(
+        lazy.toPandas()["prediction"].to_numpy(), want, rtol=1e-6)

@@ -223,27 +223,49 @@ def test_auto_never_selects_pallas_on_cpu(spark, kernel_conf):
     assert tree_impl._kernel_choice() == "xla"
 
 
-def test_fallback_when_kernel_unavailable(spark, kernel_conf, monkeypatch):
-    """The fallback ladder: pallas requested but the toolchain probe
-    fails → the fit silently lands on the XLA path, counts
-    kernel.fallback, and still produces the XLA-path model."""
+def test_explicit_pallas_raises_when_kernel_unavailable(spark, kernel_conf,
+                                                        monkeypatch):
+    """pallas requested but the toolchain probe fails: an explicit
+    'pallas' is a demand, so the fit raises the probe's own error
+    instead of landing on the XLA path and producing the right model
+    from a path nobody asked for. No kernel.fallback is counted — that
+    counter is for `auto` and the static shape guard."""
     from sml_tpu.ml import tree_impl
     from sml_tpu.native import hist_kernel
     X, y = _toy(n=3000, f=4, seed=4)
     binned, _ = tree_impl.make_bins(X, y, 32)
     es = _spec_es(X.shape[1], n_trees=3, max_depth=3)
-    GLOBAL_CONF.set("sml.tree.kernel", "xla")
-    ref = _fit(es, binned, y)
-    monkeypatch.setitem(hist_kernel._avail, "ok", False)
+    monkeypatch.setitem(hist_kernel._avail, True, "Boom: no pallas here")
     GLOBAL_CONF.set("sml.tree.kernel", "pallas")
     c0 = PROFILER.counters()
-    got = _fit(es, binned, y)
+    with pytest.raises(RuntimeError, match="Boom: no pallas here"):
+        _fit(es, binned, y)
     c1 = PROFILER.counters()
-    assert c1.get("kernel.fallback", 0.0) > c0.get("kernel.fallback", 0.0)
+    assert c1.get("kernel.fallback", 0.0) == c0.get("kernel.fallback", 0.0)
     assert c1.get("kernel.pallas_launch", 0.0) \
         == c0.get("kernel.pallas_launch", 0.0)
-    assert ref[1] == got[1]
-    _assert_trees_bitwise(ref[0], got[0])
+
+
+def test_auto_resolves_to_xla_on_tpu_while_fit_kernels_do_not_lower(
+        spark, kernel_conf):
+    """PR 21: neither fit kernel compiles for v5e as written
+    (`hist_kernel.AUTO_ON_TPU` records the compiler's messages), so on a
+    (simulated) TPU mesh `auto` resolves to xla BEFORE the ladder runs —
+    the resolver's answer for the platform, not a kernel.fallback."""
+    from sml_tpu.ml import tree_impl
+    from sml_tpu.native import hist_kernel
+    from sml_tpu.parallel import mesh as meshlib
+    assert hist_kernel.AUTO_ON_TPU is False
+    GLOBAL_CONF.set("sml.tree.kernel", "auto")
+    mesh = meshlib.get_mesh()
+    tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")  # simulate TPU
+    try:
+        c0 = PROFILER.counters()
+        assert tree_impl._kernel_choice() == "xla"
+        c1 = PROFILER.counters()
+    finally:
+        tree_impl._platform_memo.clear()
+    assert c1.get("kernel.fallback", 0.0) == c0.get("kernel.fallback", 0.0)
 
 
 def test_dispatch_count_parity_gate(spark, kernel_conf):
@@ -277,14 +299,20 @@ def test_dispatch_count_parity_gate(spark, kernel_conf):
                                    rtol=0, atol=0)
 
 
-def test_kernel_for_demotes_oversized_specs_on_tpu(spark, kernel_conf):
+def test_kernel_for_demotes_oversized_specs_on_tpu(spark, kernel_conf,
+                                                   monkeypatch):
     """The compiled split-scan kernel holds the whole widest-level
     histogram in one VMEM block — on a (simulated) TPU mesh a spec past
     the budget demotes to xla with a kernel.fallback count instead of
-    failing to lower mid-trace; interpret mode (CPU) never demotes."""
+    failing to lower mid-trace; interpret mode (CPU) never demotes. The
+    budget counts the 128-lane padding of the block's minor dimension of
+    3, which rules the kernel out at the course's ml11 shape."""
     from sml_tpu.ml import tree_impl
     from sml_tpu.ml.tree_impl import TreeSpec
+    from sml_tpu.native import hist_kernel
     from sml_tpu.parallel import mesh as meshlib
+    # the simulated TPU has no Mosaic: stand in for its compiled probe
+    monkeypatch.setitem(hist_kernel._avail, False, None)
     GLOBAL_CONF.set("sml.tree.kernel", "pallas")
     small = TreeSpec(max_depth=4, n_bins=32, n_features=6, feature_k=6,
                      min_instances=1, min_info_gain=0.0, reg_lambda=0.0,
@@ -302,6 +330,8 @@ def test_kernel_for_demotes_oversized_specs_on_tpu(spark, kernel_conf):
         c1 = PROFILER.counters()
         assert c1.get("kernel.fallback", 0.0) \
             == c0.get("kernel.fallback", 0.0) + 1
+        ml11 = small._replace(max_depth=6, n_bins=64, n_features=10)
+        assert tree_impl._kernel_for(ml11) == "xla"  # 10.5 MB padded
     finally:
         tree_impl._platform_memo.clear()
 

@@ -145,9 +145,11 @@ def test_audit_lists_dispatches_with_predictions(spark, recorder):
 
 
 @pytest.fixture
-def tunneled(monkeypatch):
-    """Pinned fake tunnel calibration (as in test_dispatch.py)."""
+def remote_device(monkeypatch):
+    """A TPU this process does not own, with a pinned slow link
+    calibration (as in test_dispatch.py) — the case `auto` prices."""
     monkeypatch.setattr(dispatch, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "_locally_attached", lambda: False)
     cal = dispatch._Calibration()
     cal._done = True
     cal.rt_fixed = 0.15
@@ -157,7 +159,7 @@ def tunneled(monkeypatch):
     yield cal
 
 
-def test_forced_device_misroute_flagged(recorder, tunneled):
+def test_forced_device_misroute_flagged(recorder, remote_device):
     """Satellite: sml.dispatch.mode=device on tiny work must surface a
     predicted-vs-actual inversion in the audit — the forced device route
     measured far slower than the host prediction."""
@@ -179,7 +181,7 @@ def test_forced_device_misroute_flagged(recorder, tunneled):
     assert "MISROUTE" in report and "predicted-inversion" in report
 
 
-def test_probe_decisions_are_not_double_counted(recorder, tunneled,
+def test_probe_decisions_are_not_double_counted(recorder, remote_device,
                                                 monkeypatch):
     """_route_mesh prices with internal decide() probes; the audit must
     count DISPATCHES, not probes — exactly one row per routed program."""
@@ -195,7 +197,7 @@ def test_probe_decisions_are_not_double_counted(recorder, tunneled,
     # resident device wins but the H2D charge flips it -> the priced path
     obs._audit.reset()
     X = np.random.default_rng(3).normal(size=(4096, 64)).astype(np.float32)
-    tunneled.h2d_bw = 1e6
+    remote_device.h2d_bw = 1e6
     _mesh, route = _staging._route_mesh(WorkHint(flops=5e9, kind="blas"),
                                         (X,), may_promote=False)
     assert route == "host"
@@ -205,7 +207,7 @@ def test_probe_decisions_are_not_double_counted(recorder, tunneled,
 
 
 def test_uncalibrated_forced_route_does_not_calibrate(recorder, monkeypatch):
-    """audit_preroute on a forced route must not trigger the tunnel
+    """audit_preroute on a forced route must not trigger the link
     calibration probe (observability must not change engine behavior);
     the uncalibrated record is marked and exempt from host-side misroute
     judgment."""
@@ -225,7 +227,7 @@ def test_uncalibrated_forced_route_does_not_calibrate(recorder, monkeypatch):
     assert not rec.misroute
 
 
-def test_audit_not_recorded_when_disabled(tunneled):
+def test_audit_not_recorded_when_disabled(remote_device):
     GLOBAL_CONF.set("sml.obs.enabled", False)
     assert not obs.RECORDER.enabled
     obs._audit.reset()

@@ -194,33 +194,53 @@ def test_auto_never_selects_pallas_on_cpu(spark, infer_conf):
             n_rows=4096)
 
 
-def test_fallback_when_kernel_unavailable(spark, infer_conf, monkeypatch):
-    """Requested pallas with a dead toolchain: the resolver lands on xla
-    and counts infer.kernel.fallback — scoring never crashes."""
+def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
+                                                        monkeypatch):
+    """Requested pallas with a dead toolchain: an explicit 'pallas' is a
+    demand, so the resolver raises the probe's own error — it must not
+    land on xla and report the right numbers from another path. Only
+    `auto` on a TPU mesh may decline, and that is counted."""
     from sml_tpu.ml import inference, tree_impl
     from sml_tpu.native import hist_kernel
-    monkeypatch.setitem(hist_kernel._avail, "ok", False)
+    from sml_tpu.parallel import mesh as meshlib
+    monkeypatch.setitem(hist_kernel._avail, True, "Boom: no pallas here")
     GLOBAL_CONF.set("sml.infer.kernel", "pallas")
     f0 = inference._KERNEL_STATE["fallbacks"]
-    k, br, _ = inference.resolve_infer_kernel(
-        n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
-    assert (k, br) == ("xla", 0)
-    assert inference._KERNEL_STATE["fallbacks"] == f0 + 1
+    with pytest.raises(RuntimeError, match="Boom: no pallas here"):
+        inference.resolve_infer_kernel(
+            n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
+    assert inference._KERNEL_STATE["fallbacks"] == f0
     X, y = _toy(n=2000)
     spec = _fit_kind("dt", X, y, 32)
     binned = tree_impl.bin_with(np.asarray(X, np.float64), spec.binning)
-    m = _margins(spec, binned, "pallas")  # scores via the xla fallback
-    GLOBAL_CONF.set("sml.infer.kernel", "xla")
-    np.testing.assert_array_equal(m, _margins(spec, binned, "xla"))
+    with pytest.raises(RuntimeError, match="sml.infer.kernel=pallas"):
+        _margins(spec, binned, "pallas")
+    # auto on a (simulated) TPU mesh whose COMPILED probe fails: xla,
+    # counted as a fallback — the one place the ladder may decline
+    monkeypatch.setitem(hist_kernel._avail, False, "Boom: no mosaic")
+    GLOBAL_CONF.set("sml.infer.kernel", "auto")
+    mesh = meshlib.get_mesh()
+    tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")
+    try:
+        k, br, _ = inference.resolve_infer_kernel(
+            n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
+    finally:
+        tree_impl._platform_memo.clear()
+    assert (k, br) == ("xla", 0)
+    assert inference._KERNEL_STATE["fallbacks"] == f0 + 1
 
 
-def test_vmem_guard_demotes_oversized_specs_on_tpu(spark, infer_conf):
+def test_vmem_guard_demotes_oversized_specs_on_tpu(spark, infer_conf,
+                                                   monkeypatch):
     """On (simulated) real TPU the resolver clamps block_rows to the
     traversal VMEM budget, and a spec whose resident node tables alone
     bust it demotes to xla with fallback + demotion counts; CPU
     interpret mode never clamps or demotes."""
     from sml_tpu.ml import inference, tree_impl
+    from sml_tpu.native import hist_kernel
     from sml_tpu.parallel import mesh as meshlib
+    # the simulated TPU has no Mosaic: stand in for its compiled probe
+    monkeypatch.setitem(hist_kernel._avail, False, None)
     GLOBAL_CONF.set("sml.infer.kernel", "pallas")
     GLOBAL_CONF.set("sml.infer.kernelBlockRows", 10 ** 6)
     k, br, _ = inference.resolve_infer_kernel(
@@ -233,7 +253,7 @@ def test_vmem_guard_demotes_oversized_specs_on_tpu(spark, infer_conf):
         k, br, _ = inference.resolve_infer_kernel(
             n_trees=8, depth=5, n_nodes=63, n_feat=10, n_bins=32,
             n_rows=4096)
-        assert k == "pallas" and 8 <= br < 10 ** 6  # clamped to budget
+        assert k == "pallas" and 32 <= br < 10 ** 6  # clamped to budget
         from sml_tpu.native import traverse_kernel as _tk
         assert br == _tk.max_block_rows(8, 63, 10)  # ONE arithmetic
         f0 = inference._KERNEL_STATE["fallbacks"]
