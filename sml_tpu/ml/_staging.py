@@ -611,14 +611,17 @@ def stage_sharded(*arrays: np.ndarray):
 
 
 def data_parallel(fn: Callable, *, out_replicated: bool = True,
-                  replicated_argnums: Tuple[int, ...] = ()) -> Callable:
+                  replicated_argnums: Tuple[int, ...] = (),
+                  name: Optional[str] = None) -> Callable:
     """jit(shard_map(fn)) over the active mesh's data axis.
 
     `fn` sees per-chip row blocks and may call `parallel.collectives.psum`
     etc. on the "data" axis; outputs are replicated (each chip returns the
     same reduced value) unless out_replicated=False (then row-sharded).
     Args listed in `replicated_argnums` (rng keys, small parameter vectors)
-    are broadcast to every chip instead of row-sharded.
+    are broadcast to every chip instead of row-sharded. `name` names the
+    jitted program (`jit_<name>` in a profiler trace and in the compile
+    cache's key) where "wrapped" would say nothing.
 
     Donation is deliberately NOT offered here: any input of a
     data_parallel program may be a staging-cache-owned buffer, and
@@ -641,6 +644,8 @@ def data_parallel(fn: Callable, *, out_replicated: bool = True,
                                           out_specs=out_spec)
         return mapped(*args)
 
+    if name:
+        wrapped.__name__ = wrapped.__qualname__ = name
     return jax.jit(wrapped)
 
 

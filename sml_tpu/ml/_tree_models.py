@@ -21,7 +21,9 @@ from typing import Dict, List, Optional
 import numpy as np
 import pandas as pd
 
+from ..obs import RECORDER
 from ..parallel import dispatch
+from ..utils.profiler import PROFILER
 from .base import Estimator, Model, RegStatsHook, load_arrays, save_arrays
 from .feature import _as_object_series
 from .linalg import DenseVector, vector_series
@@ -191,41 +193,49 @@ def _cached_bins(X, y32, max_bins, categorical):
     parameter set — re-quantizing 1M rows per fit was ~0.3s apiece.
     Byte-budgeted and locked like the staging cache (same concurrent
     TpuTrials path, same multi-100MB operands)."""
+    from ..native.build import load_library
     from ._staging import _content_key, _normalize
     from .tree_impl import make_bins
-    Xc = _normalize(X)
-    key = (_content_key(Xc), _content_key(_normalize(y32)), int(max_bins),
-           tuple(sorted((categorical or {}).items())))
-    while True:
-        with _bins_lock:
-            hit = _bins_cache.get(key)
-            if hit is None and key not in _bins_inflight:
-                _bins_inflight[key] = _threading.Event()
-                break  # this thread computes
-            waiter = _bins_inflight.get(key) if hit is None else None
-        if hit is not None:
-            return hit
-        # another tuning trial is quantizing the SAME matrix: wait for it
-        # instead of paying the ~0.3s re-binning the cache exists to avoid
-        waiter.wait()
-    try:
-        hit = make_bins(Xc, y32, max_bins, categorical)
-        cost = hit[0].nbytes
-        with _bins_lock:
-            _bins_cache[key] = hit
-            _bins_cache_order.append((key, cost))
-            _bins_cache_bytes[0] += cost
-            while _bins_cache_bytes[0] > _BINS_CACHE_MAX_BYTES \
-                    and len(_bins_cache_order) > 1:
-                old, old_cost = _bins_cache_order.pop(0)
-                _bins_cache.pop(old, None)
-                _bins_cache_bytes[0] -= old_cost
-    finally:
-        with _bins_lock:
-            ev = _bins_inflight.pop(key, None)
-        if ev is not None:
-            ev.set()
-    return hit
+    with PROFILER.span("fit.quantize", rows=int(X.shape[0])) as note:
+        with PROFILER.span("fit.quantize.key"):
+            Xc = _normalize(X)
+            key = (_content_key(Xc), _content_key(_normalize(y32)),
+                   int(max_bins), tuple(sorted((categorical or {}).items())))
+        while True:
+            with _bins_lock:
+                hit = _bins_cache.get(key)
+                if hit is None and key not in _bins_inflight:
+                    _bins_inflight[key] = _threading.Event()
+                    break  # this thread computes
+                waiter = _bins_inflight.get(key) if hit is None else None
+            if hit is not None:
+                note["hit"] = True
+                return hit
+            # another tuning trial is quantizing the SAME matrix: wait for
+            # it instead of paying the ~0.3s re-binning the cache exists to
+            # avoid
+            waiter.wait()
+        note["hit"] = False
+        try:
+            with PROFILER.span("fit.quantize.bins",
+                               native=load_library("binning") is not None):
+                hit = make_bins(Xc, y32, max_bins, categorical)
+            cost = hit[0].nbytes
+            with _bins_lock:
+                _bins_cache[key] = hit
+                _bins_cache_order.append((key, cost))
+                _bins_cache_bytes[0] += cost
+                while _bins_cache_bytes[0] > _BINS_CACHE_MAX_BYTES \
+                        and len(_bins_cache_order) > 1:
+                    old, old_cost = _bins_cache_order.pop(0)
+                    _bins_cache.pop(old, None)
+                    _bins_cache_bytes[0] -= old_cost
+        finally:
+            with _bins_lock:
+                ev = _bins_inflight.pop(key, None)
+            if ev is not None:
+                ev.set()
+        return hit
 
 
 def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
@@ -256,8 +266,10 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
         F = binned.shape[1]
     else:
         if missing is not None and not np.isnan(missing):
-            X = X.copy()
-            X[X == missing] = np.nan
+            with PROFILER.span("fit.featurize", rows=int(X.shape[0]),
+                               bytes=int(X.nbytes)):
+                X = X.copy()
+                X[X == missing] = np.nan
         F = X.shape[1]
         # bin on host FIRST so the dispatcher can probe the staging cache
         # with the actual device operand; histogram builds dominate the
@@ -269,8 +281,14 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
         flops=2.0 * n_trees * max_depth * binned.shape[0] * F * max_bins,
         kind="scatter")
     with routed_for(hint, binned):
-        staged = stage_tree_data(X, y32, max_bins, categorical,
-                                 prebinned=(binned, binning))
+        with PROFILER.span("fit.stage", rows=int(binned.shape[0])) as note:
+            put = RECORDER.counters().get("staging.h2d_bytes", 0.0)
+            staged = stage_tree_data(X, y32, max_bins, categorical,
+                                     prebinned=(binned, binning))
+            y_dev = stage_aligned(y32, staged.n_padded)
+            note["bytes"] = int(RECORDER.counters().get(
+                "staging.h2d_bytes", 0.0) - put)
+            note["hit"] = note["bytes"] == 0
         spec = TreeSpec(max_depth=max_depth, n_bins=max_bins, n_features=F,
                         feature_k=feature_k or F, min_instances=min_instances,
                         min_info_gain=min_info_gain, reg_lambda=reg_lambda,
@@ -279,7 +297,6 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
             tree=spec, n_trees=n_trees, loss=loss, boosting=boosting,
             bootstrap=bootstrap and n_trees > 1, subsample=float(subsample),
             step_size=float(step_size))
-        y_dev = stage_aligned(y32, staged.n_padded)
         trees, base = tree_impl.fit_ensemble_on_device(
             staged.binned_dev, y_dev, staged.mask_dev, es, seed=seed,
             rounds_per_dispatch=rounds_per_dispatch, on_rounds=on_rounds)
@@ -296,10 +313,21 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
     # subsample bounded by sml.obs.driftBaselineRows (the chunked path
     # passes its full-data ingest sketch instead). Host-side numpy only
     # — capture must not perturb the fit's program/dispatch counters
-    from ..obs import drift as _drift
-    spec.baseline = _drift.capture_fit_baseline(
-        X, y32, categorical, spec, binned=binned, sketch=baseline_sketch)
+    spec.baseline = _capture_baseline(X, y32, categorical, spec, binned,
+                                      baseline_sketch)
     return spec
+
+
+def _capture_baseline(X, y32, categorical, spec, binned, sketch):
+    """`drift.capture_fit_baseline` under its span: with the recorder on
+    it is a host pass inside every fit (a sketch of the strided rows and
+    a NumPy descent of them through every tree)."""
+    from ..obs import drift as _drift
+    with PROFILER.span("fit.baseline", trees=len(spec.trees)) as note:
+        baseline = _drift.capture_fit_baseline(
+            X, y32, categorical, spec, binned=binned, sketch=sketch)
+        note["rows"] = None if baseline is None else baseline.sampled_rows
+    return baseline
 
 
 def _resume_ensemble(spec: _EnsembleSpec, binned: np.ndarray,
@@ -368,9 +396,8 @@ def _resume_ensemble(spec: _EnsembleSpec, binned: np.ndarray,
     out = _EnsembleSpec(trees, spec.depth, spec.binning, weights,
                         float(spec.base), F, spec.mode)
     categorical = {f: len(r) for f, r in spec.binning.cat_remap.items()}
-    from ..obs import drift as _drift
-    out.baseline = _drift.capture_fit_baseline(
-        X, y32, categorical, out, binned=binned, sketch=baseline_sketch)
+    out.baseline = _capture_baseline(X, y32, categorical, out, binned,
+                                     baseline_sketch)
     return out
 
 
@@ -604,7 +631,6 @@ def fused_reg_stats_from_matrix(spec, X: np.ndarray, lab: np.ndarray,
         import jax.numpy as _jnp
         if getattr(_jnp, link, None) is None:
             return None  # unresolvable device link: materialize path wins
-    from ..utils.profiler import PROFILER
     with PROFILER.span("binning.predict", rows=int(X.shape[0])):
         binned = bin_with(np.asarray(X, dtype=np.float64), spec.binning)
     n = binned.shape[0]
@@ -706,10 +732,13 @@ class _TreeEstimatorBase(Estimator, _TreeParams):
     _loss = "squared"
 
     def _extract(self, df):
-        X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
-                             self.getOrDefault("labelCol"))
-        ok = np.isfinite(y)
-        return X[ok], y[ok], _categorical_slots(df, self.getOrDefault("featuresCol"))
+        with PROFILER.span("fit.featurize") as note:
+            X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
+                                 self.getOrDefault("labelCol"))
+            ok = np.isfinite(y)
+            X, y = X[ok], y[ok]
+            note["rows"], note["bytes"] = int(X.shape[0]), int(X.nbytes)
+        return X, y, _categorical_slots(df, self.getOrDefault("featuresCol"))
 
     def _seed(self) -> int:
         s = self.getOrDefault("seed")

@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..utils.profiler import PROFILER
 from .param import Params
 
 
@@ -132,11 +133,12 @@ class Estimator(Params, Saveable):
     def fit(self, df, params: Optional[dict] = None):
         if params:
             return self.copy(params).fit(df)
-        # flight-recorder run autologging: with the recorder on and a
-        # tracking run active, the OUTERMOST fit logs engine.* metric
-        # deltas to the run (obs.autolog_fit is a cheap no-op otherwise)
+        # flight recorder: with the recorder on, the OUTERMOST fit is the
+        # root span of the fit's span tree and, under an active tracking
+        # run, logs engine.* metric deltas to it (obs.autolog_fit is a
+        # cheap no-op otherwise)
         from ..obs import autolog_fit
-        with autolog_fit(self):
+        with autolog_fit(self, df):
             return self._fit(df)
 
     def _fit(self, df):
@@ -202,7 +204,9 @@ def _attach_fused_features(cur, fitted_transforms, est, raw_pdf):
         from .featurizer import prep_overwrites_label
         if prep_overwrites_label(fitted_transforms[:-1], est):
             return cur
-        X, keep = feat.transform_with_mask(raw_pdf)
+        with PROFILER.span("fit.featurize", rows=len(raw_pdf)) as note:
+            X, keep = feat.transform_with_mask(raw_pdf)
+            note["bytes"] = int(X.nbytes)
         cur._featurized = {assembler.getOrDefault("outputCol"):
                            (X, keep, raw_pdf)}
         return cur
@@ -248,7 +252,9 @@ class Pipeline(Estimator):
             from ..frame.dataframe import DataFrame as _DF
             # build the 1-partition frame from the frame's memoized concat:
             # repeated fits on a cached frame re-use one materialization
-            raw_pdf = cur.toPandas()
+            with PROFILER.span("fit.collect") as note:
+                raw_pdf = cur.toPandas()
+                note["rows"] = len(raw_pdf)
             session = getattr(cur, "_session", None)
 
             def make_frame(pdf):
@@ -273,20 +279,22 @@ class Pipeline(Estimator):
                 return PipelineModel(fitted_prep + [stages[-1].fit(shim)])
             one = make_frame(raw_pdf)
             cur = one
+        last = len(stages) - 1
         for i, stage in enumerate(stages):
-            if isinstance(stage, Estimator):
-                if i == len(stages) - 1:
-                    cur = _attach_fused_features(cur, fitted, stage, raw_pdf)
-                model = stage.fit(cur)
-                fitted.append(model)
-                if i < len(stages) - 1:
-                    cur = model.transform(cur)
-            elif isinstance(stage, Transformer):
-                fitted.append(stage)
-                if i < len(stages) - 1:
-                    cur = stage.transform(cur)
-            else:
+            if not isinstance(stage, (Estimator, Transformer)):
                 raise TypeError(f"stage {stage!r} is neither Estimator nor Transformer")
+            if i == last:
+                if isinstance(stage, Estimator):
+                    cur = _attach_fused_features(cur, fitted, stage, raw_pdf)
+                    stage = stage.fit(cur)
+                fitted.append(stage)
+                break
+            # a prep stage: its fit, and its (lazy) transform for the next
+            with PROFILER.span("fit.prep", stages=1):
+                if isinstance(stage, Estimator):
+                    stage = stage.fit(cur)
+                fitted.append(stage)
+                cur = stage.transform(cur)
         return PipelineModel(fitted)
 
     def copy(self, extra=None) -> "Pipeline":

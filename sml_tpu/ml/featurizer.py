@@ -26,6 +26,8 @@ from typing import List, NamedTuple, Optional
 import numpy as np
 import pandas as pd
 
+from ..utils.profiler import PROFILER
+
 
 class CompactParts(NamedTuple):
     """Compact pre-expansion form of a numeric+one-hot feature block.
@@ -572,12 +574,14 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
             ins = list(st.getOrDefault("inputCols") or [])
             if any(c not in raw_pdf.columns for c in ins):
                 return None
-            fitted.append(st.fit(raw_frame))
+            with PROFILER.span("fit.prep", stages=1):
+                fitted.append(st.fit(raw_frame))
         elif isinstance(st, StringIndexer):
             ins, outs = st._in_out()
             if any(c not in raw_pdf.columns for c in ins):
                 return None
-            m = st.fit(raw_frame)
+            with PROFILER.span("fit.prep", stages=1):
+                m = st.fit(raw_frame)
             extra = 1 if st.getOrDefault("handleInvalid") == "keep" else 0
             for oc, ls in zip(outs, m.labelsArray):
                 idx_labels[oc] = ls
@@ -631,7 +635,9 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
                 shim._featurized_compact = {out_col: (parts, raw_pdf)}
                 return fitted, shim
 
-    X, keep = feat.transform_with_mask(raw_pdf)
+    with PROFILER.span("fit.featurize", rows=len(raw_pdf)) as note:
+        X, keep = feat.transform_with_mask(raw_pdf)
+        note["bytes"] = int(X.nbytes)
     shim = make_frame(raw_pdf)
     shim._ml_attrs = dict(attrs)
     shim._ml_attrs[out_col] = {"slots": slots, "numFeatures": pos}

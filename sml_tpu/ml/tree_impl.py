@@ -37,6 +37,7 @@ import numpy as np
 
 from ..parallel import collectives as coll
 from ..parallel import mesh as meshlib
+from ..utils.profiler import PROFILER
 from ._staging import data_parallel, stage_sharded, transient_hbm
 
 
@@ -318,7 +319,6 @@ def _kernel_choice() -> str:
         "sml.tree.kernel", GLOBAL_CONF.get("sml.tree.kernel"),
         _mesh_platform(), _hk.AUTO_ON_TPU)
     if fell_back:
-        from ..utils.profiler import PROFILER
         PROFILER.count("kernel.fallback")
     return kernel
 
@@ -344,7 +344,6 @@ def _kernel_for(spec: TreeSpec) -> str:
         from ..native.hist_kernel import LANES
         rows = spec.n_features * spec.n_bins * (-(-width // 8) * 8)
         if rows * LANES * 4 > _SCAN_VMEM_BUDGET:
-            from ..utils.profiler import PROFILER
             PROFILER.count("kernel.fallback")
             return "xla"
     return kernel
@@ -434,11 +433,12 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
         # program was built for a host mesh with the knob on (hier_ici is
         # the static ici width), else the flat allreduce over the row
         # axes — same result, different hop structure and byte counters
-        if hier_ici > 1:
-            return coll.psum_hierarchical(
-                part, ici_axis=meshlib.ICI_AXIS,
-                dcn_axis=meshlib.DCN_AXIS, ici_size=hier_ici)
-        return coll.psum(part, axes if len(axes) > 1 else axes[0])
+        with jax.named_scope("tree.hist.allreduce"):
+            if hier_ici > 1:
+                return coll.psum_hierarchical(
+                    part, ici_axis=meshlib.ICI_AXIS,
+                    dcn_axis=meshlib.DCN_AXIS, ici_size=hier_ici)
+            return coll.psum(part, axes if len(axes) > 1 else axes[0])
 
     use_pallas = kernel == "pallas"
     if use_pallas:
@@ -478,170 +478,179 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
         for level in range(D):
             width = 2 ** level
             base = width - 1
-            lid = node - base
-            in_level = active & (lid >= 0) & (lid < width)
-            lid_c = jnp.where(in_level, lid, 0)
-            wq = jnp.where(in_level, weight, 0.0)
-            if subtract and level > 0:
-                # rows histogram only into their LEFT-child slot; right
-                # children come from parent − left below
-                half = width // 2
-                is_left = (lid_c % 2) == 0
-                wl = jnp.where(is_left, wq, 0.0)
-                hw, lid_h, w_eff = half, lid_c // 2, wl
-            else:
-                hw, lid_h, w_eff = width, lid_c, wq
-            if use_pallas:
-                # fused bin-accumulate straight from the compact bin
-                # cache operand: the one-hot tiles live only in VMEM
-                # block_rows is the HOST-resolved value carried by this
-                # program's cache key; the kernel never reads conf at
-                # trace time (0 means one full block)
-                part = _hk.hist_accumulate(
-                    binned if binned_c is None else binned_c,
-                    lid_h, grad, hess, w_eff, n_bins=B, n_slots=hw,
-                    hist_dtype=hist_dtype, interpret=interp,
-                    block_rows=block_rows)
-            else:
-                node1hot = jax.nn.one_hot(lid_h, hw, dtype=hist_dtype) \
-                    * (w_eff > 0)[:, None].astype(hist_dtype)
-                stats = jnp.stack([grad * w_eff, hess * w_eff, w_eff],
-                                  axis=1)
-                ns = (node1hot[:, :, None]
-                      * stats[:, None, :].astype(hist_dtype)
-                      ).reshape(n, hw * 3)
-                # bf16 operands (the one-hot side is EXACT in bf16), f32
-                # accumulation: the MXU's native mode. B1t is
-                # pre-transposed OUTSIDE the tree scan — a .T here would
-                # re-materialize a ~1GB transpose every level of every
-                # tree
-                part = jax.lax.dot_general(
-                    B1t, ns, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            hist = _psum_merge(part)
-            if subtract and level > 0:
-                half = width // 2
-                left = hist.reshape(F, B, half, 3)
-                # a parent that did not split has no children: gate its
-                # whole histogram to zero, as the direct path computes
-                parent = hist_prev * \
-                    split_prev.astype(jnp.float32)[None, None, :, None]
-                right = parent - left
-                hist = jnp.stack([left, right], axis=3) \
-                    .reshape(F, B, width, 3)
-            else:
-                hist = hist.reshape(F, B, width, 3)
-            if dyn is not None or spec.feature_k < F:
-                # under dyn the draw ALWAYS happens (feature_k is traced);
-                # with feature_k == F the mask is all-True, so a
-                # no-subspace trial sees the identical candidate set its
-                # own static program (which skips the draw) produces. The
-                # draw stays OUTSIDE the pallas kernel so both paths
-                # consume the same randomness
-                u = jax.random.uniform(
-                    jax.random.fold_in(jax.random.wrap_key_data(feat_rng), level),
-                    (width, F))
-                ranks = jnp.argsort(jnp.argsort(u, axis=1), axis=1)
-                fk = spec.feature_k if dyn is None else dyn.feature_k
-                fmask = ranks < fk                             # (width, F)
-            else:
-                fmask = None
-            if use_pallas:
-                # fused split scan: cumsum + gain + masks + argmax in one
-                # kernel on the post-psum histogram; only the (6, width)
-                # best-split pack leaves it
-                pack6 = _hk.split_scan(
-                    hist,
-                    jnp.ones((width, F), jnp.float32) if fmask is None
-                    else fmask.astype(jnp.float32),
-                    jnp.asarray(min_inst, jnp.float32).reshape(1, 1),
-                    reg_lambda=spec.reg_lambda, gamma=spec.gamma,
-                    interpret=interp)
-                best_f = pack6[0].astype(jnp.int32)
-                best_b = pack6[1].astype(jnp.int32)
-                best_gain = pack6[2]
-                gG, gH, gW = pack6[3], pack6[4], pack6[5]
-            else:
-                hG = jnp.transpose(hist[..., 0], (2, 0, 1))          # (width,F,B)
-                hH = jnp.transpose(hist[..., 1], (2, 0, 1))
-                hW = jnp.transpose(hist[..., 2], (2, 0, 1))
-                GL = jnp.cumsum(hG, axis=2)
-                HL = jnp.cumsum(hH, axis=2)
-                WL = jnp.cumsum(hW, axis=2)
-                G = GL[:, :, -1:]
-                H = HL[:, :, -1:]
-                W = WL[:, :, -1:]
-                lam = spec.reg_lambda
-                score = (GL ** 2 / (HL + lam + 1e-12)
-                         + (G - GL) ** 2 / (H - HL + lam + 1e-12)
-                         - G ** 2 / (H + lam + 1e-12))
-                ok = ((WL >= min_inst)
-                      & ((W - WL) >= min_inst))
-                ok = ok & (jnp.arange(B)[None, None, :] < B - 1)
-                if fmask is not None:
-                    ok = ok & fmask[:, :, None]
-                score = jnp.where(ok, score, -jnp.inf)
-                flat_best = jnp.argmax(score.reshape(width, F * B), axis=1)
-                best_f = (flat_best // B).astype(jnp.int32)
-                best_b = (flat_best % B).astype(jnp.int32)
-                best_gain = 0.5 * jnp.take_along_axis(
-                    score.reshape(width, F * B), flat_best[:, None],
-                    axis=1)[:, 0] - spec.gamma
-                gG, gH, gW = G[:, 0, 0], H[:, 0, 0], W[:, 0, 0]
-            do_split = (best_gain > min_gain) & jnp.isfinite(best_gain)
-            if dyn is not None:  # trial's own maxDepth: no splits beyond it
-                do_split = do_split & (level < dyn.depth)
-            idx = base + jnp.arange(width)
-            node_G = node_G.at[idx].set(gG)
-            node_H = node_H.at[idx].set(gH)
-            node_W = node_W.at[idx].set(gW)
-            split_feature = split_feature.at[idx].set(
-                jnp.where(do_split, best_f, -1))
-            split_bin = split_bin.at[idx].set(best_b)
-            gains = gains.at[idx].set(jnp.where(do_split, best_gain, 0.0))
-            # row-dependent gathers (table[my_idx], take_along_axis) lower
-            # to XLA's generic scratch-memory gather on TPU — ~22ms per
-            # call at 800k rows, THE dominant cost of the whole build. The
-            # same lookups as masked sums are plain VPU work.
-            lid_eq = lid_c[:, None] == jnp.arange(width,
-                                                  dtype=jnp.int32)[None, :]
-            my_f = jnp.sum(jnp.where(lid_eq, best_f[None, :], 0), axis=1)
-            my_b = jnp.sum(jnp.where(lid_eq, best_b[None, :], 0), axis=1)
-            my_split = jnp.any(lid_eq & do_split[None, :], axis=1)
-            feat_eq = my_f[:, None] == jnp.arange(F, dtype=jnp.int32)[None, :]
-            xbin = jnp.sum(jnp.where(feat_eq, binned, 0), axis=1)
-            go_right = xbin > my_b
-            child = 2 * node + 1 + go_right.astype(jnp.int32)
-            node = jnp.where(in_level & my_split, child, node)
-            active = in_level & my_split
+            with jax.named_scope("tree.hist"):
+                lid = node - base
+                in_level = active & (lid >= 0) & (lid < width)
+                lid_c = jnp.where(in_level, lid, 0)
+                wq = jnp.where(in_level, weight, 0.0)
+                if subtract and level > 0:
+                    # rows histogram only into their LEFT-child slot; right
+                    # children come from parent − left below
+                    half = width // 2
+                    is_left = (lid_c % 2) == 0
+                    wl = jnp.where(is_left, wq, 0.0)
+                    hw, lid_h, w_eff = half, lid_c // 2, wl
+                else:
+                    hw, lid_h, w_eff = width, lid_c, wq
+                if use_pallas:
+                    # fused bin-accumulate straight from the compact bin
+                    # cache operand: the one-hot tiles live only in VMEM
+                    # block_rows is the HOST-resolved value carried by this
+                    # program's cache key; the kernel never reads conf at
+                    # trace time (0 means one full block)
+                    part = _hk.hist_accumulate(
+                        binned if binned_c is None else binned_c,
+                        lid_h, grad, hess, w_eff, n_bins=B, n_slots=hw,
+                        hist_dtype=hist_dtype, interpret=interp,
+                        block_rows=block_rows)
+                else:
+                    node1hot = jax.nn.one_hot(lid_h, hw, dtype=hist_dtype) \
+                        * (w_eff > 0)[:, None].astype(hist_dtype)
+                    stats = jnp.stack([grad * w_eff, hess * w_eff, w_eff],
+                                      axis=1)
+                    ns = (node1hot[:, :, None]
+                          * stats[:, None, :].astype(hist_dtype)
+                          ).reshape(n, hw * 3)
+                    # bf16 operands (the one-hot side is EXACT in bf16), f32
+                    # accumulation: the MXU's native mode. B1t is
+                    # pre-transposed OUTSIDE the tree scan — a .T here would
+                    # re-materialize a ~1GB transpose every level of every
+                    # tree
+                    part = jax.lax.dot_general(
+                        B1t, ns, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                hist = _psum_merge(part)
+                if subtract and level > 0:
+                    half = width // 2
+                    left = hist.reshape(F, B, half, 3)
+                    # a parent that did not split has no children: gate its
+                    # whole histogram to zero, as the direct path computes
+                    parent = hist_prev * \
+                        split_prev.astype(jnp.float32)[None, None, :, None]
+                    right = parent - left
+                    hist = jnp.stack([left, right], axis=3) \
+                        .reshape(F, B, width, 3)
+                else:
+                    hist = hist.reshape(F, B, width, 3)
+            with jax.named_scope("tree.split"):
+                if dyn is not None or spec.feature_k < F:
+                    # under dyn the draw ALWAYS happens (feature_k is traced);
+                    # with feature_k == F the mask is all-True, so a
+                    # no-subspace trial sees the identical candidate set its
+                    # own static program (which skips the draw) produces. The
+                    # draw stays OUTSIDE the pallas kernel so both paths
+                    # consume the same randomness
+                    u = jax.random.uniform(
+                        jax.random.fold_in(
+                            jax.random.wrap_key_data(feat_rng), level),
+                        (width, F))
+                    ranks = jnp.argsort(jnp.argsort(u, axis=1), axis=1)
+                    fk = spec.feature_k if dyn is None else dyn.feature_k
+                    fmask = ranks < fk                             # (width, F)
+                else:
+                    fmask = None
+                if use_pallas:
+                    # fused split scan: cumsum + gain + masks + argmax in one
+                    # kernel on the post-psum histogram; only the (6, width)
+                    # best-split pack leaves it
+                    pack6 = _hk.split_scan(
+                        hist,
+                        jnp.ones((width, F), jnp.float32) if fmask is None
+                        else fmask.astype(jnp.float32),
+                        jnp.asarray(min_inst, jnp.float32).reshape(1, 1),
+                        reg_lambda=spec.reg_lambda, gamma=spec.gamma,
+                        interpret=interp)
+                    best_f = pack6[0].astype(jnp.int32)
+                    best_b = pack6[1].astype(jnp.int32)
+                    best_gain = pack6[2]
+                    gG, gH, gW = pack6[3], pack6[4], pack6[5]
+                else:
+                    hG = jnp.transpose(hist[..., 0], (2, 0, 1))  # (width,F,B)
+                    hH = jnp.transpose(hist[..., 1], (2, 0, 1))
+                    hW = jnp.transpose(hist[..., 2], (2, 0, 1))
+                    GL = jnp.cumsum(hG, axis=2)
+                    HL = jnp.cumsum(hH, axis=2)
+                    WL = jnp.cumsum(hW, axis=2)
+                    G = GL[:, :, -1:]
+                    H = HL[:, :, -1:]
+                    W = WL[:, :, -1:]
+                    lam = spec.reg_lambda
+                    score = (GL ** 2 / (HL + lam + 1e-12)
+                             + (G - GL) ** 2 / (H - HL + lam + 1e-12)
+                             - G ** 2 / (H + lam + 1e-12))
+                    ok = ((WL >= min_inst)
+                          & ((W - WL) >= min_inst))
+                    ok = ok & (jnp.arange(B)[None, None, :] < B - 1)
+                    if fmask is not None:
+                        ok = ok & fmask[:, :, None]
+                    score = jnp.where(ok, score, -jnp.inf)
+                    flat_best = jnp.argmax(score.reshape(width, F * B), axis=1)
+                    best_f = (flat_best // B).astype(jnp.int32)
+                    best_b = (flat_best % B).astype(jnp.int32)
+                    best_gain = 0.5 * jnp.take_along_axis(
+                        score.reshape(width, F * B), flat_best[:, None],
+                        axis=1)[:, 0] - spec.gamma
+                    gG, gH, gW = G[:, 0, 0], H[:, 0, 0], W[:, 0, 0]
+                do_split = (best_gain > min_gain) & jnp.isfinite(best_gain)
+                if dyn is not None:  # trial's own maxDepth: none beyond it
+                    do_split = do_split & (level < dyn.depth)
+                idx = base + jnp.arange(width)
+                node_G = node_G.at[idx].set(gG)
+                node_H = node_H.at[idx].set(gH)
+                node_W = node_W.at[idx].set(gW)
+                split_feature = split_feature.at[idx].set(
+                    jnp.where(do_split, best_f, -1))
+                split_bin = split_bin.at[idx].set(best_b)
+                gains = gains.at[idx].set(jnp.where(do_split, best_gain, 0.0))
+            with jax.named_scope("tree.route"):
+                # row-dependent gathers (table[my_idx], take_along_axis) lower
+                # to XLA's generic scratch-memory gather on TPU — ~22ms per
+                # call at 800k rows, THE dominant cost of the whole build. The
+                # same lookups as masked sums are plain VPU work.
+                lid_eq = lid_c[:, None] == jnp.arange(width,
+                                                      dtype=jnp.int32)[None, :]
+                my_f = jnp.sum(jnp.where(lid_eq, best_f[None, :], 0), axis=1)
+                my_b = jnp.sum(jnp.where(lid_eq, best_b[None, :], 0), axis=1)
+                my_split = jnp.any(lid_eq & do_split[None, :], axis=1)
+                feat_eq = my_f[:, None] == \
+                    jnp.arange(F, dtype=jnp.int32)[None, :]
+                xbin = jnp.sum(jnp.where(feat_eq, binned, 0), axis=1)
+                go_right = xbin > my_b
+                child = 2 * node + 1 + go_right.astype(jnp.int32)
+                node = jnp.where(in_level & my_split, child, node)
+                active = in_level & my_split
             hist_prev = hist
             split_prev = do_split
 
         # leaf stats for the last level
         width = 2 ** D
         base = width - 1
-        lid = node - base
-        in_level = (lid >= 0) & (lid < width) & (weight > 0)
-        lid_c = jnp.where(in_level, lid, 0)
-        wq = jnp.where(in_level, weight, 0.0)
-        node1hot = jax.nn.one_hot(lid_c, width, dtype=jnp.float32) \
-            * (wq > 0)[:, None]
-        lstats = _psum_merge(node1hot.T @ jnp.stack(
-            [grad * wq, hess * wq, wq], axis=1))
-        idx = base + jnp.arange(width)
-        node_G = node_G.at[idx].set(lstats[:, 0])
-        node_H = node_H.at[idx].set(lstats[:, 1])
-        node_W = node_W.at[idx].set(lstats[:, 2])
-        leaf_value = -node_G / (node_H + spec.reg_lambda + 1e-12)
-        # empty nodes (zero cover) inherit the parent value so unseen routes
-        # at predict time fall back gracefully; D passes propagate top-down
-        parent = jnp.maximum((jnp.arange(n_nodes) - 1) // 2, 0)
-        for _ in range(D):
-            leaf_value = jnp.where(node_W > 0, leaf_value, leaf_value[parent])
-            split_feature = jnp.where(node_W > 0, split_feature, -1)
-        pack = jnp.stack([split_feature.astype(jnp.float32),
-                          split_bin.astype(jnp.float32),
-                          leaf_value, gains, node_H])
+        with jax.named_scope("tree.hist"):
+            lid = node - base
+            in_level = (lid >= 0) & (lid < width) & (weight > 0)
+            lid_c = jnp.where(in_level, lid, 0)
+            wq = jnp.where(in_level, weight, 0.0)
+            node1hot = jax.nn.one_hot(lid_c, width, dtype=jnp.float32) \
+                * (wq > 0)[:, None]
+            lstats = _psum_merge(node1hot.T @ jnp.stack(
+                [grad * wq, hess * wq, wq], axis=1))
+        with jax.named_scope("tree.update"):
+            idx = base + jnp.arange(width)
+            node_G = node_G.at[idx].set(lstats[:, 0])
+            node_H = node_H.at[idx].set(lstats[:, 1])
+            node_W = node_W.at[idx].set(lstats[:, 2])
+            leaf_value = -node_G / (node_H + spec.reg_lambda + 1e-12)
+            # empty nodes (zero cover) inherit the parent value so unseen
+            # routes at predict time fall back gracefully; D passes
+            # propagate top-down
+            parent = jnp.maximum((jnp.arange(n_nodes) - 1) // 2, 0)
+            for _ in range(D):
+                leaf_value = jnp.where(node_W > 0, leaf_value,
+                                       leaf_value[parent])
+                split_feature = jnp.where(node_W > 0, split_feature, -1)
+            pack = jnp.stack([split_feature.astype(jnp.float32),
+                              split_bin.astype(jnp.float32),
+                              leaf_value, gains, node_H])
         # `node` is each row's terminal node — the build IS the traversal,
         # so boosting margin updates need one gather, not a depth-long
         # re-walk of the tree it just built
@@ -745,12 +754,13 @@ def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
         # compact operand survives alongside — the kernel path histograms
         # straight from it
         binned_c = binned
-        binned = binned.astype(jnp.int32)
-        if kernel == "pallas":
-            B1t = None  # kernel one-hots bin tiles in VMEM per block
-        else:
-            B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
-                .reshape(n, F * B).T  # transposed ONCE, reused every tree
+        with jax.named_scope("tree.operand"):
+            binned = binned.astype(jnp.int32)
+            if kernel == "pallas":
+                B1t = None  # kernel one-hots bin tiles in VMEM per block
+            else:
+                B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
+                    .reshape(n, F * B).T  # transposed ONCE, reused every tree
         # ONE replicated sampling stream (fold_in(0) preserves the
         # historical single-device draws bit-for-bit); per-chip weights
         # come from slicing the global draw, not from per-chip keys
@@ -761,35 +771,39 @@ def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
         n = binned.shape[0]
 
         def round_fn(margin, t):
-            if es.boosting:
-                if es.loss == "logistic":
-                    p = jax.nn.sigmoid(margin)
-                    grad = p - y
-                    hess = jnp.maximum(p * (1 - p), 1e-6)
+            with jax.named_scope("tree.update"):
+                if es.boosting:
+                    if es.loss == "logistic":
+                        p = jax.nn.sigmoid(margin)
+                        grad = p - y
+                        hess = jnp.maximum(p * (1 - p), 1e-6)
+                    else:
+                        grad = margin - y
+                        hess = jnp.ones_like(y)
                 else:
-                    grad = margin - y
+                    grad = -y
                     hess = jnp.ones_like(y)
-            else:
-                grad = -y
-                hess = jnp.ones_like(y)
-            kt = jax.random.fold_in(key, t)
-            if es.bootstrap and es.n_trees > 1:
-                w = _sliced_draw(n, data_width, lambda s: jax.random.poisson(
-                    kt, es.subsample, s).astype(jnp.float32), axes)
-            elif es.subsample < 1.0:
-                w = _sliced_draw(n, data_width, lambda s: jax.random.bernoulli(
-                    kt, es.subsample, s).astype(jnp.float32), axes)
-            else:
-                w = jnp.ones((n,), jnp.float32)
-            w = w * mask
-            feat_rng = jax.random.key_data(jax.random.fold_in(
-                jax.random.wrap_key_data(rng), t))  # same across chips
+                kt = jax.random.fold_in(key, t)
+                if es.bootstrap and es.n_trees > 1:
+                    w = _sliced_draw(
+                        n, data_width, lambda s: jax.random.poisson(
+                            kt, es.subsample, s).astype(jnp.float32), axes)
+                elif es.subsample < 1.0:
+                    w = _sliced_draw(
+                        n, data_width, lambda s: jax.random.bernoulli(
+                            kt, es.subsample, s).astype(jnp.float32), axes)
+                else:
+                    w = jnp.ones((n,), jnp.float32)
+                w = w * mask
+                feat_rng = jax.random.key_data(jax.random.fold_in(
+                    jax.random.wrap_key_data(rng), t))  # same across chips
             pack, node_fin = build(B1t, binned, grad, hess, w, feat_rng,
                                    binned_c=binned_c)
             if es.boosting:
                 # the build routed every row to its terminal node already:
                 # the margin update is one gather, not a depth-long re-walk
-                margin = margin + es.step_size * pack[2][node_fin]
+                with jax.named_scope("tree.update"):
+                    margin = margin + es.step_size * pack[2][node_fin]
             return margin, pack
 
         return round_fn
@@ -908,7 +922,6 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
     (sml_tpu/ct): an interrupted or preempted boost resumes from the
     last dispatch boundary instead of restarting the fit."""
     from ..parallel import prewarm as _prewarm
-    from ..utils.profiler import PROFILER
     rng = jax.random.key_data(jax.random.PRNGKey(seed))
     packs_parts = []   # no-hook path: device packs, ONE batched D2H at end
     host_packs = []    # hook path: each pack fetched ONCE at its boundary
@@ -923,8 +936,9 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
                 "args": _prewarm.arg_specs(binned_dev, y_dev, mask_dev,
                                            margin)})
             PROFILER.count("tree.fit_dispatch")
-            margin, packs = _compiled_chunk(es, c, kernel)(
-                binned_dev, y_dev, mask_dev, margin, rng, jnp.int32(t))
+            with PROFILER.span("fit.dispatch"):
+                margin, packs = _compiled_chunk(es, c, kernel)(
+                    binned_dev, y_dev, mask_dev, margin, rng, jnp.int32(t))
             t += c
             if on_rounds is None:
                 packs_parts.append(packs)
@@ -933,8 +947,12 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
                 if t < es.n_trees:
                     on_rounds(t, _unpack_trees(
                         np.concatenate(host_packs, axis=0)))
-        packs = (np.concatenate(host_packs, axis=0) if host_packs
-                 else np.concatenate(jax.device_get(packs_parts), axis=0))
+        if not host_packs:
+            with PROFILER.span("fit.device_wait"):
+                jax.block_until_ready(packs_parts)
+            with PROFILER.span("fit.readback"):
+                host_packs = jax.device_get(packs_parts)
+        packs = np.concatenate(host_packs, axis=0)
     return _unpack_trees(packs)
 
 
@@ -1021,7 +1039,6 @@ def resume_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
     appended rounds only; the caller prepends the saved trees."""
     from ..conf import GLOBAL_CONF
     from ..parallel import dispatch as _dispatch
-    from ..utils.profiler import PROFILER
     if not es.boosting:
         raise ValueError("warm-start resume requires a boosting ensemble "
                          "(forest/DT rounds are independent — refit whole)")
@@ -1069,7 +1086,6 @@ def fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
     at; see `_boost_rounds`)."""
     from ..parallel import dispatch as _dispatch
     from ..parallel import mesh as _meshlib
-    from ..utils.profiler import PROFILER
     with PROFILER.span(
             "program.tree_ensemble", rows=int(binned_dev.shape[0]),
             route="host" if _dispatch.is_host_mesh(_meshlib.get_mesh())
@@ -1097,7 +1113,7 @@ def _ensemble_compiled(es: EnsembleSpec, kernel: Optional[str] = None,
         _ensemble_cache[key] = data_parallel(
             _make_ensemble_program(es, _data_width(mesh), kernel, brows,
                                    meshlib.row_axes(mesh), _hier_ici(mesh)),
-            replicated_argnums=(3,))
+            replicated_argnums=(3,), name="tree_ensemble")
     return _ensemble_cache[key]
 
 
@@ -1113,6 +1129,23 @@ def _onehot_bytes(spec: TreeSpec, rows: int, kernel: str) -> int:
         return 0
     return int(rows) * spec.n_features * spec.n_bins \
         * np.dtype(_hist_dtype()).itemsize
+
+
+def _run_and_read(compiled, *args):
+    """One dispatch of a compiled fit program and ONE batched D2H of all
+    it returns, as the three host phases of the fit's span tree: the call
+    returns (`fit.dispatch`), the device finishes (`fit.device_wait`),
+    the result is copied to the host (`fit.readback`). `device_get`
+    alone would wait just the same; split, the wait has its own name."""
+    with PROFILER.span("fit.dispatch"):
+        out = compiled(*args)
+    with PROFILER.span("fit.device_wait"):
+        out = jax.block_until_ready(out)
+    with PROFILER.span("fit.readback") as note:
+        host = jax.device_get(out)
+        note["bytes"] = sum(int(a.nbytes)
+                            for a in jax.tree_util.tree_leaves(host))
+    return host
 
 
 def _fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
@@ -1131,7 +1164,6 @@ def _fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
     compiled = _ensemble_compiled(es, kernel)
     rng = jax.random.key_data(jax.random.PRNGKey(seed))
     from ..parallel import prewarm as _prewarm
-    from ..utils.profiler import PROFILER
     _prewarm.record("tree_ensemble", {
         "es": _es_meta(es), "kernel": kernel,
         "kernel_rows": _kernel_block_rows(kernel),
@@ -1139,8 +1171,8 @@ def _fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
     PROFILER.count("tree.fit_dispatch")
     with transient_hbm("hist_onehot",
                        _onehot_bytes(es.tree, binned_dev.shape[0], kernel)):
-        packs, base = jax.device_get(compiled(binned_dev, y_dev, mask_dev,
-                                              rng))
+        packs, base = _run_and_read(compiled, binned_dev, y_dev, mask_dev,
+                                    rng)
     # ^ one batched D2H transfer for (packs, base): every device→host
     # read has a fixed cost, so never fetch leaves separately
     return _unpack_trees(packs), float(base)
@@ -1201,11 +1233,12 @@ def _unpack_trees(packs) -> list:
     """(T, 5, n_nodes) device pack → FittedTree list — the ONE place that
     knows the pack layout (shared by the single-fit and fold-batched
     unpack paths)."""
-    return [FittedTree(split_feature=p[0].astype(np.int32),
-                       split_bin=p[1].astype(np.int32),
-                       leaf_value=p[2].astype(np.float32),
-                       gain=p[3].astype(np.float32),
-                       cover=p[4].astype(np.float32)) for p in packs]
+    with PROFILER.span("fit.unpack", trees=len(packs)):
+        return [FittedTree(split_feature=p[0].astype(np.int32),
+                           split_bin=p[1].astype(np.int32),
+                           leaf_value=p[2].astype(np.float32),
+                           gain=p[3].astype(np.float32),
+                           cover=p[4].astype(np.float32)) for p in packs]
 
 
 def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
@@ -1221,7 +1254,6 @@ def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
     fold."""
     from ..parallel import dispatch as _dispatch
     from ..parallel import mesh as _meshlib
-    from ..utils.profiler import PROFILER
     from ._staging import stage_stacked_cached
 
     mesh = _meshlib.get_mesh()
@@ -1245,7 +1277,7 @@ def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
             transient_hbm("hist_onehot",
                           _onehot_bytes(es.tree, fo * n_pad, kernel)):
         PROFILER.count("tree.fit_dispatch")
-        packs, bases = jax.device_get(compiled(b_dev, y_dev, m_dev, rng))
+        packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rng)
     return [(_unpack_trees(packs[k]), float(bases[k])) for k in range(fo)]
 
 
@@ -1309,12 +1341,13 @@ def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
                 bootstrap, subsample):
         n = binned.shape[0]
         binned_c = binned
-        binned = binned.astype(jnp.int32)
-        if kernel == "pallas":
-            B1t = None  # kernel one-hots bin tiles in VMEM per block
-        else:
-            B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
-                .reshape(n, F * B).T
+        with jax.named_scope("tree.operand"):
+            binned = binned.astype(jnp.int32)
+            if kernel == "pallas":
+                B1t = None  # kernel one-hots bin tiles in VMEM per block
+            else:
+                B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
+                    .reshape(n, F * B).T
         key = jax.random.fold_in(jax.random.wrap_key_data(rng), 0)
         base = base_of(y, mask)
         dyn = TrialDyn(depth=depth, feature_k=feature_k,
@@ -1474,7 +1507,6 @@ def fit_ensembles_trials(bst, yst, mst, es: EnsembleSpec, rngs,
     the caller slices each element down to its own numTrees."""
     from ..parallel import dispatch as _dispatch
     from ..parallel import prewarm as _prewarm
-    from ..utils.profiler import PROFILER
     from ._staging import stage_stacked_cached, stage_trial_stacked_cached
 
     mesh = meshlib.get_mesh()
@@ -1513,8 +1545,8 @@ def fit_ensembles_trials(bst, yst, mst, es: EnsembleSpec, rngs,
             transient_hbm("hist_onehot",
                           _onehot_bytes(es.tree, e_pad * n_pad, kernel)):
         PROFILER.count("tree.fit_dispatch")
-        packs, bases = jax.device_get(compiled(
-            b_dev, y_dev, m_dev, rngs, *dyns))
+        packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rngs,
+                                     *dyns)
     return packs[:E], bases[:E]
 
 
@@ -1686,7 +1718,6 @@ def fit_tree(binned_dev, grad_dev, hess_dev, weight_dev, spec: TreeSpec,
     compiled = _tree_cache[key]
     if feat_key is None:
         feat_key = jax.random.key_data(jax.random.PRNGKey(rng))
-    from ..utils.profiler import PROFILER
     PROFILER.count("tree.fit_dispatch")
     with transient_hbm("hist_onehot",
                        _onehot_bytes(spec, binned_dev.shape[0], kernel)):

@@ -69,7 +69,7 @@ from . import _audit, _context, _ledger
 from . import drift as drift  # noqa: F401 — re-exported subsystem
 from ._audit import records as audit_records, report as audit_report
 from ._context import TraceContext, activate as activate_trace, \
-    current as current_trace, hex_id as trace_hex, new_trace
+    current as current_trace, hex_id as trace_hex, new_trace, open_trace
 from ._ledger import LEDGER, report as memory_report
 from ._metrics import METRICS, LogHistogram, merge_snapshots
 from ._recorder import RECORDER, Event
@@ -325,13 +325,32 @@ _fit_depth = threading.local()
 
 
 @contextlib.contextmanager
-def autolog_fit(estimator):
-    """Wrap one Estimator.fit: with the recorder on, autologging enabled
-    (`sml.obs.autoLogRunMetrics`) and a tracking run active on this
-    thread, log the fit's `engine.*` metric DELTAS to the run — the
-    MLflow system-metrics mirror. Only the OUTERMOST fit on a thread logs
-    (a Pipeline's stage fits and a CrossValidator's inner fits fold into
-    their parent, exactly like nested autologged models)."""
+def _fit_root(estimator, df):
+    """The root of a fit's span tree: a `fit` span that opens a trace of
+    the fit's own (unless a context already rides the thread, whose unit
+    the fit then belongs to). Every `PROFILER.span` below is a child
+    unit, so the recorded spans share the `trace` id and name their
+    `parent` (docs/OBSERVABILITY.md "The span tree of a fit"). `rows`
+    only where the frame is materialized already: counting costs nothing."""
+    from ..utils.profiler import PROFILER
+    parts = getattr(df, "_parts", None)
+    opened = open_trace() if current_trace() is None else None
+    with activate_trace(opened), \
+            PROFILER.span("fit", estimator=type(estimator).__name__,
+                          rows=None if parts is None
+                          else sum(len(p) for p in parts)):
+        yield
+
+
+@contextlib.contextmanager
+def autolog_fit(estimator, df=None):
+    """Wrap one Estimator.fit of `df`. With the recorder on, the OUTERMOST
+    fit on a thread is the root span `fit` of a span tree (`_fit_root`)
+    and, with autologging enabled (`sml.obs.autoLogRunMetrics`) and a
+    tracking run active on this thread, logs the fit's `engine.*` metric
+    DELTAS to the run — the MLflow system-metrics mirror. A Pipeline's stage fits and a
+    CrossValidator's inner fits fold into their parent, exactly like
+    nested autologged models."""
     if not RECORDER.enabled:
         yield
         return
@@ -345,7 +364,9 @@ def autolog_fit(estimator):
             run = tracking.active_run()
             if run is not None:
                 before = engine_metrics()
-        yield
+        with (_fit_root(estimator, df) if depth == 0
+              else contextlib.nullcontext()):
+            yield
     finally:
         _fit_depth.d = depth
         if run is not None and before is not None:

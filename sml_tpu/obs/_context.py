@@ -60,9 +60,11 @@ def hex_id(ident: Optional[int]) -> Optional[str]:
 
 @dataclass(frozen=True)
 class TraceContext:
-    """One logical unit of work's position in the causal tree."""
+    """One logical unit of work's position in the causal tree. A trace
+    that has no span yet (`open_trace`) has `span_id` None: the first
+    child opened under it is the trace's root, with no parent."""
     trace_id: int
-    span_id: int
+    span_id: Optional[int]
     parent_id: Optional[int] = None
 
     def child(self) -> "TraceContext":
@@ -88,6 +90,15 @@ def new_trace() -> Optional[TraceContext]:
     if not RECORDER.enabled:
         return None
     return TraceContext(_next_id(), _next_id(), None)
+
+
+def open_trace() -> Optional[TraceContext]:
+    """A fresh trace with no span of its own yet (None when the recorder
+    is off): the first `PROFILER.span` under it becomes the trace's root
+    span, with no parent — how a fit's span tree starts."""
+    if not RECORDER.enabled:
+        return None
+    return TraceContext(_next_id(), None, None)
 
 
 def mint_request(rows: Optional[int] = None,

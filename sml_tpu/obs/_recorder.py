@@ -33,6 +33,8 @@ class Event:
           "compile". Counter events carry the post-increment cumulative
           total (gauges carry the current value) in args["total"], so the
           trace exporter can render counter tracks without replaying.
+          Span events carry `trace` / `span` / `parent` ids in args when
+          a trace context rode the thread (obs/_context.py).
     ts:   seconds since the recorder epoch (reset() re-zeros it).
     dur:  seconds, spans only.
     tid:  small dense per-thread id (stable within a recorder lifetime).
@@ -113,7 +115,12 @@ class Recorder:
              ts: Optional[float] = None,
              args: Optional[Dict[str, object]] = None) -> None:
         """Record one event. `ts` is an absolute perf_counter stamp (span
-        starts); None stamps now. Cheap no-op when disabled."""
+        starts); None stamps now. A span also adds its duration and one
+        call to the running totals `span_s.<name>` / `span_n.<name>`
+        (under the one lock taken here, no extra ring event): busy
+        seconds and work done by span name, read through `counters()`
+        like every counter, whatever the ring still holds. Cheap no-op
+        when disabled."""
         if not self.enabled:
             return
         at = (ts if ts is not None else time.perf_counter()) - self._epoch
@@ -129,6 +136,11 @@ class Recorder:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
             self._ring.append(ev)
+            if kind == "span":
+                totals, busy, calls = self._totals, "span_s." + name, \
+                    "span_n." + name
+                totals[busy] = totals.get(busy, 0.0) + (dur or 0.0)
+                totals[calls] = totals.get(calls, 0.0) + 1.0
             sink = self._ensure_sink()
             if sink is not None:  # under the lock: lines must not interleave
                 self._write_sink(ev, sink)

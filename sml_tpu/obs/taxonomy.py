@@ -27,6 +27,13 @@ SPANS = {
     # ML engine
     "fused_transform", "binning.predict",
     "program.*",          # program.<fn> / program.tree_ensemble / ...
+    # one span tree a fit (docs/OBSERVABILITY.md "The span tree of a
+    # fit"): the root `fit` opened by the outermost Estimator.fit, and
+    # its host phases fit.collect / fit.prep / fit.featurize /
+    # fit.quantize (.key, .bins) / fit.stage / fit.baseline, plus
+    # fit.dispatch / fit.device_wait / fit.readback / fit.unpack inside
+    # the tree programs' spans
+    "fit", "fit.*",
     # serving layer: one coalesced device dispatch of the micro-batcher
     "serve.batch",
     # per-device straggler attribution (obs/_skew.py): skew.compute /
@@ -38,6 +45,11 @@ SPANS = {
 }
 
 COUNTERS = {
+    # running totals the recorder keeps for EVERY span name (no call
+    # site): span_s.<name> seconds inside spans of that name,
+    # span_n.<name> how many ended — read as deltas between two
+    # `RECORDER.counters()` snapshots
+    "span_s.*", "span_n.*",
     # stall watchdog (obs/_watchdog.py): flagged in-flight tickets
     "stall.*",
     # black-box postmortem (obs/blackbox.py): bundles written

@@ -1,3 +1,3 @@
-from .profiler import PROFILER, start_device_trace
+from .profiler import PROFILER
 
-__all__ = ["PROFILER", "start_device_trace"]
+__all__ = ["PROFILER"]

@@ -4,13 +4,15 @@ The reference leans on the Spark UI / Ganglia for shuffle, storage and
 executor metrics (`SML/ML 00b - Spark Review.py:78-84`,
 `SML/ML Electives/MLE 05 - Best Practices.py:31-36`). The replacement is a
 structured in-process trace: every engine op records name, wall time, rows,
-and bytes; `report()` renders the UI-equivalent table and
-`start_device_trace` wires `jax.profiler` for XLA-level traces.
+and bytes; `report()` renders the UI-equivalent table. While the flight
+recorder is on, every span is also a `jax.profiler.TraceAnnotation`, so any
+`jax.profiler` trace carries the engine's spans on its host plane.
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -85,69 +87,90 @@ class Profiler:
         return GLOBAL_CONF.getBool("sml.profiler.enabled")
 
     @contextlib.contextmanager
-    def span(self, name: str, rows: Optional[int] = None, **meta) -> Iterator[None]:
+    def span(self, name: str, rows: Optional[int] = None,
+             **meta) -> Iterator[Dict[str, object]]:
         """Nested spans subtract from the parent's SELF time, so a
         `materialize` that waits on a device program reports only its own
         host-side cost — totals in the report stay attributable.
 
+        Yields the span's `meta` dict: what is known only when the work
+        is done (a cache hit, the bytes moved, `rows`) is added to it
+        inside the block and lands on the recorded span.
+
         Runs when the profiler OR the flight recorder is on; the recorder
-        additionally gets a timestamped span event (for the Chrome trace)
-        tagged with the riding trace context (obs/_context.py), and, for
-        spans carrying a dispatch `route`, registers a stall-watchdog
-        ticket (expected wall = the audit's prediction for this thread's
-        pending decision) and feeds the measured wall time back to the
-        dispatch audit."""
+        additionally gets a timestamped span event (for the Chrome trace).
+        Under a riding trace context (obs/_context.py) the span is a
+        CHILD unit of it: the event carries the context's `trace` id, a
+        `span` id of its own and its `parent`'s span id, and the child is
+        the active context inside the block, so spans nest as they ran.
+        The span is also a `jax.profiler.TraceAnnotation`, so a profiler
+        trace shows it on the host plane above the device ops it caused.
+        For spans carrying a dispatch `route`, it registers a
+        stall-watchdog ticket (expected wall = the audit's prediction for
+        this thread's pending decision) and feeds the measured wall time
+        back to the dispatch audit."""
         prof_on = self.enabled
         obs_on = _OBS.enabled
         if not prof_on and not obs_on:
-            yield
+            yield meta
             return
         route = meta.get("route")
-        ticket = None
-        if obs_on and route in ("host", "device"):
-            # a dispatch launch in flight: the watchdog flags it if it
-            # exceeds stallFactor x its own predicted wall (floor
-            # stallMillis) — obs/_watchdog.py
-            ticket = _OBS_WATCHDOG.open(
-                "dispatch", name,
-                expected_s=_obs_audit.expected_wall(route),
-                trace=_obs_ctx.current())
-        if prof_on:
-            gen = self._gen
-            tls = self._tls
-            if getattr(tls, "gen", None) != gen:
-                tls.stack = []   # stale stack from before a reset()
-                tls.gen = gen
-            stack = tls.stack
-            child_acc = [0.0]
-            stack.append(child_acc)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            _OBS_WATCHDOG.close(ticket)
-            if prof_on:
-                if self._gen == gen:
-                    stack.pop()
-                    if stack:
-                        stack[-1][0] += dt
-                    with self._lock:
-                        self._spans.append(
-                            Span(name, dt, rows, meta,
-                                 self_s=max(0.0, dt - child_acc[0])))
-                # else: reset() fired mid-span — this span's timing
-                # straddles it and the stack was invalidated; drop both
-            if obs_on and _OBS.enabled:
-                ctx = _obs_ctx.current()
-                if ctx is not None and "trace" not in meta:
-                    _OBS.span(name, t0, dt, rows=rows,
-                              trace=ctx.trace_id, span=ctx.span_id,
-                              **meta)
-                else:
-                    _OBS.span(name, t0, dt, rows=rows, **meta)
+        ticket = ctx = None
+        with contextlib.ExitStack() as entered:
+            if obs_on:
+                parent = _obs_ctx.current()
+                if parent is not None and "trace" not in meta:
+                    ctx = entered.enter_context(
+                        _obs_ctx.activate(parent.child()))
                 if route in ("host", "device"):
-                    _obs_audit.attach(route, name, dt)
+                    # a dispatch launch in flight: the watchdog flags it if
+                    # it exceeds stallFactor x its own predicted wall (floor
+                    # stallMillis) — obs/_watchdog.py
+                    ticket = _OBS_WATCHDOG.open(
+                        "dispatch", name,
+                        expected_s=_obs_audit.expected_wall(route),
+                        trace=parent)
+                # only a process that already runs jax can be tracing
+                jax = sys.modules.get("jax")
+                if jax is not None:
+                    entered.enter_context(jax.profiler.TraceAnnotation(name))
+            if prof_on:
+                gen = self._gen
+                tls = self._tls
+                if getattr(tls, "gen", None) != gen:
+                    tls.stack = []   # stale stack from before a reset()
+                    tls.gen = gen
+                stack = tls.stack
+                child_acc = [0.0]
+                stack.append(child_acc)
+            t0 = time.perf_counter()
+            try:
+                yield meta
+            finally:
+                dt = time.perf_counter() - t0
+                if "rows" in meta:   # counted inside the block
+                    rows = meta.pop("rows")
+                _OBS_WATCHDOG.close(ticket)
+                if prof_on:
+                    if self._gen == gen:
+                        stack.pop()
+                        if stack:
+                            stack[-1][0] += dt
+                        with self._lock:
+                            self._spans.append(
+                                Span(name, dt, rows, meta,
+                                     self_s=max(0.0, dt - child_acc[0])))
+                    # else: reset() fired mid-span — this span's timing
+                    # straddles it and the stack was invalidated; drop both
+                if obs_on and _OBS.enabled:
+                    if ctx is not None:
+                        _OBS.span(name, t0, dt, rows=rows,
+                                  trace=ctx.trace_id, span=ctx.span_id,
+                                  parent=ctx.parent_id, **meta)
+                    else:
+                        _OBS.span(name, t0, dt, rows=rows, **meta)
+                    if route in ("host", "device"):
+                        _obs_audit.attach(route, name, dt)
 
     def spans(self) -> List[Span]:
         with self._lock:
@@ -203,14 +226,3 @@ class Profiler:
 
 
 PROFILER = Profiler()
-
-
-@contextlib.contextmanager
-def start_device_trace(logdir: str) -> Iterator[None]:
-    """XLA-level trace (TensorBoard-compatible) around a block."""
-    import jax  # lazy: the profiler itself must stay importable jax-free
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

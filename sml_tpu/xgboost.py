@@ -71,13 +71,7 @@ class _XgboostBase(_TreeEstimatorBase, _XgboostParams):
                 raise TypeError(f"unexpected param {k!r}")
 
     def _fit(self, df):
-        from .ml._staging import extract_xy
-        import numpy as np
-        X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
-                             self.getOrDefault("labelCol"))
-        ok = np.isfinite(y)
-        X, y = X[ok], y[ok]
-        cat = _categorical_slots(df, self.getOrDefault("featuresCol"))
+        X, y, cat = self._extract(df)
         spec = _fit_ensemble(
             X, y, categorical=cat,
             max_depth=int(self.getOrDefault("max_depth")),
