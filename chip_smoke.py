@@ -16,6 +16,7 @@ XgboostRegressor(40 rounds, depth 6, 64 bins) on log(price)):
           dispatch on route `device`, no host-routed / shed request, no
           interpreted or fallen-back kernel, bins on TPU devices (one shard
           per chip and an all-reduce in the fit program on several chips),
+          the one-hot histogram operand compiled OUTSIDE the loop over rounds,
           the three native host libraries loaded, and the compiled scoring
           kernel agreeing with the XLA traversal
 
@@ -358,13 +359,20 @@ def main() -> int:
     check("one addressable shard of the bin matrix on each chip",
           shard_devs == sorted(d.id for d in jax.devices()),
           f"shards on devices {shard_devs}")
+    # the executable the fits just ran, compiled again from the very arrays
+    # they were given (a compile-cache hit on the chip)
+    row = jax.ShapeDtypeStruct((train_bins.shape[0],), np.float32,
+                               sharding=meshlib.data_sharding(mesh, 1))
+    hlo = fit_progs[0][1].lower(
+        train_bins, row, row,
+        jax.ShapeDtypeStruct((2,), np.uint32)).compile().as_text()
+    in_loop = tree_impl.ops_in_loop_bodies(hlo, "tree.operand")
+    check("the one-hot operand is built outside the loop over rounds",
+          "tree.operand" in hlo and not in_loop
+          and bool(tree_impl.ops_in_loop_bodies(hlo, "tree.hist")),
+          f"{len(in_loop)} tree.operand instructions in a while body"
+          + (f": {in_loop[:4]}" if in_loop else ""))
     if device["count"] > 1:
-        n_pad = train_bins.shape[0]
-        row = jax.ShapeDtypeStruct((n_pad,), np.float32,
-                                   sharding=meshlib.data_sharding(mesh, 1))
-        hlo = fit_progs[0][1].lower(
-            train_bins, row, row,
-            jax.ShapeDtypeStruct((2,), np.uint32)).compile().as_text()
         check("the compiled boosting program contains an all-reduce",
               "all-reduce" in hlo, f"{hlo.count('all-reduce')} mentions")
 

@@ -28,6 +28,7 @@ every boosting round (SURVEY §7 hard part #6).
 from __future__ import annotations
 
 import math
+import re
 from functools import partial
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -512,10 +513,12 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                           * stats[:, None, :].astype(hist_dtype)
                           ).reshape(n, hw * 3)
                     # bf16 operands (the one-hot side is EXACT in bf16), f32
-                    # accumulation: the MXU's native mode. B1t is
-                    # pre-transposed OUTSIDE the tree scan — a .T here would
-                    # re-materialize a ~1GB transpose every level of every
-                    # tree
+                    # accumulation: the MXU's native mode. B1t comes
+                    # pre-transposed from `_tree_operand`, built once a
+                    # dispatch and held outside the loop over rounds by its
+                    # optimization_barrier (without it XLA's fusible sinking
+                    # rebuilds it every round); each dot reads all of it
+                    # from HBM
                     part = jax.lax.dot_general(
                         B1t, ns, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
@@ -726,17 +729,90 @@ def _sliced_draw(n: int, data_width: int, draw, axes=None):
     return jax.lax.dynamic_slice(full, (idx * n,), (n,))
 
 
+def _tree_operand(binned_c, n_bins: int, hist_dtype, kernel: str,
+                  barrier: bool = True):
+    """The histogram operand of every XLA-path tree program, built ONCE a
+    dispatch: `(binned, B1t)` = the compact bins widened to int32 and
+    their one-hot, `(F·B, n)` in `hist_dtype`, pre-transposed for the
+    histogram dot (a `.T` at the dot would re-materialize a gigabyte
+    transpose every level of every tree). `B1t` is `None` under
+    `kernel="pallas"`: that path one-hots bin tiles in VMEM, a row block
+    at a time, straight from the compact operand.
+
+    The `optimization_barrier` is what keeps "once" true. The one-hot is
+    loop-invariant and cheap to express (broadcast, compare, convert), and
+    XLA:TPU's fusible-sinking pass moves exactly such producers INTO a
+    while loop that consumes them, trading recomputation for live memory
+    (the body's name then ends `…sunk`): though written outside the
+    `lax.scan` over rounds it is then rebuilt every round, 4.4 GB read
+    and 2.2 GB written at 1.7 M rows × 640 columns, longer than the
+    round's four histogram dots take (PERF.md §6, PR 27). Behind the
+    barrier it is an opaque buffer the loop carries as an operand; the
+    barrier is the identity on its value. `barrier=False` is for a
+    program with NO loop to sink into (`_build_tree_program`): there it
+    could only change how a backend fuses the one-hot into its consumers."""
+    with jax.named_scope("tree.operand"):
+        # compact uint8/uint16 bins widen ON-DEVICE (a fused VPU cast over
+        # the 4x-smaller staged matrix), never on the host/H2D path
+        binned = binned_c.astype(jnp.int32)
+        if kernel == "pallas":
+            return binned, None
+        n, F = binned.shape
+        B1t = jax.nn.one_hot(binned, n_bins, dtype=hist_dtype) \
+            .reshape(n, F * n_bins).T
+        return binned, (jax.lax.optimization_barrier(B1t) if barrier
+                        else B1t)
+
+
+def ops_in_loop_bodies(hlo_text: str, scope: str = "tree.operand") -> list:
+    """Names of the instructions of a COMPILED program (`.compile()
+    .as_text()`) whose `op_name` holds `scope` and that run inside a while
+    loop: in a loop's body or condition, or in any computation those call
+    (fusions, nested loops, branches). Empty is the proof that the
+    compiler left `_tree_operand` outside the loop over rounds; chip_smoke
+    and tests/test_tree_operand.py hold it to that, so that a jax or
+    libtpu that learns to sink past the barrier fails loudly."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line)
+    # what a computation calls: the computations its instructions name in
+    # an attribute (calls=, to_apply=, body=, branch_computations={a, b})
+    called = re.compile(r"[={,]\s*%?([\w.\-]+)")
+    calls = {c: {m for ln in lines for m in called.findall(
+                 ln.split(" = ", 1)[1].split(", metadata=")[0]) if m in comps}
+             for c, lines in comps.items()}
+    todo = [m for lines in comps.values() for ln in lines
+            if re.search(r"\swhile\(", ln)
+            for m in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
+    inside = set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in inside:
+            inside.add(c)
+            todo.extend(calls[c])
+    held = re.compile(r'op_name="[^"]*' + re.escape(scope))
+    return [ln.split(" = ", 1)[0].strip().removeprefix("ROOT ")
+            for c in sorted(inside) for ln in comps[c] if held.search(ln)]
+
+
 def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
                      kernel: str = "xla", block_rows: int = 0,
                      axes=None, hier_ici: int = 0):
     """The shared internals of every ensemble program shape: `prepare`
-    widens the compact quantized bins on-device and hoists the one-hot
-    transpose; `make_round` returns the per-round scan body. Factored so
-    the monolithic program and the chunked boosting program are the SAME
-    math — a parity test holds them together. `data_width` is the mesh's
-    STATIC data-axis size (part of every program cache's mesh-id key):
-    sampling draws span `local_rows * data_width` so every layout selects
-    the same global weights (see `_sliced_draw`). Under
+    builds the histogram operand once a dispatch (`_tree_operand`: the
+    bins widened on-device, their one-hot behind a barrier the compiler
+    cannot sink into the loop); `make_round` returns the per-round scan
+    body. Factored so the monolithic program and the chunked boosting
+    program are the SAME math — a parity test holds them together.
+    `data_width` is the mesh's STATIC data-axis size (part of every
+    program cache's mesh-id key): sampling draws span `local_rows *
+    data_width` so every layout selects the same global weights (see
+    `_sliced_draw`). Under
     `kernel="pallas"` the fit-long B1t one-hot resident is never built
     (B1t=None) — the pallas kernel one-hots VMEM bin tiles per row block
     from the COMPACT operand instead."""
@@ -745,22 +821,12 @@ def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
                                kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
-    B, F = spec.n_bins, spec.n_features
 
     def prepare(binned, rng):
-        n = binned.shape[0]
-        # compact uint8/uint16 bins widen ON-DEVICE (a fused VPU cast over
-        # the 4x-smaller staged matrix), never on the host/H2D path; the
-        # compact operand survives alongside — the kernel path histograms
-        # straight from it
+        # the compact operand survives alongside the widened one — the
+        # kernel path histograms straight from it
         binned_c = binned
-        with jax.named_scope("tree.operand"):
-            binned = binned.astype(jnp.int32)
-            if kernel == "pallas":
-                B1t = None  # kernel one-hots bin tiles in VMEM per block
-            else:
-                B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
-                    .reshape(n, F * B).T  # transposed ONCE, reused every tree
+        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel)
         # ONE replicated sampling stream (fold_in(0) preserves the
         # historical single-device draws bit-for-bit); per-chip weights
         # come from slicing the global draw, not from per-chip keys
@@ -1118,10 +1184,16 @@ def _ensemble_compiled(es: EnsembleSpec, kernel: Optional[str] = None,
 
 
 def _onehot_bytes(spec: TreeSpec, rows: int, kernel: str) -> int:
-    """HBM bytes of the XLA path's fit-long one-hot resident (`B1t`: rows
-    × F × bins in hist_dtype) — the dominant transient the ledger charges
-    for the duration of a tree-fit dispatch (every tree program shape,
-    fit_tree included). The pallas kernel path never materializes it (bin
+    """HBM bytes of the XLA path's one-hot resident (`B1t`: rows × F ×
+    bins in hist_dtype) — the dominant transient the ledger charges for
+    the duration of a tree-fit dispatch (every tree program shape,
+    fit_tree included). Dispatch-long BY CONSTRUCTION: `_tree_operand`
+    builds it once and its optimization_barrier makes it a buffer the
+    loop over rounds carries (left to itself the compiler rebuilds it
+    every round: as many bytes alive, a round at a time). While it is built the
+    int32 broadcast it is compared from (4 bytes an element, twice these
+    bytes) is alive beside it; that is not charged here (PERF.md §3,
+    `memory_peak_bytes`). The pallas kernel path never materializes it (bin
     tiles one-hot in VMEM per row block), so its charge is zero: the
     `hbm.hist_onehot_bytes` gauge difference IS the kernel's residency
     win."""
@@ -1334,20 +1406,13 @@ def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
                                kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
-    B, F = spec.n_bins, spec.n_features
     base_of = _base_margin_fn(es.loss, axes)
 
     def program(binned, y, mask, rng, depth, feature_k, min_inst, mig,
                 bootstrap, subsample):
         n = binned.shape[0]
         binned_c = binned
-        with jax.named_scope("tree.operand"):
-            binned = binned.astype(jnp.int32)
-            if kernel == "pallas":
-                B1t = None  # kernel one-hots bin tiles in VMEM per block
-            else:
-                B1t = jax.nn.one_hot(binned, B, dtype=hist_dtype) \
-                    .reshape(n, F * B).T
+        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel)
         key = jax.random.fold_in(jax.random.wrap_key_data(rng), 0)
         base = base_of(y, mask)
         dyn = TrialDyn(depth=depth, feature_k=feature_k,
@@ -1675,20 +1740,17 @@ def _build_tree_program(spec: TreeSpec, hist_dtype=jnp.float32,
                         kernel: str = "xla", block_rows: int = 0,
                         axes=None, hier_ici: int = 0):
     """Single-tree program (kept for the dryrun/compile-check path)."""
-    B, F = spec.n_bins, spec.n_features
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
                                kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
 
     def program(binned, grad, hess, weight, feat_rng):
-        n = binned.shape[0]
         binned_c = binned
-        binned = binned.astype(jnp.int32)  # compact bins widen on-device
-        if kernel == "pallas":
-            B1t = None
-        else:
-            B1t = jax.nn.one_hot(binned, B,
-                                 dtype=hist_dtype).reshape(n, F * B).T
+        # no loop over rounds here, so nothing to hold the operand out of
+        # (and XLA:CPU sums the dot in another order behind a barrier: one
+        # ulp in a leaf, which tests/test_hist_kernel.py's parity pins see)
+        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel,
+                                    barrier=False)
         pack, _ = build(B1t, binned, grad, hess, weight, feat_rng,
                         binned_c=binned_c)
         return (pack[0].astype(jnp.int32), pack[1].astype(jnp.int32),

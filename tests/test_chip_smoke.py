@@ -38,6 +38,8 @@ def test_rehearsal_passes_with_pallas_interpreted(tmp_path):
     assert summary["failures"] == []
     # the pallas answers were compared with the XLA traversal's
     assert summary["pallas_vs_xla_max_abs_diff"] is not None
+    # the executable it ran was read back: the operand is outside the loop
+    assert "[PASS] the one-hot operand is built outside the loop" in proc.stdout
     # conftest provisions 8 virtual devices: the multi-chip checks ran
     if result["device"]["count"] > 1:
         assert "contains an all-reduce" in proc.stdout

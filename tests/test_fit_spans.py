@@ -216,7 +216,7 @@ def test_recorder_off_no_event_and_no_total(spark):
 
 
 # ------------------------------------------------------------ named scopes
-def _lowered_text(boosting: bool) -> str:
+def _lowered(boosting: bool):
     spec = tree_impl.TreeSpec(
         max_depth=2, n_bins=8, n_features=3, feature_k=3 if boosting else 2,
         min_instances=1, min_info_gain=0.0, reg_lambda=0.0, gamma=0.0)
@@ -224,17 +224,17 @@ def _lowered_text(boosting: bool) -> str:
         tree=spec, n_trees=2, loss="squared", boosting=boosting,
         bootstrap=not boosting, subsample=1.0, step_size=0.1)
     n = 64
-    lowered = tree_impl._ensemble_compiled(es).lower(
+    return tree_impl._ensemble_compiled(es).lower(
         jnp.zeros((n, 3), jnp.uint8), jnp.zeros((n,), jnp.float32),
         jnp.ones((n,), jnp.float32),
         jax.random.key_data(jax.random.PRNGKey(0)))
-    return lowered.as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("boosting", [True, False],
                          ids=["boosted", "bagged"])
 def test_the_ensemble_program_carries_every_scope(boosting):
-    text = _lowered_text(boosting)
+    lowered = _lowered(boosting)
+    text = lowered.as_text(debug_info=True)
     assert "@jit_tree_ensemble" in text      # the program has a name
     stacks = set(re.findall(r'"([^"]*tree\.[^"]*)"', text))
     found = {s for stack in stacks
@@ -248,3 +248,14 @@ def test_the_ensemble_program_carries_every_scope(boosting):
     assert any(re.search(r"tree\.operand/.*_one_hot", s) for s in stacks)
     assert any(s.endswith("tree.hist/dot_general") for s in stacks)
     assert any("tree.route/" in s for s in stacks)
+    assert any(s.endswith("tree.operand/optimization_barrier")
+               for s in stacks)
+    # the operand is built before the loop over rounds, never inside it:
+    # in the executable every op_name is whole, from the program down
+    # (for the chip's compiler: tests/test_tree_operand.py)
+    hlo = lowered.compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    assert any("while/body" in s and "tree.hist" in s for s in names)
+    assert not [s for s in names if "tree.operand" in s and "while/body" in s]
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand") == []
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.hist")

@@ -1,0 +1,273 @@
+"""The histogram operand is built once a dispatch (ISSUE 27).
+
+`tree_impl._tree_operand` is the one place that widens the bins and builds
+the bf16 one-hot `B1t`, and it ends in an `optimization_barrier`: without
+it XLA:TPU's fusible sinking rebuilds the one-hot inside the loop over
+rounds. Three things are held here, none of which needs the chip:
+
+  (a) the jaxpr of every looping program has the barrier once, outside the
+      scan, and the histogram dot reads it as a scan constant;
+  (b) compiled for a described v5e chip, no `tree.operand` instruction is
+      inside the while loop, and the loop carries the one-hot as an operand;
+  (c) the barrier is the identity: the fitted packs are bit-equal without it.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from sml_tpu.ml import tree_impl
+from sml_tpu.parallel import mesh as meshlib
+
+D = meshlib.DATA_AXIS
+F, B = 3, 8
+
+
+def _es(boosting: bool, n_trees: int = 2, depth: int = 2):
+    spec = tree_impl.TreeSpec(
+        max_depth=depth, n_bins=B, n_features=F,
+        feature_k=F if boosting else 2, min_instances=1, min_info_gain=0.0,
+        reg_lambda=1.0 if boosting else 0.0, gamma=0.0)
+    return tree_impl.EnsembleSpec(
+        tree=spec, n_trees=n_trees, loss="squared", boosting=boosting,
+        bootstrap=not boosting, subsample=1.0, step_size=0.1)
+
+
+def _rows(n: int, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    binned = rng.integers(0, B, size=(n, F)).astype(np.uint8)
+    y = (binned[:, 0] * 0.5 - binned[:, 1] * 0.25
+         + rng.normal(0, 0.1, n)).astype(np.float32)
+    return binned, y, np.ones(n, np.float32), \
+        np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))
+
+
+def _program(which: str, es):
+    """(per-chip program, its arguments at 64 rows, which are row-sharded)."""
+    binned, y, mask, rng = _rows(64)
+    made = (es, 1, "xla", 0, (D,), 0)
+    if which == "ensemble":
+        return tree_impl._make_ensemble_program(*made), \
+            (binned, y, mask, rng), (True, True, True, False)
+    if which == "chunk":
+        return tree_impl._make_chunk_program(es, 2, *made[1:]), \
+            (binned, y, mask, np.zeros(64, np.float32), rng, np.int32(0)), \
+            (True, True, True, True, False, False)
+    assert which == "trials"
+    return tree_impl._make_trials_program(*made), \
+        (binned, y, mask, rng, np.int32(2), np.int32(2), np.float32(1.0),
+         np.float32(0.0), np.bool_(True), np.float32(1.0)), \
+        (True, True, True) + (False,) * 7
+
+
+def _cpu_mesh():
+    return Mesh(np.array(jax.devices()[:1]), (D,))
+
+
+def _sharded(program, args, by_row, mesh):
+    specs = tuple(P(D, *([None] * (np.ndim(a) - 1))) if r else P()
+                  for a, r in zip(args, by_row))
+    return jax.shard_map(program, mesh=mesh, in_specs=specs, out_specs=P(),
+                         check_vma=False), specs
+
+
+def _walk(jaxpr, in_scan=False):
+    """Every equation of a jaxpr and of the jaxprs it holds, with whether a
+    scan encloses it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_scan
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, in_scan or eqn.primitive.name == "scan")
+
+
+# ------------------------------------------------------------- (a) the jaxpr
+@pytest.mark.parametrize("which,boosting", [
+    ("ensemble", True), ("ensemble", False), ("chunk", True),
+    ("trials", False)], ids=["ensemble-boosted", "ensemble-bagged", "chunk",
+                             "trials"])
+def test_one_barrier_outside_the_scan_feeds_the_histogram_dot(which, boosting):
+    program, args, by_row = _program(which, _es(boosting))
+    mapped, _ = _sharded(program, args, by_row, _cpu_mesh())
+    eqns = list(_walk(jax.make_jaxpr(mapped)(*args).jaxpr))
+    barriers = [(e, inside) for e, inside in eqns
+                if e.primitive.name == "optimization_barrier"]
+    assert len(barriers) == 1
+    barrier, inside = barriers[0]
+    assert not inside, "the barrier lies outside the scan over rounds"
+    assert "tree.operand" in str(barrier.source_info.name_stack)
+    (b1t,) = barrier.outvars
+    assert (b1t.aval.shape, b1t.aval.dtype) == ((F * B, 64),
+                                                tree_impl._hist_dtype())
+
+    scans = [e for e, _ in eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    scan = scans[0]
+    body = scan.params["jaxpr"].jaxpr
+    consts = body.invars[:scan.params["num_consts"]]
+    # (the leaves' statistics are a second, small dot under tree.hist, of
+    # the node one-hot: it is made in the body, and rightly)
+    dots = [e for e, _ in _walk(body) if e.primitive.name == "dot_general"
+            and "tree.hist" in str(e.source_info.name_stack)
+            and e.invars[0].aval.shape == (F * B, 64)]
+    assert len(dots) == 2, "one histogram dot a level, in the scan body"
+    for dot in dots:
+        lhs = dot.invars[0]
+        assert lhs in consts, "the dot's operand is computed in the body"
+        assert scan.invars[consts.index(lhs)] is b1t, \
+            "the dot reads another array than the barrier's"
+
+
+def test_the_single_tree_program_builds_it_the_same_way_without_a_barrier():
+    """One helper, but no loop to keep the operand out of: no barrier, so
+    the program XLA:CPU compiles is the one it always was."""
+    spec = _es(True).tree
+    program = tree_impl._build_tree_program(spec, jnp.bfloat16, "xla", 0,
+                                            (D,), 0)
+    binned, y, mask, rng = _rows(64)
+    args = (binned, -y, mask, mask, rng)
+    mapped, _ = _sharded(program, args, (True, True, True, True, False),
+                         _cpu_mesh())
+    eqns = [e for e, _ in _walk(jax.make_jaxpr(mapped)(*args).jaxpr)]
+    names = [e.primitive.name for e in eqns]
+    assert "optimization_barrier" not in names and "scan" not in names
+    assert any("tree.operand" in str(e.source_info.name_stack)
+               and e.outvars[0].aval.shape == (F * B, 64) for e in eqns)
+
+
+def test_pallas_builds_no_operand():
+    binned, none = tree_impl._tree_operand(
+        jnp.zeros((16, F), jnp.uint8), B, jnp.bfloat16, "pallas")
+    assert none is None and binned.dtype == jnp.int32
+
+
+# ------------------------------------------- (b) compiled for a described v5e
+@pytest.fixture(scope="module")
+def one_chip_mesh():
+    """A mesh over ONE described v5e chip: libtpu compiles for it with no
+    chip attached (`.claude/skills/verify/SKILL.md`). Described here and not
+    at import, so that only the worker given this file loads libtpu."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    patch = pytest.MonkeyPatch()
+    for key, value in (("TPU_LOG_DIR", "disabled"),
+                       ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                       ("TPU_WORKER_HOSTNAMES", "localhost"),
+                       ("TPU_SKIP_MDS_QUERY", "1")):
+        patch.setenv(key, value)
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises, it is a skip
+        patch.undo()
+        pytest.skip(f"no v5e:1x1 topology can be described here: {e}")
+    # an executable compiled for a described chip cannot be read back from
+    # the persistent cache without one: keep these compiles out of it
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the program asks the ACTIVE mesh, the CPU's, for its operand type:
+    # give it the chip's
+    patch.setattr(tree_impl, "_hist_dtype", lambda: jnp.bfloat16)
+    yield Mesh(np.array(topo.devices[:1]), (D,))
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+    patch.undo()
+
+
+AOT_ROWS = 8192
+
+
+def _compiled_for(mesh, es, which: str = "ensemble") -> str:
+    """A program's optimized HLO for `mesh`'s chip, at 8,192 rows."""
+    program, args, by_row = _program(which, es)
+    mapped, specs = _sharded(program, args, by_row, mesh)
+    shapes = [jax.ShapeDtypeStruct(
+        (AOT_ROWS,) + np.shape(a)[1:] if r else np.shape(a),
+        np.asarray(a).dtype, sharding=NamedSharding(mesh, s))
+        for a, r, s in zip(args, by_row, specs)]
+    return jax.jit(mapped).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("which,boosting", [
+    ("ensemble", True), ("ensemble", False), ("chunk", True),
+    ("trials", False)], ids=["boosted", "bagged", "chunk", "trials"])
+def test_compiled_for_v5e_the_operand_is_outside_the_loop(one_chip_mesh,
+                                                          which, boosting):
+    hlo = _compiled_for(one_chip_mesh, _es(boosting, n_trees=3), which)
+    assert "tree.operand" in hlo and "tree.hist" in hlo   # metadata is there
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.hist"), \
+        "the check finds the loop and what runs in it"
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand") == []
+    # the loop over rounds (the forest's Poisson draw is a loop too)
+    carried = [ln.split(" while(")[0] for ln in hlo.splitlines()
+               if re.search(r"\swhile\(", ln)]
+    assert sum(f"bf16[{F * B},{AOT_ROWS}]" in c for c in carried) == 1, \
+        "the loop over rounds carries the one-hot as an operand"
+
+
+def test_the_check_sees_an_operand_that_was_sunk(one_chip_mesh, monkeypatch):
+    """The control: without the barrier libtpu 0.0.34 sinks the one-hot into
+    the loop, and `ops_in_loop_bodies` says so. Should a later compiler stop
+    sinking, this test fails and the barrier can be reconsidered."""
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    hlo = _compiled_for(one_chip_mesh, _es(True, n_trees=3))
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand")
+
+
+def test_ops_in_loop_bodies_follows_calls():
+    hlo = """HloModule m
+%fused (p: s32[4]) -> s32[4] {
+  %p = s32[4] parameter(0)
+  ROOT %a = s32[4] add(%p, %p), metadata={op_name="jit(f)/tree.operand/add"}
+}
+%body (t: (s32[], s32[4])) -> (s32[], s32[4]) {
+  %t = (s32[], s32[4]) parameter(0)
+  %x = s32[4] get-tuple-element(%t), index=1
+  %f = s32[4] conditional(%p, %x, %x), branch_computations={%cond, %fused}
+  ROOT %r = (s32[], s32[4]) tuple(%i, %f), metadata={op_name="jit(f)/while/body/tree.hist/t"}
+}
+%cond (t: (s32[], s32[4])) -> pred[] {
+  %t = (s32[], s32[4]) parameter(0)
+  ROOT %c = pred[] constant(false)
+}
+ENTRY %main (a: s32[4]) -> s32[4] {
+  %a = s32[4] parameter(0)
+  %o = s32[4] add(%a, %a), metadata={op_name="jit(f)/tree.operand/outside"}
+  %w = (s32[], s32[4]) while(%tup), condition=%cond, body=%body
+  ROOT %g = s32[4] get-tuple-element(%w), index=1
+}
+"""
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand") == ["%a"]
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.hist") == ["%r"]
+    assert tree_impl.ops_in_loop_bodies(hlo.replace("%", ""),
+                                        "tree.operand") == ["a"]
+
+
+# --------------------------------------------- (c) the barrier is the identity
+@pytest.mark.parametrize("boosting", [True, False], ids=["boosted", "bagged"])
+def test_fitted_packs_are_bit_equal_without_the_barrier(boosting, monkeypatch):
+    es = _es(boosting, n_trees=4, depth=3)
+    binned, y, mask, rng = _rows(512, seed=3)
+
+    def fit():
+        program = tree_impl._make_ensemble_program(es, 1, "xla", 0, (D,), 0)
+        mapped, _ = _sharded(program, (binned, y, mask, rng),
+                             (True, True, True, False), _cpu_mesh())
+        packs, base = jax.jit(mapped)(binned, y, mask, rng)
+        return np.asarray(packs), float(base)
+
+    with_barrier = fit()
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    without = fit()
+    assert with_barrier[0].shape == (4, 5, 15)
+    assert (with_barrier[0][:, 0] >= 0).any(), "the trees split"
+    np.testing.assert_array_equal(with_barrier[0], without[0])
+    assert with_barrier[1] == without[1]
