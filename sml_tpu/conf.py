@@ -132,12 +132,12 @@ _register("sml.compile.cacheDir", "", str,
           "whenever this key is set "
           "(parallel.dispatch.ensure_compile_cache)")
 _register("sml.split.sortMemoBytes", 1 << 30, int,
-          "Byte bound for randomSplit's pre-split sort memo (each entry "
-          "pins the source partition AND its sorted copy); entries for a "
-          "frame are also dropped by DataFrame.unpersist. Sized like the "
-          "sibling caches so one bench-scale frame's partitions fit — a "
-          "budget below one split's working set makes every later weight "
-          "cell re-sort (FIFO evicts the in-flight split's own entries)")
+          "Byte bound for randomSplit's pre-split sort memo (each entry is "
+          "the permutation that sorts one partition, 8 bytes a row; the "
+          "partition itself is not kept alive); entries for a frame are "
+          "also dropped by DataFrame.unpersist. A budget below one split's "
+          "working set makes every later weight cell re-sort (the in-flight "
+          "split's own entries are evicted)")
 _register("sml.obs.enabled", False, _to_bool,
           "Flight-recorder event bus (sml_tpu.obs): record typed engine "
           "events (spans, counters, dispatch decisions, cache traffic, "

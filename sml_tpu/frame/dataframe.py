@@ -630,11 +630,14 @@ class DataFrame:
                     u = rng.random(len(pdf))
                     mask = (u >= lo) & (u < hi)
                     return pdf[mask].reset_index(drop=True)
-                from .sampling import partition_uniforms, presplit_sort
-                pdf = presplit_sort(pdf)
+                from .sampling import partition_uniforms, presplit_order
+                order = presplit_order(pdf)
                 u = partition_uniforms(seed, ctx.partition_index, len(pdf))
                 mask = (u >= lo) & (u < hi)
-                return pdf[mask].reset_index(drop=True)
+                if order is None:
+                    return pdf[mask].reset_index(drop=True)
+                # row i of the SORTED partition is row order[i] of this one
+                return pdf.take(order[mask]).reset_index(drop=True)
 
             out = parent._derive(fn)
             out._op = "randomSplit"

@@ -32,7 +32,9 @@ Spark's UTF8 binary order for ASCII data.
 from __future__ import annotations
 
 import ctypes
+import functools
 import threading
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -182,22 +184,27 @@ def row_uniforms(seed: int, start: int, n: int) -> np.ndarray:
 
 
 # ------------------------------------------------------- pre-split local sort
-# id(source pdf) -> (source, sorted, cost_bytes). BYTE-bounded like the
-# repo's other memos (sml.split.sortMemoBytes): each entry strong-refs a
-# full partition AND its sorted copy, so a count-only bound could pin
-# multi-GB of pandas data for the process lifetime.
+# id(source pdf) -> (weak reference to the source, its sort order, bytes).
+# What is kept is the PERMUTATION that sorts a partition (8 bytes a row),
+# not a sorted copy of it, and the source is not kept alive: a cached
+# frame's partitions live as long as the frame does, anything else dies
+# with its materialization and takes its entry along. BYTE-bounded like
+# the repo's other memos (sml.split.sortMemoBytes), least recently used
+# first. (It held the source and a sorted copy, 230 bytes a row each for
+# the benchmark's table: a frame of 8 M rows never fitted the bound, and
+# every split of it sorted all of it again, 44 s a split: PERF.md §6.)
 _sort_memo: dict = {}
 _sort_memo_bytes: list = [0]
-_sort_lock = threading.Lock()
+# re-entrant: an entry's weak-reference callback takes it too, and a
+# source can die (and call back) while this thread holds it
+_sort_lock = threading.RLock()
 
 
-def _pdf_cost(pdf: pd.DataFrame) -> int:
-    """Approximate resident bytes (deep=True counts string payloads —
-    cheap next to the sort this memo amortizes)."""
-    try:
-        return int(pdf.memory_usage(index=True, deep=True).sum())
-    except Exception:
-        return int(pdf.shape[0] * max(pdf.shape[1], 1) * 8)
+def _forget_order(key: int, ref) -> None:
+    with _sort_lock:
+        hit = _sort_memo.get(key)
+        if hit is not None and hit[0] is ref:
+            _sort_memo_bytes[0] -= _sort_memo.pop(key)[2]
 
 
 def drop_sort_memo_for(parts) -> None:
@@ -212,22 +219,12 @@ def drop_sort_memo_for(parts) -> None:
             _sort_memo_bytes[0] -= _sort_memo.pop(k)[2]
 
 
-def presplit_sort(pdf: pd.DataFrame) -> pd.DataFrame:
-    """Dataset.randomSplit's per-partition local sort: every sortable
-    column ascending, in schema order, nulls first — making row order
-    deterministic regardless of upstream partition materialization.
+def _sort_order(pdf: pd.DataFrame) -> Optional[np.ndarray]:
+    """The permutation of Dataset.randomSplit's per-partition local sort:
+    every sortable column ascending, in schema order, nulls first, stable.
     Unsortable columns (vector/extension payloads, mixed objects) are
-    pruned from the sort order, as Spark prunes unsortable types."""
-    with _sort_lock:
-        hit = _sort_memo.get(id(pdf))
-        if hit is not None and hit[0] is pdf:
-            # LRU touch (dicts iterate in insertion order): a split's
-            # later weight cells re-hit its partitions, so eviction under
-            # byte pressure should fall on stale splits first
-            _sort_memo.pop(id(pdf))
-            _sort_memo[id(pdf)] = hit
-    if hit is not None and hit[0] is pdf:
-        return hit[1]
+    pruned from the sort order, as Spark prunes unsortable types; None
+    where nothing is left to sort by."""
     cols = []
     for c in pdf.columns:
         dt = pdf[c].dtype
@@ -235,39 +232,100 @@ def presplit_sort(pdf: pd.DataFrame) -> pd.DataFrame:
             cols.append(c)
         elif dt == object or "string" in str(dt) or "large_string" in str(dt):
             cols.append(c)
-    out = pdf
-    if cols:
+    keys = pdf[cols].reset_index(drop=True)   # its sorted index IS the order
+    while cols:
         try:
-            out = pdf.sort_values(cols, kind="stable", na_position="first",
-                                  ignore_index=True)
+            return _lexicographic_order(keys, cols)
         except Exception:
             # a column that passed the dtype screen but still won't sort
             # (mixed-type object payloads): drop offenders one at a time —
             # probing a head slice can miss a late mixed value
-            sortable = list(cols)
-            while sortable:
-                try:
-                    out = pdf.sort_values(sortable, kind="stable",
-                                          na_position="first",
-                                          ignore_index=True)
-                    break
-                except Exception:
-                    sortable.pop()
-            else:
-                out = pdf
-    # memoize per partition object: every weight cell of one randomSplit
-    # sorts the SAME partition — k cells must not pay k sorts. Strong ref
-    # to the source keeps its id valid. LRU within the byte budget; the
-    # NEWEST entry always stays (the split's remaining cells are about to
-    # hit it) even when it alone exceeds the budget.
+            cols.pop()
+    return None
+
+
+def _sorted_index(keys: pd.DataFrame, cols: list) -> np.ndarray:
+    return keys.sort_values(cols, kind="stable",
+                            na_position="first").index.to_numpy(copy=True)
+
+
+def _lexicographic_order(keys: pd.DataFrame, cols: list) -> np.ndarray:
+    """`_sorted_index(keys, cols)`, by way of the columns that decide it
+    where that is shorter."""
+    try:
+        order = _order_by_leading_columns(keys, cols)
+    except Exception:
+        order = None        # whatever it tripped on, the full sort decides
+    return _sorted_index(keys, cols) if order is None else order
+
+
+def _order_by_leading_columns(keys: pd.DataFrame,
+                              cols: list) -> Optional[np.ndarray]:
+    """A sort by every column factorizes every column, and most decide
+    nothing: once a leading run of columns tells the rows apart, the order
+    is that run's. So: the shortest run that tells a strided sample's rows
+    apart nearly as well as every column does, a sort by it, then the rows
+    that still tie on it with a neighbour (equal values, or both null)
+    sorted among themselves by every column. The result is the full
+    sort's, row for row; None where this way is not the shorter one. For
+    the benchmark's table 5 of 23 columns and a third of the time
+    (PERF.md §6, PR 28)."""
+    n = len(keys)
+    sample = keys.iloc[::max(1, n // 4096)]
+    enough = (~sample.duplicated(cols)).sum() - len(sample) // 16
+    lead = next((m for m in range(1, len(cols))
+                 if (~sample.duplicated(cols[:m])).sum() >= enough),
+                len(cols))
+    if lead == len(cols) or n < 2:
+        return None
+    order = _sorted_index(keys, cols[:lead])
+    same = np.ones(n - 1, dtype=bool)       # row i + 1 ties with row i
+    for c in cols[:lead]:
+        codes = pd.factorize(keys[c].take(order))[0]      # null: -1
+        same &= codes[1:] == codes[:-1]
+    if not same.any():
+        return order
+    tied = np.flatnonzero(np.r_[same, False] | np.r_[False, same])
+    if len(tied) > n // 4:
+        return None
+    # the tied rows hold the same places, group by group, as they do in
+    # the full sort: the leading columns are the first of every column
+    order[tied] = _sorted_index(keys.take(order[tied]), cols)
+    return order
+
+
+def presplit_order(pdf: pd.DataFrame) -> Optional[np.ndarray]:
+    """`_sort_order(pdf)`, memoized per partition OBJECT: every weight cell
+    of one randomSplit, and every later split of a cached frame, sorts the
+    SAME partitions — k cells and n seeds must not pay k x n sorts."""
+    key = id(pdf)
+    with _sort_lock:
+        hit = _sort_memo.get(key)
+        if hit is not None and hit[0]() is pdf:
+            # LRU touch (dicts iterate in insertion order)
+            _sort_memo[key] = _sort_memo.pop(key)
+            return hit[1]
+    order = _sort_order(pdf)
     from ..conf import GLOBAL_CONF
     budget = GLOBAL_CONF.getInt("sml.split.sortMemoBytes")
-    # an unsortable partition memoizes (pdf, pdf): charge the one object
-    cost = _pdf_cost(pdf) + (0 if out is pdf else _pdf_cost(out))
+    cost = 0 if order is None else int(order.nbytes)
+    ref = weakref.ref(pdf, functools.partial(_forget_order, key))
     with _sort_lock:
-        if id(pdf) not in _sort_memo:
-            _sort_memo[id(pdf)] = (pdf, out, cost)
-            _sort_memo_bytes[0] += cost
+        stale = _sort_memo.pop(key, None)
+        if stale is not None:
+            _sort_memo_bytes[0] -= stale[2]
+        _sort_memo[key] = (ref, order, cost)
+        _sort_memo_bytes[0] += cost
+        # the NEWEST entry always stays: the split's remaining cells are
+        # about to hit it
         while _sort_memo_bytes[0] > budget and len(_sort_memo) > 1:
             _sort_memo_bytes[0] -= _sort_memo.pop(next(iter(_sort_memo)))[2]
-    return out
+    return order
+
+
+def presplit_sort(pdf: pd.DataFrame) -> pd.DataFrame:
+    """The partition in its pre-split order (`presplit_order`)."""
+    order = presplit_order(pdf)
+    if order is None:
+        return pdf
+    return pdf.take(order).reset_index(drop=True)

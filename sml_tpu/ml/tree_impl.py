@@ -1874,6 +1874,12 @@ def stage_tree_data(X: np.ndarray, y: np.ndarray, max_bins: int,
     else:
         binned, binning = make_bins(X, y, max_bins, categorical)
     binned_dev, mask_dev, n_true = stage_sharded(binned)
+    # the layout this fit runs on, as staged: devices that hold a shard of
+    # the bin matrix, and the rows (padding included) on the fullest
+    shards = binned_dev.addressable_shards
+    PROFILER.count("fit.shards", len({s.device for s in shards}))
+    PROFILER.count("fit.shard_rows_max",
+                   max(s.data.shape[0] for s in shards))
     return StagedData(binned=binned, binned_dev=binned_dev, mask_dev=mask_dev,
                       y=y, n_true=n_true, binning=binning,
                       n_padded=binned_dev.shape[0])

@@ -90,6 +90,11 @@ COUNTERS = {
                           # derives distinct-programs-per-leg from these)
     "tree.fit_dispatch",  # device launches of tree-fit programs (the
                           # grid-fused CV dispatch-count contract)
+    # the layout a tree fit staged its bin matrix on
+    # (tree_impl.stage_tree_data): fit.shards += devices that hold a shard,
+    # fit.shard_rows_max += rows (padding included) on the fullest. Read as
+    # deltas over a window's fits: what `num_workers` came to on the mesh
+    "fit.shards", "fit.shard_rows_max",
     # prewarm manifest (parallel/prewarm.py): recorded signatures,
     # replayed/failed first-dispatches, pool-size attribution
     "prewarm.*",

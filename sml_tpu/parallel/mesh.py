@@ -145,6 +145,31 @@ def submeshes(k: int, mesh: Optional[Mesh] = None) -> list:
     return [cached[i % groups] for i in range(k)]
 
 
+def worker_mesh(num_workers: Optional[int] = None,
+                mesh: Optional[Mesh] = None) -> Mesh:
+    """The mesh a fit that names its `num_workers` runs on: `num_workers`
+    data shards, as sparkdl's `XgboostRegressor(num_workers=k)` names the
+    task slots its table is spread over (`SML/ML 11 - XGBoost.py:55-72`).
+
+    None is the active mesh, as is a `num_workers` equal to the active
+    mesh's row shards (the SAME object, so the per-mesh program and
+    staging caches hit); another `num_workers` that divides the devices is
+    the first of the `submeshes` that wide. Anything else is refused: a
+    layout the host cannot give is never replaced by one it can."""
+    mesh = mesh or get_mesh()
+    if num_workers is None:
+        return mesh
+    k, n = int(num_workers), mesh_device_count(mesh)
+    if k == data_width(mesh):
+        return mesh
+    if k < 1 or n % k:
+        raise ValueError(
+            f"num_workers={num_workers} data shards cannot be cut from the "
+            f"{n} device(s) of the active mesh {dict(mesh.shape)}: give a "
+            f"divisor of {n}, or None for the mesh as it is")
+    return submeshes(n // k, mesh)[0]
+
+
 _trial_mesh_cache: dict = {}
 
 

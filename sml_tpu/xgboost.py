@@ -6,8 +6,17 @@ MLlib Pipeline (`SML/ML 11 - XGBoost.py:55-72`). There the gradient/histogram
 aggregation is Rabit allreduce in C++; here the SAME second-order histogram
 boosting runs as the jitted mesh program in `sml_tpu.ml.tree_impl`, whose
 per-level reduction is one psum over ICI — `tpu_hist`, the `gpu_hist`
-equivalent named in SURVEY §2.2 P9. `num_workers` maps to mesh data-shards;
-`use_gpu`/`device` is accepted for surface parity ('tpu' is the only engine).
+equivalent named in SURVEY §2.2 P9. `use_gpu`/`device` is accepted for
+surface parity ('tpu' is the only engine).
+
+`num_workers` is the layout of the fit, as the notebook uses it to name the
+cluster's task slots: the table's rows are sharded over that many devices
+(`parallel.mesh.worker_mesh`), bin edges, merged histograms, split
+selection and the trees replicated. None fits on the active mesh as it
+stands; the active mesh's own width is the active mesh; a divisor of its
+devices is the first submesh that wide; any other number raises a
+`ValueError` that names it and the device count, so a layout the host
+cannot give is never quietly replaced by one it can.
 
 Quantized shared-histogram engine (the GPU boosting design of
 arXiv:1806.11248 mapped to the mesh): features quantize ONCE into a compact
@@ -27,6 +36,7 @@ from typing import Optional
 from .ml._tree_models import (_EnsembleSpec, _TreeClassificationModel,
                               _TreeEstimatorBase, _TreeRegressionModel,
                               _categorical_slots, _fit_ensemble)
+from .parallel import mesh as meshlib
 
 
 class _XgboostParams:
@@ -45,7 +55,9 @@ class _XgboostParams:
         self._declareParam("random_state", default=0, doc="seed")
         self._declareParam("missing", default=float("nan"), doc="value treated as missing")
         self._declareParam("num_workers", default=None,
-                           doc="data shards (defaults to mesh size)")
+                           doc="data shards of the fit's mesh (None: the "
+                               "active mesh; else a width the devices "
+                               "divide into, or the fit raises)")
         self._declareParam("use_gpu", default=False, doc="accepted for surface parity")
         self._declareParam("device", default="tpu", doc="compute engine")
         self._declareParam("tree_method", default="tpu_hist", doc="histogram engine")
@@ -71,6 +83,13 @@ class _XgboostBase(_TreeEstimatorBase, _XgboostParams):
                 raise TypeError(f"unexpected param {k!r}")
 
     def _fit(self, df):
+        # the layout first: a num_workers the host cannot give raises
+        # before a row is featurized
+        with meshlib.use_mesh_local(
+                meshlib.worker_mesh(self.getOrDefault("num_workers"))):
+            return self._fit_on_mesh(df)
+
+    def _fit_on_mesh(self, df):
         X, y, cat = self._extract(df)
         spec = _fit_ensemble(
             X, y, categorical=cat,

@@ -187,6 +187,45 @@ def test_presplit_sort_orders_rows_nulls_first():
     assert list(out["x"].iloc[1:]) == [1.0, 2.0, 3.0]
 
 
+def _split_frames():
+    rng = np.random.default_rng(28)
+    n = 30_000
+    base = pd.DataFrame({
+        "flag": rng.choice(["t", "f"], n), "k": rng.integers(0, 19, n) * 1.0,
+        "hood": rng.choice(["Mission", "Castro", "Marina", None], n),
+        "lat": rng.random(n), "beds": rng.integers(0, 5, n) * 1.0,
+        "price": np.round(rng.normal(100, 30, n))})
+    again = base.iloc[rng.integers(0, n, 2000)].copy()
+    again.iloc[:1000, again.columns.get_loc("price")] += 1.0
+    few_ties = pd.concat([base, again]).sample(frac=1.0, random_state=1)
+    nulls = base.copy()
+    nulls.loc[rng.random(n) < 0.02, "lat"] = np.nan
+    nulls.loc[rng.random(n) < 0.02, "k"] = np.nan
+    runs = pd.DataFrame({"a": np.repeat(np.arange(n // 4), 4) * 1.0,
+                         "b": rng.normal(size=n)})[::-1]
+    return {"told apart by five of six columns": base,
+            "a few rows tie on them": few_ties,
+            "nulls among them": nulls,
+            "every row a duplicate": base[["flag", "k"]],
+            "ties the sample cannot see": runs,
+            "strings as StringDtype": base.astype({"flag": "string",
+                                                   "hood": "string"}),
+            "five rows": base.iloc[:5], "no row": base.iloc[:0]}
+
+
+@pytest.mark.parametrize("case", list(_split_frames()))
+def test_presplit_order_is_the_sort_by_every_column(case):
+    """`_sort_order` sorts by the leading columns that tell the rows apart
+    and settles what still ties by every column: the permutation is the
+    full stable sort's, nulls first, row for row."""
+    from sml_tpu.frame import sampling
+    pdf = _split_frames()[case]
+    want = pdf.reset_index(drop=True).sort_values(
+        list(pdf.columns), kind="stable", na_position="first").index.to_numpy()
+    got = sampling._sort_order(pdf)
+    assert np.array_equal(got, want)
+
+
 def test_legacy_sampler_conf(spark):
     from sml_tpu.conf import GLOBAL_CONF
     pdf = pd.DataFrame({"a": np.arange(4000, dtype=float)})
@@ -200,3 +239,55 @@ def test_legacy_sampler_conf(spark):
     finally:
         GLOBAL_CONF.set("sml.split.sampler", "spark")
     assert legacy_rows != spark_rows  # distinct documented mechanisms
+
+
+def test_presplit_memo_keeps_the_order_not_the_partition(spark):
+    """The memo holds 8 bytes a row (the permutation), whatever the
+    partition's width, and does not keep a partition alive: a cached
+    frame's later splits sort nothing again, and a partition that dies
+    takes its entry along. (It held the source and a sorted copy, so a
+    frame wider than the bound re-sorted at every split.)"""
+    import gc
+
+    from sml_tpu.frame import sampling
+    rng = np.random.default_rng(3)
+    n = 20_000
+    pdf = pd.DataFrame({"a": rng.normal(size=n),
+                        "s": rng.choice(["x", "y", "z"], n),
+                        **{f"w{i}": rng.normal(size=n) for i in range(20)}})
+    df = spark.createDataFrame(pdf).repartition(4).cache()
+    df.count()
+    sorts = []
+    real = sampling._sort_order
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sampling, "_sort_order",
+                      lambda p: sorts.append(len(p)) or real(p))
+        first = df.randomSplit([0.8, 0.2], seed=1)
+        rows = [part.count() for part in first]
+        assert sum(rows) == n and len(sorts) == 4
+        for seed in (2, 3, 4):
+            again = df.randomSplit([0.8, 0.2], seed=seed)
+            assert sum(part.count() for part in again) == n
+        assert len(sorts) == 4, "a cached frame's partitions sort once"
+    mine = [k for k, (ref, _, _) in sampling._sort_memo.items()
+            if any(ref() is p for p in df._parts)]
+    assert len(mine) == 4
+    assert sum(sampling._sort_memo[k][2] for k in mine) == 8 * n
+    # the split is what the sorted partition would give, row for row
+    part = df._parts[0]
+    u = sampling.partition_uniforms(1, 0, len(part))
+    want = sampling.presplit_sort(part)[u < 0.8].reset_index(drop=True)
+    pd.testing.assert_frame_equal(first[0]._parts[0], want)
+    # an entry dies with its partition
+    loose = pdf.iloc[:100].reset_index(drop=True)
+    key = id(loose)
+    assert sampling.presplit_order(loose) is not None
+    assert key in sampling._sort_memo
+    before = sampling._sort_memo_bytes[0]
+    del loose
+    gc.collect()
+    assert key not in sampling._sort_memo
+    # (the collection may have taken other dead frames' entries along)
+    assert sampling._sort_memo_bytes[0] <= before - 800
+    df.unpersist()
+    assert not any(k in sampling._sort_memo for k in mine)

@@ -153,6 +153,88 @@ def test_xgboost_regressor_in_pipeline(friedman_df):
     assert r2 > 0.8
 
 
+def _layout_of_a_fit(df, estimator):
+    """(devices that held a shard, rows on the fullest, the mesh the tree
+    program was dispatched under, the model) of one fit, from the
+    recorder's counters."""
+    from sml_tpu import obs
+    from sml_tpu.conf import GLOBAL_CONF
+    from sml_tpu.ml import tree_impl
+    from sml_tpu.parallel import mesh as meshlib
+    seen = []
+    real = tree_impl.fit_ensemble_on_device
+
+    def spy(*args, **kwargs):
+        seen.append(meshlib.get_mesh())
+        return real(*args, **kwargs)
+
+    was = GLOBAL_CONF.get("sml.obs.enabled")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tree_impl, "fit_ensemble_on_device", spy)
+        GLOBAL_CONF.set("sml.obs.enabled", True)
+        obs.reset()
+        try:
+            model = estimator.fit(df)
+            counters = obs.RECORDER.counters()
+        finally:
+            GLOBAL_CONF.set("sml.obs.enabled", was)
+            obs.reset()
+    assert len(seen) == 1
+    return (counters["fit.shards"], counters["fit.shard_rows_max"],
+            seen[0], model)
+
+
+@pytest.mark.parametrize("workers,shards", [(None, 8), (8, 8), (4, 4),
+                                            (2, 2), (1, 1)])
+def test_num_workers_is_the_layout_of_the_fit(friedman_df, workers, shards):
+    """ML 11's `num_workers`: None and the active mesh's own width fit on
+    the active mesh ITSELF (program caches hit), a divisor of its devices
+    on the first submesh that wide; the counters say what was staged."""
+    from sml_tpu.parallel import mesh as meshlib
+    df = _assembled(friedman_df)
+    active = meshlib.get_mesh()
+    assert meshlib.data_width(active) == 8
+    xgb = XgboostRegressor(n_estimators=3, max_depth=3, max_bins=16,
+                           num_workers=workers)
+    held, fullest, mesh, model = _layout_of_a_fit(df, xgb)
+    assert held == shards
+    assert fullest == meshlib.bucket_rows(3000, shards) // shards
+    assert meshlib.data_width(mesh) == shards
+    assert (mesh is active) == (shards == 8)
+    if shards < 8:
+        assert mesh is meshlib.submeshes(8 // shards)[0]
+        assert [d.id for d in mesh.devices.flat] == list(range(shards))
+    assert meshlib.get_mesh() is active      # bound for the fit alone
+    assert model.getOrDefault("num_workers") == workers
+    pred = model.transform(df).toPandas()["prediction"]
+    assert np.isfinite(pred).all()
+
+
+@pytest.mark.parametrize("workers", [3, 16, 0, -4])
+def test_num_workers_the_host_cannot_give_is_refused(friedman_df, workers):
+    from sml_tpu.xgboost import XgboostClassifier
+    df = _assembled(friedman_df)
+    for cls in (XgboostRegressor, XgboostClassifier):
+        with pytest.raises(ValueError) as e:
+            cls(n_estimators=2, max_depth=2, num_workers=workers).fit(df)
+        assert f"num_workers={workers}" in str(e.value)
+        assert "8 device(s)" in str(e.value)
+
+
+def test_num_workers_on_a_one_device_mesh(friedman_df):
+    """A four-way layout on a host that has one chip raises; it is not
+    fitted on the one."""
+    from sml_tpu.parallel import mesh as meshlib
+    df = _assembled(friedman_df)
+    with meshlib.use_mesh(meshlib.build_mesh(1)) as one:
+        with pytest.raises(ValueError, match=r"num_workers=4 .* 1 device"):
+            XgboostRegressor(n_estimators=2, num_workers=4).fit(df)
+        held, _, mesh, _ = _layout_of_a_fit(
+            df, XgboostRegressor(n_estimators=2, max_depth=2, max_bins=16,
+                                 num_workers=1))
+        assert held == 1 and mesh is one
+
+
 def test_native_binning_matches_numpy():
     """native/binning.cc vs the NumPy searchsorted path: identical bins,
     including NaN/±inf (→ bin 0) and categorical remap slots."""
