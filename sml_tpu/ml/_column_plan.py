@@ -1,0 +1,299 @@
+"""Fit-time column plan: one job a raw column, the columns side by side.
+
+`featurizer._try_fast_fit` recognises the course's chain
+[Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator] and
+hands this module one JOB for every raw column the chain reads. A job
+visits its column once and does everything the column needs: the stage's
+fit statistic (the Imputer's surrogate, the StringIndexer's labels) AND
+the column's values for the feature block. Jobs share nothing, so they run
+side by side on one pool of host threads; the results do not depend on
+how many workers there are or on the order the jobs finish in.
+
+No two threads store into the same cache lines: a job writes its column
+CONTIGUOUSLY, one row of a (jobs, n) float32 scratch, and the row-major
+(n, d) block the estimators take is interleaved from the scratch in
+blocks of rows, each block one task of the same pool, with the
+assembler's finite check on the block just written (ten threads each
+storing every tenth float of the block move it through memory ten times:
+PERF.md section 6, PR 29).
+
+Every bit of the result is the sequential code's (`Imputer._fit`,
+`StringIndexer._fit`, `CompiledFeaturizer.transform_with_mask`): a column
+is float64 until its write, and where a value can only be had from the
+pandas call (a mean's summation order, a mode's tie) or the column's
+storage has no path that releases the interpreter lock (object strings, a
+numeric column fed to an indexer), the job makes today's call on its
+column, correct and no faster (`legacy` in its result).
+
+Jobs open no spans and bump no counters: the caller's thread does both,
+so `span_s.*` stay wall seconds of one thread.
+"""
+
+from __future__ import annotations
+
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
+from typing import List, NamedTuple, Optional
+
+import numpy as np
+import pandas as pd
+
+from .feature import imputer_surrogate, indexer_labels, order_labels
+from .featurizer import _IndexSource, _numeric
+
+#: rows of the block one interleave task writes: 65,536 x 10 float32 is
+#: 2.6 MB, read from ten contiguous runs and stored once
+_BLOCK_ROWS = 65536
+
+#: below this many rows the jobs run inline on the calling thread: waking
+#: the pool costs what the jobs of such a table do. The course's chain
+#: (3 strings, 7 medians) on the chip tool's one-chip host, 13 cores, inline
+#: against pool, median ms of 30: 16,000 rows 3.9 / 7.6, 48,000 8.7 / 9.5,
+#: 64,000 11.1 / 10.4, 96,000 16.7 / 10.7, 256,000 41.8 / 12.8 (PERF.md
+#: section 6, PR 29)
+_INLINE_ROWS = 65536
+
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _cores() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # not Linux
+        return os.cpu_count() or 1
+
+
+def _executor() -> ThreadPoolExecutor:
+    """THE pool of the process, sized from the cores the process may run
+    on (as `native/binning.cc` sizes itself): `TpuTrials` and
+    `CrossValidator(parallelism>1)` fit pipelines from several threads at
+    once, and a pool a fit would multiply the threads. A job never waits
+    on another job, so fits sharing the pool cannot deadlock."""
+    global _pool
+    if _pool is None:
+        with _pool_lock:
+            if _pool is None:
+                _pool = ThreadPoolExecutor(max_workers=_cores(),
+                                           thread_name_prefix="sml-column")
+    return _pool
+
+
+class JobResult(NamedTuple):
+    surrogate: Optional[float] = None      # an imputed column's fill
+    labels: Optional[List[str]] = None     # an indexed column's labels
+    invalid: Optional[np.ndarray] = None   # rows an indexer "skip" drops
+    legacy: bool = False                   # ran today's per-column code
+
+
+class NumericJob:
+    """A numeric raw column: one extraction to float64, the Imputer's
+    surrogate from it, the fill, the float32 write.
+
+    `blockwise` says the assembler slot sits in a run of two or more plain
+    numeric inputs: `transform_with_mask` extracts such a run as one block
+    and turns what is not finite into the fill, or into NaN where nothing
+    imputes the column, while a lone unimputed column keeps its +-inf."""
+
+    def __init__(self, col: str, strategy: Optional[str] = None):
+        self.col = col
+        self.strategy = strategy
+        self.blockwise = False
+        self.row: Optional[int] = None   # its row of the scratch, if assembled
+
+    def run(self, pdf: pd.DataFrame, out: Optional[np.ndarray]) -> JobResult:
+        col = pdf[self.col]
+        fast = isinstance(col.dtype, np.dtype) and col.dtype.kind in "fiu"
+        v = None
+        if fast:
+            v = col.to_numpy(np.float64)   # a view of a float64 column
+        elif out is not None:
+            v = self._extract_as_today(col)
+        fill = None
+        if self.strategy == "median" and fast:
+            # Series.median of the non-NaN values is np.median of them
+            nan = np.isnan(v)
+            vals = v[~nan] if nan.any() else v
+            fill = float(np.median(vals)) if len(vals) else 0.0
+        elif self.strategy is not None:
+            fill = imputer_surrogate(col, self.strategy)
+        if out is not None:
+            out[:] = v   # the float32 cast of the block assignment
+            if fill is not None or self.blockwise:
+                bad = ~np.isfinite(v)
+                if bad.any():
+                    out[bad] = np.nan if fill is None else fill
+        return JobResult(surrogate=fill, legacy=not fast)
+
+    def _extract_as_today(self, col: pd.Series) -> np.ndarray:
+        if self.blockwise:   # extract_numeric_block's, a column at a time
+            try:
+                return col.to_numpy(np.float64, na_value=np.nan)
+            except (TypeError, ValueError):
+                pass
+        return _numeric(col)
+
+
+def _arrow_strings(col: pd.Series):
+    """The column's Arrow chunks when it is Arrow-backed STRING storage
+    (as `_IndexSource._arrow_codes` decides it), else None."""
+    pa_arr = getattr(getattr(col, "array", None), "_pa_array", None)
+    if pa_arr is None:
+        return None
+    import pyarrow as pa
+    t = pa_arr.type
+    if pa.types.is_string(t) or pa.types.is_large_string(t) \
+            or pa.types.is_string_view(t):
+        return pa_arr
+    return None
+
+
+class StringJob:
+    """A StringIndexer input column: ONE encoding of the column (codes and
+    distinct values), the labels from the counts of the codes, the codes
+    carried through the rank table into the scratch row, and the column's
+    own invalid-row handling (`error` raises the stage's message, `skip`
+    returns the mask, `keep` maps to len(labels)). An unassembled column
+    only has its labels made, as the sequential fast fit never resolved
+    it either."""
+
+    def __init__(self, col: str, order: str, invalid: str):
+        self.col = col
+        self.order = order
+        self.invalid = invalid
+        self.row: Optional[int] = None
+
+    def run(self, pdf: pd.DataFrame, out: Optional[np.ndarray]) -> JobResult:
+        col = pdf[self.col]
+        pa_arr = _arrow_strings(col)
+        if pa_arr is None:
+            return self._run_as_today(pdf, col, out)
+        enc = pa_arr.dictionary_encode().unify_dictionaries()
+        values = enc.chunk(0).dictionary.to_pylist() if enc.num_chunks else []
+        k = len(values)
+        # nulls take code k: one table lookup resolves them with the rest
+        codes = [c.indices.fill_null(k).to_numpy(zero_copy_only=False)
+                 for c in enc.chunks]
+        counts = np.zeros(k + 1, dtype=np.int64)
+        for c in codes:
+            counts += np.bincount(c, minlength=k + 1)
+        labels = order_labels(values, counts[:k], self.order)
+        if out is None:
+            return JobResult(labels=labels)
+        # at fit every value that is not null has a label: invalid == null
+        invalid = None
+        if counts[k]:
+            if self.invalid == "error":
+                first = np.concatenate([c == k for c in codes]).argmax()
+                raise ValueError(
+                    f"Unseen label {col.iloc[first]!r} in column "
+                    f"{self.col!r} (handleInvalid='error')")
+            if self.invalid == "skip":
+                invalid = np.concatenate([c == k for c in codes])
+        rank = {lab: i for i, lab in enumerate(labels)}
+        table = np.empty(k + 1, dtype=np.float32)
+        table[:k] = [rank[v] for v in values]
+        table[k] = len(labels) if self.invalid == "keep" else np.nan
+        lo = 0
+        for c in codes:
+            np.take(table, c, out=out[lo:lo + len(c)], mode="clip")
+            lo += len(c)
+        return JobResult(labels=labels, invalid=invalid)
+
+    def _run_as_today(self, pdf, col, out) -> JobResult:
+        labels = indexer_labels(col, self.order)
+        if out is None:
+            return JobResult(labels=labels, legacy=True)
+        src = _IndexSource(self.col, np.asarray(labels, dtype=object),
+                           self.invalid)
+        drop = np.zeros(len(pdf), dtype=bool)
+        out[:] = src.resolve(pdf, drop)
+        return JobResult(labels=labels, legacy=True,
+                         invalid=drop if drop.any() else None)
+
+
+class Plan:
+    """The jobs of one fit, run (each keeps its `result`), the scratch they
+    wrote (row i is the assembler's input i), and `block()` to interleave
+    it."""
+
+    def __init__(self, pdf: pd.DataFrame, jobs: list, write: bool = True):
+        self.rows = len(pdf)
+        self.inline = self.rows < _INLINE_ROWS
+        self.workers = 1 if self.inline else _cores()
+        self.jobs = jobs
+        assembled = sum(j.row is not None for j in jobs)
+        self.scratch = scratch = np.empty(
+            (assembled, self.rows), dtype=np.float32) if write else None
+        # write=False: the statistics alone, no row of any column
+        results = self._run(
+            [lambda j=j: j.run(pdf, None if scratch is None or j.row is None
+                               else scratch[j.row]) for j in jobs])
+        for j, r in zip(jobs, results):
+            j.result = r
+
+    def _run(self, tasks: list) -> list:
+        """Every task's result, in the tasks' order. All of them end
+        before an error is raised, on the caller's thread: the first in
+        the tasks' order, as the sequential pass would have met it."""
+        if self.inline:
+            return [t() for t in tasks]
+        pool = _executor()
+        futures = [pool.submit(t) for t in tasks]
+        wait(futures)
+        return [f.result() for f in futures]
+
+    def block(self, onehot: List[Optional[int]], check_finite: bool):
+        """(X, keep) as `transform_with_mask` returns them: the row-major
+        float32 block, rows an indexer skips dropped (keep None where none
+        is), the assembler's handleInvalid="error" raised. `onehot[i]` is
+        None where row i of the scratch is one column of the block, else
+        the width its codes are expanded to."""
+        n, scratch = self.rows, self.scratch
+        widths = [1 if w is None else w for w in onehot]
+        los = np.cumsum([0] + widths)
+        masks = [j.result.invalid for j in self.jobs
+                 if j.result.invalid is not None]
+        drop = np.logical_or.reduce(masks) if masks else None
+        out = np.empty((n, los[-1]), dtype=np.float32)
+        plain = all(w is None for w in onehot)
+
+        def task(r0: int) -> bool:
+            r1 = min(r0 + _BLOCK_ROWS, n)
+            blk = out[r0:r1]
+            if plain:
+                blk[...] = scratch[:, r0:r1].T
+            else:
+                for lo, w, row in zip(los, onehot, scratch):
+                    _write_slot(blk, lo, w, row[r0:r1])
+            if not check_finite or np.isfinite(blk).all():
+                return True
+            return drop is not None \
+                and bool(np.isfinite(blk[~drop[r0:r1]]).all())
+
+        if not all(self._run([lambda r0=r0: task(r0)
+                              for r0 in range(0, n, _BLOCK_ROWS)])):
+            raise ValueError(
+                "VectorAssembler found NaN/null in assembled features; set "
+                "handleInvalid='skip' or impute first")
+        if drop is None:
+            return out, None
+        keep = ~drop
+        return out[keep], keep
+
+
+def _write_slot(blk: np.ndarray, lo: int, onehot: Optional[int],
+                src: np.ndarray) -> None:
+    if onehot is None:
+        blk[:, lo] = src
+        return
+    # _OneHotSource.write on a block of rows: a code past the width (the
+    # dropped last) is a row of zeros, an invalid one a row of NaN
+    hi = lo + onehot
+    na = ~np.isfinite(src)
+    ok = ~na & (src >= 0) & (src < onehot)
+    blk[:, lo:hi] = 0.0
+    blk[np.nonzero(ok)[0], lo + src[ok].astype(np.intp)] = 1.0
+    if na.any():
+        blk[na, lo:hi] = np.nan

@@ -123,6 +123,30 @@ class VectorAssembler(Transformer):
 
 
 # --------------------------------------------------------------------------
+def order_labels(labels, counts, order: str) -> List[str]:
+    """Distinct `labels` (with their `counts`, read by the frequency
+    orders only) in a `stringOrderType`'s order. Frequency ties break
+    count descending then label ascending (MLlib), and the two reversed
+    orders reverse the whole list."""
+    if order.startswith("frequency"):
+        lab = [k for k, _ in sorted(zip(labels, counts),
+                                    key=lambda kv: (-kv[1], kv[0]))]
+    else:
+        lab = sorted(labels)
+    return lab[::-1] if order in ("frequencyAsc", "alphabetDesc") else lab
+
+
+def indexer_labels(col: pd.Series, order: str) -> List[str]:
+    """One column's StringIndexer labels, whatever its storage (the
+    column plan's jobs count an Arrow-backed column's codes instead:
+    `_column_plan.StringJob`)."""
+    s = col.dropna().astype(str)
+    if order.startswith("frequency"):
+        counts = s.value_counts()
+        return order_labels(counts.index, counts.to_numpy(), order)
+    return order_labels(s.unique(), None, order)
+
+
 class StringIndexer(Estimator):
     """Map string categories → double indices ordered by descending frequency
     (ties broken lexically), matching MLlib's default `frequencyDesc`."""
@@ -153,21 +177,7 @@ class StringIndexer(Estimator):
         in_cols, out_cols = self._in_out()
         order = self.getOrDefault("stringOrderType")
         pdf = df.toPandas()
-        labels: List[List[str]] = []
-        for c in in_cols:
-            s = pdf[c].dropna().astype(str)
-            if order.startswith("frequency"):
-                counts = s.value_counts()
-                # stable order: count desc then label asc (MLlib tie-break)
-                items = sorted(counts.items(), key=lambda kv: (-kv[1], kv[0]))
-                lab = [k for k, _ in items]
-                if order == "frequencyAsc":
-                    lab = lab[::-1]
-            else:
-                lab = sorted(s.unique())
-                if order == "alphabetDesc":
-                    lab = lab[::-1]
-            labels.append(lab)
+        labels = [indexer_labels(pdf[c], order) for c in in_cols]
         m = StringIndexerModel(labels=labels)
         m._inherit_params(self)
         return m
@@ -333,6 +343,19 @@ class OneHotEncoderModel(Model):
 
 
 # --------------------------------------------------------------------------
+def imputer_surrogate(col: pd.Series, strategy: str) -> float:
+    """One column's Imputer fill: NaN (and what does not parse) is
+    missing, +-inf is a value, an empty column fills with 0."""
+    s = pd.to_numeric(col, errors="coerce").dropna()
+    if not len(s):
+        return 0.0
+    if strategy == "median":
+        return float(s.median())
+    if strategy == "mode":
+        return float(s.mode().iloc[0])
+    return float(s.mean())
+
+
 class Imputer(Estimator):
     """Fill numeric nulls with per-column median/mean/mode
     (`ML 01:251-256` uses strategy="median")."""
@@ -356,15 +379,7 @@ class Imputer(Estimator):
         in_cols = list(self.getOrDefault("inputCols"))
         strategy = self.getOrDefault("strategy")
         pdf = df.toPandas()
-        surrogates = {}
-        for c in in_cols:
-            s = pd.to_numeric(pdf[c], errors="coerce").dropna()
-            if strategy == "median":
-                surrogates[c] = float(s.median()) if len(s) else 0.0
-            elif strategy == "mode":
-                surrogates[c] = float(s.mode().iloc[0]) if len(s) else 0.0
-            else:
-                surrogates[c] = float(s.mean()) if len(s) else 0.0
+        surrogates = {c: imputer_surrogate(pdf[c], strategy) for c in in_cols}
         m = ImputerModel(surrogates=surrogates)
         m._inherit_params(self)
         return m

@@ -21,11 +21,13 @@ VectorAssembler(handleInvalid in ("error", "keep")).
 
 from __future__ import annotations
 
+import itertools
 from typing import List, NamedTuple, Optional
 
 import numpy as np
 import pandas as pd
 
+from ..obs._recorder import RECORDER as _OBS
 from ..utils.profiler import PROFILER
 
 
@@ -486,20 +488,35 @@ class CompiledFeaturizer:
 
 def try_fast_fit(stages, raw_pdf, make_frame):
     """Whole-pipeline fused FIT: for the standard course chain
-    [Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator],
-    fit every prep stage from the RAW pandas (their inputs are raw columns),
-    derive OneHotEncoder sizes from the indexer's labels (`max(idx)+1 ==
-    len(labels)` when labels come from the same data), reconstruct the
-    assembler's slot metadata analytically, and hand the estimator a frame
-    carrying the one-pass assembled block — NO transform chain ever
-    materializes. Returns (fitted_prep_stages, estimator_input_frame) or
-    None (caller falls back to the generic sequential fit, which is always
-    correct); the caller runs the estimator fit itself so estimator errors
-    propagate unmasked.
+    [Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator]
+    every prep stage reads RAW columns, so the chain becomes a column plan
+    (`_column_plan`): one job a raw column makes the stage's fit statistic
+    and the column's values of the feature block in one visit, the jobs
+    side by side. The fitted stage models are made from the jobs' results
+    (OneHotEncoder sizes from the indexer's labels: `max(idx)+1 ==
+    len(labels)` when labels come from the same data), the assembler's
+    slot metadata is reconstructed analytically, and the estimator gets a
+    frame carrying the assembled block: NO transform chain ever
+    materializes. Returns (fitted_prep_stages, estimator_input_frame), or
+    None where the plan declines (counter `featurize.plan.declined`, the
+    reason on its event): the caller falls back to the generic sequential
+    fit, which is always correct. The caller runs the estimator fit itself
+    so estimator errors propagate unmasked.
     """
     if len(stages) < 2 or raw_pdf is None:
-        return None
-    return _try_fast_fit(stages, raw_pdf, make_frame)
+        return _decline("no chain of stages over a frame")
+    try:
+        return _try_fast_fit(stages, raw_pdf, make_frame)
+    except Exception as e:
+        _decline(f"a job raised {type(e).__name__}")
+        raise
+
+
+def _decline(reason: str) -> None:
+    PROFILER.count("featurize.plan.declined")
+    _OBS.emit("featurize", "featurize.plan.declined",
+              args={"reason": reason})
+    return None
 
 
 def produced_columns(prep_stages) -> set:
@@ -546,102 +563,161 @@ def prep_overwrites_label(prep_stages, est) -> bool:
 
 
 def _try_fast_fit(stages, raw_pdf, make_frame):
+    from . import _column_plan as cp
     from .base import Estimator
-    from .feature import (Imputer, OneHotEncoder, OneHotEncoderModel,
-                          StringIndexer, VectorAssembler)
+    from .feature import (Imputer, ImputerModel, OneHotEncoder,
+                          OneHotEncoderModel, StringIndexer,
+                          StringIndexerModel, VectorAssembler)
     *prep, est = stages
     if not isinstance(est, Estimator):
-        return None
+        return _decline("the last stage is no estimator")
     if not (est.hasParam("featuresCol") and est.hasParam("labelCol")):
-        return None
+        return _decline("the estimator reads no featuresCol and labelCol")
     if not prep or not isinstance(prep[-1], VectorAssembler):
-        return None
+        return _decline("no VectorAssembler before the estimator")
     assembler = prep[-1]
     if est.getOrDefault("featuresCol") != assembler.getOrDefault("outputCol"):
-        return None
+        return _decline("the estimator does not read the assembler's output")
     if est.getOrDefault("labelCol") not in raw_pdf.columns:
-        return None
+        return _decline("labelCol is no raw column")
     if prep_overwrites_label(prep[:-1], est):
-        return None  # a prep stage rewrites the label: raw labels are wrong
+        return _decline("a prep stage rewrites the label")
+    invalid = assembler.getOrDefault("handleInvalid")
+    if invalid not in ("error", "keep"):
+        return _decline("assembler handleInvalid='skip'")  # by finiteness
 
-    raw_frame = make_frame(raw_pdf)
-    fitted = []
-    attrs = {}          # column -> ml attrs (categorical cardinalities)
-    idx_labels = {}     # indexer output col -> label list
-    ohe_widths = {}     # ohe output col -> vector width
+    # one job a (stage, raw column). `produced`: an Imputer's or indexer's
+    # output column -> its job; `encoded`: an encoder's output column ->
+    # (the indexer's job, dropLast); `plans`: a prep stage with the jobs
+    # its model is made from
+    jobs, produced, encoded, plans = [], {}, {}, []
     for st in prep[:-1]:
+        if isinstance(st, OneHotEncoder):
+            ins, outs = st._in_out()
+            made = [produced.get(c) for c in ins]
+            if not all(isinstance(j, cp.StringJob) for j in made):
+                return _decline("an encoder over a column no indexer made")
+            drop_last = bool(st.getOrDefault("dropLast"))
+            encoded.update((oc, (j, drop_last)) for oc, j in zip(outs, made))
+            plans.append((st, made))
+            continue
         if isinstance(st, Imputer):
             ins = list(st.getOrDefault("inputCols") or [])
-            if any(c not in raw_pdf.columns for c in ins):
-                return None
-            with PROFILER.span("fit.prep", stages=1):
-                fitted.append(st.fit(raw_frame))
+            outs = list(st.getOrDefault("outputCols") or ins)
+            made = [cp.NumericJob(c, st.getOrDefault("strategy"))
+                    for c in ins]
         elif isinstance(st, StringIndexer):
             ins, outs = st._in_out()
-            if any(c not in raw_pdf.columns for c in ins):
-                return None
-            with PROFILER.span("fit.prep", stages=1):
-                m = st.fit(raw_frame)
-            extra = 1 if st.getOrDefault("handleInvalid") == "keep" else 0
-            for oc, ls in zip(outs, m.labelsArray):
-                idx_labels[oc] = ls
-                attrs[oc] = {"categorical": len(ls) + extra}
-            fitted.append(m)
-        elif isinstance(st, OneHotEncoder):
-            ins, outs = st._in_out()
-            if any(c not in idx_labels for c in ins):
-                return None  # OHE over a non-indexer column: generic path
-            sizes = [len(idx_labels[c]) for c in ins]
-            m = OneHotEncoderModel(categorySizes=sizes)
-            m._inherit_params(st)
-            drop_last = bool(m.getOrDefault("dropLast"))
-            for oc, size in zip(outs, sizes):
-                ohe_widths[oc] = size - 1 if drop_last else size
-            fitted.append(m)
+            made = [cp.StringJob(c, st.getOrDefault("stringOrderType"),
+                                 st.getOrDefault("handleInvalid"))
+                    for c in ins]
         else:
-            return None
-    fitted.append(assembler)
+            return _decline(f"a {type(st).__name__} stage outside the chain")
+        if any(c not in raw_pdf.columns or c in produced or c in encoded
+               for c in ins):
+            return _decline(f"a {type(st).__name__} over a produced column")
+        produced.update(zip(outs, made))
+        jobs += made
+        plans.append((st, made))
 
-    feat = CompiledFeaturizer.from_stages(fitted[:-1], assembler)
-    if feat is None:
-        return None
-    # the assembler's slot metadata (VectorAssembler._transform computes
-    # this from column attrs + row peeks; here widths are known statically)
-    slots, pos = {}, 0
+    # the assembler's inputs in its order, each a row of the scratch
+    sources = []   # (job, None | the encoder's dropLast)
     for c in assembler.getOrDefault("inputCols"):
-        if c in attrs and "categorical" in attrs[c]:
-            slots[pos] = int(attrs[c]["categorical"])
-            pos += 1
-        elif c in ohe_widths:
-            pos += ohe_widths[c]
-        else:
-            pos += 1
-    out_col = assembler.getOrDefault("outputCol")
+        job, drop_last = encoded.get(c) or (produced.get(c), None)
+        if job is None:
+            if c not in raw_pdf.columns:
+                return _decline("an assembler input nothing makes")
+            job = cp.NumericJob(c)
+            jobs.append(job)
+        elif job.row is not None:
+            return _decline("a column assembled twice")
+        job.row = len(sources)
+        sources.append((job, drop_last))
+    # `transform_with_mask` extracts a run of plain numeric inputs as one
+    # block (see NumericJob.blockwise)
+    numeric = [isinstance(job, cp.NumericJob) for job, _ in sources]
+    for i, (job, _) in enumerate(sources):
+        if numeric[i]:
+            job.blockwise = any(numeric[max(i - 1, 0):i] + numeric[i + 1:i + 2])
+    # an error is raised in the assembler's order, as the one pass met it
+    jobs.sort(key=lambda j: len(jobs) if j.row is None else j.row)
 
     # huge linear fits skip X entirely: the compact block stages n*(p+k)
     # words and expands one-hots on-chip (CompactParts; the 8M-row scale
-    # path). Gated by size so course-scale fits keep the materialized
-    # block and its golden-pinned numerics bit-for-bit.
+    # path, which keeps its own extraction). Gated by size so course-scale
+    # fits keep the materialized block and its golden-pinned numerics
+    # bit-for-bit. The width follows the labels; the inputs bound it below
+    n = len(raw_pdf)
+    compact_bytes = None
     if type(est).__name__ in ("LinearRegression", "LogisticRegression"):
         from ..conf import GLOBAL_CONF
-        if len(raw_pdf) * feat.width * 4 \
-                >= GLOBAL_CONF.getInt("sml.linear.compactBytes"):
-            parts = feat.compact_parts(raw_pdf)
-            if parts is not None:
-                shim = make_frame(raw_pdf)
-                shim._ml_attrs = dict(attrs)
-                shim._ml_attrs[out_col] = {"slots": slots,
-                                           "numFeatures": pos}
-                shim._featurized_compact = {out_col: (parts, raw_pdf)}
-                return fitted, shim
+        compact_bytes = GLOBAL_CONF.getInt("sml.linear.compactBytes")
+    surely_compact = compact_bytes is not None \
+        and n * len(sources) * 4 >= compact_bytes
 
-    with PROFILER.span("fit.featurize", rows=len(raw_pdf)) as note:
-        X, keep = feat.transform_with_mask(raw_pdf)
-        note["bytes"] = int(X.nbytes)
+    X = keep = None
+    with PROFILER.span("fit.featurize", rows=n, columns=len(jobs)) as note:
+        plan = cp.Plan(raw_pdf, jobs, write=not surely_compact)
+        note["workers"] = plan.workers
+        # an encoder's width follows its indexer's labels
+        onehot = [None if drop_last is None
+                  else len(job.result.labels) - int(drop_last)
+                  for job, drop_last in sources]
+        los = list(itertools.accumulate(
+            (1 if w is None else w for w in onehot), initial=0))
+        width = los[-1]
+        compact = compact_bytes is not None \
+            and n * width * 4 >= compact_bytes
+        if not compact:
+            X, keep = plan.block(onehot, check_finite=invalid == "error")
+            note["bytes"] = int(X.nbytes)
+
+    with PROFILER.span("fit.prep", stages=len(plans)):
+        fitted = []
+        attrs = {}          # column -> ml attrs (categorical cardinalities)
+        for st, made in plans:
+            if isinstance(st, Imputer):
+                m = ImputerModel(surrogates={
+                    j.col: j.result.surrogate for j in made})
+            elif isinstance(st, StringIndexer):
+                m = StringIndexerModel(labels=[j.result.labels for j in made])
+                extra = 1 if st.getOrDefault("handleInvalid") == "keep" else 0
+                for oc, ls in zip(st._in_out()[1], m.labelsArray):
+                    attrs[oc] = {"categorical": len(ls) + extra}
+            else:
+                m = OneHotEncoderModel(
+                    categorySizes=[len(j.result.labels) for j in made])
+            fitted.append(m._inherit_params(st))
+        fitted.append(assembler)
+
+    parts = None
+    if compact:
+        feat = CompiledFeaturizer.from_stages(fitted[:-1], assembler)
+        parts = feat.compact_parts(raw_pdf) if feat is not None else None
+        if parts is None:   # a NaN the expanded block would carry
+            if plan.scratch is None:
+                return _decline("the compact form declined, no block made")
+            with PROFILER.span("fit.featurize", rows=n) as note:
+                X, keep = plan.block(onehot, check_finite=invalid == "error")
+                note["bytes"] = int(X.nbytes)
+    PROFILER.count("featurize.plan.fits")
+    legacy = sum(j.result.legacy for j in jobs)
+    if legacy:
+        PROFILER.count("featurize.plan.columns_legacy", legacy)
+
+    # the assembler's slot metadata (VectorAssembler._transform computes
+    # this from column attrs + row peeks; here widths are known statically)
+    out_col = assembler.getOrDefault("outputCol")
     shim = make_frame(raw_pdf)
     shim._ml_attrs = dict(attrs)
-    shim._ml_attrs[out_col] = {"slots": slots, "numFeatures": pos}
-    shim._featurized = {out_col: (X, keep, raw_pdf)}
+    shim._ml_attrs[out_col] = {
+        "slots": {lo: attrs[c]["categorical"] for lo, c in zip(
+            los, assembler.getOrDefault("inputCols")) if c in attrs},
+        "numFeatures": width}
+    if parts is not None:
+        shim._featurized_compact = {out_col: (parts, raw_pdf)}
+    else:
+        shim._featurized = {out_col: (X, keep, raw_pdf)}
     # the ESTIMATOR fit happens in the caller, OUTSIDE any fallback guard:
     # its errors (bad hyperparameters, device OOM) must propagate, not
     # trigger a silent re-fit through the generic path

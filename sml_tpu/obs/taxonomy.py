@@ -95,6 +95,12 @@ COUNTERS = {
     # fit.shard_rows_max += rows (padding included) on the fullest. Read as
     # deltas over a window's fits: what `num_workers` came to on the mesh
     "fit.shards", "fit.shard_rows_max",
+    # the fit-time column plan (ml/_column_plan.py, featurizer.try_fast_fit):
+    # fits that took the plan / fits that fell through to the generic
+    # sequential fit (the reason rides the event of the same name) /
+    # columns that ran the sequential per-column code inside their job
+    "featurize.plan.fits", "featurize.plan.declined",
+    "featurize.plan.columns_legacy",
     # prewarm manifest (parallel/prewarm.py): recorded signatures,
     # replayed/failed first-dispatches, pool-size attribution
     "prewarm.*",
@@ -207,6 +213,8 @@ GAUGES = {
 
 EVENTS = {
     "dispatch.*",         # dispatch.host / dispatch.device
+    "featurize.plan.declined",  # why a fit did not take the column plan
+                          # (args: reason), beside the counter of that name
     "cache.*",            # cache.evict / ...
     "collective.*",       # collective.psum / ...
     "compile.*",          # compile.trace / compile.cache_dir
