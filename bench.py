@@ -1263,113 +1263,9 @@ def multihost_main(rows: int) -> None:
     }))
 
 
-# ---------------------------------------------------------- kernelbench leg
+# ------------------------------------------- kernelbench inference autotuner
 KERNELBENCH_ROWS = 60_000
 KERNELBENCH_TREES = 8
-
-
-def run_kernelbench(rows: int = KERNELBENCH_ROWS) -> dict:
-    """`--kernelbench`: the fused-kernel sweep (ISSUE 9) — the same
-    bootstrap-forest fit across a maxBins × maxDepth grid, timed once
-    under `sml.tree.kernel=xla` (the one-hot dot + cumsum HLO chain) and
-    once under `=pallas` (the fused `native/hist_kernel.py` bin-accumulate
-    + split-scan launches), best-of-3 warm fits per leg with the compile
-    paid in a warmup fit.
-
-    Per leg the sidecar records both walls, the ratio, the per-path
-    `kernel.*` counter deltas captured from the warmup trace
-    (pallas_launch/interpret are trace-time statics, like collective.*),
-    and a bit-parity check of the two paths' predictions. On non-TPU
-    backends the pallas path runs in INTERPRET mode — those numbers
-    measure emulation overhead, not kernel speed (the `interpret` flag in
-    the block says which kind of run this is); `obs/regress.py` judges
-    `kernel.fallback` growth across committed sidecars as a regression
-    either way. Results merge into the bench sidecar as the `kernel`
-    block, rendered by scripts/render_perf.py."""
-    import jax
-
-    from sml_tpu import obs
-    from sml_tpu.conf import GLOBAL_CONF
-    from sml_tpu.ml._tree_models import _fit_ensemble
-
-    rng = np.random.default_rng(9)
-    F = 10
-    X = rng.normal(size=(rows, F)).astype(np.float32)
-    y = (X[:, 0] * 2 - X[:, 1] ** 2 + 0.3 * X[:, 3]
-         + rng.normal(0, 0.3, rows)).astype(np.float32)
-    probe = X[:4096]
-
-    prev_obs = GLOBAL_CONF.get("sml.obs.enabled")
-    prev_kernel = GLOBAL_CONF.get("sml.tree.kernel")
-    GLOBAL_CONF.set("sml.obs.enabled", True)
-    legs = []
-    try:
-        for max_bins in (32, 128):
-            for max_depth in (4, 6):
-                entry = {"max_bins": max_bins, "max_depth": max_depth}
-                counters = {}
-                preds = {}
-                for path in ("xla", "pallas"):
-                    GLOBAL_CONF.set("sml.tree.kernel", path)
-
-                    def fit():
-                        return _fit_ensemble(
-                            X, y, categorical={}, max_depth=max_depth,
-                            max_bins=max_bins, min_instances=1,
-                            min_info_gain=0.0, n_trees=KERNELBENCH_TREES,
-                            feature_k=None, bootstrap=True, subsample=1.0,
-                            seed=7, loss="squared")
-
-                    obs.reset()
-                    spec = fit()  # warmup: compile + trace-time counters
-                    snap = obs.RECORDER.counters()
-                    for k, v in snap.items():
-                        if k.startswith(("kernel.", "tree.fit_dispatch")):
-                            counters[f"{path}:{k}"] = float(v)
-                    best = float("inf")
-                    for _ in range(3):
-                        t0 = time.perf_counter()
-                        fit()
-                        best = min(best, time.perf_counter() - t0)
-                    entry[f"{path}_s"] = round(best, 4)
-                    preds[path] = spec.predict_margin(probe)
-                entry["pallas_vs_xla"] = round(
-                    entry["xla_s"] / entry["pallas_s"], 3)
-                entry["parity_ok"] = bool(
-                    np.array_equal(preds["xla"], preds["pallas"]))
-                entry["kernel_counters"] = {
-                    "kernel.pallas_launch":
-                        counters.get("pallas:kernel.pallas_launch", 0.0),
-                    "kernel.interpret":
-                        counters.get("pallas:kernel.interpret", 0.0),
-                    "kernel.fallback":
-                        counters.get("pallas:kernel.fallback", 0.0)
-                        + counters.get("xla:kernel.fallback", 0.0),
-                }
-                legs.append(entry)
-                print(f"  kernel b{max_bins} d{max_depth}: "
-                      f"xla {entry['xla_s']:.3f}s, pallas "
-                      f"{entry['pallas_s']:.3f}s "
-                      f"({entry['pallas_vs_xla']}x, parity="
-                      f"{entry['parity_ok']}, launches "
-                      f"{entry['kernel_counters']['kernel.pallas_launch']:.0f})",
-                      file=sys.stderr)
-    finally:
-        GLOBAL_CONF.set("sml.obs.enabled", bool(prev_obs))
-        GLOBAL_CONF.set("sml.tree.kernel", prev_kernel)
-    return {
-        "rows": rows, "n_features": F, "n_trees": KERNELBENCH_TREES,
-        "backend": jax.default_backend(),
-        "interpret": jax.default_backend() != "tpu",
-        "note": "best-of-3 warm fits per (maxBins, maxDepth, path); "
-                "kernel.* counters are per-TRACE statics from the warmup "
-                "fit; on non-TPU backends the pallas path runs in "
-                "interpret mode (parity, not speed — see docs/KERNELS.md)",
-        "legs": legs,
-    }
-
-
-# ------------------------------------------- kernelbench inference autotuner
 KERNELBENCH_INFER_SHAPES = ((32, 4), (128, 6))   # (maxBins, maxDepth)
 KERNELBENCH_INFER_BATCHES = (8192, 49152)        # scoring batch widths
 KERNELBENCH_INFER_BLOCKS = (512, 2048, 8192)     # pallas block_rows sweep
@@ -1393,8 +1289,7 @@ def run_kernelbench_infer(rows: int = KERNELBENCH_ROWS) -> dict:
     the round trip: with the sweep conf restored, the live resolver
     returns each point's persisted winner from the manifest alone, and
     the `infer_kernel` prewarm rebuilder replays one entry clean.
-    Results merge into the sidecar as the `kernel_infer` block —
-    separate from the fit sweep's `kernel` block, so the two coexist —
+    Results merge into the sidecar as the `kernel_infer` block,
     rendered by scripts/render_perf.py; `obs/regress.py` flags a
     vanished block, fallback growth, or a lost beats-default/replay
     proof."""
@@ -1535,32 +1430,24 @@ def run_kernelbench_infer(rows: int = KERNELBENCH_ROWS) -> dict:
 
 
 def kernelbench_main(rows: int) -> None:
-    """Run the fit-kernel sweep AND the inference autotuner standalone,
-    merge their blocks into the bench sidecar — `kernel` (fit) and
-    `kernel_infer` (scoring) are SEPARATE keys so neither run clobbers
-    the other — and print the short headline JSON last."""
-    block = run_kernelbench(rows)
+    """Run the inference autotuner standalone, merge its block into the
+    bench sidecar as `kernel_infer` (the sidecar's `kernel` block is the
+    recorded sweep of the fit kernels that PR 30 deleted: data, left as
+    it is) and print the short headline JSON last."""
     infer_block = run_kernelbench_infer(rows)
     doc = {}
     if os.path.exists(LEGS_FILE):
         with open(LEGS_FILE) as f:
             doc = json.load(f)
-    doc["kernel"] = block
     doc["kernel_infer"] = infer_block
     with open(LEGS_FILE, "w") as f:
         json.dump(doc, f, indent=1)
-    best = max(e["pallas_vs_xla"] for e in block["legs"])
     print(json.dumps({
-        "metric": "fused-kernel sweep (pallas vs xla)",
-        "value": best,
-        "unit": "x vs xla path (best leg)",
-        "backend": block["backend"],
-        "interpret": block["interpret"],
-        "parity_ok": all(e["parity_ok"] for e in block["legs"])
-        and all(e["parity_ok"] for e in infer_block["legs"]),
-        "fallbacks": sum(e["kernel_counters"]["kernel.fallback"]
-                         for e in block["legs"])
-        + infer_block["fallbacks"],
+        "metric": "traversal-kernel autotune sweep",
+        "backend": infer_block["backend"],
+        "interpret": infer_block["interpret"],
+        "parity_ok": all(e["parity_ok"] for e in infer_block["legs"]),
+        "fallbacks": infer_block["fallbacks"],
         "infer_tuned_beats_default": infer_block["tuned_beats_default"],
         "infer_replay_ok": infer_block["replay_ok"],
         "legs_file": "bench_legs.json",
@@ -3229,12 +3116,11 @@ if __name__ == "__main__":
     parser.add_argument("--multihost-rows", type=int, default=MULTIHOST_ROWS,
                         help="row count for the --multihost leg")
     parser.add_argument("--kernelbench", action="store_true",
-                        help="run ONLY the fused-kernel sweep (maxBins × "
-                             "maxDepth, sml.tree.kernel=pallas vs =xla, "
-                             "best-of-3 warm fits) and merge the `kernel` "
-                             "block into the bench sidecar; on non-TPU "
-                             "backends the pallas path runs in interpret "
-                             "mode (parity, not speed)")
+                        help="run ONLY the traversal-kernel autotune "
+                             "sweep and merge the `kernel_infer` block "
+                             "into the bench sidecar; on non-TPU backends "
+                             "the pallas path runs in interpret mode "
+                             "(parity, not speed)")
     parser.add_argument("--kernelbench-rows", type=int,
                         default=KERNELBENCH_ROWS,
                         help="row count for the --kernelbench leg")

@@ -150,7 +150,7 @@ def main() -> int:
     GLOBAL_CONF.set("sml.obs.enabled", True)
     obs.reset()
     modes = {k: GLOBAL_CONF.get(k) for k in
-             ("sml.dispatch.mode", "sml.tree.kernel", "sml.infer.kernel")}
+             ("sml.dispatch.mode", "sml.infer.kernel")}
     print(f"conf: {modes}")
 
     failures = []
@@ -311,8 +311,7 @@ def main() -> int:
     check("every audited dispatch on route `device`",
           bool(audit) and routes == ["device"],
           f"{len(audit)} rows, routes {routes}, reasons {reasons}")
-    for name in ("serve.host_routed", "serve.shed", "kernel.fallback",
-                 "infer.kernel.fallback"):
+    for name in ("serve.host_routed", "serve.shed", "infer.kernel.fallback"):
         check(f"{name} == 0", c.get(name, 0.0) == 0.0, str(c.get(name, 0.0)))
     if on_tpu:
         check("kernel.interpret == 0", c.get("kernel.interpret", 0.0) == 0.0,
@@ -331,22 +330,19 @@ def main() -> int:
     # (sml.tree.roundsPerDispatch = 0): `tree_impl._ensemble_compiled`
     fit_progs = [(k, v) for k, v in tree_impl._ensemble_cache.items()
                  if id(mesh) in k]
-    fit_kernels = sorted({k[-2] for k, _ in fit_progs})
     score_kernel = inference.kernel_report()["kernel"]
-    check("one fit program, built by one kernel", len(fit_progs) == 1
-          and len(fit_kernels) == 1, f"{len(fit_progs)} programs")
-    print(f"  kernel that built the trees: {fit_kernels} "
-          f"(kernel.pallas_launch traced "
-          f"{c.get('kernel.pallas_launch', 0.0):.0f}x); kernel that scored: "
-          f"{score_kernel} (resolutions: pallas "
+    check("one fit program", len(fit_progs) == 1, f"{len(fit_progs)} programs")
+    print(f"  kernel that scored: {score_kernel} (kernel.pallas_launch "
+          f"traced {c.get('kernel.pallas_launch', 0.0):.0f}x; "
+          f"resolutions: pallas "
           f"{c.get('infer.kernel.pallas', 0.0):.0f}, xla "
           f"{c.get('infer.kernel.xla', 0.0):.0f})")
     check("scoring resolved to one kernel throughout",
           (c.get("infer.kernel.pallas", 0.0) == 0.0)
           != (c.get("infer.kernel.xla", 0.0) == 0.0))
     if on_tpu and not rehearsal:
-        check("auto resolved as recorded for TPU (fit xla, score pallas)",
-              fit_kernels == ["xla"] and score_kernel == "pallas")
+        check("auto resolved as recorded for TPU (score pallas)",
+              score_kernel == "pallas")
 
     bins = _staging.bin_cache_arrays()
     train_bins = max(bins, key=lambda a: a.shape[0])
@@ -410,7 +406,7 @@ def main() -> int:
         "device": device, "rehearsal": rehearsal, "rows": rows,
         "versions": versions, "conf": modes,
         "rmse_xgb": rmse, "golden_rmse_xgb": golden,
-        "fit_kernel": fit_kernels, "score_kernel": score_kernel,
+        "score_kernel": score_kernel,
         "pallas_vs_xla_max_abs_diff": parity,
         "seconds_to_first_result": {
             "fit": round(fit_first_s, 3), "eval": round(eval_first_s, 3),

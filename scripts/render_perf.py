@@ -487,7 +487,9 @@ def render(sidecar: dict) -> str:
             "(the interpret-mode contract); `launches` is the per-trace "
             "pallas_call count (2 kernels × levels); any `fallbacks` "
             "growth across committed sidecars is flagged by "
-            "`scripts/bench_diff.py`.")
+            "`scripts/bench_diff.py`. The fit kernels were deleted in "
+            "PR 30 ([KERNELS.md](KERNELS.md)): this is "
+            "the recorded run, kept as data.")
     ki = sidecar.get("kernel_infer")
     if ki:
         lines.append("")

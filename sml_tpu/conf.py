@@ -80,24 +80,6 @@ _register("sml.mesh.hostGroups", 0, int,
           "on a real multi-host slice, else 1); N>0 = N virtual host "
           "groups partitioning the flat device set (the single-machine "
           "testing story for the multi-host code path)")
-_register("sml.tree.kernel", "auto", str,
-          "Histogram-build + split-scan implementation for tree fits: "
-          "'xla' = the one-hot dot + cumsum HLO chain (the pre-kernel "
-          "path, kept verbatim); 'pallas' = the fused "
-          "sml_tpu/native/hist_kernel.py Pallas kernels (bin-accumulate "
-          "straight from the compact bin cache, in-register gain scan; "
-          "runs in interpret mode on non-TPU backends — the tier-1 "
-          "bit-parity testing story) and RAISES where they cannot launch; "
-          "'auto' = xla everywhere today: neither fit kernel compiles "
-          "for TPU v5e as written (native/hist_kernel.AUTO_ON_TPU), and "
-          "auto never emulates off-TPU. See docs/KERNELS.md")
-_register("sml.tree.kernelBlockRows", 4096, int,
-          "Row-block size of the pallas bin-accumulate kernel's grid on "
-          "hardware (bounds the VMEM one-hot tile to ~blockRows*F*bins "
-          "elements; actual block is the largest divisor of the per-chip "
-          "padded rows at or under this). Interpret mode always runs ONE "
-          "block so kernel math is op-for-op the XLA path's "
-          "(bit-parity)")
 _register("sml.split.sampler", "spark", str,
           "randomSplit sampler: 'spark' = draw-for-draw Spark parity "
           "(per-partition determinism sort + XORShiftRandom Bernoulli "

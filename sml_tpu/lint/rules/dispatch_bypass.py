@@ -18,7 +18,7 @@ so kernels live only in the sanctioned `sml_tpu/native/` module
 Also flagged: direct invocation of the traversal kernel entry,
 `forest_traverse(...)` / `traverse_kernel.forest_traverse(...)`, outside
 the `score_block` dispatch glue (`ml/inference.py`'s
-`_forest_margin_path`) — mirroring the fit-kernel fence. A bypassing
+`_forest_margin_path`). A bypassing
 call skips `resolve_infer_kernel`, so the VMEM demotion guard, the
 autotuned-spec lookup, and the `infer.kernel.*` counters never see the
 launch.
@@ -51,7 +51,7 @@ ALLOWLIST: Dict[str, Dict[str, str]] = {
     "sml_tpu/native/": {
         # form-scoped entry: blesses ONLY pallas_call launches (counted
         # via kernel.pallas_launch/kernel.interpret and governed by
-        # tree_impl._kernel_choice's fallback ladder — docs/KERNELS.md);
+        # inference.resolve_infer_kernel's fallback ladder — docs/KERNELS.md);
         # a bare jax.jit added under native/ still flags like anywhere
         "form:pallas_call": "THE sanctioned custom-kernel module: every "
                             "pallas_call here is counted and "
@@ -178,7 +178,7 @@ def check(project: Project) -> List[Violation]:
                     f"site) or add an allowlist entry with a reason"))
                 return
             fix = ("move the kernel into sml_tpu/native/ (the sanctioned "
-                   "kernel module behind tree_impl._kernel_choice)"
+                   "kernel directory, behind a resolver that counts it)"
                    if "pallas_call" in label else
                    "compile through ml._staging.data_parallel/"
                    "cached_data_parallel")

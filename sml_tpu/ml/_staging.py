@@ -378,10 +378,8 @@ def transient_hbm(pool: str, nbytes: int):
     """Account a dispatch's dominant TRANSIENT device working set in the
     HBM ledger for the duration of the call (alloc on entry, free on
     exit) — live/peak visibility for program-internal buffers the staging
-    caches never own. The tree fit paths charge the XLA path's fit-long
-    one-hot resident (`hist_onehot`) through this; the pallas kernel path
-    charges zero, so the ledger shows the bytes the kernel keeps out of
-    HBM. No-ops on nbytes <= 0."""
+    caches never own. The tree fit paths charge the dispatch-long one-hot
+    resident (`hist_onehot`) through this. No-ops on nbytes <= 0."""
     if nbytes <= 0:
         yield
         return

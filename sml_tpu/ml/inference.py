@@ -100,22 +100,6 @@ def _kernel_fallback() -> None:
     _KERNEL_STATE["fallbacks"] += 1
 
 
-def _infer_kernel_choice() -> str:
-    """Resolve `sml.infer.kernel` to the concrete scoring path ("pallas"
-    / "xla") for the ACTIVE mesh — the same resolution as the fit side's
-    `tree_impl._kernel_choice` (`hist_kernel.resolve_mode`); the one
-    fallback counts `infer.kernel.fallback`."""
-    from ..conf import GLOBAL_CONF
-    from ..native import hist_kernel as _hk, traverse_kernel as _tk
-    from .tree_impl import _mesh_platform
-    kernel, fell_back = _hk.resolve_mode(
-        "sml.infer.kernel", GLOBAL_CONF.get("sml.infer.kernel"),
-        _mesh_platform(), _tk.AUTO_ON_TPU)
-    if fell_back:
-        _kernel_fallback()
-    return kernel
-
-
 def infer_spec_key(n_trees: int, depth: int, n_feat: int, n_bins: int,
                    n_rows: int) -> dict:
     """The autotuner's lookup key: (model shape, maxBins, batch width).
@@ -182,6 +166,8 @@ def resolve_infer_kernel(n_trees: int, depth: int, n_nodes: int,
     pair keys the program cache and the prewarm signature, so a change
     compiles fresh."""
     from ..conf import GLOBAL_CONF
+    from ..native import traverse_kernel as _tk
+    from .tree_impl import _mesh_platform
     if GLOBAL_CONF.getBool("sml.infer.autotune"):
         from ..parallel import prewarm as _prewarm
         key = infer_spec_key(n_trees, depth, n_feat, n_bins, n_rows)
@@ -191,9 +177,7 @@ def resolve_infer_kernel(n_trees: int, depth: int, n_nodes: int,
             block_rows = int(spec.get("block_rows", 0))
             tuned = True
             if kernel == "pallas":
-                from ..native import hist_kernel as _hk
-                from .tree_impl import _mesh_platform
-                if _hk.probe(interpret=_mesh_platform() != "tpu"):
+                if _tk.probe(interpret=_mesh_platform() != "tpu"):
                     _kernel_fallback()
                     kernel, block_rows, tuned = "xla", 0, False
                 else:
@@ -208,7 +192,12 @@ def resolve_infer_kernel(n_trees: int, depth: int, n_nodes: int,
                         kernel, block_rows, tuned = "xla", 0, False
             _note_spec(kernel, block_rows, tuned=tuned)
             return kernel, block_rows, tuned
-    kernel = _infer_kernel_choice()
+    # `sml.infer.kernel` for the ACTIVE mesh; `auto` on a TPU whose
+    # toolchain probe fails is the one fallback, and it is counted
+    kernel, fell_back = _tk.resolve_mode(
+        GLOBAL_CONF.get("sml.infer.kernel"), _mesh_platform())
+    if fell_back:
+        _kernel_fallback()
     if kernel != "pallas":
         _note_spec("xla", 0, tuned=False)
         return "xla", 0, False

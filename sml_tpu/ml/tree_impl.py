@@ -285,12 +285,12 @@ _platform_memo: Dict[int, tuple] = {}
 
 def _mesh_platform(mesh=None) -> str:
     """The active mesh's device platform, memoized per mesh identity:
-    `_hist_dtype` and `_kernel_choice` both run inside every fit setup,
-    and walking `mesh.devices.flat` allocates a fresh device list per
-    call. Mesh identity keys the memo (a new/rebuilt mesh re-probes);
-    conf is deliberately NOT part of the memo — knobs like
-    `sml.tree.kernel` are read fresh by their own resolvers on top of
-    the memoized platform, so a conf change takes effect immediately."""
+    `_hist_dtype` and the scoring kernel's resolver run inside every fit
+    and scoring setup, and walking `mesh.devices.flat` allocates a fresh
+    device list per call. Mesh identity keys the memo (a new/rebuilt
+    mesh re-probes); conf is deliberately NOT part of the memo — knobs
+    like `sml.infer.kernel` are read fresh by their own resolvers on top
+    of the memoized platform, so a conf change takes effect immediately."""
     mesh = mesh or meshlib.get_mesh()
     key = id(mesh)
     hit = _platform_memo.get(key)
@@ -305,62 +305,6 @@ def _hist_dtype():
     """bf16 histogram operands on TPU (exact one-hot, f32 accumulation on
     the MXU); f32 elsewhere — XLA:CPU has no bf16xbf16=f32 dot."""
     return jnp.bfloat16 if _mesh_platform() == "tpu" else jnp.float32
-
-
-def _kernel_choice() -> str:
-    """Resolve `sml.tree.kernel` to the concrete build path ("pallas" /
-    "xla") for the ACTIVE mesh (`hist_kernel.resolve_mode`) — the
-    resolved value is part of every tree-program cache key and rides the
-    prewarm manifest so replay rebuilds the same executable. The one
-    fallback (`auto` on a TPU whose toolchain probe fails) counts
-    `kernel.fallback`."""
-    from ..conf import GLOBAL_CONF
-    from ..native import hist_kernel as _hk
-    kernel, fell_back = _hk.resolve_mode(
-        "sml.tree.kernel", GLOBAL_CONF.get("sml.tree.kernel"),
-        _mesh_platform(), _hk.AUTO_ON_TPU)
-    if fell_back:
-        PROFILER.count("kernel.fallback")
-    return kernel
-
-
-#: compiled split_scan holds the whole per-level (F, B, width, 3) f32
-#: histogram as ONE un-gridded VMEM block whose minor dimension of 3 pads
-#: to 128 lanes; past this budget it cannot compile (Mosaic's scoped
-#: VMEM limit on v5e is 16 MiB, shared with the operands)
-_SCAN_VMEM_BUDGET = 8 << 20
-
-
-def _kernel_for(spec: TreeSpec) -> str:
-    """Per-fit kernel resolution: `_kernel_choice` plus a STATIC shape
-    guard for the compiled path — the split-scan kernel takes the whole
-    widest-level histogram (F · bins · 2^(depth-1) rows of 3 f32, each
-    padded to a 128-lane tile) as one VMEM block, so specs past
-    `_SCAN_VMEM_BUDGET` demote to xla with a `kernel.fallback` count
-    instead of failing to compile mid-trace. Interpret mode has no VMEM
-    and never demotes."""
-    kernel = _kernel_choice()
-    if kernel == "pallas" and _mesh_platform() == "tpu":
-        width = 2 ** max(spec.max_depth - 1, 0)
-        from ..native.hist_kernel import LANES
-        rows = spec.n_features * spec.n_bins * (-(-width // 8) * 8)
-        if rows * LANES * 4 > _SCAN_VMEM_BUDGET:
-            PROFILER.count("kernel.fallback")
-            return "xla"
-    return kernel
-
-
-def _kernel_block_rows(kernel: str) -> int:
-    """Resolved `sml.tree.kernelBlockRows` for pallas programs (0 on the
-    XLA path, which has no block scheme). Read ONCE per program build and
-    carried in every tree program cache key AND the prewarm manifest —
-    toggling the knob must compile a fresh executable, not silently
-    replay one traced under the old block scheme (the same contract
-    `sml.tpu.donate` and `sml.tree.histSubtraction` already honor)."""
-    if kernel != "pallas":
-        return 0
-    from ..conf import GLOBAL_CONF
-    return GLOBAL_CONF.getInt("sml.tree.kernelBlockRows")
 
 
 def _hist_subtract() -> bool:
@@ -388,8 +332,7 @@ def _hier_ici(mesh=None) -> int:
 
 
 def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
-                       subtract: bool = True, kernel: str = "xla",
-                       block_rows: int = 0, axes=None, hier_ici: int = 0):
+                       subtract: bool = True, axes=None, hier_ici: int = 0):
     """Pure per-chip tree-build fn (called inside shard_map): one level-wise
     pass, histograms as one-hot dots, psum merges. Returns stacked node
     arrays as a single (5, n_nodes) f32 pack (one transfer, one scan slot).
@@ -414,17 +357,7 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
     grid-fused batching path): the loop still unrolls to spec.max_depth,
     but splits are gated off at level >= dyn.depth, so a shallower trial
     produces the tree its own static program would have (deeper nodes
-    keep zero cover and inherit the parent value).
-
-    `kernel="pallas"` swaps the histogram dot and the gain scan for the
-    fused `native/hist_kernel.py` launches (bin-accumulate straight from
-    the compact `binned_c` operand — callers pass B1t=None — then the
-    in-register split scan on the post-psum histogram); the psum, the
-    histogram-subtraction gating, the RF-subspace draw, and the row
-    routing stay in the shared glue, so per-chip partials and randomness
-    are identical to the XLA path. On non-TPU platforms the kernels run
-    in interpret mode (single row block — bit-parity with this very
-    function's XLA branch, asserted by tests/test_hist_kernel.py)."""
+    keep zero cover and inherit the parent value)."""
     D, B, F = spec.max_depth, spec.n_bins, spec.n_features
     n_nodes = 2 ** (D + 1) - 1
     axes = tuple(axes) if axes else (meshlib.DATA_AXIS,)
@@ -441,23 +374,7 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                     dcn_axis=meshlib.DCN_AXIS, ici_size=hier_ici)
             return coll.psum(part, axes if len(axes) > 1 else axes[0])
 
-    use_pallas = kernel == "pallas"
-    if use_pallas:
-        from ..native import hist_kernel as _hk
-        interp = _mesh_platform() != "tpu"
-        if not interp and block_rows:
-            # the accumulate kernel's per-block one-hot tile is
-            # block_rows·F·B·itemsize of VMEM: clamp the conf target to
-            # the same budget the split-scan guard enforces, so an
-            # oversized tile shrinks the block instead of failing to
-            # lower (the conf value stays the cache key — this clamp is
-            # a pure function of (spec, conf), both already keyed)
-            per_row = F * B * np.dtype(hist_dtype).itemsize
-            block_rows = max(
-                min(block_rows, _SCAN_VMEM_BUDGET // max(per_row, 1)), 8)
-
-    def build(B1t, binned, grad, hess, weight, feat_rng, dyn=None,
-              binned_c=None):
+    def build(B1t, binned, grad, hess, weight, feat_rng, dyn=None):
         min_inst = spec.min_instances if dyn is None else dyn.min_instances
         min_gain = spec.min_info_gain if dyn is None else dyn.min_info_gain
         n = binned.shape[0]
@@ -493,35 +410,23 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                     hw, lid_h, w_eff = half, lid_c // 2, wl
                 else:
                     hw, lid_h, w_eff = width, lid_c, wq
-                if use_pallas:
-                    # fused bin-accumulate straight from the compact bin
-                    # cache operand: the one-hot tiles live only in VMEM
-                    # block_rows is the HOST-resolved value carried by this
-                    # program's cache key; the kernel never reads conf at
-                    # trace time (0 means one full block)
-                    part = _hk.hist_accumulate(
-                        binned if binned_c is None else binned_c,
-                        lid_h, grad, hess, w_eff, n_bins=B, n_slots=hw,
-                        hist_dtype=hist_dtype, interpret=interp,
-                        block_rows=block_rows)
-                else:
-                    node1hot = jax.nn.one_hot(lid_h, hw, dtype=hist_dtype) \
-                        * (w_eff > 0)[:, None].astype(hist_dtype)
-                    stats = jnp.stack([grad * w_eff, hess * w_eff, w_eff],
-                                      axis=1)
-                    ns = (node1hot[:, :, None]
-                          * stats[:, None, :].astype(hist_dtype)
-                          ).reshape(n, hw * 3)
-                    # bf16 operands (the one-hot side is EXACT in bf16), f32
-                    # accumulation: the MXU's native mode. B1t comes
-                    # pre-transposed from `_tree_operand`, built once a
-                    # dispatch and held outside the loop over rounds by its
-                    # optimization_barrier (without it XLA's fusible sinking
-                    # rebuilds it every round); each dot reads all of it
-                    # from HBM
-                    part = jax.lax.dot_general(
-                        B1t, ns, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
+                node1hot = jax.nn.one_hot(lid_h, hw, dtype=hist_dtype) \
+                    * (w_eff > 0)[:, None].astype(hist_dtype)
+                stats = jnp.stack([grad * w_eff, hess * w_eff, w_eff],
+                                  axis=1)
+                ns = (node1hot[:, :, None]
+                      * stats[:, None, :].astype(hist_dtype)
+                      ).reshape(n, hw * 3)
+                # bf16 operands (the one-hot side is EXACT in bf16), f32
+                # accumulation: the MXU's native mode. B1t comes
+                # pre-transposed from `_tree_operand`, built once a
+                # dispatch and held outside the loop over rounds by its
+                # optimization_barrier (without it XLA's fusible sinking
+                # rebuilds it every round); each dot reads all of it
+                # from HBM
+                part = jax.lax.dot_general(
+                    B1t, ns, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
                 hist = _psum_merge(part)
                 if subtract and level > 0:
                     half = width // 2
@@ -540,9 +445,7 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                     # under dyn the draw ALWAYS happens (feature_k is traced);
                     # with feature_k == F the mask is all-True, so a
                     # no-subspace trial sees the identical candidate set its
-                    # own static program (which skips the draw) produces. The
-                    # draw stays OUTSIDE the pallas kernel so both paths
-                    # consume the same randomness
+                    # own static program (which skips the draw) produces
                     u = jax.random.uniform(
                         jax.random.fold_in(
                             jax.random.wrap_key_data(feat_rng), level),
@@ -552,48 +455,32 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                     fmask = ranks < fk                             # (width, F)
                 else:
                     fmask = None
-                if use_pallas:
-                    # fused split scan: cumsum + gain + masks + argmax in one
-                    # kernel on the post-psum histogram; only the (6, width)
-                    # best-split pack leaves it
-                    pack6 = _hk.split_scan(
-                        hist,
-                        jnp.ones((width, F), jnp.float32) if fmask is None
-                        else fmask.astype(jnp.float32),
-                        jnp.asarray(min_inst, jnp.float32).reshape(1, 1),
-                        reg_lambda=spec.reg_lambda, gamma=spec.gamma,
-                        interpret=interp)
-                    best_f = pack6[0].astype(jnp.int32)
-                    best_b = pack6[1].astype(jnp.int32)
-                    best_gain = pack6[2]
-                    gG, gH, gW = pack6[3], pack6[4], pack6[5]
-                else:
-                    hG = jnp.transpose(hist[..., 0], (2, 0, 1))  # (width,F,B)
-                    hH = jnp.transpose(hist[..., 1], (2, 0, 1))
-                    hW = jnp.transpose(hist[..., 2], (2, 0, 1))
-                    GL = jnp.cumsum(hG, axis=2)
-                    HL = jnp.cumsum(hH, axis=2)
-                    WL = jnp.cumsum(hW, axis=2)
-                    G = GL[:, :, -1:]
-                    H = HL[:, :, -1:]
-                    W = WL[:, :, -1:]
-                    lam = spec.reg_lambda
-                    score = (GL ** 2 / (HL + lam + 1e-12)
-                             + (G - GL) ** 2 / (H - HL + lam + 1e-12)
-                             - G ** 2 / (H + lam + 1e-12))
-                    ok = ((WL >= min_inst)
-                          & ((W - WL) >= min_inst))
-                    ok = ok & (jnp.arange(B)[None, None, :] < B - 1)
-                    if fmask is not None:
-                        ok = ok & fmask[:, :, None]
-                    score = jnp.where(ok, score, -jnp.inf)
-                    flat_best = jnp.argmax(score.reshape(width, F * B), axis=1)
-                    best_f = (flat_best // B).astype(jnp.int32)
-                    best_b = (flat_best % B).astype(jnp.int32)
-                    best_gain = 0.5 * jnp.take_along_axis(
-                        score.reshape(width, F * B), flat_best[:, None],
-                        axis=1)[:, 0] - spec.gamma
-                    gG, gH, gW = G[:, 0, 0], H[:, 0, 0], W[:, 0, 0]
+                hG = jnp.transpose(hist[..., 0], (2, 0, 1))  # (width,F,B)
+                hH = jnp.transpose(hist[..., 1], (2, 0, 1))
+                hW = jnp.transpose(hist[..., 2], (2, 0, 1))
+                GL = jnp.cumsum(hG, axis=2)
+                HL = jnp.cumsum(hH, axis=2)
+                WL = jnp.cumsum(hW, axis=2)
+                G = GL[:, :, -1:]
+                H = HL[:, :, -1:]
+                W = WL[:, :, -1:]
+                lam = spec.reg_lambda
+                score = (GL ** 2 / (HL + lam + 1e-12)
+                         + (G - GL) ** 2 / (H - HL + lam + 1e-12)
+                         - G ** 2 / (H + lam + 1e-12))
+                ok = ((WL >= min_inst)
+                      & ((W - WL) >= min_inst))
+                ok = ok & (jnp.arange(B)[None, None, :] < B - 1)
+                if fmask is not None:
+                    ok = ok & fmask[:, :, None]
+                score = jnp.where(ok, score, -jnp.inf)
+                flat_best = jnp.argmax(score.reshape(width, F * B), axis=1)
+                best_f = (flat_best // B).astype(jnp.int32)
+                best_b = (flat_best % B).astype(jnp.int32)
+                best_gain = 0.5 * jnp.take_along_axis(
+                    score.reshape(width, F * B), flat_best[:, None],
+                    axis=1)[:, 0] - spec.gamma
+                gG, gH, gW = G[:, 0, 0], H[:, 0, 0], W[:, 0, 0]
                 do_split = (best_gain > min_gain) & jnp.isfinite(best_gain)
                 if dyn is not None:  # trial's own maxDepth: none beyond it
                     do_split = do_split & (level < dyn.depth)
@@ -729,15 +616,12 @@ def _sliced_draw(n: int, data_width: int, draw, axes=None):
     return jax.lax.dynamic_slice(full, (idx * n,), (n,))
 
 
-def _tree_operand(binned_c, n_bins: int, hist_dtype, kernel: str,
-                  barrier: bool = True):
-    """The histogram operand of every XLA-path tree program, built ONCE a
+def _tree_operand(binned_c, n_bins: int, hist_dtype, barrier: bool = True):
+    """The histogram operand of every tree program, built ONCE a
     dispatch: `(binned, B1t)` = the compact bins widened to int32 and
     their one-hot, `(F·B, n)` in `hist_dtype`, pre-transposed for the
     histogram dot (a `.T` at the dot would re-materialize a gigabyte
-    transpose every level of every tree). `B1t` is `None` under
-    `kernel="pallas"`: that path one-hots bin tiles in VMEM, a row block
-    at a time, straight from the compact operand.
+    transpose every level of every tree).
 
     The `optimization_barrier` is what keeps "once" true. The one-hot is
     loop-invariant and cheap to express (broadcast, compare, convert), and
@@ -755,8 +639,6 @@ def _tree_operand(binned_c, n_bins: int, hist_dtype, kernel: str,
         # compact uint8/uint16 bins widen ON-DEVICE (a fused VPU cast over
         # the 4x-smaller staged matrix), never on the host/H2D path
         binned = binned_c.astype(jnp.int32)
-        if kernel == "pallas":
-            return binned, None
         n, F = binned.shape
         B1t = jax.nn.one_hot(binned, n_bins, dtype=hist_dtype) \
             .reshape(n, F * n_bins).T
@@ -801,7 +683,6 @@ def ops_in_loop_bodies(hlo_text: str, scope: str = "tree.operand") -> list:
 
 
 def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
-                     kernel: str = "xla", block_rows: int = 0,
                      axes=None, hier_ici: int = 0):
     """The shared internals of every ensemble program shape: `prepare`
     builds the histogram operand once a dispatch (`_tree_operand`: the
@@ -812,28 +693,21 @@ def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
     `data_width` is the mesh's STATIC data-axis size (part of every
     program cache's mesh-id key): sampling draws span `local_rows *
     data_width` so every layout selects the same global weights (see
-    `_sliced_draw`). Under
-    `kernel="pallas"` the fit-long B1t one-hot resident is never built
-    (B1t=None) — the pallas kernel one-hots VMEM bin tiles per row block
-    from the COMPACT operand instead."""
+    `_sliced_draw`)."""
     spec = es.tree
     hist_dtype = _hist_dtype()
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
-                               kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
 
     def prepare(binned, rng):
-        # the compact operand survives alongside the widened one — the
-        # kernel path histograms straight from it
-        binned_c = binned
-        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel)
+        binned, B1t = _tree_operand(binned, spec.n_bins, hist_dtype)
         # ONE replicated sampling stream (fold_in(0) preserves the
         # historical single-device draws bit-for-bit); per-chip weights
         # come from slicing the global draw, not from per-chip keys
         key = jax.random.fold_in(jax.random.wrap_key_data(rng), 0)
-        return binned, binned_c, B1t, key
+        return binned, B1t, key
 
-    def make_round(binned, binned_c, B1t, y, mask, key, rng):
+    def make_round(binned, B1t, y, mask, key, rng):
         n = binned.shape[0]
 
         def round_fn(margin, t):
@@ -863,8 +737,7 @@ def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
                 w = w * mask
                 feat_rng = jax.random.key_data(jax.random.fold_in(
                     jax.random.wrap_key_data(rng), t))  # same across chips
-            pack, node_fin = build(B1t, binned, grad, hess, w, feat_rng,
-                                   binned_c=binned_c)
+            pack, node_fin = build(B1t, binned, grad, hess, w, feat_rng)
             if es.boosting:
                 # the build routed every row to its terminal node already:
                 # the margin update is one gather, not a depth-long re-walk
@@ -889,21 +762,19 @@ def _data_width(mesh=None) -> int:
 
 
 def _make_ensemble_program(es: EnsembleSpec, data_width: int = 1,
-                           kernel: str = "xla", block_rows: int = 0,
                            axes=None, hier_ici: int = 0):
     """The WHOLE forest/boosting fit as one XLA program: `lax.scan` over
     trees, margins and sampling weights living in HBM for the entire fit.
     One dispatch + one packed device→host transfer per ensemble — the
     per-tree host round-trips disappear."""
-    prepare, make_round = _ensemble_pieces(es, data_width, kernel,
-                                           block_rows, axes, hier_ici)
+    prepare, make_round = _ensemble_pieces(es, data_width, axes, hier_ici)
     base_of = _base_margin_fn(es.loss, axes)
 
     def program(binned, y, mask, rng):
-        binned, binned_c, B1t, key = prepare(binned, rng)
+        binned, B1t, key = prepare(binned, rng)
         base = base_of(y, mask)
         margin0 = jnp.full((binned.shape[0],), base, dtype=jnp.float32)
-        round_fn = make_round(binned, binned_c, B1t, y, mask, key, rng)
+        round_fn = make_round(binned, B1t, y, mask, key, rng)
         _, packs = jax.lax.scan(round_fn, margin0, jnp.arange(es.n_trees))
         return packs, base
 
@@ -911,18 +782,16 @@ def _make_ensemble_program(es: EnsembleSpec, data_width: int = 1,
 
 
 def _make_chunk_program(es: EnsembleSpec, chunk: int, data_width: int = 1,
-                        kernel: str = "xla", block_rows: int = 0,
                         axes=None, hier_ici: int = 0):
     """`chunk` boosting rounds as one dispatch: the margin carry enters and
     leaves as a row-sharded HBM buffer (donated between dispatches by the
     caller), `t0` offsets the round index so sampling streams and feature
     subspaces match the monolithic scan round-for-round."""
-    prepare, make_round = _ensemble_pieces(es, data_width, kernel,
-                                           block_rows, axes, hier_ici)
+    prepare, make_round = _ensemble_pieces(es, data_width, axes, hier_ici)
 
     def program(binned, y, mask, margin, rng, t0):
-        binned, binned_c, B1t, key = prepare(binned, rng)
-        round_fn = make_round(binned, binned_c, B1t, y, mask, key, rng)
+        binned, B1t, key = prepare(binned, rng)
+        round_fn = make_round(binned, B1t, y, mask, key, rng)
         margin, packs = jax.lax.scan(
             round_fn, margin, t0 + jnp.arange(chunk, dtype=jnp.int32))
         return margin, packs
@@ -934,15 +803,10 @@ _chunk_cache: Dict[tuple, object] = {}
 _base_prog_cache: Dict[tuple, object] = {}
 
 
-def _compiled_chunk(es: EnsembleSpec, chunk: int,
-                    kernel: Optional[str] = None,
-                    block_rows: Optional[int] = None):
+def _compiled_chunk(es: EnsembleSpec, chunk: int):
     from ..parallel import mesh as _meshlib
     from ..conf import GLOBAL_CONF
     mesh = _meshlib.get_mesh()
-    kernel = kernel or _kernel_for(es.tree)
-    brows = _kernel_block_rows(kernel) if block_rows is None \
-        else int(block_rows)
     # donate the margin carry so chunk k+1 reuses chunk k's HBM (the
     # chain's only fresh buffer — bins/labels/mask stay cache-owned
     # and are never donated); XLA:CPU ignores donation, so skip it
@@ -952,13 +816,12 @@ def _compiled_chunk(es: EnsembleSpec, chunk: int,
     plat = _mesh_platform(mesh)
     donate = (3,) if plat != "cpu" \
         and GLOBAL_CONF.getBool("sml.tpu.donate") else ()
-    key = (es, chunk, id(mesh), _hist_subtract(), _hier_ici(mesh), donate,
-           kernel, brows)
+    key = (es, chunk, id(mesh), _hist_subtract(), _hier_ici(mesh), donate)
     if key not in _chunk_cache:
         from ..obs import note_compile
         note_compile(f"tree_chunk_{chunk}")
-        program = _make_chunk_program(es, chunk, _data_width(mesh), kernel,
-                                      brows, _meshlib.row_axes(mesh),
+        program = _make_chunk_program(es, chunk, _data_width(mesh),
+                                      _meshlib.row_axes(mesh),
                                       _hier_ici(mesh))
         P = jax.sharding.PartitionSpec
         Dx = _meshlib.row_spec_entry(mesh)
@@ -971,8 +834,7 @@ def _compiled_chunk(es: EnsembleSpec, chunk: int,
 
 
 def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
-                  chunk: int, kernel: str, margin, t0: int = 0,
-                  on_rounds=None):
+                  chunk: int, margin, t0: int = 0, on_rounds=None):
     """The staged boosting dispatch loop: rounds [t0, es.n_trees) in
     ceil((n_trees - t0)/chunk) dispatches over a margin carry (donated
     between chunks). Shared by the fresh chunked fit (t0=0, margin =
@@ -993,17 +855,16 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
     host_packs = []    # hook path: each pack fetched ONCE at its boundary
     t = int(t0)
     with transient_hbm("hist_onehot",
-                       _onehot_bytes(es.tree, binned_dev.shape[0], kernel)):
+                       _onehot_bytes(es.tree, binned_dev.shape[0])):
         while t < es.n_trees:
             c = min(chunk, es.n_trees - t)
             _prewarm.record("tree_chunk", {
-                "es": _es_meta(es), "chunk": int(c), "kernel": kernel,
-                "kernel_rows": _kernel_block_rows(kernel),
+                "es": _es_meta(es), "chunk": int(c),
                 "args": _prewarm.arg_specs(binned_dev, y_dev, mask_dev,
                                            margin)})
             PROFILER.count("tree.fit_dispatch")
             with PROFILER.span("fit.dispatch"):
-                margin, packs = _compiled_chunk(es, c, kernel)(
+                margin, packs = _compiled_chunk(es, c)(
                     binned_dev, y_dev, mask_dev, margin, rng, jnp.int32(t))
             t += c
             if on_rounds is None:
@@ -1023,15 +884,13 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
 
 
 def _fit_ensemble_chunked(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
-                          seed: int, chunk: int,
-                          kernel: Optional[str] = None, on_rounds=None):
+                          seed: int, chunk: int, on_rounds=None):
     """Boosting rounds in ceil(n_trees/chunk) dispatches. The margin never
     visits the host between chunks — it carries as a donated device buffer
     — and per-chunk tree packs are fetched once at the end (one batched
     D2H). Bit-identical to the monolithic program on equal backends."""
     from ..parallel import mesh as _meshlib
     mesh = _meshlib.get_mesh()
-    kernel = kernel or _kernel_for(es.tree)
     bkey = (es.loss, id(mesh))
     if bkey not in _base_prog_cache:
         _base_prog_cache[bkey] = data_parallel(
@@ -1049,7 +908,7 @@ def _fit_ensemble_chunked(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
         hook = None if on_rounds is None \
             else (lambda t, tr: on_rounds(t, tr, base))
         trees = _boost_rounds(binned_dev, y_dev, mask_dev, es, seed, chunk,
-                              kernel, margin, t0=0, on_rounds=hook)
+                              margin, t0=0, on_rounds=hook)
     finally:
         LEDGER.free("boost_margin", margin_bytes)
     return trees, base
@@ -1111,7 +970,6 @@ def resume_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
     t0 = len(init_trees)
     if es.n_trees <= t0:
         return [], float(base)
-    kernel = _kernel_for(es.tree)
     rounds = (rounds_per_dispatch if rounds_per_dispatch is not None
               else GLOBAL_CONF.getInt("sml.tree.roundsPerDispatch"))
     chunk = rounds if 0 < rounds else (es.n_trees - t0)
@@ -1133,8 +991,7 @@ def resume_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
             hook = None if on_rounds is None \
                 else (lambda t, tr: on_rounds(t, tr, float(base)))
             trees = _boost_rounds(binned_dev, y_dev, mask_dev, es, seed,
-                                  chunk, kernel, margin, t0=t0,
-                                  on_rounds=hook)
+                                  chunk, margin, t0=t0, on_rounds=hook)
         finally:
             LEDGER.free("boost_margin", margin_bytes)
     return trees, float(base)
@@ -1160,31 +1017,24 @@ def fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
                                        rounds_per_dispatch, on_rounds)
 
 
-def _ensemble_compiled(es: EnsembleSpec, kernel: Optional[str] = None,
-                       block_rows: Optional[int] = None):
+def _ensemble_compiled(es: EnsembleSpec):
     """The monolithic whole-ensemble program from its per-mesh cache —
     shared by the fit path and the prewarm rebuilder (warming must
-    populate the SAME cache entry the fit will hit). `kernel` is the
-    RESOLVED build path ("pallas"/"xla"): part of the cache key, and
-    replay passes the manifest-recorded value so a prewarm rebuilds the
-    executable the fit actually compiled."""
-    kernel = kernel or _kernel_for(es.tree)
-    brows = _kernel_block_rows(kernel) if block_rows is None \
-        else int(block_rows)
+    populate the SAME cache entry the fit will hit)."""
     mesh = meshlib.get_mesh()
-    key = (es, id(mesh), _hist_subtract(), _hier_ici(mesh), kernel, brows)
+    key = (es, id(mesh), _hist_subtract(), _hier_ici(mesh))
     if key not in _ensemble_cache:
         from ..obs import note_compile
         note_compile("tree_ensemble")
         _ensemble_cache[key] = data_parallel(
-            _make_ensemble_program(es, _data_width(mesh), kernel, brows,
+            _make_ensemble_program(es, _data_width(mesh),
                                    meshlib.row_axes(mesh), _hier_ici(mesh)),
             replicated_argnums=(3,), name="tree_ensemble")
     return _ensemble_cache[key]
 
 
-def _onehot_bytes(spec: TreeSpec, rows: int, kernel: str) -> int:
-    """HBM bytes of the XLA path's one-hot resident (`B1t`: rows × F ×
+def _onehot_bytes(spec: TreeSpec, rows: int) -> int:
+    """HBM bytes of the one-hot resident (`B1t`: rows × F ×
     bins in hist_dtype) — the dominant transient the ledger charges for
     the duration of a tree-fit dispatch (every tree program shape,
     fit_tree included). Dispatch-long BY CONSTRUCTION: `_tree_operand`
@@ -1193,12 +1043,7 @@ def _onehot_bytes(spec: TreeSpec, rows: int, kernel: str) -> int:
     every round: as many bytes alive, a round at a time). While it is built the
     int32 broadcast it is compared from (4 bytes an element, twice these
     bytes) is alive beside it; that is not charged here (PERF.md §3,
-    `memory_peak_bytes`). The pallas kernel path never materializes it (bin
-    tiles one-hot in VMEM per row block), so its charge is zero: the
-    `hbm.hist_onehot_bytes` gauge difference IS the kernel's residency
-    win."""
-    if kernel == "pallas":
-        return 0
+    `memory_peak_bytes`)."""
     return int(rows) * spec.n_features * spec.n_bins \
         * np.dtype(_hist_dtype()).itemsize
 
@@ -1225,24 +1070,21 @@ def _fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
                             rounds_per_dispatch: Optional[int] = None,
                             on_rounds=None):
     from ..conf import GLOBAL_CONF
-    kernel = _kernel_for(es.tree)
     rounds = (rounds_per_dispatch if rounds_per_dispatch is not None
               else GLOBAL_CONF.getInt("sml.tree.roundsPerDispatch"))
     if es.boosting and (0 < rounds < es.n_trees or on_rounds is not None):
         return _fit_ensemble_chunked(binned_dev, y_dev, mask_dev, es,
                                      seed, rounds if 0 < rounds
-                                     else es.n_trees, kernel,
-                                     on_rounds=on_rounds)
-    compiled = _ensemble_compiled(es, kernel)
+                                     else es.n_trees, on_rounds=on_rounds)
+    compiled = _ensemble_compiled(es)
     rng = jax.random.key_data(jax.random.PRNGKey(seed))
     from ..parallel import prewarm as _prewarm
     _prewarm.record("tree_ensemble", {
-        "es": _es_meta(es), "kernel": kernel,
-        "kernel_rows": _kernel_block_rows(kernel),
+        "es": _es_meta(es),
         "args": _prewarm.arg_specs(binned_dev, y_dev, mask_dev)})
     PROFILER.count("tree.fit_dispatch")
     with transient_hbm("hist_onehot",
-                       _onehot_bytes(es.tree, binned_dev.shape[0], kernel)):
+                       _onehot_bytes(es.tree, binned_dev.shape[0])):
         packs, base = _run_and_read(compiled, binned_dev, y_dev, mask_dev,
                                     rng)
     # ^ one batched D2H transfer for (packs, base): every device→host
@@ -1334,12 +1176,10 @@ def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
     y_dev = stage_stacked_cached(yst)
     m_dev = stage_stacked_cached(mst)
 
-    kernel = _kernel_for(es.tree)
-    compiled = _folds_compiled(es, fo, kernel)
+    compiled = _folds_compiled(es, fo)
     from ..parallel import prewarm as _prewarm
     _prewarm.record("tree_folds", {
-        "es": _es_meta(es), "fo": int(fo), "kernel": kernel,
-        "kernel_rows": _kernel_block_rows(kernel),
+        "es": _es_meta(es), "fo": int(fo),
         "args": _prewarm.arg_specs(b_dev, y_dev, m_dev)})
     rng = jax.random.key_data(jax.random.PRNGKey(seed))
     with PROFILER.span(
@@ -1347,27 +1187,22 @@ def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
             route="host" if _dispatch.is_host_mesh(mesh) else "device",
             trees=es.n_trees * fo), \
             transient_hbm("hist_onehot",
-                          _onehot_bytes(es.tree, fo * n_pad, kernel)):
+                          _onehot_bytes(es.tree, fo * n_pad)):
         PROFILER.count("tree.fit_dispatch")
         packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rng)
     return [(_unpack_trees(packs[k]), float(bases[k])) for k in range(fo)]
 
 
-def _folds_compiled(es: EnsembleSpec, fo: int, kernel: Optional[str] = None,
-                    block_rows: Optional[int] = None):
+def _folds_compiled(es: EnsembleSpec, fo: int):
     """The fold-batched program from its per-mesh cache (shared with the
     prewarm rebuilder)."""
     mesh = meshlib.get_mesh()
-    kernel = kernel or _kernel_for(es.tree)
-    brows = _kernel_block_rows(kernel) if block_rows is None \
-        else int(block_rows)
-    key = (es, fo, id(mesh), _hist_subtract(), _hier_ici(mesh), kernel,
-           brows)
+    key = (es, fo, id(mesh), _hist_subtract(), _hier_ici(mesh))
     if key not in _folds_cache:
         from ..obs import note_compile
         note_compile(f"tree_ensemble_folds_{fo}")
-        program = _make_ensemble_program(es, _data_width(mesh), kernel,
-                                         brows, meshlib.row_axes(mesh),
+        program = _make_ensemble_program(es, _data_width(mesh),
+                                         meshlib.row_axes(mesh),
                                          _hier_ici(mesh))
 
         def batched(binned_f, y_f, mask_f, rng):
@@ -1389,7 +1224,6 @@ _trials_cache: Dict[tuple, object] = {}
 
 
 def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
-                         kernel: str = "xla", block_rows: int = 0,
                          axes=None, hier_ici: int = 0):
     """Per-ELEMENT ensemble program with TRACED hyperparameters, vmapped
     over the trial axis by `fit_ensembles_trials`: `es` carries the grid
@@ -1404,15 +1238,13 @@ def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
     spec = es.tree
     hist_dtype = _hist_dtype()
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
-                               kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
     base_of = _base_margin_fn(es.loss, axes)
 
     def program(binned, y, mask, rng, depth, feature_k, min_inst, mig,
                 bootstrap, subsample):
         n = binned.shape[0]
-        binned_c = binned
-        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel)
+        binned, B1t = _tree_operand(binned, spec.n_bins, hist_dtype)
         key = jax.random.fold_in(jax.random.wrap_key_data(rng), 0)
         base = base_of(y, mask)
         dyn = TrialDyn(depth=depth, feature_k=feature_k,
@@ -1431,8 +1263,7 @@ def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
                           jnp.where(subsample < 1.0, bern, ones)) * mask
             feat_rng = jax.random.key_data(jax.random.fold_in(
                 jax.random.wrap_key_data(rng), t))
-            pack, _ = build(B1t, binned, grad, hess, w, feat_rng, dyn=dyn,
-                            binned_c=binned_c)
+            pack, _ = build(B1t, binned, grad, hess, w, feat_rng, dyn=dyn)
             return carry, pack
 
         _, packs = jax.lax.scan(round_fn, 0.0, jnp.arange(es.n_trees))
@@ -1441,9 +1272,7 @@ def _make_trials_program(es: EnsembleSpec, data_width: int = 1,
     return program
 
 
-def _trials_compiled(es: EnsembleSpec, n_elems: int, mesh=None,
-                     kernel: Optional[str] = None,
-                     block_rows: Optional[int] = None):
+def _trials_compiled(es: EnsembleSpec, n_elems: int, mesh=None):
     """The trial-batched program from its per-mesh cache (shared with the
     prewarm rebuilder). Cache key carries only STATIC maxima — a grid
     whose per-trial values change but whose maxima land on the same
@@ -1453,16 +1282,12 @@ def _trials_compiled(es: EnsembleSpec, n_elems: int, mesh=None,
     replicating, and each trial lane's histogram psums span only its own
     n_dev/trial_dim-wide data axis."""
     mesh = mesh or meshlib.get_mesh()
-    kernel = kernel or _kernel_for(es.tree)
-    brows = _kernel_block_rows(kernel) if block_rows is None \
-        else int(block_rows)
-    key = (es, n_elems, id(mesh), _hist_subtract(), _hier_ici(mesh),
-           kernel, brows)
+    key = (es, n_elems, id(mesh), _hist_subtract(), _hier_ici(mesh))
     if key not in _trials_cache:
         from ..obs import note_compile
         note_compile(f"tree_ensemble_trials_{n_elems}")
-        program = _make_trials_program(es, _data_width(mesh), kernel,
-                                       brows, meshlib.row_axes(mesh),
+        program = _make_trials_program(es, _data_width(mesh),
+                                       meshlib.row_axes(mesh),
                                        _hier_ici(mesh))
 
         def batched(binned_e, y_e, mask_e, rngs, *dyns):
@@ -1576,7 +1401,6 @@ def fit_ensembles_trials(bst, yst, mst, es: EnsembleSpec, rngs,
 
     mesh = meshlib.get_mesh()
     E, n_pad = bst.shape[0], bst.shape[1]
-    kernel = _kernel_for(es.tree)
     tdim = _trial_axis_width(E, n_pad)
     dyns = [np.asarray(depth, np.int32), np.asarray(feature_k, np.int32),
             np.asarray(min_inst, np.float32),
@@ -1592,23 +1416,22 @@ def fit_ensembles_trials(bst, yst, mst, es: EnsembleSpec, rngs,
         b_dev = stage_trial_stacked_cached(bst, tmesh)
         y_dev = stage_trial_stacked_cached(yst, tmesh)
         m_dev = stage_trial_stacked_cached(mst, tmesh)
-        compiled = _trials_compiled(es, e_pad, tmesh, kernel)
+        compiled = _trials_compiled(es, e_pad, tmesh)
     else:
         e_pad = E
         b_dev = stage_stacked_cached(bst)
         y_dev = stage_stacked_cached(yst)
         m_dev = stage_stacked_cached(mst)
-        compiled = _trials_compiled(es, E, kernel=kernel)
+        compiled = _trials_compiled(es, E)
     _prewarm.record("tree_trials", {
         "es": _es_meta(es), "n_elems": int(e_pad), "trial_dim": int(tdim),
-        "kernel": kernel, "kernel_rows": _kernel_block_rows(kernel),
         "args": _prewarm.arg_specs(b_dev, y_dev, m_dev)})
     with PROFILER.span(
             "program.tree_ensemble_trials", rows=int(e_pad * n_pad),
             route="host" if _dispatch.is_host_mesh(mesh) else "device",
             trees=es.n_trees * e_pad), \
             transient_hbm("hist_onehot",
-                          _onehot_bytes(es.tree, e_pad * n_pad, kernel)):
+                          _onehot_bytes(es.tree, e_pad * n_pad)):
         PROFILER.count("tree.fit_dispatch")
         packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rngs,
                                      *dyns)
@@ -1657,32 +1480,18 @@ def _replay_zeros(meta, n: int):
     return out
 
 
-def _replay_kernel(meta: dict) -> tuple:
-    """(kernel, block_rows) as recorded in the manifest: replay must
-    rebuild the SAME executable the recorded fit compiled — flag AND
-    block scheme — regardless of the replaying process's live conf.
-    Pre-kernel manifests carry neither — those resolve live (None)."""
-    k = meta.get("kernel")
-    k = str(k) if k in ("pallas", "xla") else None
-    r = meta.get("kernel_rows")
-    r = int(r) if k is not None and isinstance(r, (int, float)) else None
-    return k, r
-
-
 def _replay_tree_ensemble(meta: dict) -> None:
     es = _es_from_meta(meta)
     b, y, m = _replay_zeros(meta, 3)
     rng = jax.random.key_data(jax.random.PRNGKey(0))
-    jax.device_get(_ensemble_compiled(es, *_replay_kernel(meta))(
-        b, y, m, rng))
+    jax.device_get(_ensemble_compiled(es)(b, y, m, rng))
 
 
 def _replay_tree_chunk(meta: dict) -> None:
     es = _es_from_meta(meta)
     b, y, m, margin = _replay_zeros(meta, 4)
     rng = jax.random.key_data(jax.random.PRNGKey(0))
-    jax.device_get(_compiled_chunk(es, int(meta["chunk"]),
-                                   *_replay_kernel(meta))(
+    jax.device_get(_compiled_chunk(es, int(meta["chunk"]))(
         b, y, m, margin, rng, jnp.int32(0)))
 
 
@@ -1690,8 +1499,7 @@ def _replay_tree_folds(meta: dict) -> None:
     es = _es_from_meta(meta)
     b, y, m = _replay_zeros(meta, 3)
     rng = jax.random.key_data(jax.random.PRNGKey(0))
-    jax.device_get(_folds_compiled(es, int(meta["fo"]),
-                                   *_replay_kernel(meta))(b, y, m, rng))
+    jax.device_get(_folds_compiled(es, int(meta["fo"]))(b, y, m, rng))
 
 
 def _replay_tree_trials(meta: dict) -> None:
@@ -1711,11 +1519,10 @@ def _replay_tree_trials(meta: dict) -> None:
             arrs.append(jax.device_put(
                 a, jax.sharding.NamedSharding(tmesh, spec)))
         b, y, m = arrs
-        compiled = _trials_compiled(es, E, tmesh, *_replay_kernel(meta))
+        compiled = _trials_compiled(es, E, tmesh)
     else:
         b, y, m = _replay_zeros(meta, 3)
-        kk, kr = _replay_kernel(meta)
-        compiled = _trials_compiled(es, E, kernel=kk, block_rows=kr)
+        compiled = _trials_compiled(es, E)
     rngs = np.zeros((E, 2), np.uint32)
     jax.device_get(compiled(
         b, y, m, rngs,
@@ -1737,22 +1544,18 @@ _register_prewarm_rebuilders()
 
 
 def _build_tree_program(spec: TreeSpec, hist_dtype=jnp.float32,
-                        kernel: str = "xla", block_rows: int = 0,
                         axes=None, hier_ici: int = 0):
     """Single-tree program (kept for the dryrun/compile-check path)."""
     build = _make_tree_builder(spec, hist_dtype, subtract=_hist_subtract(),
-                               kernel=kernel, block_rows=block_rows,
                                axes=axes, hier_ici=hier_ici)
 
     def program(binned, grad, hess, weight, feat_rng):
-        binned_c = binned
         # no loop over rounds here, so nothing to hold the operand out of
         # (and XLA:CPU sums the dot in another order behind a barrier: one
-        # ulp in a leaf, which tests/test_hist_kernel.py's parity pins see)
-        binned, B1t = _tree_operand(binned_c, spec.n_bins, hist_dtype, kernel,
+        # ulp in a leaf)
+        binned, B1t = _tree_operand(binned, spec.n_bins, hist_dtype,
                                     barrier=False)
-        pack, _ = build(B1t, binned, grad, hess, weight, feat_rng,
-                        binned_c=binned_c)
+        pack, _ = build(B1t, binned, grad, hess, weight, feat_rng)
         return (pack[0].astype(jnp.int32), pack[1].astype(jnp.int32),
                 pack[2], pack[3], pack[4])
 
@@ -1766,15 +1569,13 @@ def fit_tree(binned_dev, grad_dev, hess_dev, weight_dev, spec: TreeSpec,
              rng: int = 0, feat_key: Optional[np.ndarray] = None) -> FittedTree:
     """Build one tree on the mesh from pre-staged device arrays."""
     from ..parallel import mesh as _meshlib
-    kernel = _kernel_for(spec)
-    brows = _kernel_block_rows(kernel)
     mesh = _meshlib.get_mesh()
-    key = (spec, id(mesh), _hist_subtract(), _hier_ici(mesh), kernel, brows)
+    key = (spec, id(mesh), _hist_subtract(), _hier_ici(mesh))
     if key not in _tree_cache:
         from ..obs import note_compile
         note_compile("tree_single")
         _tree_cache[key] = data_parallel(
-            _build_tree_program(spec, _hist_dtype(), kernel, brows,
+            _build_tree_program(spec, _hist_dtype(),
                                 _meshlib.row_axes(mesh), _hier_ici(mesh)),
             replicated_argnums=(4,))
     compiled = _tree_cache[key]
@@ -1782,7 +1583,7 @@ def fit_tree(binned_dev, grad_dev, hess_dev, weight_dev, spec: TreeSpec,
         feat_key = jax.random.key_data(jax.random.PRNGKey(rng))
     PROFILER.count("tree.fit_dispatch")
     with transient_hbm("hist_onehot",
-                       _onehot_bytes(spec, binned_dev.shape[0], kernel)):
+                       _onehot_bytes(spec, binned_dev.shape[0])):
         out = compiled(binned_dev, grad_dev, hess_dev, weight_dev, feat_key)
         sf, sb, lv, g, cov = jax.device_get(out)  # one batched transfer
     sf, lv = sf.copy(), lv.copy()

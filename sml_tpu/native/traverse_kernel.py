@@ -1,10 +1,10 @@
 """Pallas fused batched tree-traversal kernel for the serving hot path.
 
-PR 9 fused the *fit* hot path; scoring still rode the XLA ensemble
-traversal in `ml/inference.py` (`_forest_margin`): per level, the
-per-node one-hot, the feature-select one-hot, and the `(T, rows)`
-per-tree margin stack are all separate HLOs whose intermediates
-round-trip HBM between levels. This kernel fuses the whole descent
+The XLA ensemble traversal in `ml/inference.py` (`_forest_margin`)
+scores level by level: the per-node one-hot, the feature-select
+one-hot, and the `(T, rows)` per-tree margin stack are all separate
+HLOs whose intermediates round-trip HBM between levels. This kernel
+fuses the whole descent
 ON-CHIP — the accelerator-side batched traversal of "Booster: An
 Accelerator for Gradient Boosting Decision Trees" (arXiv:2011.02022),
 with the batched node layout of "GPU-acceleration for Large-scale Tree
@@ -48,17 +48,73 @@ the fallback ladder stay authoritative.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
-
-import numpy as np
+from typing import Dict, Optional, Tuple
 
 from ..utils.profiler import PROFILER
-from .hist_kernel import LANES as _LANES
 
-#: `sml.infer.kernel=auto` selects this kernel on a TPU mesh: it compiles
-#: for v5e (jax 0.9.0 / libtpu 0.0.34) at the course shapes and agrees
-#: with the XLA traversal there (PR 21 chip run, docs/KERNELS.md)
-AUTO_ON_TPU = True
+#: minor-dimension tile of every VMEM array: what a VMEM guard must pad to
+LANES = 128
+
+#: interpret flag -> None (a launch worked) | the error text it raised
+_avail: Dict[bool, Optional[str]] = {}
+
+
+def probe(interpret: bool) -> Optional[str]:
+    """Whether the Pallas toolchain can launch a kernel in this process,
+    probed ONCE per mode with a tiny kernel: `interpret=True` on non-TPU
+    backends, a Mosaic COMPILE and run on a TPU mesh. Returns None when
+    the launch worked, else the error it raised, so callers can raise
+    the compiler's own message (`resolve_mode`). This proves the
+    toolchain, not that every kernel body lowers at every shape: a body
+    that cannot compile fails at its own first launch, and nothing
+    catches that."""
+    if interpret not in _avail:
+        try:
+            import jax
+            import jax.numpy as jnp
+            from jax.experimental import pallas as pl
+
+            def _probe(x_ref, o_ref):
+                o_ref[...] = x_ref[...] + 1.0
+
+            out = pl.pallas_call(
+                _probe,
+                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+                interpret=interpret,
+            )(jnp.ones((8, 128), jnp.float32))
+            _avail[interpret] = None if float(out[0, 0]) == 2.0 \
+                else "probe kernel returned a wrong value"
+        except Exception as e:  # noqa: BLE001 — reported, never swallowed
+            _avail[interpret] = f"{type(e).__name__}: {e}"
+    return _avail[interpret]
+
+
+def resolve_mode(mode, platform: str) -> Tuple[str, bool]:
+    """(kernel, fell_back) for the value `mode` of `sml.infer.kernel` on
+    a mesh of `platform` (docs/KERNELS.md). 'xla' short-circuits. 'auto'
+    selects pallas only on a TPU mesh, where this kernel compiles for
+    v5e (jax 0.9.0 / libtpu 0.0.34) at the course shapes and agrees with
+    the XLA traversal (PR 21 chip run) — elsewhere xla is the resolver's
+    answer for the platform, not a fallback; a TPU whose toolchain probe
+    then fails is the one fallback (the caller counts it). An explicit
+    'pallas' is a demand: interpret mode off-TPU, a compiled launch on
+    TPU, and a toolchain that cannot launch raises its own error. Any
+    other value raises (a typo must not silently land on either path)."""
+    mode = str(mode).strip().lower()
+    if mode not in ("auto", "pallas", "xla"):
+        raise ValueError(
+            f"sml.infer.kernel must be one of auto/pallas/xla, got {mode!r}")
+    on_tpu = platform == "tpu"
+    if mode == "xla" or (mode == "auto" and not on_tpu):
+        return "xla", False
+    err = probe(interpret=not on_tpu)
+    if err is None:
+        return "pallas", False
+    if mode == "pallas":
+        raise RuntimeError(f"sml.infer.kernel=pallas but a Pallas kernel "
+                           f"cannot launch on this {platform} mesh: {err}")
+    return "xla", True
+
 
 #: compiled-path VMEM budget per grid step. Mosaic's scoped limit on v5e
 #: is 16 MiB; `traverse_vmem_bytes` is within ~7% of what the compiler
@@ -69,7 +125,7 @@ _SUBLANES = 32  # row-block multiple that suits every bin dtype (uint8)
 
 
 def _lane_pad(k: int) -> int:
-    return -(-int(k) // _LANES) * _LANES
+    return -(-int(k) // LANES) * LANES
 
 
 def traverse_vmem_bytes(block_rows: int, n_trees: int, n_nodes: int,
@@ -86,7 +142,7 @@ def traverse_vmem_bytes(block_rows: int, n_trees: int, n_nodes: int,
     node-table term alone)."""
     blk = max(int(block_rows), 0)
     per_row = 4 * (2 * _lane_pad(n_nodes) + 2 * _lane_pad(n_feat)
-                   + 4 * _LANES)
+                   + 4 * LANES)
     tables = 12 * (-(-int(n_trees) // 8) * 8) * _lane_pad(n_nodes)
     return int(blk * per_row + tables)
 
@@ -112,9 +168,8 @@ def _block_plan(n: int, interpret: bool,
     (the sublane tile of a uint8 bin block; a block narrower than the
     array must be tile-aligned), so every grid step sees a full block —
     rows are bucket-padded by staging, so aligned divisors are dense.
-    Unlike the fit kernel's plan this never changes results: the
-    traversal has no cross-row reduction, so blocking is pure VMEM
-    scheduling.
+    Blocking never changes results: the traversal has no cross-row
+    reduction, so it is pure VMEM scheduling.
 
     `block_rows` is resolved HOST-side (`inference.resolve_infer_kernel`
     reads `sml.infer.kernelBlockRows` once per program build, and the

@@ -65,15 +65,11 @@ COUNTERS = {
     "staging.evict_bytes", "staging.bin_evict_bytes",
     "shuffle.rows", "shuffle.bytes",
     "cv.batchFolds.fallback",
-    # fused tree kernels (native/hist_kernel.py, docs/KERNELS.md):
-    # kernel.pallas_launch / kernel.interpret are TRACE-TIME statics
-    # (counted once per program trace, like collective.*: launches per
-    # execution = the count × executions); kernel.fallback counts fits
-    # where `auto` wanted pallas on a TPU whose toolchain probe failed,
-    # or where the static VMEM guard demoted the spec — bench_diff
-    # treats any growth as a regression (an explicit 'pallas' that
-    # cannot run raises; it is never counted)
-    "kernel.*",
+    # Pallas launches of the traversal kernel (native/traverse_kernel.py,
+    # docs/KERNELS.md): TRACE-TIME statics (counted once per program
+    # trace, like collective.*: launches per execution = the count ×
+    # executions); kernel.interpret counts those traced in interpret mode
+    "kernel.pallas_launch", "kernel.interpret",
     # host-side C++ libraries (native/build.py): a library that could not
     # be built or loaded, so its callers run the NumPy implementation
     "native.build_failed",
@@ -81,8 +77,8 @@ COUNTERS = {
     # + ml/inference.py resolution): infer.kernel.pallas / infer.kernel.xla
     # count spec resolutions landing on each path; infer.kernel.fallback
     # counts dispatches that `auto` (or a tuned spec) wanted on pallas
-    # but that demoted to XLA — obs/regress.py flags any growth, like
-    # kernel.fallback; infer.kernel.autotune_s accumulates --kernelbench
+    # but that demoted to XLA — obs/regress.py flags any growth;
+    # infer.kernel.autotune_s accumulates --kernelbench
     # sweep seconds (the cost the persisted manifest spec amortizes away)
     "infer.kernel.*",
     "compile.programs",

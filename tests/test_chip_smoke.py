@@ -34,7 +34,6 @@ def test_rehearsal_passes_with_pallas_interpreted(tmp_path):
     with open(tmp_path / "summary.json") as f:
         summary = json.load(f)
     assert summary["score_kernel"] == "pallas"
-    assert summary["fit_kernel"] == ["xla"]
     assert summary["failures"] == []
     # the pallas answers were compared with the XLA traversal's
     assert summary["pallas_vs_xla_max_abs_diff"] is not None

@@ -3,9 +3,21 @@
 GOLDEN.json pins the bench-shaped model metrics at n=100k/seed=42 on the
 CPU test mesh (f32 histograms — the TPU bench runs bf16 histogram operands
 and reports its own values in BENCH_r*.json). Any numerics change that
-moves a pinned metric fails CI; intentional changes regenerate with
+moves a pinned metric fails CI, each pin its own case so that one that
+moves does not hide the others; intentional changes regenerate with
 
-    python tests/test_golden_metrics.py --regen
+    python tests/test_golden_metrics.py --regen [metric ...]
+
+(no names: every pin; with names: those alone, the others left as they
+stand). The pins date from PR 6 but for `rmse_rf`, re-pinned in PR 30 on
+jax 0.9.0 / jaxlib 0.9.0: since jax 0.5.0 `jax_threefry_partitionable`
+defaults to True, so a key yields other random bits than the ones the pin
+was taken on, and the forest's Poisson bootstrap weights and per-node
+feature subsets are other draws (tree 0's root cover 80,141 for 80,593,
+its root split on another feature). With the flag set False this tree
+still reads the old 69.071877 to 3e-6. Nothing else pinned here draws
+from `jax.random`: the other nine held within 1.2e-5 across the same
+upgrade.
 
 Also asserts the orderings the course states in prose: LR beats the
 mean-price baseline (`ML 02:155`), tuned RF at least matches a single
@@ -144,27 +156,31 @@ def metrics():
     return compute_metrics()
 
 
-def test_metrics_match_golden(metrics):
+def _golden() -> dict:
     assert os.path.exists(GOLDEN_PATH), \
         "GOLDEN.json missing; run: python tests/test_golden_metrics.py --regen"
     with open(GOLDEN_PATH) as f:
-        golden = json.load(f)
+        return json.load(f)
+
+
+@pytest.mark.parametrize("k", list(_golden()["metrics"]))
+def test_metrics_match_golden(metrics, k):
+    golden = _golden()
     assert golden["n_rows"] == N_ROWS and golden["seed"] == 42
-    for k, want in golden["metrics"].items():
-        got = metrics[k]
-        if k == "_kmeans_centers":
-            np.testing.assert_allclose(np.asarray(got, dtype=float),
-                                       np.asarray(want, dtype=float),
-                                       atol=1e-3)
-            continue
-        # large-magnitude pins (kmeans_cost ~1e8) get a relative gate: an
-        # absolute 1e-3 there would be tighter than one float32 ULP
-        tol = max(1e-3, 1e-5 * abs(want))
-        assert abs(got - want) < tol, \
-            f"{k}: got {got}, golden {want} (Δ={abs(got - want):.2e})"
     # pin breadth: the gate must cover regression, classification,
     # recommendation, and clustering metrics (VERDICT r3 #9)
     assert len(golden["metrics"]) >= 10
+    got, want = metrics[k], golden["metrics"][k]
+    if k == "_kmeans_centers":
+        np.testing.assert_allclose(np.asarray(got, dtype=float),
+                                   np.asarray(want, dtype=float),
+                                   atol=1e-3)
+        return
+    # large-magnitude pins (kmeans_cost ~1e8) get a relative gate: an
+    # absolute 1e-3 there would be tighter than one float32 ULP
+    tol = max(1e-3, 1e-5 * abs(want))
+    assert abs(got - want) < tol, \
+        f"{k}: got {got}, golden {want} (Δ={abs(got - want):.2e})"
 
 
 def test_course_stated_orderings(metrics):
@@ -183,19 +199,29 @@ def test_course_stated_orderings(metrics):
     assert metrics["auroc_logistic"] > 0.6
 
 
-def _regen():
+def _regen(only=()):
+    """Re-pin every metric, or the ones named in `only` alone."""
+    import jax
+    import jaxlib
     # preserve foreign top-level blocks (bench_metrics_1m is written by
     # `python bench.py --pin-goldens`, not by this regen)
     doc = {}
     if os.path.exists(GOLDEN_PATH):
         with open(GOLDEN_PATH) as f:
             doc = json.load(f)
-    doc.update({"n_rows": N_ROWS, "seed": 42,
-                "environment": "virtual 8-device CPU mesh (f32 "
-                               "histograms); the TPU bench uses bf16 "
-                               "histogram operands and reports its own "
-                               "metric values in BENCH_r*.json",
-                "metrics": compute_metrics()})
+    got = compute_metrics()
+    on = f"jax {jax.__version__} / jaxlib {jaxlib.__version__}"
+    if only:
+        doc["metrics"].update({k: got[k] for k in only})
+        doc["environment"] += f"; {', '.join(only)} re-pinned on {on}"
+    else:
+        doc.update({"n_rows": N_ROWS, "seed": 42,
+                    "environment": "virtual 8-device CPU mesh (f32 "
+                                   "histograms); the TPU bench uses bf16 "
+                                   "histogram operands and reports its own "
+                                   "metric values in BENCH_r*.json; pinned "
+                                   f"on {on}",
+                    "metrics": got})
     with open(GOLDEN_PATH, "w") as f:
         json.dump(doc, f, indent=1)
     print(f"wrote {os.path.abspath(GOLDEN_PATH)}")
@@ -210,4 +236,4 @@ if __name__ == "__main__":
     import jax
     jax.config.update("jax_platforms", "cpu")
     if "--regen" in sys.argv:
-        _regen()
+        _regen(sys.argv[sys.argv.index("--regen") + 1:])

@@ -118,14 +118,14 @@ def _fit_predict(spark, X, y, estimator_factory, width, log_label=False):
 
 
 @pytest.mark.parametrize("kind", ["dt", "rf", "xgb"])
-def test_fit_goldens_8dev_vs_1dev(spark, xy, kind):
+def test_fit_goldens_8dev_vs_1dev(spark, xy, kind, same_boosted_fit):
     """The same estimator fit on 8 devices and on 1 device produces the
     same model (predictions + rmse within float reduction-order
     tolerance). Before r6, RF/boosting sampling folded the shard index
     into its key, so the fitted forest depended on the mesh LAYOUT.
 
     Boosting is held to what "the same model" can mean for rounds that
-    build on each other (`_same_boosted_fit`): the two layouts are two
+    build on each other (`conftest.same_boosted_fit`): the two layouts are two
     compilations that sum a histogram in another order, and a split whose
     two best candidates tie to the last ulp may fall either way."""
     X, y = xy
@@ -148,46 +148,15 @@ def test_fit_goldens_8dev_vs_1dev(spark, xy, kind):
     p8, rmse8 = _fit_predict(spark, X, y, factory, 8)
     p1, rmse1 = _fit_predict(spark, X, y, factory, 1)
     if kind == "xgb":
-        assert _same_boosted_fit(p8, p1, rmse8, rmse1) == []
+        assert same_boosted_fit(p8, p1, rmse8, rmse1) == []
         # the control: one layout's own predictions rounded to bfloat16 (a
         # descent in the next lower precision) are NOT the same fit
         import ml_dtypes
         low = p1.astype(ml_dtypes.bfloat16).astype(np.float64)
-        assert len(_same_boosted_fit(low, p1, rmse1, rmse1)) == 2
+        assert len(same_boosted_fit(low, p1, rmse1, rmse1)) == 2
         return
     np.testing.assert_allclose(p8, p1, rtol=1e-4, atol=1e-4)
     assert abs(rmse8 - rmse1) < 1e-4 * max(abs(rmse1), 1.0)
-
-
-def _same_boosted_fit(p8, p1, rmse8, rmse1) -> list:
-    """What fails of "8 shards fit what 1 shard fits" for a boosted
-    ensemble; empty when nothing does. Read on this table (8 rounds of
-    depth 4, XLA:CPU): rounds 0-2 are the same trees, one node of round 3
-    takes the neighbouring bin (a tie), and every later round's leaves
-    follow from that: median |difference| 1.6e-5, 1.9 % of the rows beyond
-    1e-3 (those the moved threshold re-routes, up to 0.31), rmse apart by
-    8.2e-5 of itself. The same predictions rounded to bfloat16: median
-    2.1e-3, 69 % of the rows beyond 1e-3.
-
-    - the median row agrees to 1e-4 (six times the sound reading, a
-      twentieth of the control's): most rows take the same path through
-      every tree, and differ by the order of float32 sums alone;
-    - at most 5 % of the rows differ by more than 1e-3 (sound 1.9 %,
-      control 69 %): a tie that falls the other way re-routes the rows
-      between two neighbouring thresholds of one node, not a layout's
-      worth of them. Before r6, when the shard index was folded into the
-      sampling key, every row differed;
-    - the two fits are equally good: rmse within 1e-3 of itself (a tie is
-      a tie because both splits gain the same)."""
-    gap = np.abs(np.asarray(p8, np.float64) - np.asarray(p1, np.float64))
-    failed = []
-    if not np.median(gap) <= 1e-4:
-        failed.append(f"median gap {np.median(gap)}")
-    if not np.mean(gap > 1e-3) <= 0.05:
-        failed.append(f"{np.mean(gap > 1e-3):.3f} of the rows beyond 1e-3")
-    if not abs(rmse8 - rmse1) <= 1e-3 * abs(rmse1):
-        failed.append(f"rmse {rmse8} against {rmse1}")
-    return failed
 
 
 def test_cv_avgmetrics_and_dispatch_parity_8dev_vs_1dev(spark, xy,
@@ -380,9 +349,12 @@ def test_hist_subtraction_halves_psum_payload(xy):
             obs.reset()
             with _mesh(8):
                 # fresh program per toggle (the setting is a cache key),
-                # so trace-time counters fire for both variants
+                # so trace-time counters fire for both variants; static
+                # params no other fit of the suite has (test_hierarchical
+                # fits 16 bins on this very mesh: a worker that ran it
+                # first would hit its program and count nothing)
                 _fit_ensemble(X, y, categorical={}, max_depth=4,
-                              max_bins=16, min_instances=1,
+                              max_bins=20, min_instances=1,
                               min_info_gain=0.0, n_trees=2, feature_k=None,
                               bootstrap=False, subsample=1.0, seed=3,
                               loss="squared")

@@ -176,6 +176,40 @@ def test_prewarm_skips_foreign_mesh_entries(prewarm_env, reg_frames):
     assert stats["skipped"] == len(man["entries"])
 
 
+def test_a_manifest_from_before_pr30_replays_with_its_kernel_fields_ignored(
+        prewarm_env, reg_frames):
+    """Until PR 30 every tree entry carried `kernel` and `kernel_rows` (the
+    Pallas fit kernels' switch and block scheme, deleted there). A manifest
+    an older tree wrote still replays: the fields are ignored, the tree
+    entries carry neither any more, and the replay warms the program the
+    next fit uses."""
+    from sml_tpu.ml.regression import RandomForestRegressor
+    from sml_tpu.parallel import prewarm
+
+    fdf, _ = reg_frames
+    rf = RandomForestRegressor(labelCol="label", numTrees=3, maxDepth=2,
+                               seed=5)
+    rf.fit(fdf)
+    mpath = os.path.join(prewarm_env, "prewarm_manifest.json")
+    with open(mpath) as f:
+        man = json.load(f)
+    trees = [e for e in man["entries"].values()
+             if e["kind"].startswith("tree_")]
+    assert trees
+    for e in trees:
+        assert "kernel" not in e["meta"] and "kernel_rows" not in e["meta"]
+        e["meta"].update(kernel="pallas", kernel_rows=4096)
+    with open(mpath, "w") as f:
+        json.dump(man, f)
+    prewarm._state["entries"] = None
+    _clear_program_caches()
+    stats = prewarm.prewarm(workers=2)
+    assert stats["failed"] == 0 and stats["replayed"] == stats["programs"]
+    c0 = PROFILER.counters()
+    rf.fit(fdf)
+    assert _delta(c0, PROFILER.counters(), "compile.programs") == 0
+
+
 def test_maybe_prewarm_is_opt_in_and_guarded_per_manifest_mesh(
         prewarm_env, monkeypatch, tmp_path):
     """The replay guard is keyed per (manifest, mesh) — NOT once per

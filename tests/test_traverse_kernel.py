@@ -201,9 +201,9 @@ def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
     land on xla and report the right numbers from another path. Only
     `auto` on a TPU mesh may decline, and that is counted."""
     from sml_tpu.ml import inference, tree_impl
-    from sml_tpu.native import hist_kernel
+    from sml_tpu.native import traverse_kernel
     from sml_tpu.parallel import mesh as meshlib
-    monkeypatch.setitem(hist_kernel._avail, True, "Boom: no pallas here")
+    monkeypatch.setitem(traverse_kernel._avail, True, "Boom: no pallas here")
     GLOBAL_CONF.set("sml.infer.kernel", "pallas")
     f0 = inference._KERNEL_STATE["fallbacks"]
     with pytest.raises(RuntimeError, match="Boom: no pallas here"):
@@ -217,7 +217,7 @@ def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
         _margins(spec, binned, "pallas")
     # auto on a (simulated) TPU mesh whose COMPILED probe fails: xla,
     # counted as a fallback — the one place the ladder may decline
-    monkeypatch.setitem(hist_kernel._avail, False, "Boom: no mosaic")
+    monkeypatch.setitem(traverse_kernel._avail, False, "Boom: no mosaic")
     GLOBAL_CONF.set("sml.infer.kernel", "auto")
     mesh = meshlib.get_mesh()
     tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")
@@ -237,10 +237,10 @@ def test_vmem_guard_demotes_oversized_specs_on_tpu(spark, infer_conf,
     bust it demotes to xla with fallback + demotion counts; CPU
     interpret mode never clamps or demotes."""
     from sml_tpu.ml import inference, tree_impl
-    from sml_tpu.native import hist_kernel
+    from sml_tpu.native import traverse_kernel
     from sml_tpu.parallel import mesh as meshlib
     # the simulated TPU has no Mosaic: stand in for its compiled probe
-    monkeypatch.setitem(hist_kernel._avail, False, None)
+    monkeypatch.setitem(traverse_kernel._avail, False, None)
     GLOBAL_CONF.set("sml.infer.kernel", "pallas")
     GLOBAL_CONF.set("sml.infer.kernelBlockRows", 10 ** 6)
     k, br, _ = inference.resolve_infer_kernel(

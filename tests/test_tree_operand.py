@@ -49,7 +49,7 @@ def _rows(n: int, seed: int = 0):
 def _program(which: str, es):
     """(per-chip program, its arguments at 64 rows, which are row-sharded)."""
     binned, y, mask, rng = _rows(64)
-    made = (es, 1, "xla", 0, (D,), 0)
+    made = (es, 1, (D,), 0)
     if which == "ensemble":
         return tree_impl._make_ensemble_program(*made), \
             (binned, y, mask, rng), (True, True, True, False)
@@ -128,8 +128,7 @@ def test_the_single_tree_program_builds_it_the_same_way_without_a_barrier():
     """One helper, but no loop to keep the operand out of: no barrier, so
     the program XLA:CPU compiles is the one it always was."""
     spec = _es(True).tree
-    program = tree_impl._build_tree_program(spec, jnp.bfloat16, "xla", 0,
-                                            (D,), 0)
+    program = tree_impl._build_tree_program(spec, jnp.bfloat16, (D,), 0)
     binned, y, mask, rng = _rows(64)
     args = (binned, -y, mask, mask, rng)
     mapped, _ = _sharded(program, args, (True, True, True, True, False),
@@ -141,10 +140,50 @@ def test_the_single_tree_program_builds_it_the_same_way_without_a_barrier():
                and e.outvars[0].aval.shape == (F * B, 64) for e in eqns)
 
 
-def test_pallas_builds_no_operand():
-    binned, none = tree_impl._tree_operand(
-        jnp.zeros((16, F), jnp.uint8), B, jnp.bfloat16, "pallas")
-    assert none is None and binned.dtype == jnp.int32
+# ------------------------------------- what the operand's type and size hang on
+# (before (b): its module fixture gives `_hist_dtype` the chip's answer)
+def test_mesh_platform_memo_and_invalidation(spark):
+    """`_hist_dtype`'s platform probe is memoized per MESH identity (it
+    used to walk mesh.devices.flat on every fit-setup call): the memo
+    answers for the same mesh, and a different mesh re-probes."""
+    mesh = meshlib.get_mesh()
+    tree_impl._platform_memo.clear()
+    try:
+        assert tree_impl._hist_dtype() == jnp.float32
+        assert tree_impl._platform_memo.get(id(mesh))[1] == "cpu"
+        # memo is authoritative for the same mesh: poison it, no re-probe,
+        # and the operand's type follows the memo's platform
+        tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")
+        assert tree_impl._hist_dtype() == jnp.bfloat16
+        # a DIFFERENT mesh identity re-probes (the poison doesn't leak) —
+        # including an id() COLLISION after GC: the memo re-checks identity
+        other = meshlib.build_mesh(1)
+        assert tree_impl._mesh_platform(other) == "cpu"
+        tree_impl._platform_memo[id(other)] = (mesh, "tpu")  # stale identity
+        assert tree_impl._mesh_platform(other) == "cpu"
+        tree_impl._platform_memo[id(mesh)] = (mesh, "cpu")
+        assert tree_impl._hist_dtype() == jnp.float32
+    finally:
+        tree_impl._platform_memo.clear()
+
+
+def test_onehot_ledger_reads_the_operand_for_a_fit_and_zero_after(spark):
+    """The HBM ledger charges the one-hot resident under `hist_onehot` for
+    as long as a fit's dispatch lasts: rows (as staged, padding included) x
+    F x bins x itemsize at its peak, nothing once the fit has returned."""
+    from sml_tpu.ml._staging import stage_sharded
+    from sml_tpu.obs import LEDGER
+    binned, y, _, _ = _rows(3000, seed=5)
+    b_dev, mask_dev, _ = stage_sharded(binned)
+    y_dev = tree_impl.stage_aligned(y, b_dev.shape[0])
+    LEDGER.reset_peaks()
+    tree_impl.fit_ensemble_on_device(b_dev, y_dev, mask_dev, _es(True),
+                                     seed=7)
+    pool = LEDGER.snapshot()["hist_onehot"]
+    assert pool["allocs"] == 1 and pool["frees"] == 1
+    assert pool["peak"] == b_dev.shape[0] * F * B \
+        * np.dtype(tree_impl._hist_dtype()).itemsize
+    assert pool["live"] == 0
 
 
 # ------------------------------------------- (b) compiled for a described v5e
@@ -258,7 +297,7 @@ def test_fitted_packs_are_bit_equal_without_the_barrier(boosting, monkeypatch):
     binned, y, mask, rng = _rows(512, seed=3)
 
     def fit():
-        program = tree_impl._make_ensemble_program(es, 1, "xla", 0, (D,), 0)
+        program = tree_impl._make_ensemble_program(es, 1, (D,), 0)
         mapped, _ = _sharded(program, (binned, y, mask, rng),
                              (True, True, True, False), _cpu_mesh())
         packs, base = jax.jit(mapped)(binned, y, mask, rng)
