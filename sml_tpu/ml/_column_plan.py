@@ -39,11 +39,13 @@ from typing import List, NamedTuple, Optional
 import numpy as np
 import pandas as pd
 
+from ..parallel.pipeline import mark_host_worker, on_host_worker
 from .feature import imputer_surrogate, indexer_labels, order_labels
 from .featurizer import _IndexSource, _numeric
 
 #: rows of the block one interleave task writes: 65,536 x 10 float32 is
-#: 2.6 MB, read from ten contiguous runs and stored once
+#: 2.6 MB, read from ten contiguous runs and stored once (and the rows one
+#: digitize task of the quantize plan bins, `tree_impl._bin_columns`)
 _BLOCK_ROWS = 65536
 
 #: below this many rows the jobs run inline on the calling thread: waking
@@ -51,7 +53,10 @@ _BLOCK_ROWS = 65536
 #: (3 strings, 7 medians) on the chip tool's one-chip host, 13 cores, inline
 #: against pool, median ms of 30: 16,000 rows 3.9 / 7.6, 48,000 8.7 / 9.5,
 #: 64,000 11.1 / 10.4, 96,000 16.7 / 10.7, 256,000 41.8 / 12.8 (PERF.md
-#: section 6, PR 29)
+#: section 6, PR 29). The quantize plan shares the threshold; its own
+#: crossing is lower (`make_bins` on the same host: 16,000 rows 6.5 / 6.4,
+#: 32,000 10.7 / 8.2, 65,536 20.3 / 10.8; PR 31): one rule for both, at
+#: most 10 ms dearer for a table between the two
 _INLINE_ROWS = 65536
 
 _pool: Optional[ThreadPoolExecutor] = None
@@ -67,17 +72,39 @@ def _cores() -> int:
 
 def _executor() -> ThreadPoolExecutor:
     """THE pool of the process, sized from the cores the process may run
-    on (as `native/binning.cc` sizes itself): `TpuTrials` and
-    `CrossValidator(parallelism>1)` fit pipelines from several threads at
-    once, and a pool a fit would multiply the threads. A job never waits
-    on another job, so fits sharing the pool cannot deadlock."""
+    on; the quantize plan (`tree_impl.make_bins`, `_bin_columns`) runs its
+    jobs on it too. `TpuTrials` and `CrossValidator(parallelism>1)` fit
+    pipelines from several threads at once, and a pool a fit would
+    multiply the threads. A job never waits on another job, so fits
+    sharing the pool cannot deadlock."""
     global _pool
     if _pool is None:
         with _pool_lock:
             if _pool is None:
                 _pool = ThreadPoolExecutor(max_workers=_cores(),
-                                           thread_name_prefix="sml-column")
+                                           thread_name_prefix="sml-column",
+                                           initializer=mark_host_worker)
     return _pool
+
+
+def runs_inline(rows: int) -> bool:
+    """Whether tasks over `rows` rows run on the calling thread: a table
+    under `_INLINE_ROWS`, or a caller that is itself a worker of one of
+    the process's host pools (`ml/_chunked.py` quantizes a chunk a worker;
+    a task never submits to the pool it runs on)."""
+    return rows < _INLINE_ROWS or on_host_worker()
+
+
+def run_tasks(tasks: list, inline: bool) -> list:
+    """Every task's result, in the tasks' order. All of them end before
+    an error is raised, on the caller's thread: the first in the tasks'
+    order, as the sequential pass would have met it."""
+    if inline:
+        return [t() for t in tasks]
+    pool = _executor()
+    futures = [pool.submit(t) for t in tasks]
+    wait(futures)
+    return [f.result() for f in futures]
 
 
 class JobResult(NamedTuple):
@@ -220,29 +247,19 @@ class Plan:
 
     def __init__(self, pdf: pd.DataFrame, jobs: list, write: bool = True):
         self.rows = len(pdf)
-        self.inline = self.rows < _INLINE_ROWS
+        self.inline = runs_inline(self.rows)
         self.workers = 1 if self.inline else _cores()
         self.jobs = jobs
         assembled = sum(j.row is not None for j in jobs)
         self.scratch = scratch = np.empty(
             (assembled, self.rows), dtype=np.float32) if write else None
         # write=False: the statistics alone, no row of any column
-        results = self._run(
+        results = run_tasks(
             [lambda j=j: j.run(pdf, None if scratch is None or j.row is None
-                               else scratch[j.row]) for j in jobs])
+                               else scratch[j.row]) for j in jobs],
+            self.inline)
         for j, r in zip(jobs, results):
             j.result = r
-
-    def _run(self, tasks: list) -> list:
-        """Every task's result, in the tasks' order. All of them end
-        before an error is raised, on the caller's thread: the first in
-        the tasks' order, as the sequential pass would have met it."""
-        if self.inline:
-            return [t() for t in tasks]
-        pool = _executor()
-        futures = [pool.submit(t) for t in tasks]
-        wait(futures)
-        return [f.result() for f in futures]
 
     def block(self, onehot: List[Optional[int]], check_finite: bool):
         """(X, keep) as `transform_with_mask` returns them: the row-major
@@ -272,8 +289,9 @@ class Plan:
             return drop is not None \
                 and bool(np.isfinite(blk[~drop[r0:r1]]).all())
 
-        if not all(self._run([lambda r0=r0: task(r0)
-                              for r0 in range(0, n, _BLOCK_ROWS)])):
+        if not all(run_tasks([lambda r0=r0: task(r0)
+                              for r0 in range(0, n, _BLOCK_ROWS)],
+                             self.inline)):
             raise ValueError(
                 "VectorAssembler found NaN/null in assembled features; set "
                 "handleInvalid='skip' or impute first")

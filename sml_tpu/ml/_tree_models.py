@@ -193,7 +193,6 @@ def _cached_bins(X, y32, max_bins, categorical):
     parameter set — re-quantizing 1M rows per fit was ~0.3s apiece.
     Byte-budgeted and locked like the staging cache (same concurrent
     TpuTrials path, same multi-100MB operands)."""
-    from ..native.build import load_library
     from ._staging import _content_key, _normalize
     from .tree_impl import make_bins
     with PROFILER.span("fit.quantize", rows=int(X.shape[0])) as note:
@@ -217,9 +216,7 @@ def _cached_bins(X, y32, max_bins, categorical):
             waiter.wait()
         note["hit"] = False
         try:
-            with PROFILER.span("fit.quantize.bins",
-                               native=load_library("binning") is not None):
-                hit = make_bins(Xc, y32, max_bins, categorical)
+            hit = make_bins(Xc, y32, max_bins, categorical)  # its own span
             cost = hit[0].nbytes
             with _bins_lock:
                 _bins_cache[key] = hit

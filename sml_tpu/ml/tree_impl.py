@@ -147,73 +147,140 @@ def make_bins(X: np.ndarray, y: np.ndarray, max_bins: int,
               max_categories_error: bool = True) -> Tuple[np.ndarray, Binning]:
     """Host-side discretization. Continuous features: quantile edges.
     Categorical slots: identity bins ordered by mean label; cardinality must
-    fit in max_bins, reproducing Spark's maxBins error (`ML 06:91-126`)."""
+    fit in max_bins, reproducing Spark's maxBins error (`ML 06:91-126`).
+
+    A plan of two phases on the column plan's pool (`_column_plan`: the one
+    pool of the process, inline under its row threshold and on a worker
+    thread, the same result either way): a job a COLUMN makes the column's
+    bin statistic from one visit (`_column_stats`), `finalize_binning`
+    assembles the edges, then a job a BLOCK OF ROWS writes the bins once
+    (`_bin_columns`). Jobs open no spans and bump no counters; this
+    thread does: span `fit.quantize.bins` with its two phases as children,
+    and `quantize.plan.fits` / `.inline` for where the jobs ran."""
+    from ..native.build import load_library
+    from . import _column_plan as cp
     n, F = X.shape
     categorical = categorical or {}
-    cont_quantiles: Dict[int, Optional[np.ndarray]] = {}
-    cat_means: Dict[int, np.ndarray] = {}
-    for f in range(F):
-        col = X[:, f]
-        if f in categorical:
-            card = int(categorical[f])
-            means = np.full(card, np.inf)
-            ids = col.astype(np.int64)
-            ids = np.clip(ids, 0, card - 1)
-            for c in range(card):
-                sel = ids == c
-                if sel.any():
-                    means[c] = float(y[sel].mean()) if y is not None else c
-            cat_means[f] = means
-        else:
-            finite = col[np.isfinite(col)]
-            if len(finite) == 0:
-                cont_quantiles[f] = None
-                continue
-            # edges from a deterministic subsample above 256k rows — the
-            # same approximation Spark's approxQuantile binning and
-            # sklearn's HistGradientBoosting use; full-data quantiles cost
-            # ~1.2s/fit at 1M rows and change edges negligibly
-            if len(finite) > 262_144:
-                stride = -(-len(finite) // 262_144)
-                finite = finite[::stride]
-            cont_quantiles[f] = np.quantile(
-                finite, np.linspace(0, 1, max_bins + 1)[1:-1])
-    binning, edge_list, out_dtype = finalize_binning(
-        F, max_bins, categorical, cont_quantiles, cat_means,
-        max_categories_error=max_categories_error)
-    binned = _bin_columns(X, edge_list, binning.cat_remap, out_dtype)
+    y = None if y is None else np.asarray(y)
+    inline = cp.runs_inline(n)
+    with PROFILER.span("fit.quantize.bins",
+                       native=load_library("binning") is not None,
+                       workers=1 if inline else cp._cores(), columns=F,
+                       blocks=-(-n // cp._BLOCK_ROWS)):
+        with PROFILER.span("fit.quantize.stats"):
+            probs = np.linspace(0, 1, max_bins + 1)[1:-1]
+            stats = cp.run_tasks(
+                [partial(_column_stats, X, y, f, categorical.get(f), probs)
+                 for f in range(F)], inline)
+            binning, edge_list, out_dtype = finalize_binning(
+                F, max_bins, categorical,
+                {f: q for f, q in enumerate(stats) if f not in categorical},
+                {f: stats[f] for f in categorical},
+                max_categories_error=max_categories_error)
+        with PROFILER.span("fit.quantize.digitize"):
+            binned = _bin_columns(X, edge_list, binning.cat_remap, out_dtype)
+    if inline:
+        PROFILER.count("quantize.plan.inline")
+    else:
+        PROFILER.count("quantize.plan.fits")
     return binned, binning
+
+
+def _column_stats(X: np.ndarray, y: Optional[np.ndarray], f: int,
+                  card: Optional[int], probs: np.ndarray):
+    """The bin statistic of column f from ONE visit: the job copies its
+    column out of the block (contiguous; every further pass walks 1/F of
+    the block) and returns the raw `np.quantile` values of a continuous
+    slot (None where nothing is finite), or a categorical slot's
+    per-category mean labels (inf for absent categories): what
+    `finalize_binning` takes."""
+    col = np.ascontiguousarray(X[:, f])
+    if card is None:
+        finite = col[np.isfinite(col)]
+        if len(finite) == 0:
+            return None
+        # edges from a deterministic subsample above 256k rows — the
+        # same approximation Spark's approxQuantile binning and
+        # sklearn's HistGradientBoosting use; full-data quantiles cost
+        # ~1.2s/fit at 1M rows and change edges negligibly
+        if len(finite) > 262_144:
+            stride = -(-len(finite) // 262_144)
+            finite = finite[::stride]
+        return np.quantile(finite, probs)
+    counts, grouped = _group_labels(col, int(card), y)
+    seen = np.nonzero(counts)[0]
+    means = np.full(len(counts), np.inf)
+    if y is None:
+        means[seen] = seen
+        return means
+    # each category's mean by the arithmetic a masked `y[ids == c].mean()`
+    # has, on the same values in the same order: the means decide the
+    # ORDER of the categories, so they keep their last bit
+    hi = np.cumsum(counts)
+    for c in seen:
+        means[c] = float(grouped[hi[c] - counts[c]:hi[c]].mean())
+    return means
+
+
+def _group_labels(col: np.ndarray, card: int, y: Optional[np.ndarray]):
+    """The rows of a categorical column grouped ONCE: (rows a category,
+    y's values category by category, each category's in row order), by
+    the C++ kernel when available, else a stable sort of the ids."""
+    from ..native import binning as _native_binning
+    got = _native_binning.group_labels(col, card, y)
+    if got is not None:
+        return got
+    ids = np.clip(col.astype(np.int64), 0, card - 1)
+    counts = np.bincount(ids, minlength=card)
+    if y is None:
+        return counts, None
+    # narrowed ids sort by counting (NumPy's stable sort of small integers)
+    return counts, y[np.argsort(ids.astype(bin_dtype(card)), kind="stable")]
 
 
 def _bin_columns(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
                  out_dtype=np.int32) -> np.ndarray:
-    """Full-column discretization against known edges/remaps: the threaded
-    C++ kernel (`native/binning.cc`) when available, NumPy otherwise —
-    identical semantics (searchsorted 'left'; non-finite → bin 0).
+    """Discretization against known edges/remaps, a job a block of rows:
+    every block is binned for all F columns and written once, as
+    contiguous rows of the result in `out_dtype`, by the C++ kernel
+    (`native/binning.cc`) when available, NumPy otherwise — identical
+    semantics (searchsorted 'left'; non-finite → bin 0; a categorical
+    slot's rank looked up in the same pass). The blocks run on the
+    column plan's pool, or inline for few rows (a serving batch) and on
+    a worker thread (`ml/_chunked.py` calls this a chunk a worker).
     `out_dtype` is the quantized engine's compact storage dtype (see
     `bin_dtype`); callers size it over max_bins AND every categorical
     cardinality, so all bin ids fit by construction."""
     from ..native import binning as _native_binning
+    from . import _column_plan as cp
     n, F = X.shape
-    binned = _native_binning.bin_continuous(X, edge_list, remaps)
-    if binned is not None:
-        binned = binned.astype(out_dtype, copy=False)
-    else:
-        binned = np.zeros((n, F), dtype=out_dtype)
-        for f in range(F):
-            if f in remaps:
-                continue
-            qs = edge_list[f]
-            if len(qs) == 0:
-                continue
-            col = X[:, f]
-            binned[:, f] = np.searchsorted(qs, col,
-                                           side="left").astype(out_dtype)
-            binned[~np.isfinite(col), f] = 0  # missing → lowest bin
-    for f, rank in remaps.items():
-        ids = np.clip(X[:, f].astype(np.int64), 0, len(rank) - 1)
-        binned[:, f] = rank[ids]
+    binned = np.empty((n, F), dtype=out_dtype)
+    native = _native_binning.row_binner(edge_list, remaps)
+
+    def block(r0: int) -> None:
+        rows, out = X[r0:r0 + cp._BLOCK_ROWS], binned[r0:r0 + cp._BLOCK_ROWS]
+        if native is None or not native(rows, out):
+            _bin_rows_numpy(rows, edge_list, remaps, out)
+
+    cp.run_tasks([partial(block, r0) for r0 in range(0, n, cp._BLOCK_ROWS)],
+                 cp.runs_inline(n))
     return binned
+
+
+def _bin_rows_numpy(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
+                    out: np.ndarray) -> None:
+    """`native/binning.cc`'s `bin_rows` in NumPy: the rows of X into the
+    rows of `out`, every column."""
+    for f, qs in enumerate(edge_list):
+        col = X[:, f]
+        rank = remaps.get(f)
+        if rank is not None:
+            out[:, f] = rank[np.clip(col.astype(np.int64), 0, len(rank) - 1)]
+        elif len(qs) == 0:
+            out[:, f] = 0
+        else:
+            out[:, f] = np.searchsorted(qs, col, side="left")
+            out[~np.isfinite(col), f] = 0  # missing → lowest bin
 
 
 import threading as _threading

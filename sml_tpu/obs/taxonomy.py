@@ -30,7 +30,8 @@ SPANS = {
     # one span tree a fit (docs/OBSERVABILITY.md "The span tree of a
     # fit"): the root `fit` opened by the outermost Estimator.fit, and
     # its host phases fit.collect / fit.prep / fit.featurize /
-    # fit.quantize (.key, .bins) / fit.stage / fit.baseline, plus
+    # fit.quantize (.key, .bins with its phases .stats and .digitize) /
+    # fit.stage / fit.baseline, plus
     # fit.dispatch / fit.device_wait / fit.readback / fit.unpack inside
     # the tree programs' spans
     "fit", "fit.*",
@@ -97,6 +98,11 @@ COUNTERS = {
     # columns that ran the sequential per-column code inside their job
     "featurize.plan.fits", "featurize.plan.declined",
     "featurize.plan.columns_legacy",
+    # the quantize plan (tree_impl.make_bins: a job a column for the bin
+    # statistics, a job a block of rows for the bins): a make_bins that ran
+    # its jobs on the column plan's pool / one that ran them on the caller
+    # (few rows, or the caller is itself a pool worker)
+    "quantize.plan.fits", "quantize.plan.inline",
     # prewarm manifest (parallel/prewarm.py): recorded signatures,
     # replayed/failed first-dispatches, pool-size attribution
     "prewarm.*",

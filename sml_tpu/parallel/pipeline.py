@@ -25,9 +25,25 @@ per item (the PR-2 contract); the pipeline itself runs regardless.
 
 from __future__ import annotations
 
+import threading
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Iterator, Optional
+
+_tls = threading.local()
+
+
+def mark_host_worker() -> None:
+    """The `initializer` of the process's host-side worker pools (the two
+    below and `ml/_column_plan`'s): code that would fan its own work out
+    over the column pool asks `on_host_worker()` first and runs inline on
+    a worker, so a task never submits to the pool it runs on and the
+    pools do not multiply each other's threads."""
+    _tls.host_worker = True
+
+
+def on_host_worker() -> bool:
+    return getattr(_tls, "host_worker", False)
 
 
 def prefetch_pipeline(items: Iterable, prep: Callable, dispatch: Callable,
@@ -67,7 +83,8 @@ def prefetch_pipeline(items: Iterable, prep: Callable, dispatch: Callable,
             note_pipeline(family, "drain", index_key, i)
         return out
 
-    with ThreadPoolExecutor(max_workers=max(int(workers), 1)) as ex:
+    with ThreadPoolExecutor(max_workers=max(int(workers), 1),
+                            initializer=mark_host_worker) as ex:
         it = iter(items)
         preps: deque = deque()
 
@@ -121,7 +138,8 @@ def prefetch_map(items: Iterable, fn: Callable, *, depth: int,
     at most `depth` results outstanding, so the source iterator is never
     drained eagerly. depth <= 1 is synchronous."""
     depth = max(int(depth), 1)
-    with ThreadPoolExecutor(max_workers=workers or min(depth, 4)) as ex:
+    with ThreadPoolExecutor(max_workers=workers or min(depth, 4),
+                            initializer=mark_host_worker) as ex:
         it = iter(items)
         window: deque = deque()
 
