@@ -209,7 +209,7 @@ def run_scale_leg(timings, flops, metrics, eng=None):
     from sml_tpu.ml import linear_impl
     parts, yp, yl = build_scale_parts()
     d = parts.width
-    n8 = parts.num.shape[0]
+    n8 = parts.rows
     t0 = time.perf_counter()
     res_lr = linear_impl.fit_linear_compact(parts, yp)
     res_lg = linear_impl.fit_logistic_compact(parts, yl,
@@ -225,8 +225,8 @@ def run_scale_leg(timings, flops, metrics, eng=None):
     metrics["scale_rmse_lr"] = float(np.sqrt(st.get("sse", 0.0) / n_f))
     # accuracy on the first 1M rows, computed OUTSIDE the timed region
     # (an 8M predict_affine pass costs more than the fits themselves)
-    head = parts._replace(num=parts.num[:1_000_000],
-                          codes=parts.codes[:1_000_000])
+    head = parts._replace(num=parts.num[:, :1_000_000],
+                          codes=parts.codes[:, :1_000_000])
     margin = head.predict_affine(res_lg.coefficients, res_lg.intercept)
     metrics["scale_accuracy"] = float(np.mean((margin > 0) == (yl[:1_000_000] > 0.5)))
     metrics["scale_d"] = float(d)

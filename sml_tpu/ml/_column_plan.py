@@ -1,7 +1,8 @@
 """Fit-time column plan: one job a raw column, the columns side by side.
 
 `featurizer._try_fast_fit` recognises the course's chain
-[Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator] and
+[Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator], or
+[RFormula, estimator] (a formula IS such a chain: `RFormula._chain`), and
 hands this module one JOB for every raw column the chain reads. A job
 visits its column once and does everything the column needs: the stage's
 fit statistic (the Imputer's surrogate, the StringIndexer's labels) AND
@@ -112,6 +113,7 @@ class JobResult(NamedTuple):
     labels: Optional[List[str]] = None     # an indexed column's labels
     invalid: Optional[np.ndarray] = None   # rows an indexer "skip" drops
     legacy: bool = False                   # ran today's per-column code
+    has_null: bool = False                 # an indexed column held a null
 
 
 class NumericJob:
@@ -206,8 +208,9 @@ class StringJob:
         for c in codes:
             counts += np.bincount(c, minlength=k + 1)
         labels = order_labels(values, counts[:k], self.order)
+        has_null = bool(counts[k])
         if out is None:
-            return JobResult(labels=labels)
+            return JobResult(labels=labels, has_null=has_null)
         # at fit every value that is not null has a label: invalid == null
         invalid = None
         if counts[k]:
@@ -226,57 +229,70 @@ class StringJob:
         for c in codes:
             np.take(table, c, out=out[lo:lo + len(c)], mode="clip")
             lo += len(c)
-        return JobResult(labels=labels, invalid=invalid)
+        return JobResult(labels=labels, invalid=invalid, has_null=has_null)
 
     def _run_as_today(self, pdf, col, out) -> JobResult:
         labels = indexer_labels(col, self.order)
+        has_null = bool(col.isna().any())
         if out is None:
-            return JobResult(labels=labels, legacy=True)
+            return JobResult(labels=labels, legacy=True, has_null=has_null)
         src = _IndexSource(self.col, np.asarray(labels, dtype=object),
                            self.invalid)
         drop = np.zeros(len(pdf), dtype=bool)
         out[:] = src.resolve(pdf, drop)
-        return JobResult(labels=labels, legacy=True,
+        return JobResult(labels=labels, legacy=True, has_null=has_null,
                          invalid=drop if drop.any() else None)
+
+    def category_size(self) -> int:
+        """What a OneHotEncoder fitted on this column's indices counts:
+        the labels, and the extra index "keep" gives a null when the
+        fitted table holds one (max index + 1)."""
+        r = self.result
+        return len(r.labels) + int(self.invalid == "keep" and r.has_null)
 
 
 class Plan:
     """The jobs of one fit, run (each keeps its `result`), the scratch they
-    wrote (row i is the assembler's input i), and `block()` to interleave
-    it."""
+    wrote (row i is the assembler's input i), `block()` to interleave it
+    and `compact()` to hand it over as it is."""
 
-    def __init__(self, pdf: pd.DataFrame, jobs: list, write: bool = True):
+    def __init__(self, pdf: pd.DataFrame, jobs: list):
         self.rows = len(pdf)
         self.inline = runs_inline(self.rows)
         self.workers = 1 if self.inline else _cores()
         self.jobs = jobs
         assembled = sum(j.row is not None for j in jobs)
         self.scratch = scratch = np.empty(
-            (assembled, self.rows), dtype=np.float32) if write else None
-        # write=False: the statistics alone, no row of any column
+            (assembled, self.rows), dtype=np.float32)
         results = run_tasks(
-            [lambda j=j: j.run(pdf, None if scratch is None or j.row is None
+            [lambda j=j: j.run(pdf, None if j.row is None
                                else scratch[j.row]) for j in jobs],
             self.inline)
         for j, r in zip(jobs, results):
             j.result = r
 
-    def block(self, onehot: List[Optional[int]], check_finite: bool):
+    def _dropped(self) -> Optional[np.ndarray]:
+        masks = [j.result.invalid for j in self.jobs
+                 if j.result.invalid is not None]
+        return np.logical_or.reduce(masks) if masks else None
+
+    def block(self, onehot: List[Optional[int]], invalid: str):
         """(X, keep) as `transform_with_mask` returns them: the row-major
         float32 block, rows an indexer skips dropped (keep None where none
-        is), the assembler's handleInvalid="error" raised. `onehot[i]` is
-        None where row i of the scratch is one column of the block, else
-        the width its codes are expanded to."""
+        is), and the assembler's `invalid` applied to a row that is not
+        finite: "error" raises, "skip" drops it too, "keep" leaves it.
+        `onehot[i]` is None where row i of the scratch is one column of
+        the block, else the width its codes are expanded to."""
         n, scratch = self.rows, self.scratch
         widths = [1 if w is None else w for w in onehot]
         los = np.cumsum([0] + widths)
-        masks = [j.result.invalid for j in self.jobs
-                 if j.result.invalid is not None]
-        drop = np.logical_or.reduce(masks) if masks else None
+        drop = self._dropped()
         out = np.empty((n, los[-1]), dtype=np.float32)
         plain = all(w is None for w in onehot)
 
-        def task(r0: int) -> bool:
+        def task(r0: int) -> Optional[np.ndarray]:
+            """Writes its block; the rows of it that are not finite and
+            that no indexer drops, or None where there is none."""
             r1 = min(r0 + _BLOCK_ROWS, n)
             blk = out[r0:r1]
             if plain:
@@ -284,21 +300,75 @@ class Plan:
             else:
                 for lo, w, row in zip(los, onehot, scratch):
                     _write_slot(blk, lo, w, row[r0:r1])
-            if not check_finite or np.isfinite(blk).all():
-                return True
-            return drop is not None \
-                and bool(np.isfinite(blk[~drop[r0:r1]]).all())
+            if invalid == "keep" or np.isfinite(blk).all():
+                return None
+            bad = ~np.isfinite(blk).all(axis=1)
+            if drop is not None:
+                bad &= ~drop[r0:r1]
+            return bad if bad.any() else None
 
-        if not all(run_tasks([lambda r0=r0: task(r0)
-                              for r0 in range(0, n, _BLOCK_ROWS)],
-                             self.inline)):
-            raise ValueError(
-                "VectorAssembler found NaN/null in assembled features; set "
-                "handleInvalid='skip' or impute first")
+        starts = range(0, n, _BLOCK_ROWS)
+        bads = run_tasks([lambda r0=r0: task(r0) for r0 in starts],
+                         self.inline)
+        if any(b is not None for b in bads):
+            if invalid == "error":
+                raise ValueError(
+                    "VectorAssembler found NaN/null in assembled features; "
+                    "set handleInvalid='skip' or impute first")
+            drop = np.zeros(n, dtype=bool) if drop is None else drop
+            for r0, bad in zip(starts, bads):
+                if bad is not None:
+                    drop[r0:r0 + len(bad)] |= bad
         if drop is None:
             return out, None
         keep = ~drop
         return out[keep], keep
+
+    def compact(self, onehot: List[Optional[int]], invalid: str):
+        """The scratch as `featurizer.CompactParts` (its rows ARE the
+        feature-major form: the numeric rows as they are, the code rows
+        as int32), or None where the expanded block would carry a value
+        that is not finite and the assembler does not skip the row: the
+        block path raises it, or keeps it, as the stage does."""
+        from .featurizer import CompactParts
+        scratch = self.scratch
+        drop = self._dropped()
+
+        def not_finite(i: int) -> Optional[np.ndarray]:
+            fin = np.isfinite(scratch[i])
+            return None if fin.all() else ~fin
+
+        bads = [b for b in run_tasks(
+            [lambda i=i: not_finite(i) for i in range(len(scratch))],
+            self.inline) if b is not None]
+        if bads:
+            bad = np.logical_or.reduce(bads)
+            if drop is not None:
+                bad &= ~drop
+            if bad.any():
+                if invalid != "skip":
+                    return None
+                drop = bad if drop is None else drop | bad
+        num_rows = [i for i, w in enumerate(onehot) if w is None]
+        code_rows = [i for i, w in enumerate(onehot) if w is not None]
+        at = {i: k for rows in (num_rows, code_rows)
+              for k, i in enumerate(rows)}   # a scratch row's place in its array
+        layout = [("num", at[i]) if w is None else ("oh", at[i], w)
+                  for i, w in enumerate(onehot)]
+        width = sum(1 if w is None else w for w in onehot)
+        # a run of numeric rows is a view of the scratch: no copy
+        run = num_rows and num_rows == list(range(num_rows[0],
+                                                  num_rows[-1] + 1))
+        num = scratch[num_rows[0]:num_rows[-1] + 1] if run \
+            else scratch[num_rows]
+        codes = scratch[code_rows]
+        keep = None
+        if drop is not None:
+            keep = ~drop
+            num, codes = num[:, keep], codes[:, keep]
+        return CompactParts(np.ascontiguousarray(num),
+                            np.ascontiguousarray(codes.astype(np.int32)),
+                            tuple(layout), width, keep)
 
 
 def _write_slot(blk: np.ndarray, lo: int, onehot: Optional[int],

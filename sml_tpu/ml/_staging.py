@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import contextlib
 from functools import partial
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -85,15 +85,7 @@ def extract_compact(df, featuresCol: str, labelCol: str):
         y = y[parts.keep]
     ok = np.isfinite(y)
     if not ok.all():
-        # compose the raw-row mask so parts.keep keeps describing the
-        # surviving rows of the RAW frame (its documented contract)
-        if parts.keep is not None:
-            keep = parts.keep.copy()
-            keep[keep] = ok
-        else:
-            keep = ok
-        parts = parts._replace(num=parts.num[ok], codes=parts.codes[ok],
-                               keep=keep)
+        parts = parts.take(ok)
         y = y[ok]
     return parts, y
 
@@ -109,6 +101,24 @@ _FULL_HASH_MAX_BYTES = 1 << 24    # 16 MB
 _SAMPLE_WINDOW = 1 << 16
 _SAMPLE_COUNT = 16
 _tls_keys = _threading.local()    # probe→stage key handoff
+
+
+class RowsLast(NamedTuple):
+    """A host block whose table rows run along its LAST axis (feature-
+    major: `featurizer.CompactParts`). Staging pads and shards that axis
+    and `run_data_parallel` hands the program the block split there; any
+    other array has its rows first."""
+    block: np.ndarray
+
+
+def _rows_axis(a) -> Tuple[np.ndarray, bool]:
+    """(the array, whether its rows are its last axis)."""
+    return (a.block, True) if isinstance(a, RowsLast) else (a, False)
+
+
+def _n_rows(a) -> int:
+    a, rows_last = _rows_axis(a)
+    return int(np.shape(a)[-1 if rows_last else 0])
 
 
 def _normalize(a) -> np.ndarray:
@@ -391,19 +401,30 @@ def transient_hbm(pool: str, nbytes: int):
         LEDGER.free(pool, int(nbytes))
 
 
-def stage_rows_cached(a: np.ndarray, pad_to_multiple: bool = True) -> jax.Array:
-    """device_put a row-sharded array through the content cache."""
+def stage_rows_cached(a, pad_to_multiple: bool = True) -> jax.Array:
+    """device_put a row-sharded array through the content cache; of a
+    `RowsLast` block the last axis is the one padded and sharded."""
     from ..utils.profiler import PROFILER
     mesh = meshlib.get_mesh()
     n_dev = meshlib.data_width(mesh)
+    a, rows_last = _rows_axis(a)
     a = _normalize(a)
-    key = (_memo_key(a), id(mesh), "arr", n_dev)
+    key = (_memo_key(a), id(mesh), "arrT" if rows_last else "arr", n_dev)
     hit = _stage_cache.get(key)
     if hit is None:
-        padded = (meshlib.pad_rows(
-            a, meshlib.bucket_rows(a.shape[0], n_dev))[0]
-            if pad_to_multiple else a)
-        hit = jax.device_put(padded, meshlib.data_sharding(mesh, padded.ndim))
+        padded = a
+        if pad_to_multiple and rows_last:
+            pad = meshlib.bucket_rows(a.shape[-1], n_dev) - a.shape[-1]
+            if pad:
+                padded = np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+        elif pad_to_multiple:
+            padded = meshlib.pad_rows(
+                a, meshlib.bucket_rows(a.shape[0], n_dev))[0]
+        sharding = meshlib.data_sharding(mesh, padded.ndim)
+        if rows_last:
+            sharding = NamedSharding(mesh, P(
+                *([None] * (padded.ndim - 1)), meshlib.row_spec_entry(mesh)))
+        hit = jax.device_put(padded, sharding)
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", padded.nbytes)
@@ -514,10 +535,11 @@ def _route_mesh(hint, arrays, may_promote: bool = True,
     n_dev = meshlib.data_width(dev_mesh)
     eff = hint
     keyed = []
-    kind = "stack" if stacked else "arr"
     if arrays:
         unstaged = 0.0
-        for a in arrays:
+        for given in arrays:
+            a, rows_last = _rows_axis(given)
+            kind = "stack" if stacked else "arrT" if rows_last else "arr"
             a = _normalize(a)
             ck = _memo_key(a)
             key = (ck, id(dev_mesh), kind, n_dev)
@@ -526,7 +548,7 @@ def _route_mesh(hint, arrays, may_promote: bool = True,
             # stage_bins_cached) — charge H2D only when absent from both
             if key not in _stage_cache and bkey not in _bin_stage_cache:
                 unstaged += a.nbytes
-            keyed.append(a)
+            keyed.append(RowsLast(a) if rows_last else a)
         eff = dataclasses.replace(hint,
                                   in_bytes=unstaged if unstaged else None)
     route, promote = dispatch.decide(eff)
@@ -542,7 +564,7 @@ def _route_mesh(hint, arrays, may_promote: bool = True,
             # tree/predict programs probe, not the general rows cache)
             if stacked:
                 stage_stacked_cached(a)
-            elif _is_bin_matrix(a):
+            elif not isinstance(a, RowsLast) and _is_bin_matrix(a):
                 stage_bins_cached(a)
             else:
                 stage_rows_cached(a)
@@ -584,8 +606,9 @@ def route_for_arrays(hint, *arrays) -> Tuple[object, str]:
     return _route_mesh(hint, arrays, may_promote=False)
 
 
-def stage_sharded(*arrays: np.ndarray):
-    """Pad + shard host arrays by rows over the data axis.
+def stage_sharded(*arrays):
+    """Pad + shard host arrays by rows over the data axis (a `RowsLast`
+    block by its last axis).
 
     Returns (device_arrays..., mask_device, n_true). The mask is 1.0 for real
     rows, 0.0 for padding; all statistics must be mask-weighted so padding is
@@ -600,17 +623,19 @@ def stage_sharded(*arrays: np.ndarray):
     predict, and eval-pushdown programs share ONE device copy per dataset
     under its own byte budget.
     """
-    n_true = arrays[0].shape[0]
-    outs = [stage_bins_cached(a) if _is_bin_matrix(np.asarray(a))
-            else stage_rows_cached(a) for a in arrays]
-    n_padded = outs[0].shape[0]
+    outs = [stage_rows_cached(a)
+            if isinstance(a, RowsLast) or not _is_bin_matrix(np.asarray(a))
+            else stage_bins_cached(a) for a in arrays]
+    n_true = _n_rows(arrays[0])
+    n_padded = outs[0].shape[-1 if isinstance(arrays[0], RowsLast) else 0]
     mask_dev = stage_mask_cached(n_padded, n_true)
     return (*outs, mask_dev, n_true)
 
 
 def data_parallel(fn: Callable, *, out_replicated: bool = True,
                   replicated_argnums: Tuple[int, ...] = (),
-                  name: Optional[str] = None) -> Callable:
+                  name: Optional[str] = None,
+                  rows_last_argnums: Tuple[int, ...] = ()) -> Callable:
     """jit(shard_map(fn)) over the active mesh's data axis.
 
     `fn` sees per-chip row blocks and may call `parallel.collectives.psum`
@@ -619,7 +644,8 @@ def data_parallel(fn: Callable, *, out_replicated: bool = True,
     Args listed in `replicated_argnums` (rng keys, small parameter vectors)
     are broadcast to every chip instead of row-sharded. `name` names the
     jitted program (`jit_<name>` in a profiler trace and in the compile
-    cache's key) where "wrapped" would say nothing.
+    cache's key) where "wrapped" would say nothing. Args listed in
+    `rows_last_argnums` are sharded by their LAST axis (`RowsLast`).
 
     Donation is deliberately NOT offered here: any input of a
     data_parallel program may be a staging-cache-owned buffer, and
@@ -633,8 +659,8 @@ def data_parallel(fn: Callable, *, out_replicated: bool = True,
     def spec_for(i, x):
         if i in replicated_argnums:
             return P()
-        return P(*([meshlib.row_spec_entry(mesh)]
-                   + [None] * (np.ndim(x) - 1)))
+        lead, rows = [None] * (np.ndim(x) - 1), [meshlib.row_spec_entry(mesh)]
+        return P(*(lead + rows if i in rows_last_argnums else rows + lead))
 
     def wrapped(*args):
         specs = tuple(spec_for(i, a) for i, a in enumerate(args))
@@ -679,7 +705,8 @@ class _RecordingProgram:
 
 
 def cached_data_parallel(fn: Callable, *, out_replicated: bool = True,
-                         replicated_argnums: Tuple[int, ...] = ()) -> Callable:
+                         replicated_argnums: Tuple[int, ...] = (),
+                         rows_last_argnums: Tuple[int, ...] = ()) -> Callable:
     """data_parallel with a program cache keyed by (fn, mesh, flags).
 
     jax.jit caches per function object; wrapping a fresh closure per fit
@@ -689,15 +716,19 @@ def cached_data_parallel(fn: Callable, *, out_replicated: bool = True,
     wrapped to record their shapes into the prewarm manifest.
     """
     mesh = meshlib.get_mesh()
-    key = (fn, id(mesh), out_replicated, replicated_argnums)
+    key = (fn, id(mesh), out_replicated, replicated_argnums,
+           rows_last_argnums)
     if key not in _compiled_cache:
         from ..obs import note_compile
         from ..parallel import prewarm as _prewarm
         note_compile(getattr(fn, "__name__", "fn"))
         compiled = data_parallel(
             fn, out_replicated=out_replicated,
-            replicated_argnums=replicated_argnums)
-        src = _prewarm.fn_src(fn)
+            replicated_argnums=replicated_argnums,
+            rows_last_argnums=rows_last_argnums)
+        # the manifest's replay places rows on axis 0: a feature-major
+        # program (a closure over its layout, never recordable) stays out
+        src = None if rows_last_argnums else _prewarm.fn_src(fn)
         if src is not None:
             compiled = _RecordingProgram(
                 compiled, src, (out_replicated, replicated_argnums))
@@ -737,27 +768,40 @@ def run_data_parallel(fn: Callable, *arrays, out_replicated: bool = True,
                       work: "Optional[dispatch.WorkHint]" = None):
     """One-shot: stage arrays sharded, run fn(blocks..., mask, *replicated)
     under jit+shard_map, return host numpy results. `replicated` values are
-    broadcast to all chips (small parameter vectors).
+    broadcast to all chips (small parameter vectors). An array given as
+    `RowsLast` is padded, sharded and handed to `fn` by its last axis.
 
     `work` is the caller's cost estimate; when given, the program is routed
-    host/device by the dispatcher (`parallel.dispatch`)."""
+    host/device by the dispatcher (`parallel.dispatch`).
+
+    Inside the program's span the four phases carry the tree fit's names
+    (`tree_impl._dispatch_and_read`): the staging (`fit.stage`), the call
+    until it returns (`fit.dispatch`), the device finishing
+    (`fit.device_wait`), the copy to the host (`fit.readback`)."""
     from ..utils.profiler import PROFILER
+    last = tuple(i for i, a in enumerate(arrays) if isinstance(a, RowsLast))
     with routed_for(work, *arrays) as mesh:
         route = "host" if dispatch.is_host_mesh(mesh) else "device"
+        rows = _n_rows(arrays[0]) if arrays else 0
         with PROFILER.span(f"program.{getattr(fn, '__name__', 'fn')}",
-                           rows=int(np.shape(arrays[0])[0]) if arrays else 0,
-                           route=route):
-            staged = stage_sharded(*arrays)
+                           rows=rows, route=route):
+            with PROFILER.span("fit.stage", rows=rows):
+                staged = stage_sharded(*arrays)
             dev_args, mask, _ = staged[:-2], staged[-2], staged[-1]
             n_lead = len(dev_args) + 1
             rep_nums = tuple(range(n_lead, n_lead + len(replicated)))
-            compiled = cached_data_parallel(fn, out_replicated=out_replicated,
-                                            replicated_argnums=rep_nums)
-            out = compiled(*dev_args, mask, *replicated)
+            with PROFILER.span("fit.dispatch"):
+                compiled = cached_data_parallel(
+                    fn, out_replicated=out_replicated,
+                    replicated_argnums=rep_nums, rows_last_argnums=last)
+                out = compiled(*dev_args, mask, *replicated)
+            with PROFILER.span("fit.device_wait"):
+                out = jax.block_until_ready(out)
             # ONE batched device→host transfer for the whole output tree:
             # per-leaf np.asarray pays the fixed cost of a device→host read
             # once PER ARRAY
-            host = jax.device_get(out)
+            with PROFILER.span("fit.readback"):
+                host = jax.device_get(out)
             PROFILER.count("staging.d2h_bytes", sum(
                 np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(host)))
             return host

@@ -14,6 +14,7 @@ from typing import Optional
 import numpy as np
 import pandas as pd
 
+from ..utils.profiler import PROFILER
 from .base import Estimator, Model, load_arrays, save_arrays
 from .feature import _as_object_series
 from .linalg import DenseVector, vector_series
@@ -108,8 +109,10 @@ class LogisticRegression(Estimator):
             # was read, or forever if it never was. Only the O(n log n)
             # AUC sort stays lazy; all metrics are EXACT full-data values,
             # and _force drops the arrays once reduced to floats.
-            margin = parts.predict_affine(res.coefficients, res.intercept)
-            acc = float(np.mean(((margin > 0).astype(float)) == y))
+            with PROFILER.span("fit.summary", rows=len(y)):
+                margin = parts.predict_affine(res.coefficients,
+                                              res.intercept)
+                acc = float(np.mean(((margin > 0).astype(float)) == y))
 
             def lazy_metrics(margin=margin, y=y, acc=acc):
                 return acc, _fast_auc(margin, y)

@@ -528,7 +528,10 @@ class RFormula(Estimator):
         self._set(formula=formula, featuresCol=featuresCol, labelCol=labelCol,
                   handleInvalid=handleInvalid)
 
-    def _fit(self, df) -> "RFormulaModel":
+    def _terms(self, df):
+        """(label, string terms, numeric terms) of the formula over `df`:
+        THE parser, shared by the sequential fit below and the column plan
+        (`featurizer._try_fast_fit`)."""
         formula = self.getOrDefault("formula")
         m = re.match(r"\s*(.+?)\s*~\s*(.+)\s*", formula)
         if not m:
@@ -568,32 +571,47 @@ class RFormula(Estimator):
                  (t in seen or seen.add(t))]
         str_terms = [t for t in terms if sch.get(t) == "string"]
         num_terms = [t for t in terms if t not in str_terms]
+        return label, str_terms, num_terms
 
-        stages: List[Transformer] = []
+    def _chain(self, str_terms: List[str], num_terms: List[str]) -> List:
+        """The stages a formula IS, unfitted and in order: a StringIndexer
+        and a OneHotEncoder over the string terms (none where there is no
+        such term), then the assembler over the encoded columns and the
+        numeric terms."""
+        invalid = self.getOrDefault("handleInvalid")
+        chain: List = []
         assembled: List[str] = []
         if str_terms:
             idx_cols = [f"{c}__idx" for c in str_terms]
             ohe_cols = [f"{c}__ohe" for c in str_terms]
-            invalid = self.getOrDefault("handleInvalid")
-            si = StringIndexer(inputCols=str_terms, outputCols=idx_cols,
-                               handleInvalid=invalid)
-            si_model = si.fit(df)
-            indexed = si_model.transform(df)
-            ohe = OneHotEncoder(inputCols=idx_cols, outputCols=ohe_cols)
-            ohe_model = ohe.fit(indexed)
-            stages += [si_model, ohe_model]
+            chain += [StringIndexer(inputCols=str_terms, outputCols=idx_cols,
+                                    handleInvalid=invalid),
+                      OneHotEncoder(inputCols=idx_cols, outputCols=ohe_cols)]
             assembled += ohe_cols
-        assembled += num_terms
         # "error" must actually error on invalid rows (Spark contract);
         # "skip" drops them; "keep" passes NaN through
-        va = VectorAssembler(inputCols=assembled,
-                             outputCol=self.getOrDefault("featuresCol"),
-                             handleInvalid=self.getOrDefault("handleInvalid"))
-        stages.append(va)
+        chain.append(VectorAssembler(
+            inputCols=assembled + num_terms,
+            outputCol=self.getOrDefault("featuresCol"),
+            handleInvalid=invalid))
+        return chain
+
+    def _model(self, stages: List[Transformer], label: str) -> "RFormulaModel":
         model = RFormulaModel(stages=stages, label=label,
                               labelCol=self.getOrDefault("labelCol"))
         model._inherit_params(self)
         return model
+
+    def _fit(self, df) -> "RFormulaModel":
+        label, str_terms, num_terms = self._terms(df)
+        stages: List[Transformer] = []
+        cur = df
+        for stage in self._chain(str_terms, num_terms):
+            if isinstance(stage, Estimator):
+                stage = stage.fit(cur)
+                cur = stage.transform(cur)
+            stages.append(stage)
+        return self._model(stages, label)
 
 
 class RFormulaModel(Model):

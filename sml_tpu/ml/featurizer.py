@@ -40,26 +40,48 @@ class CompactParts(NamedTuple):
     code_col, width) entries. The device programs expand one-hots ON CHIP
     (`linear_impl._expand_masked`) — staging ships n*(p+k) words instead
     of n*d, a ~6x H2D cut at the course's schema.
+
+    Both arrays are FEATURE-MAJOR, a slot a row and the table's rows along
+    the last axis: the column plan's scratch is written that way (a job a
+    contiguous row), and on the chip the last axis is the 128-lane one, so
+    (17, n) float32 takes n x 24 words of HBM where (n, 17) takes n x 128
+    (PERF.md section 6, PR 32: the row-major program asked the v5e for
+    26 GB at 6.8 M rows).
     """
-    num: np.ndarray                 # (n, p) float32 numeric slots
-    codes: np.ndarray               # (n, k) int32 category codes
+    num: np.ndarray                 # (p, n) float32 numeric slots
+    codes: np.ndarray               # (k, n) int32 category codes
     layout: tuple                   # slot-order expansion recipe
     width: int                      # expanded feature count d
     keep: Optional[np.ndarray]      # row-keep mask (indexer "skip" drops)
 
+    @property
+    def rows(self) -> int:
+        return self.num.shape[1]
+
+    def take(self, ok: np.ndarray) -> "CompactParts":
+        """The rows `ok` (a mask over this block's rows) keeps; `keep`
+        goes on describing the surviving rows of the RAW frame."""
+        if self.keep is not None:
+            keep = self.keep.copy()
+            keep[keep] = ok
+        else:
+            keep = ok
+        return self._replace(num=np.ascontiguousarray(self.num[:, ok]),
+                             codes=np.ascontiguousarray(self.codes[:, ok]),
+                             keep=keep)
+
     def expand_host(self) -> np.ndarray:
         """(n, d) float32 — the exact block the generic featurizer would
         build; the memory-heavy fallback for paths that need X itself."""
-        n = self.num.shape[0]
-        out = np.zeros((n, self.width), dtype=np.float32)
+        out = np.zeros((self.rows, self.width), dtype=np.float32)
         lo = 0
         for item in self.layout:
             if item[0] == "num":
-                out[:, lo] = self.num[:, item[1]]
+                out[:, lo] = self.num[item[1]]
                 lo += 1
             else:
                 _, j, width = item
-                idx = self.codes[:, j]
+                idx = self.codes[j]
                 ok = (idx >= 0) & (idx < width)
                 rows = np.nonzero(ok)[0]
                 out[rows, lo + idx[rows].astype(np.intp)] = 1.0
@@ -70,26 +92,20 @@ class CompactParts(NamedTuple):
         """X @ coef + intercept without expanding: numeric dot + one
         embedding-table lookup per encoded column (w·onehot(i) == w[i])."""
         coef = np.asarray(coef, dtype=np.float64)
-        acc = np.full(self.num.shape[0], float(intercept), dtype=np.float64)
+        acc = np.full(self.rows, float(intercept), dtype=np.float64)
         lo = 0
-        num_cols, num_w = [], []
         for item in self.layout:
             if item[0] == "num":
-                num_cols.append(item[1])
-                num_w.append(coef[lo])
+                acc += coef[lo] * self.num[item[1]]   # float64 products
                 lo += 1
             else:
                 _, j, width = item
-                idx = self.codes[:, j]
-                table = coef[lo:lo + width]
-                ok = (idx >= 0) & (idx < width)
-                contrib = np.zeros(len(idx), dtype=np.float64)
-                contrib[ok] = table[idx[ok].astype(np.intp)]
-                acc += contrib
+                idx = self.codes[j]
+                # a code past the width (the dropped last, a "keep"
+                # overflow) is a row of zeros: it reads the appended 0
+                table = np.append(coef[lo:lo + width], 0.0)
+                acc += table[np.where((idx >= 0) & (idx < width), idx, width)]
                 lo += width
-        if num_cols:
-            acc += self.num[:, num_cols].astype(np.float64) \
-                @ np.asarray(num_w)
         return acc
 
 
@@ -415,8 +431,8 @@ class CompiledFeaturizer:
         if drop.any():
             keep = ~drop
             num, codes = num[keep], codes[keep]
-        return CompactParts(np.ascontiguousarray(num),
-                            np.ascontiguousarray(codes),
+        return CompactParts(np.ascontiguousarray(num.T),
+                            np.ascontiguousarray(codes.T),
                             tuple(layout), self.width, keep)
 
     def _slot_map(self) -> dict:
@@ -488,7 +504,9 @@ class CompiledFeaturizer:
 
 def try_fast_fit(stages, raw_pdf, make_frame):
     """Whole-pipeline fused FIT: for the standard course chain
-    [Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator]
+    [Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator],
+    or [RFormula, estimator] (the formula's own indexer, encoder and
+    assembler: `RFormula._chain`),
     every prep stage reads RAW columns, so the chain becomes a column plan
     (`_column_plan`): one job a raw column makes the stage's fit statistic
     and the column's values of the feature block in one visit, the jobs
@@ -566,25 +584,45 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
     from . import _column_plan as cp
     from .base import Estimator
     from .feature import (Imputer, ImputerModel, OneHotEncoder,
-                          OneHotEncoderModel, StringIndexer,
+                          OneHotEncoderModel, RFormula, StringIndexer,
                           StringIndexerModel, VectorAssembler)
     *prep, est = stages
     if not isinstance(est, Estimator):
         return _decline("the last stage is no estimator")
     if not (est.hasParam("featuresCol") and est.hasParam("labelCol")):
         return _decline("the estimator reads no featuresCol and labelCol")
+    # a formula IS an indexer, an encoder and an assembler over raw
+    # columns (`RFormula._chain`): its jobs are theirs, and its model is
+    # made from their models at the end. `label_pdf` is where the
+    # estimator's labels are read: the raw table, with the formula's
+    # label under the formula's labelCol where the two names differ
+    rformula = label_source = None
+    label_pdf = raw_pdf
+    if any(isinstance(st, RFormula) for st in prep):
+        if len(prep) != 1:
+            return _decline("a RFormula stage beside other prep stages")
+        rformula = prep[0]
+        label_source, str_terms, num_terms = rformula._terms(
+            make_frame(raw_pdf))
+        prep = rformula._chain(str_terms, num_terms)
+        label_col = rformula.getOrDefault("labelCol")
+        if label_source != label_col \
+                and est.getOrDefault("labelCol") == label_col:
+            if label_source not in raw_pdf.columns:
+                return _decline("the formula's label is no raw column")
+            label_pdf = raw_pdf.copy(deep=False)
+            label_pdf[label_col] = pd.to_numeric(raw_pdf[label_source],
+                                                 errors="coerce")
     if not prep or not isinstance(prep[-1], VectorAssembler):
         return _decline("no VectorAssembler before the estimator")
     assembler = prep[-1]
     if est.getOrDefault("featuresCol") != assembler.getOrDefault("outputCol"):
         return _decline("the estimator does not read the assembler's output")
-    if est.getOrDefault("labelCol") not in raw_pdf.columns:
+    if est.getOrDefault("labelCol") not in label_pdf.columns:
         return _decline("labelCol is no raw column")
     if prep_overwrites_label(prep[:-1], est):
         return _decline("a prep stage rewrites the label")
     invalid = assembler.getOrDefault("handleInvalid")
-    if invalid not in ("error", "keep"):
-        return _decline("assembler handleInvalid='skip'")  # by finiteness
 
     # one job a (stage, raw column). `produced`: an Imputer's or indexer's
     # output column -> its job; `encoded`: an encoder's output column ->
@@ -643,33 +681,31 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
     jobs.sort(key=lambda j: len(jobs) if j.row is None else j.row)
 
     # huge linear fits skip X entirely: the compact block stages n*(p+k)
-    # words and expands one-hots on-chip (CompactParts; the 8M-row scale
-    # path, which keeps its own extraction). Gated by size so course-scale
-    # fits keep the materialized block and its golden-pinned numerics
-    # bit-for-bit. The width follows the labels; the inputs bound it below
+    # words and expands one-hots on-chip (CompactParts: the plan's scratch
+    # as it was written, a job a row). Gated by size so course-scale fits
+    # keep the materialized block and its golden-pinned numerics
+    # bit-for-bit. The width follows the labels
     n = len(raw_pdf)
     compact_bytes = None
     if type(est).__name__ in ("LinearRegression", "LogisticRegression"):
         from ..conf import GLOBAL_CONF
         compact_bytes = GLOBAL_CONF.getInt("sml.linear.compactBytes")
-    surely_compact = compact_bytes is not None \
-        and n * len(sources) * 4 >= compact_bytes
 
-    X = keep = None
+    X = keep = parts = None
     with PROFILER.span("fit.featurize", rows=n, columns=len(jobs)) as note:
-        plan = cp.Plan(raw_pdf, jobs, write=not surely_compact)
+        plan = cp.Plan(raw_pdf, jobs)
         note["workers"] = plan.workers
         # an encoder's width follows its indexer's labels
         onehot = [None if drop_last is None
-                  else len(job.result.labels) - int(drop_last)
+                  else job.category_size() - int(drop_last)
                   for job, drop_last in sources]
         los = list(itertools.accumulate(
             (1 if w is None else w for w in onehot), initial=0))
         width = los[-1]
-        compact = compact_bytes is not None \
-            and n * width * 4 >= compact_bytes
-        if not compact:
-            X, keep = plan.block(onehot, check_finite=invalid == "error")
+        if compact_bytes is not None and n * width * 4 >= compact_bytes:
+            parts = plan.compact(onehot, invalid)
+        if parts is None:   # also: a NaN the expanded block would carry
+            X, keep = plan.block(onehot, invalid)
             note["bytes"] = int(X.nbytes)
 
     with PROFILER.span("fit.prep", stages=len(plans)):
@@ -686,20 +722,12 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
                     attrs[oc] = {"categorical": len(ls) + extra}
             else:
                 m = OneHotEncoderModel(
-                    categorySizes=[len(j.result.labels) for j in made])
+                    categorySizes=[j.category_size() for j in made])
             fitted.append(m._inherit_params(st))
         fitted.append(assembler)
+        if rformula is not None:
+            fitted = [rformula._model(fitted, label_source)]
 
-    parts = None
-    if compact:
-        feat = CompiledFeaturizer.from_stages(fitted[:-1], assembler)
-        parts = feat.compact_parts(raw_pdf) if feat is not None else None
-        if parts is None:   # a NaN the expanded block would carry
-            if plan.scratch is None:
-                return _decline("the compact form declined, no block made")
-            with PROFILER.span("fit.featurize", rows=n) as note:
-                X, keep = plan.block(onehot, check_finite=invalid == "error")
-                note["bytes"] = int(X.nbytes)
     PROFILER.count("featurize.plan.fits")
     legacy = sum(j.result.legacy for j in jobs)
     if legacy:
@@ -715,9 +743,9 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
             los, assembler.getOrDefault("inputCols")) if c in attrs},
         "numFeatures": width}
     if parts is not None:
-        shim._featurized_compact = {out_col: (parts, raw_pdf)}
+        shim._featurized_compact = {out_col: (parts, label_pdf)}
     else:
-        shim._featurized = {out_col: (X, keep, raw_pdf)}
+        shim._featurized = {out_col: (X, keep, label_pdf)}
     # the ESTIMATOR fit happens in the caller, OUTSIDE any fallback guard:
     # its errors (bad hyperparameters, device OOM) must propagate, not
     # trigger a silent re-fit through the generic path

@@ -17,7 +17,7 @@ All passes are masked so row padding (static shapes for XLA) is inert.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ import numpy as np
 
 from ..parallel import collectives as coll
 from ..parallel.dispatch import WorkHint
-from ._staging import run_data_parallel
+from ._staging import RowsLast, run_data_parallel
 
 
 class LinearFit(NamedTuple):
@@ -37,16 +37,97 @@ class LinearFit(NamedTuple):
     stats: Optional[dict] = None
 
 
+# ------------------------------------------------- standardized coordinates
+# Every device pass of this module (the Gram, a Newton step; the row-major
+# block and the compact form alike) runs on the standardized slots
+# Z = (X - shift) / scale, and the host maps what comes back to the raw
+# coordinates in float64 (`_raw_map`). The map is exact for whatever
+# (shift, scale) the pass used, so an answer does not move under it;
+# float32 can only reach the answer there: a raw latitude of 37.76 +- 0.026
+# beside the intercept gives a Gram whose condition number is past 1e7, on
+# which the Newton steps of the course's own table diverged and its least
+# squares were 1.7 standard errors off (PERF.md section 6, PR 32).
+#
+# `scale` is the power of two under the slot's deviation and `shift` the
+# multiple of it nearest the mean (`_dyadic`), so Z's deviation is in
+# [1, 2), its mean within a half of 0, and Z is EXACT in float32 wherever
+# X - shift is: a count, a small integer or a one-hot slot stays one, and
+# the sums of a Gram over such slots stay exact whatever their order.
+_EXPONENT = 0x7F800000
+
+
+def _dyadic(mean, std):
+    """(shift, scale) of `_moments`' (mean, std), a slot each: the power
+    of two not over std (its mantissa bits masked off) and the multiple of
+    that nearest the mean."""
+    scale = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(std, jnp.uint32)
+        & jnp.uint32(_EXPONENT), jnp.float32)
+    return jnp.round(mean / scale) * scale, scale
+
+
+def _dyadic_host(mean, std):
+    """`_dyadic` in NumPy, float32 out, for moments made on the host."""
+    scale = np.ldexp(1.0, np.frexp(std)[1] - 1)     # std = m * 2**e, m >= 0.5
+    return ((np.round(mean / scale) * scale).astype(np.float32),
+            scale.astype(np.float32))
+
+
+def _moments(pieces, mask):
+    """(shift, scale) of every slot (`_dyadic`) from its mean and
+    deviation over the table's true rows. `pieces` are (width, rows)
+    blocks, reduced one by one so that no block is made for them; a
+    constant slot gets a deviation of 1."""
+    n = coll.psum(jnp.sum(mask))
+    mean = jnp.concatenate(
+        [coll.psum(jnp.sum(p * mask[None, :], axis=1)) for p in pieces]) / n
+    lo, var = 0, []
+    for p in pieces:
+        c = (p - mean[lo:lo + p.shape[0], None]) * mask[None, :]
+        var.append(coll.psum(jnp.sum(c * c, axis=1)))
+        lo += p.shape[0]
+    std = jnp.sqrt(jnp.concatenate(var) / n)
+    return _dyadic(mean, jnp.where(
+        std >= jnp.finfo(jnp.float32).tiny, std, 1.0))
+
+
+def _standardized_rows(Xb, mask, shift, scale):
+    """[Z 1] of a row-major block, rows masked."""
+    Z = (Xb - shift[None, :]) / scale[None, :]
+    return jnp.concatenate([Z, jnp.ones_like(mask)[:, None]],
+                           axis=1) * mask[:, None]
+
+
+def _raw_map(shift, scale) -> np.ndarray:
+    """T, (d+1, d+1) float64, with [X 1]^T = T @ [Z 1]^T. A Gram or a
+    Hessian of [Z 1] goes to the raw coordinates as T @ A @ T.T, a moment
+    or a gradient as T @ b, raw coefficients to the standardized ones as
+    T.T @ w and back by a solve."""
+    d = len(scale)
+    T = np.eye(d + 1)
+    T[np.arange(d), np.arange(d)] = np.asarray(scale, dtype=np.float64)
+    T[:d, d] = np.asarray(shift, dtype=np.float64)
+    return T
+
+
+def _gram_to_raw(out):
+    """(A, b, n, yy) of [X 1] in float64 from a standardized Gram pass's
+    (A, b, n, yy, shift, scale)."""
+    A, b, n, yy, shift, scale = out
+    T = _raw_map(shift, scale)
+    return (T @ np.asarray(A, dtype=np.float64) @ T.T,
+            T @ np.asarray(b, dtype=np.float64), float(n), float(yy))
+
+
 def _gram_pass(Xb, yb, mask):
-    Xb = Xb * mask[:, None]
+    shift, scale = _moments([Xb.T], mask)
+    Za = _standardized_rows(Xb, mask, shift, scale)
     yb = yb * mask
-    ones = mask[:, None]
-    Xa = jnp.concatenate([Xb, ones], axis=1)
-    A = coll.psum(Xa.T @ Xa)            # MXU matmul then ICI allreduce
-    b = coll.psum(Xa.T @ yb)
+    A = coll.psum(Za.T @ Za)            # MXU matmul then ICI allreduce
+    b = coll.psum(Za.T @ yb)
     n = coll.psum(jnp.sum(mask))
     yy = coll.psum(jnp.sum(yb * yb))
-    return A, b, n, yy
+    return A, b, n, yy, shift, scale
 
 
 def gram_stats(X: np.ndarray, y: np.ndarray) -> Tuple[np.ndarray, np.ndarray, float, float]:
@@ -56,11 +137,9 @@ def gram_stats(X: np.ndarray, y: np.ndarray) -> Tuple[np.ndarray, np.ndarray, fl
     n_rows, d = X.shape
     # asarray, not astype: astype always copies, which both costs ~0.1s/GB
     # and defeats the staging cache's identity keys on repeated fits
-    A, b, n, yy = run_data_parallel(
+    return _gram_to_raw(run_data_parallel(
         _gram_pass, np.asarray(X, np.float32), np.asarray(y, np.float32),
-        work=WorkHint(flops=2.0 * n_rows * (d + 1) ** 2, kind="blas"))
-    return (np.asarray(A, dtype=np.float64), np.asarray(b, dtype=np.float64),
-            float(n), float(yy))
+        work=WorkHint(flops=2.0 * n_rows * (d + 1) ** 2, kind="blas")))
 
 
 def _fit_stats(A, b, n_f, yy, w_full):
@@ -162,25 +241,44 @@ def _solve_gram(A, b, n_f, yy, d, *, regParam, elasticNetParam,
 
 
 # --------------------------------------------- compact (expand-on-device)
-def _expand_masked(num_b, codes_b, mask, layout):
-    """Per-chip expansion of a CompactParts block into [X 1], rows masked.
-
-    One-hot pieces are `code == iota` compares on the VPU — the (n, d)
-    block exists only in HBM on the chip, never on the host or the H2D path
-    (featurizer.CompactParts). Out-of-range codes (handleInvalid="keep"
-    overflow slots) yield all-zero rows exactly like the host writer.
-    Padding rows carry code 0, so EVERY piece is mask-multiplied."""
+def _expand_pieces(num_t, codes_t, layout):
+    """The slots of a CompactParts block in the assembler's order, each a
+    (width, rows) float32 piece (`code == iota` compares on the VPU)."""
     pieces = []
     for item in layout:
         if item[0] == "num":
-            pieces.append(num_b[:, item[1]][:, None])
+            pieces.append(num_t[item[1]][None, :])
         else:
             _, j, width = item
-            iota = jnp.arange(width, dtype=codes_b.dtype)
-            pieces.append((codes_b[:, j][:, None]
-                           == iota[None, :]).astype(jnp.float32))
-    pieces.append(jnp.ones((num_b.shape[0], 1), dtype=jnp.float32))
-    return jnp.concatenate(pieces, axis=1) * mask[:, None]
+            iota = jnp.arange(width, dtype=codes_t.dtype)
+            pieces.append((codes_t[j][None, :]
+                           == iota[:, None]).astype(jnp.float32))
+    return pieces
+
+
+def _expand_masked(num_t, codes_t, mask, layout):
+    """Per-chip expansion of a CompactParts block into [Z 1]^T, a slot a
+    ROW and the chip's table rows along the last axis, rows masked, Z the
+    standardized slots; with the (shift, scale) it used (`_raw_map`).
+
+    The block exists only in HBM on the chip, never on the host or the
+    H2D path (featurizer.CompactParts). Out-of-range codes
+    (handleInvalid="keep" overflow slots) are all-zero rows of X exactly
+    like the host writer's. Padding rows carry code 0, so EVERY piece is
+    mask-multiplied.
+
+    Feature-major because the last axis is the chip's 128-lane one: a
+    (rows, width) piece pads its width to 128 lanes, and the seven pieces
+    of the course's table with their concatenation asked the v5e for
+    26 GB at 6.8 M rows; (d + 1, rows) pads d + 1 to a multiple of 8."""
+    with jax.named_scope("linear.expand"):
+        pieces = _expand_pieces(num_t, codes_t, layout)
+        shift, scale = _moments(pieces, mask)
+        Z = (jnp.concatenate(pieces, axis=0)
+             - shift[:, None]) / scale[:, None]
+        ones = jnp.ones((1, num_t.shape[1]), dtype=jnp.float32)
+        return (jnp.concatenate([Z, ones], axis=0) * mask[None, :],
+                shift, scale)
 
 
 _compact_gram_fns: dict = {}
@@ -191,17 +289,18 @@ def _compact_gram_fn(layout):
     if fn is not None:
         return fn
 
-    def gram_compact(num_b, codes_b, yb, mask):
+    def gram_compact(num_t, codes_t, yb, mask):
         # f32 matmul precision: bf16 operand truncation would corrupt the
         # Gram moments (counts up to n and squared sums are not bf16-exact)
         with jax.default_matmul_precision("float32"):
-            Xa = _expand_masked(num_b, codes_b, mask, layout)
+            Za, shift, scale = _expand_masked(num_t, codes_t, mask,
+                                              layout)
             yb = yb * mask
-            A = coll.psum(Xa.T @ Xa)
-            b = coll.psum(Xa.T @ yb)
+            A = coll.psum(Za @ Za.T)
+            b = coll.psum(Za @ yb)
             n = coll.psum(jnp.sum(mask))
             yy = coll.psum(jnp.sum(yb * yb))
-        return A, b, n, yy
+        return A, b, n, yy, shift, scale
 
     gram_compact.__name__ = f"gram_compact_{abs(hash(layout)) % 99991}"
     _compact_gram_fns[layout] = gram_compact
@@ -212,14 +311,12 @@ def gram_stats_compact(parts, y: np.ndarray):
     """gram_stats over a featurizer.CompactParts block: one device pass,
     one-hot slots expanded on-chip (SURVEY §2.2 P2 at beyond-one-machine
     scale — `SML/ML 00b - Spark Review.py:84`)."""
-    n_rows = parts.num.shape[0]
+    n_rows = parts.rows
     d = parts.width
-    A, b, n, yy = run_data_parallel(
-        _compact_gram_fn(parts.layout), parts.num, parts.codes,
-        np.asarray(y, np.float32),
-        work=WorkHint(flops=2.0 * n_rows * (d + 1) ** 2, kind="blas"))
-    return (np.asarray(A, dtype=np.float64), np.asarray(b, dtype=np.float64),
-            float(n), float(yy))
+    return _gram_to_raw(run_data_parallel(
+        _compact_gram_fn(parts.layout), RowsLast(parts.num),
+        RowsLast(parts.codes), np.asarray(y, np.float32),
+        work=WorkHint(flops=2.0 * n_rows * (d + 1) ** 2, kind="blas")))
 
 
 def fit_linear_compact(parts, y: np.ndarray, *, regParam: float = 0.0,
@@ -248,7 +345,7 @@ def _compact_irls_fn(layout, maxIter: int, tol: float):
     if fn is not None:
         return fn
 
-    def irls_compact(num_b, codes_b, yb, mask):
+    def irls_compact(num_t, codes_t, yb, mask):
         """WHOLE-FIT fused IRLS: the expanded block stays resident in HBM
         and all maxIter Newton steps — grad/Hessian psum, (d+1)² solve,
         damping, convergence freeze — run in ONE dispatch. The host loop
@@ -256,37 +353,46 @@ def _compact_irls_fn(layout, maxIter: int, tol: float):
         course-scale d that fixed cost IS the fit time. Semantics mirror
         fit_logistic's lam=0 loop: step = solve(H + 1e-8 I, g), damp to
         the midpoint when the log-likelihood drops by >1e3, freeze after
-        max|Δw| < tol (executed iterations are reported)."""
+        max|Δw| < tol (executed iterations are reported). The block is
+        [Z 1]^T (`_expand_masked`): a product over the table's rows
+        contracts its last axis. `w` stays in the standardized space for
+        all the steps and is returned there with the shift and the scale;
+        the caller maps it back in float64 (`_raw_map`)."""
         with jax.default_matmul_precision("float32"):
-            Xa = _expand_masked(num_b, codes_b, mask, layout)
-            d1 = Xa.shape[1]
+            Xa, shift, scale = _expand_masked(num_t, codes_t, mask, layout)
+            d1 = Xa.shape[0]
             eye = jnp.eye(d1, dtype=jnp.float32)
 
             def body(carry, _):
                 w, prev_ll, done, iters = carry
-                eta = Xa @ w
-                p = jax.nn.sigmoid(eta)
-                Wd = jnp.maximum(p * (1 - p), 1e-6) * mask
-                grad = coll.psum(Xa.T @ ((p - yb) * mask))
-                hess = coll.psum((Xa * Wd[:, None]).T @ Xa)
-                ll = coll.psum(jnp.sum(mask * (
-                    yb * jax.nn.log_sigmoid(eta)
-                    + (1 - yb) * jax.nn.log_sigmoid(-eta))))
-                step = jnp.linalg.solve(hess + 1e-8 * eye, grad)
-                w_new = w - step
-                conv = jnp.max(jnp.abs(w_new - w)) < tol
-                damp = ll < prev_ll - 1e3
-                w_next = jnp.where(done, w,
-                                   jnp.where(damp, (w + w_new) / 2, w_new))
-                iters = iters + jnp.where(done, 0, 1)
+                with jax.named_scope("linear.irls.margin"):
+                    eta = w @ Xa
+                    p = jax.nn.sigmoid(eta)
+                    Wd = jnp.maximum(p * (1 - p), 1e-6) * mask
+                with jax.named_scope("linear.irls.grad"):
+                    grad = coll.psum(Xa @ ((p - yb) * mask))
+                    ll = coll.psum(jnp.sum(mask * (
+                        yb * jax.nn.log_sigmoid(eta)
+                        + (1 - yb) * jax.nn.log_sigmoid(-eta))))
+                with jax.named_scope("linear.irls.hess"):
+                    hess = coll.psum((Xa * Wd[None, :]) @ Xa.T)
+                with jax.named_scope("linear.irls.solve"):
+                    step = jnp.linalg.solve(hess + 1e-8 * eye, grad)
+                    w_new = w - step
+                    conv = jnp.max(jnp.abs(w_new - w)) < tol
+                    damp = ll < prev_ll - 1e3
+                    w_next = jnp.where(
+                        done, w, jnp.where(damp, (w + w_new) / 2, w_new))
+                    iters = iters + jnp.where(done, 0, 1)
                 return (w_next, jnp.where(done, prev_ll, ll),
                         done | conv, iters), None
 
             init = (jnp.zeros((d1,), jnp.float32), jnp.float32(-jnp.inf),
                     jnp.bool_(False), jnp.int32(0))
-            (w, _, _, iters), _ = jax.lax.scan(body, init, None,
-                                               length=maxIter)
-        return w, iters
+            with jax.named_scope("linear.irls"):
+                (w, _, _, iters), _ = jax.lax.scan(body, init, None,
+                                                   length=maxIter)
+        return w, shift, scale, iters
 
     irls_compact.__name__ = \
         f"irls_compact_{abs(hash(key)) % 99991}"
@@ -299,20 +405,29 @@ def fit_logistic_compact(parts, y: np.ndarray, *, maxIter: int = 100,
     """Unpenalized binomial logistic fit over a CompactParts block — the
     fused-IRLS device program (see _compact_irls_fn). Penalized configs
     need the materialized block (prox shrinkage on raw coefficients);
-    callers route those through parts.expand_host() + fit_logistic."""
-    n_rows, d = parts.num.shape[0], parts.width
-    w, iters = run_data_parallel(
+    callers route those through parts.expand_host() + fit_logistic.
+    Counters: `linear.irls.fits`, `linear.irls.steps_run` (the steps the
+    device executed: the scan's length, whatever converged) and
+    `linear.irls.iterations` (the steps that moved `w`)."""
+    from ..utils.profiler import PROFILER
+    n_rows, d = parts.rows, parts.width
+    z, shift, scale, iters = run_data_parallel(
         _compact_irls_fn(parts.layout, int(maxIter), float(tol)),
-        parts.num, parts.codes, np.asarray(y, np.float32),
+        RowsLast(parts.num), RowsLast(parts.codes),
+        np.asarray(y, np.float32),
         work=WorkHint(flops=3.0 * maxIter * n_rows * (d + 1) ** 2,
                       kind="blas"))
-    w = np.asarray(w, dtype=np.float64)
+    PROFILER.count("linear.irls.fits")
+    PROFILER.count("linear.irls.steps_run", int(maxIter))
+    PROFILER.count("linear.irls.iterations", int(iters))
+    w = np.linalg.solve(_raw_map(shift, scale).T, np.asarray(z, np.float64))
     return LinearFit(w[:d], float(w[d]), int(iters))
 
 
-def _newton_pass(Xb, yb, mask, wb):
-    ones = mask[:, None]
-    Xa = jnp.concatenate([Xb * mask[:, None], ones], axis=1)
+def _newton_pass(Xb, yb, mask, wb, shift, scale):
+    """Gradient, Hessian and log-likelihood at `wb`, all three in the
+    standardized coordinates that `shift` and `scale` define."""
+    Xa = _standardized_rows(Xb, mask, shift, scale)
     eta = Xa @ wb
     p = jax.nn.sigmoid(eta)
     Wdiag = jnp.maximum(p * (1 - p), 1e-6) * mask
@@ -336,13 +451,17 @@ def fit_logistic(X: np.ndarray, y: np.ndarray, *, regParam: float = 0.0,
     lam = float(regParam)
     l2 = lam * (1 - float(elasticNetParam))
     l1 = lam * float(elasticNetParam)
-    if standardization and lam > 0:
-        # f64 accumulation without materializing an f64 copy of X
-        pen_scale = np.maximum(X.var(axis=0, dtype=np.float64), 1e-12)
-    else:
-        pen_scale = np.ones(d)
+    # f64 accumulation without materializing an f64 copy of X
+    var = X.var(axis=0, dtype=np.float64)
+    pen_scale = np.maximum(var, 1e-12) if standardization and lam > 0 \
+        else np.ones(d)
+    # the device works at the standardized Z, the loop below in the raw
+    # coordinates as ever: each pass's results are mapped back (`_raw_map`)
+    standard = _dyadic_host(X.mean(axis=0, dtype=np.float64),
+                            np.sqrt(np.where(var > 0, var, 1.0)))
+    T = _raw_map(*standard)
 
-    w = np.zeros(d + 1, dtype=np.float32)
+    w = np.zeros(d + 1)
     n_f = float(len(y))
     prev_ll = -np.inf
     iters = 0
@@ -352,14 +471,15 @@ def fit_logistic(X: np.ndarray, y: np.ndarray, *, regParam: float = 0.0,
     for it in range(maxIter):
         grad, hess, ll = run_data_parallel(
             _newton_pass, X32, y32,
-            replicated=(jnp.asarray(w),), work=newton_work)
-        grad = np.asarray(grad, dtype=np.float64)
-        hess = np.asarray(hess, dtype=np.float64)
+            replicated=(jnp.asarray(T.T @ w, jnp.float32), *standard),
+            work=newton_work)
+        grad = T @ np.asarray(grad, dtype=np.float64)
+        hess = T @ np.asarray(hess, dtype=np.float64) @ T.T
         if l2 > 0:
             grad[:d] += l2 * n_f * pen_scale * w[:d]
             hess[:d, :d] += l2 * n_f * np.diag(pen_scale)
         step = np.linalg.solve(hess + 1e-8 * np.eye(d + 1), grad)
-        w_new = w - step.astype(np.float32)
+        w_new = w - step
         if l1 > 0:  # proximal shrink on coefficients (not intercept)
             # standardized L1 is λα·Σ σ_j|w_j| in raw space — linear in σ,
             # unlike the quadratic L2 term's σ²
@@ -367,7 +487,10 @@ def fit_logistic(X: np.ndarray, y: np.ndarray, *, regParam: float = 0.0,
             w_new[:d] = np.sign(w_new[:d]) * np.maximum(
                 np.abs(w_new[:d]) - l1 * n_f * np.sqrt(pen_scale) / scale, 0.0)
         iters = it + 1
-        if np.max(np.abs(w_new - w)) < tol:
+        # converged where the fused program says so (`_compact_irls_fn`):
+        # by the step in the standardized coordinates, where float32's
+        # noise in a step is of the order of tol and not of a raw slot's
+        if np.max(np.abs(T.T @ (w_new - w))) < tol:
             w = w_new
             break
         if float(ll) < prev_ll - 1e3:  # diverging: damp
@@ -375,9 +498,7 @@ def fit_logistic(X: np.ndarray, y: np.ndarray, *, regParam: float = 0.0,
         else:
             w = w_new
         prev_ll = float(ll)
-    if not fitIntercept:
-        return LinearFit(np.asarray(w[:d], dtype=np.float64), 0.0, iters)
-    return LinearFit(np.asarray(w[:d], dtype=np.float64), float(w[d]), iters)
+    return LinearFit(w[:d], float(w[d]) if fitIntercept else 0.0, iters)
 
 
 def predict_linear(X: np.ndarray, coefficients: np.ndarray, intercept: float) -> np.ndarray:

@@ -33,7 +33,9 @@ SPANS = {
     # fit.quantize (.key, .bins with its phases .stats and .digitize) /
     # fit.stage / fit.baseline, plus
     # fit.dispatch / fit.device_wait / fit.readback / fit.unpack inside
-    # the tree programs' spans
+    # the tree programs' spans and, but for fit.unpack, inside every
+    # `_staging.run_data_parallel` program (the linear family's), and
+    # fit.summary (the logistic training summary's host pass)
     "fit", "fit.*",
     # serving layer: one coalesced device dispatch of the micro-batcher
     "serve.batch",
@@ -103,6 +105,10 @@ COUNTERS = {
     # its jobs on the column plan's pool / one that ran them on the caller
     # (few rows, or the caller is itself a pool worker)
     "quantize.plan.fits", "quantize.plan.inline",
+    # the fused logistic fit (linear_impl.fit_logistic_compact): programs
+    # run / Newton steps the device executed (the scan's length, whatever
+    # converged) / steps that moved the coefficients (what a fit reports)
+    "linear.irls.fits", "linear.irls.steps_run", "linear.irls.iterations",
     # prewarm manifest (parallel/prewarm.py): recorded signatures,
     # replayed/failed first-dispatches, pool-size attribution
     "prewarm.*",

@@ -261,6 +261,40 @@ def test_the_check_sees_an_operand_that_was_sunk(one_chip_mesh, monkeypatch):
     assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand")
 
 
+def test_the_fused_logistic_fit_fits_a_v5e_at_the_cells_size(one_chip_mesh):
+    """`mle03_logreg.fit_logistic`'s program at its own shapes (6,815,744
+    padded rows, 17 numeric and 5 coded columns, 62 slots), compiled for
+    the chip: it fits (the row-major expansion asked for 26 GB, ISSUE 32),
+    the block is (63, rows) and not a lane-padded (rows, 63), and the
+    scopes the benchmark's readers look for are in the metadata. In this
+    file because one worker holds libtpu (the fixture above)."""
+    from sml_tpu.ml import linear_impl
+    rows = 6_815_744
+    layout = tuple(("oh", j, w) for j, w in enumerate((1, 35, 5, 2, 2))) \
+        + tuple(("num", i) for i in range(17))
+    fn = linear_impl._compact_irls_fn(layout, 100, 1e-6)
+    last, flat = P(None, D), P(D)
+    mapped = meshlib.shard_map_compat(
+        fn, mesh=one_chip_mesh, in_specs=(last, last, flat, flat),
+        out_specs=P())
+    shapes = [jax.ShapeDtypeStruct(shape, dtype,
+                                   sharding=NamedSharding(one_chip_mesh, s))
+              for shape, dtype, s in (((17, rows), jnp.float32, last),
+                                      ((5, rows), jnp.int32, last),
+                                      ((rows,), jnp.float32, flat),
+                                      ((rows,), jnp.float32, flat))]
+    compiled = jax.jit(mapped).lower(*shapes).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held < 6e9, f"{held / 1e9:.2f} GB of a 16 GB chip"
+    hlo = compiled.as_text()
+    assert f"f32[63,{rows}]" in hlo and f"f32[{rows},63]" not in hlo
+    for scope in ("linear.expand", "linear.irls/", "linear.irls.margin",
+                  "linear.irls.grad", "linear.irls.hess",
+                  "linear.irls.solve"):
+        assert scope in hlo, scope
+
+
 def test_ops_in_loop_bodies_follows_calls():
     hlo = """HloModule m
 %fused (p: s32[4]) -> s32[4] {
