@@ -259,8 +259,12 @@ class DataFrame:
         """Concatenate all partitions; the result is memoized per frame.
         Frames are immutable once materialized, and under pandas>=3
         copy-on-write the returned shallow copy is mutation-safe for the
-        caller, so repeated toPandas (every pipeline stage fit calls it)
-        costs one concat total instead of one per call."""
+        caller, so repeated toPandas (every stage of the generic sequential
+        `Pipeline.fit` calls it, and every collect) costs one concat total
+        instead of one per call. The fit-time column plan does NOT call it
+        for a frame of several partitions: its jobs read the partitions
+        where they lie (`ml/_column_plan.Pieces`), and only a frame that
+        already holds this memo is read from it."""
         if self._pdf_cache is None:
             self._pdf_cache = _concat(self._materialize()).reset_index(drop=True)
         return self._pdf_cache.copy(deep=False)

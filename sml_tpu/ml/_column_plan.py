@@ -10,6 +10,15 @@ the column's values for the feature block. Jobs share nothing, so they run
 side by side on one pool of host threads; the results do not depend on
 how many workers there are or on the order the jobs finish in.
 
+A job reads its column WHERE IT LIES: the frame's materialized partitions
+(`Pieces`), piece by piece, in the order the table-wide concat would lay
+them. No such concat is made on the plan's path (`DataFrame.toPandas` was
+one thread copying every column of the table, the ones no job reads too,
+before a job could start: PERF.md section 6, PR 33); a job gathers ITS
+column alone where a statistic needs all of it (a median), beside the other
+jobs. A frame that already holds its concat, or has one partition, is one
+piece, and a job of one piece is the code as it was.
+
 No two threads store into the same cache lines: a job writes its column
 CONTIGUOUSLY, one row of a (jobs, n) float32 scratch, and the row-major
 (n, d) block the estimators take is interleaved from the scratch in
@@ -24,7 +33,10 @@ is float64 until its write, and where a value can only be had from the
 pandas call (a mean's summation order, a mode's tie) or the column's
 storage has no path that releases the interpreter lock (object strings, a
 numeric column fed to an indexer), the job makes today's call on its
-column, correct and no faster (`legacy` in its result).
+column, correct and no faster (`legacy` in its result). A column whose
+pieces disagree in storage (an all-null piece read back as object or
+float, object strings in one piece) is gathered to the ONE pandas column
+the concat would hold, and the job runs on that as on a table of one piece.
 
 Jobs open no spans and bump no counters: the caller's thread does both,
 so `span_s.*` stay wall seconds of one thread.
@@ -41,6 +53,7 @@ import numpy as np
 import pandas as pd
 
 from ..parallel.pipeline import mark_host_worker, on_host_worker
+from ..utils.profiler import PROFILER
 from .feature import imputer_surrogate, indexer_labels, order_labels
 from .featurizer import _IndexSource, _numeric
 
@@ -57,7 +70,12 @@ _BLOCK_ROWS = 65536
 #: section 6, PR 29). The quantize plan shares the threshold; its own
 #: crossing is lower (`make_bins` on the same host: 16,000 rows 6.5 / 6.4,
 #: 32,000 10.7 / 8.2, 65,536 20.3 / 10.8; PR 31): one rule for both, at
-#: most 10 ms dearer for a table between the two
+#: most 10 ms dearer for a table between the two. Under it a frame of
+#: several partitions is also read as its ONE concat (`Pieces.of`): a pandas
+#: Series a piece a column costs more than the concat of such a table (same
+#: chain and host, 8 partitions, inline, the concat's table / the pieces,
+#: median ms of 30: 1,005 rows 3.60 / 6.16, 7,002 4.67 / 7.30, 64,897
+#: 16.82 / 17.36; at 1.6 M rows, pooled, 272 / 97; PR 33)
 _INLINE_ROWS = 65536
 
 _pool: Optional[ThreadPoolExecutor] = None
@@ -108,6 +126,84 @@ def run_tasks(tasks: list, inline: bool) -> list:
     return [f.result() for f in futures]
 
 
+class Pieces:
+    """The rows of one fit as the jobs read them: pandas tables with the
+    same columns, in the order the table-wide concat lays their rows
+    (`parts`). `of(frame)` lists a frame's materialized partitions, no
+    copy. It adapts on what the frame shows: one that already holds its
+    concat (`_pdf_cache`: it was fitted or collected before), has one
+    partition, fewer than `_INLINE_ROWS` rows (a Series a piece a column
+    costs more than the concat of such a table: PERF.md section 6, PR 33)
+    or partitions that differ in their columns, is that one table, as
+    `toPandas` gives it."""
+
+    def __init__(self, parts: List[pd.DataFrame]):
+        self.parts = parts
+        self.columns = parts[0].columns
+        self.rows = sum(len(p) for p in parts)
+        self._gathered: dict = {}
+
+    @classmethod
+    def of(cls, frame) -> "Pieces":
+        if frame._pdf_cache is None:
+            parts = [p for p in frame._materialize() if len(p.columns)]
+            if len(parts) > 1 and sum(map(len, parts)) >= _INLINE_ROWS \
+                    and all(p.columns.equals(parts[0].columns)
+                            for p in parts[1:]):
+                return cls(parts)
+        return cls.collected(frame)
+
+    @classmethod
+    def collected(cls, frame) -> "Pieces":
+        """The frame's table-wide concat as one piece (what the generic
+        sequential fit reads), counted where this call makes it: the
+        frame keeps it for whoever fits it again."""
+        if getattr(frame, "_pdf_cache", None) is None:
+            PROFILER.count("featurize.collect.concats")
+        return cls([frame.toPandas()])
+
+    def pieces(self, col: str) -> List[pd.Series]:
+        return [p[col] for p in self.parts]
+
+    def column(self, col: str) -> pd.Series:
+        """The column as the concat holds it, ONE pandas column: the
+        piece's own where there is one piece, else gathered (once) by
+        pandas' concat, so with its rule for the common storage of pieces
+        that disagree, and its values."""
+        if len(self.parts) == 1:
+            return self.parts[0][col]
+        got = self._gathered.get(col)
+        if got is None:
+            got = self._gathered[col] = pd.concat(
+                self.pieces(col), ignore_index=True)
+        return got
+
+    def table(self, cols: List[str]) -> pd.DataFrame:
+        """A table that holds these columns of the concat: the piece
+        itself where there is one, else the gathered columns (no copy of
+        them)."""
+        if len(self.parts) == 1:
+            return self.parts[0]
+        return pd.DataFrame({c: self.column(c) for c in cols}, copy=False)
+
+    def schema(self):
+        """The concat's schema without the concat: a column's storage
+        follows from the pieces' (a zero-row concat applies the same rule),
+        and only a column of objects is gathered, for the look at its
+        values `infer_schema_from_pandas` takes. None for one piece: the
+        frame infers it from that."""
+        from ..frame.types import StructType, infer_schema_from_pandas
+        if len(self.parts) == 1:
+            return None
+        empty = pd.concat([p.iloc[:0] for p in self.parts], ignore_index=True)
+        fields = infer_schema_from_pandas(empty).fields
+        for i, c in enumerate(self.columns):
+            if empty[c].dtype == object:
+                fields[i], = infer_schema_from_pandas(
+                    self.column(c).to_frame()).fields
+        return StructType(fields)
+
+
 class JobResult(NamedTuple):
     surrogate: Optional[float] = None      # an imputed column's fill
     labels: Optional[List[str]] = None     # an indexed column's labels
@@ -131,28 +227,42 @@ class NumericJob:
         self.blockwise = False
         self.row: Optional[int] = None   # its row of the scratch, if assembled
 
-    def run(self, pdf: pd.DataFrame, out: Optional[np.ndarray]) -> JobResult:
-        col = pdf[self.col]
-        fast = isinstance(col.dtype, np.dtype) and col.dtype.kind in "fiu"
-        v = None
+    def run(self, src: Pieces, out: Optional[np.ndarray]) -> JobResult:
+        cols = src.pieces(self.col)
+        fast = all(_plain_numbers(c) for c in cols)
+        if not fast and len(cols) > 1:   # as the table of one piece reads it
+            cols = [src.column(self.col)]
+            fast = _plain_numbers(cols[0])
+        vs = None   # the column in float64, a piece an array
         if fast:
-            v = col.to_numpy(np.float64)   # a view of a float64 column
+            # (a view of a float64 piece)
+            vs = [c.to_numpy(np.float64) for c in cols]
         elif out is not None:
-            v = self._extract_as_today(col)
+            vs = [self._extract_as_today(cols[0])]
         fill = None
         if self.strategy == "median" and fast:
-            # Series.median of the non-NaN values is np.median of them
-            nan = np.isnan(v)
-            vals = v[~nan] if nan.any() else v
-            fill = float(np.median(vals)) if len(vals) else 0.0
+            # Series.median of the non-NaN values is np.median of them, in
+            # the column's order: the one gather a median needs
+            vals = []
+            for v in vs:
+                nan = np.isnan(v)
+                vals.append(v[~nan] if nan.any() else v)
+            gathered = len(vals) > 1   # a buffer of this job's own
+            vals = np.concatenate(vals) if gathered else vals[0]
+            fill = float(np.median(vals, overwrite_input=gathered)) \
+                if len(vals) else 0.0
         elif self.strategy is not None:
-            fill = imputer_surrogate(col, self.strategy)
+            fill = imputer_surrogate(src.column(self.col), self.strategy)
         if out is not None:
-            out[:] = v   # the float32 cast of the block assignment
-            if fill is not None or self.blockwise:
-                bad = ~np.isfinite(v)
-                if bad.any():
-                    out[bad] = np.nan if fill is None else fill
+            lo = 0
+            for v in vs:
+                piece = out[lo:lo + len(v)]
+                lo += len(v)
+                piece[:] = v   # the float32 cast of the block assignment
+                if fill is not None or self.blockwise:
+                    bad = ~np.isfinite(v)
+                    if bad.any():
+                        piece[bad] = np.nan if fill is None else fill
         return JobResult(surrogate=fill, legacy=not fast)
 
     def _extract_as_today(self, col: pd.Series) -> np.ndarray:
@@ -162,6 +272,10 @@ class NumericJob:
             except (TypeError, ValueError):
                 pass
         return _numeric(col)
+
+
+def _plain_numbers(col: pd.Series) -> bool:
+    return isinstance(col.dtype, np.dtype) and col.dtype.kind in "fiu"
 
 
 def _arrow_strings(col: pd.Series):
@@ -193,11 +307,23 @@ class StringJob:
         self.invalid = invalid
         self.row: Optional[int] = None
 
-    def run(self, pdf: pd.DataFrame, out: Optional[np.ndarray]) -> JobResult:
-        col = pdf[self.col]
-        pa_arr = _arrow_strings(col)
+    def run(self, src: Pieces, out: Optional[np.ndarray]) -> JobResult:
+        cols = src.pieces(self.col)
+        pa_arr = _arrow_strings(cols[0])
+        if len(cols) > 1:
+            # the pieces' chunks as ONE chunked array, no copy (what the
+            # concat of Arrow string storage is); pieces that disagree in
+            # storage run as the table of one piece does
+            arrs = [_arrow_strings(c) for c in cols]
+            if any(a is None or a.type != pa_arr.type for a in arrs) \
+                    or any(c.dtype != cols[0].dtype for c in cols):
+                pa_arr = _arrow_strings(src.column(self.col))
+            else:
+                import pyarrow as pa
+                pa_arr = pa.chunked_array(
+                    [ch for a in arrs for ch in a.chunks], type=pa_arr.type)
         if pa_arr is None:
-            return self._run_as_today(pdf, col, out)
+            return self._run_as_today(src.column(self.col), out)
         enc = pa_arr.dictionary_encode().unify_dictionaries()
         values = enc.chunk(0).dictionary.to_pylist() if enc.num_chunks else []
         k = len(values)
@@ -216,8 +342,9 @@ class StringJob:
         if counts[k]:
             if self.invalid == "error":
                 first = np.concatenate([c == k for c in codes]).argmax()
+                null = src.column(self.col).iloc[first]   # as pandas shows it
                 raise ValueError(
-                    f"Unseen label {col.iloc[first]!r} in column "
+                    f"Unseen label {null!r} in column "
                     f"{self.col!r} (handleInvalid='error')")
             if self.invalid == "skip":
                 invalid = np.concatenate([c == k for c in codes])
@@ -231,15 +358,15 @@ class StringJob:
             lo += len(c)
         return JobResult(labels=labels, invalid=invalid, has_null=has_null)
 
-    def _run_as_today(self, pdf, col, out) -> JobResult:
+    def _run_as_today(self, col: pd.Series, out) -> JobResult:
         labels = indexer_labels(col, self.order)
         has_null = bool(col.isna().any())
         if out is None:
             return JobResult(labels=labels, legacy=True, has_null=has_null)
         src = _IndexSource(self.col, np.asarray(labels, dtype=object),
                            self.invalid)
-        drop = np.zeros(len(pdf), dtype=bool)
-        out[:] = src.resolve(pdf, drop)
+        drop = np.zeros(len(col), dtype=bool)
+        out[:] = src.resolve(col.to_frame(self.col), drop)
         return JobResult(labels=labels, legacy=True, has_null=has_null,
                          invalid=drop if drop.any() else None)
 
@@ -252,12 +379,13 @@ class StringJob:
 
 
 class Plan:
-    """The jobs of one fit, run (each keeps its `result`), the scratch they
-    wrote (row i is the assembler's input i), `block()` to interleave it
-    and `compact()` to hand it over as it is."""
+    """The jobs of one fit, run over the table's pieces (each keeps its
+    `result`), the scratch they wrote (row i is the assembler's input i,
+    its rows in the pieces' order), `block()` to interleave it and
+    `compact()` to hand it over as it is."""
 
-    def __init__(self, pdf: pd.DataFrame, jobs: list):
-        self.rows = len(pdf)
+    def __init__(self, src: Pieces, jobs: list):
+        self.rows = src.rows
         self.inline = runs_inline(self.rows)
         self.workers = 1 if self.inline else _cores()
         self.jobs = jobs
@@ -265,7 +393,7 @@ class Plan:
         self.scratch = scratch = np.empty(
             (assembled, self.rows), dtype=np.float32)
         results = run_tasks(
-            [lambda j=j: j.run(pdf, None if j.row is None
+            [lambda j=j: j.run(src, None if j.row is None
                                else scratch[j.row]) for j in jobs],
             self.inline)
         for j, r in zip(jobs, results):

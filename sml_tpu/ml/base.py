@@ -239,46 +239,66 @@ class Pipeline(Estimator):
         stages = self.getStages()
         fitted: List[Transformer] = []
         cur = df
-        # Fit-time fast path: collapse to ONE partition so each stage's
-        # per-partition fn runs once over the whole frame and inter-stage
-        # concats are no-ops. Row-local transforms are partition-count
-        # invariant and global fits (Imputer median, StringIndexer
-        # frequencies) already aggregate across partitions, so results are
-        # unchanged — only the constant factor is (r2 spent ~0.7s/fit in
-        # repeated 8-way concats, VERDICT weak #1). The returned model is
-        # partitioning-agnostic either way.
+        # Fit-time fast path. The standard prep chain fits as a column
+        # plan over the frame's partitions WHERE THEY LIE
+        # (`featurizer.try_fast_fit`, `_column_plan.Pieces`): no table-wide
+        # concat is made, and `fit.collect` holds what is left of
+        # gathering (listing the pieces, the columns the estimator reads
+        # whole). Any other chain collapses to ONE partition, so each
+        # stage's per-partition fn runs once over the whole frame and
+        # inter-stage concats are no-ops. Row-local transforms are
+        # partition-count invariant and global fits (Imputer median,
+        # StringIndexer frequencies) already aggregate across partitions,
+        # so results are unchanged — only the constant factor is (r2 spent
+        # ~0.7s/fit in repeated 8-way concats, VERDICT weak #1). The
+        # returned model is partitioning-agnostic either way.
         raw_pdf = None
         if hasattr(cur, "toPandas") and hasattr(cur, "_ml_attrs"):
             from ..frame.dataframe import DataFrame as _DF
-            # build the 1-partition frame from the frame's memoized concat:
-            # repeated fits on a cached frame re-use one materialization
-            with PROFILER.span("fit.collect") as note:
-                raw_pdf = cur.toPandas()
-                note["rows"] = len(raw_pdf)
+            from ._column_plan import Pieces
+            from .featurizer import label_columns, try_fast_fit
             session = getattr(cur, "_session", None)
 
-            def make_frame(pdf):
-                f = _DF.from_partitions([pdf], session=session)
+            def make_frame(parts, schema=None):
+                f = _DF.from_partitions(parts, session=session, schema=schema)
                 f._ml_attrs = dict(df._ml_attrs)
                 return f
 
+            # a frame that holds its concat already (it was fitted or
+            # collected before: a grid or a cross-validation over one
+            # split), has one partition or is small is read as that one
+            # table (`Pieces.of`)
+            with PROFILER.span("fit.collect") as note:
+                raw = Pieces.of(cur) if isinstance(cur, _DF) \
+                    else Pieces.collected(cur)
+                for c in label_columns(stages[-1]) if stages else []:
+                    if c in raw.columns:
+                        raw.column(c)   # gathered here, read at the end
+                note["rows"], note["pieces"] = raw.rows, len(raw.parts)
+
             # whole-chain fused fit (featurizer.try_fast_fit): the standard
-            # prep chain fits from the raw pandas and the estimator reads a
+            # prep chain fits from the raw pieces and the estimator reads a
             # one-pass assembled block — nothing else materializes. Only
             # the host-side CHAIN COMPILATION is guarded (any surprise
             # falls back to the always-correct generic path); the
             # estimator fit — the device work — runs unguarded so its
             # real errors propagate.
-            from .featurizer import try_fast_fit
             try:
-                fast = try_fast_fit(stages, raw_pdf, make_frame)
+                fast = try_fast_fit(stages, raw, make_frame)
             except Exception:
                 fast = None
             if fast is not None:
                 fitted_prep, shim = fast
                 return PipelineModel(fitted_prep + [stages[-1].fit(shim)])
-            one = make_frame(raw_pdf)
-            cur = one
+            if len(raw.parts) > 1:
+                # the plan declined: the generic sequential fit reads the
+                # frame's memoized concat, so repeated fits on a cached
+                # frame re-use one materialization
+                with PROFILER.span("fit.collect") as note:
+                    raw = Pieces.collected(cur)
+                    note["rows"] = raw.rows
+            raw_pdf, = raw.parts
+            cur = make_frame([raw_pdf])
         last = len(stages) - 1
         for i, stage in enumerate(stages):
             if not isinstance(stage, (Estimator, Transformer)):

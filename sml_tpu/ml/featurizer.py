@@ -502,8 +502,10 @@ class CompiledFeaturizer:
         return X, keep, cols
 
 
-def try_fast_fit(stages, raw_pdf, make_frame):
-    """Whole-pipeline fused FIT: for the standard course chain
+def try_fast_fit(stages, raw, make_frame):
+    """Whole-pipeline fused FIT over `raw`, the training table as
+    `_column_plan.Pieces` (the frame's partitions where they lie; no
+    table-wide concat is made here). For the standard course chain
     [Imputer?, StringIndexer?, OneHotEncoder?, VectorAssembler, estimator],
     or [RFormula, estimator] (the formula's own indexer, encoder and
     assembler: `RFormula._chain`),
@@ -515,16 +517,18 @@ def try_fast_fit(stages, raw_pdf, make_frame):
     len(labels)` when labels come from the same data), the assembler's
     slot metadata is reconstructed analytically, and the estimator gets a
     frame carrying the assembled block: NO transform chain ever
-    materializes. Returns (fitted_prep_stages, estimator_input_frame), or
+    materializes, and that frame lies over the same pieces (`make_frame`
+    makes it from a list of them). Returns (fitted_prep_stages,
+    estimator_input_frame), or
     None where the plan declines (counter `featurize.plan.declined`, the
     reason on its event): the caller falls back to the generic sequential
     fit, which is always correct. The caller runs the estimator fit itself
     so estimator errors propagate unmasked.
     """
-    if len(stages) < 2 or raw_pdf is None:
+    if len(stages) < 2 or raw is None:
         return _decline("no chain of stages over a frame")
     try:
-        return _try_fast_fit(stages, raw_pdf, make_frame)
+        return _try_fast_fit(stages, raw, make_frame)
     except Exception as e:
         _decline(f"a job raised {type(e).__name__}")
         raise
@@ -567,20 +571,26 @@ def produced_columns(prep_stages) -> set:
     return produced
 
 
+def label_columns(est) -> List[str]:
+    """The columns an estimator reads beside its features: labelCol and,
+    where one is set, weightCol (none for a stage that has no labelCol)."""
+    if not (hasattr(est, "hasParam") and est.hasParam("labelCol")):
+        return []
+    cols = [est.getOrDefault("labelCol")]
+    if est.hasParam("weightCol") and est.getOrDefault("weightCol"):
+        cols.append(est.getOrDefault("weightCol"))
+    return cols
+
+
 def prep_overwrites_label(prep_stages, est) -> bool:
     """True when any prep stage's OUTPUT columns collide with the
     estimator's labelCol/weightCol — the fused fast paths read labels from
     the RAW pandas, so a stage that rewrites the label there would make
     them train on pre-transform values."""
-    label_like = {est.getOrDefault("labelCol")}
-    if est.hasParam("weightCol"):
-        w = est.getOrDefault("weightCol")
-        if w:
-            label_like.add(w)
-    return bool(produced_columns(prep_stages) & label_like)
+    return bool(produced_columns(prep_stages) & set(label_columns(est)))
 
 
-def _try_fast_fit(stages, raw_pdf, make_frame):
+def _try_fast_fit(stages, raw, make_frame):
     from . import _column_plan as cp
     from .base import Estimator
     from .feature import (Imputer, ImputerModel, OneHotEncoder,
@@ -593,32 +603,29 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
         return _decline("the estimator reads no featuresCol and labelCol")
     # a formula IS an indexer, an encoder and an assembler over raw
     # columns (`RFormula._chain`): its jobs are theirs, and its model is
-    # made from their models at the end. `label_pdf` is where the
-    # estimator's labels are read: the raw table, with the formula's
-    # label under the formula's labelCol where the two names differ
-    rformula = label_source = None
-    label_pdf = raw_pdf
+    # made from their models at the end. `renamed` is the formula's label
+    # where the estimator reads it under the formula's labelCol and the
+    # two names differ
+    rformula = label_source = renamed = None
     if any(isinstance(st, RFormula) for st in prep):
         if len(prep) != 1:
             return _decline("a RFormula stage beside other prep stages")
         rformula = prep[0]
         label_source, str_terms, num_terms = rformula._terms(
-            make_frame(raw_pdf))
+            make_frame(raw.parts, raw.schema()))
         prep = rformula._chain(str_terms, num_terms)
         label_col = rformula.getOrDefault("labelCol")
         if label_source != label_col \
                 and est.getOrDefault("labelCol") == label_col:
-            if label_source not in raw_pdf.columns:
+            if label_source not in raw.columns:
                 return _decline("the formula's label is no raw column")
-            label_pdf = raw_pdf.copy(deep=False)
-            label_pdf[label_col] = pd.to_numeric(raw_pdf[label_source],
-                                                 errors="coerce")
+            renamed = label_source
     if not prep or not isinstance(prep[-1], VectorAssembler):
         return _decline("no VectorAssembler before the estimator")
     assembler = prep[-1]
     if est.getOrDefault("featuresCol") != assembler.getOrDefault("outputCol"):
         return _decline("the estimator does not read the assembler's output")
-    if est.getOrDefault("labelCol") not in label_pdf.columns:
+    if renamed is None and est.getOrDefault("labelCol") not in raw.columns:
         return _decline("labelCol is no raw column")
     if prep_overwrites_label(prep[:-1], est):
         return _decline("a prep stage rewrites the label")
@@ -651,7 +658,7 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
                     for c in ins]
         else:
             return _decline(f"a {type(st).__name__} stage outside the chain")
-        if any(c not in raw_pdf.columns or c in produced or c in encoded
+        if any(c not in raw.columns or c in produced or c in encoded
                for c in ins):
             return _decline(f"a {type(st).__name__} over a produced column")
         produced.update(zip(outs, made))
@@ -663,7 +670,7 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
     for c in assembler.getOrDefault("inputCols"):
         job, drop_last = encoded.get(c) or (produced.get(c), None)
         if job is None:
-            if c not in raw_pdf.columns:
+            if c not in raw.columns:
                 return _decline("an assembler input nothing makes")
             job = cp.NumericJob(c)
             jobs.append(job)
@@ -685,7 +692,7 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
     # as it was written, a job a row). Gated by size so course-scale fits
     # keep the materialized block and its golden-pinned numerics
     # bit-for-bit. The width follows the labels
-    n = len(raw_pdf)
+    n = raw.rows
     compact_bytes = None
     if type(est).__name__ in ("LinearRegression", "LogisticRegression"):
         from ..conf import GLOBAL_CONF
@@ -693,7 +700,7 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
 
     X = keep = parts = None
     with PROFILER.span("fit.featurize", rows=n, columns=len(jobs)) as note:
-        plan = cp.Plan(raw_pdf, jobs)
+        plan = cp.Plan(raw, jobs)
         note["workers"] = plan.workers
         # an encoder's width follows its indexer's labels
         onehot = [None if drop_last is None
@@ -729,6 +736,7 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
             fitted = [rformula._model(fitted, label_source)]
 
     PROFILER.count("featurize.plan.fits")
+    PROFILER.count("featurize.plan.pieces", len(raw.parts))
     legacy = sum(j.result.legacy for j in jobs)
     if legacy:
         PROFILER.count("featurize.plan.columns_legacy", legacy)
@@ -736,12 +744,21 @@ def _try_fast_fit(stages, raw_pdf, make_frame):
     # the assembler's slot metadata (VectorAssembler._transform computes
     # this from column attrs + row peeks; here widths are known statically)
     out_col = assembler.getOrDefault("outputCol")
-    shim = make_frame(raw_pdf)
+    shim = make_frame(raw.parts)
     shim._ml_attrs = dict(attrs)
     shim._ml_attrs[out_col] = {
         "slots": {lo: attrs[c]["categorical"] for lo, c in zip(
             los, assembler.getOrDefault("inputCols")) if c in attrs},
         "numFeatures": width}
+    # what the estimator reads beside the block: ONE gathered column a
+    # name (`Pipeline._fit` gathered them inside `fit.collect`)
+    cols = [c for c in label_columns(est) if c in raw.columns]
+    if renamed is None:
+        label_pdf = raw.table(cols)
+    else:
+        label_pdf = raw.table(cols + [renamed]).copy(deep=False)
+        label_pdf[est.getOrDefault("labelCol")] = pd.to_numeric(
+            label_pdf[renamed], errors="coerce")
     if parts is not None:
         shim._featurized_compact = {out_col: (parts, label_pdf)}
     else:

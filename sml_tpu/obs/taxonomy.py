@@ -97,9 +97,13 @@ COUNTERS = {
     # the fit-time column plan (ml/_column_plan.py, featurizer.try_fast_fit):
     # fits that took the plan / fits that fell through to the generic
     # sequential fit (the reason rides the event of the same name) /
-    # columns that ran the sequential per-column code inside their job
+    # columns that ran the sequential per-column code inside their job /
+    # pieces (the frame's partitions, or its one table) the jobs read / fits
+    # that made the frame's table-wide concat (the plan declined, or the
+    # frame is one partition: a plan fit of several pieces makes none)
     "featurize.plan.fits", "featurize.plan.declined",
-    "featurize.plan.columns_legacy",
+    "featurize.plan.columns_legacy", "featurize.plan.pieces",
+    "featurize.collect.concats",
     # the quantize plan (tree_impl.make_bins: a job a column for the bin
     # statistics, a job a block of rows for the bins): a make_bins that ran
     # its jobs on the column plan's pool / one that ran them on the caller

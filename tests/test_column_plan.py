@@ -1,7 +1,9 @@
 """The fit-time column plan (`ml/_column_plan.py`, `featurizer.try_fast_fit`):
 one job a raw column, the jobs side by side on one pool. Every bit of what
 the estimator is handed (the block, the keep mask, the fitted prep models,
-the shim's attrs) equals the generic sequential fit's, whatever the pool."""
+the shim's attrs) equals the generic sequential fit's, whatever the pool,
+and whatever pieces the frame's rows lie in: the plan reads the partitions
+where they lie and makes no table-wide concat."""
 
 import sys
 import threading
@@ -18,8 +20,10 @@ from sml_tpu import obs
 from sml_tpu.conf import GLOBAL_CONF
 from sml_tpu.ml import Pipeline
 from sml_tpu.ml.base import Estimator, Model
-from sml_tpu.ml.feature import (Imputer, OneHotEncoder, StandardScaler,
-                                StringIndexer, VectorAssembler)
+from sml_tpu.frame.dataframe import DataFrame
+from sml_tpu.ml.feature import (Imputer, OneHotEncoder, RFormula,
+                                StandardScaler, StringIndexer,
+                                VectorAssembler)
 from sml_tpu.ml.regression import RandomForestRegressor
 
 
@@ -36,11 +40,20 @@ class _Spy(Estimator):
         self._declareParam("labelCol", default="label", doc="label")
 
     def _fit(self, df):
+        self.frame = df
+        compact = getattr(df, "_featurized_compact", None)
+        if compact is not None:
+            (self.parts, self.labels), = compact.values()
+            return _SpyModel()
         if not hasattr(df, "_featurized"):
             df.toPandas()   # the generic chain raises what is really wrong
-        (X, keep, _raw), = df._featurized.values()
+        (X, keep, self.labels), = df._featurized.values()
         self.seen = (X, keep, dict(df._ml_attrs))
         return _SpyModel()
+
+
+#: `try_fast_fit` hands the compact form to the linear family, by name
+_CompactSpy = type("LinearRegression", (_Spy,), {})
 
 
 def _table(n, seed, strings="arrow", nulls=True):
@@ -203,10 +216,18 @@ def test_both_sides_of_the_inline_threshold(spark, monkeypatch, counters,
         workers.append((self.inline, self.workers))
 
     monkeypatch.setattr(cp.Plan, "__init__", spying)
+    before = counters.counters()
     _both(spark, monkeypatch, pdf, lambda: _chain(drop_last=True),
           partitions=3)
     assert workers == [(rows < cp._INLINE_ROWS,
                         1 if rows < cp._INLINE_ROWS else cp._cores())]
+    # under it the plan reads the frame's concat, as it always did for a
+    # table that small; over it the three pieces, and only the generic
+    # fit of `_both` makes a concat
+    after = counters.counters()
+    moved = [after.get(k, 0) - before.get(k, 0) for k in (
+        "featurize.plan.pieces", "featurize.collect.concats")]
+    assert moved == ([1, 2] if rows < cp._INLINE_ROWS else [3, 1])
 
 
 @pytest.mark.parametrize("strategy", ["median", "mean", "mode"])
@@ -363,7 +384,7 @@ def test_an_unseen_label_raises_from_inside_a_job(spark, monkeypatch,
 
     monkeypatch.setattr(cp.StringJob, "run", spying)
     with pytest.raises(ValueError) as err:
-        cp.Plan(pdf, jobs)
+        cp.Plan(cp.Pieces([pdf]), jobs)
     assert str(err.value) == message
     assert raised_on and raised_on[0] is not threading.current_thread()
     with pytest.raises(ValueError) as err:
@@ -386,29 +407,240 @@ def test_the_assembler_error_rides_the_interleave(spark, monkeypatch):
     assert len(X) == 499 and not keep[333]
 
 
+# -- the frame's rows where they lie ----------------------------------------
+def _cut(pdf, sizes):
+    """The table's rows in pieces of these sizes, in order."""
+    assert sum(sizes) == len(pdf)
+    bounds = np.cumsum([0] + list(sizes))
+    return [pdf.iloc[lo:hi].reset_index(drop=True)
+            for lo, hi in zip(bounds, bounds[1:])]
+
+
+def _null_strings(parts):
+    """Piece 1 read back with no string in it: `cat` a column of None
+    objects, `other` a column of NaN floats."""
+    parts[1]["cat"] = pd.Series([None] * len(parts[1]), dtype=object)
+    parts[1]["other"] = np.nan
+    return parts
+
+
+def _object_strings(parts):
+    parts[1]["cat"] = parts[1]["cat"].astype(object)
+    parts[1]["other"] = parts[1]["other"].astype(object)
+    return parts
+
+
+def _int_and_float(parts):
+    z = parts[1]["z"].astype(np.float64)
+    z.iloc[::9] = np.nan
+    parts[1]["z"] = z
+    parts[2]["z"] = parts[2]["z"].astype(np.int32)
+    parts[0]["w"] = parts[0]["w"].astype(np.float32)
+    return parts
+
+
+#: the columns a layout's pieces disagree on: gathered to one column each
+DISAGREE = {"a_piece_of_null_strings": {"cat", "other"},
+            "object_strings_beside_arrow": {"cat", "other"}}
+
+#: rows -> the pieces' sizes; what is done to the pieces
+LAYOUTS = {
+    "one_piece": (lambda n: [n], None),
+    "three_uneven_pieces": (lambda n: [n // 2, 1, n - n // 2 - 1], None),
+    "eight_pieces_one_empty": (
+        lambda n: [n // 7] * 3 + [0] + [n // 7] * 3 + [n - 6 * (n // 7)],
+        None),
+    "a_piece_of_null_strings": (lambda n: [n // 3, n // 5, n - n // 3 - n // 5],
+                                _null_strings),
+    "object_strings_beside_arrow": (
+        lambda n: [n // 3, n // 5, n - n // 3 - n // 5], _object_strings),
+    "int_in_one_piece_float_in_another": (
+        lambda n: [n // 4, n // 2, n - n // 4 - n // 2], _int_and_float),
+}
+
+
+def _tree_chain():
+    return _chain(drop_last=True, assembler_invalid="keep")
+
+
+def _formula_chain():
+    return [RFormula(formula="label ~ .", handleInvalid="skip")]
+
+
+def _fit_frame(df, stages, spy):
+    before = obs.RECORDER.counters()
+    model = Pipeline(stages=stages + [spy]).fit(df)
+    after = obs.RECORDER.counters()
+    moved = {k.split("featurize.")[1]: after[k] - before.get(k, 0)
+             for k in after if k.startswith("featurize.")
+             and after[k] != before.get(k, 0)}
+    return model.stages[:-1], spy, moved
+
+
+def _assert_same_formula(mine, theirs):
+    assert (mine.label_source, mine._label_col) \
+        == (theirs.label_source, theirs._label_col)
+    assert mine._params_to_dict() == theirs._params_to_dict()
+    assert [type(s) for s in mine.stages] == [type(s) for s in theirs.stages]
+    for a, b in zip(mine.stages, theirs.stages):
+        assert a._params_to_dict() == b._params_to_dict()
+        assert getattr(a, "labelsArray", None) == getattr(b, "labelsArray", None)
+        assert getattr(a, "categorySizes", None) \
+            == getattr(b, "categorySizes", None)
+
+
+def _same_bits(a, b):
+    return a.dtype == b.dtype and a.shape == b.shape \
+        and a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["inline", "pooled"])
+@pytest.mark.parametrize("chain", ["tree_block", "formula_compact"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_fit_on_the_pieces_is_the_fit_on_their_concat(
+        spark, monkeypatch, counters, layout, chain, pooled):
+    """Stage models, block, `keep`, compact parts and label: to the bit
+    what the one-piece concat gives, which is what the generic sequential
+    fit gives; and the concat is not made."""
+    # (a table this small reads its concat: the threshold is taken away,
+    # and the inline case keeps its jobs on the calling thread)
+    monkeypatch.setattr(cp, "_INLINE_ROWS", 0)
+    monkeypatch.setattr(cp, "_BLOCK_ROWS", 97)
+    if not pooled:
+        monkeypatch.setattr(cp, "runs_inline", lambda rows: True)
+    held = GLOBAL_CONF.get("sml.linear.compactBytes")
+    GLOBAL_CONF.set("sml.linear.compactBytes", 0)
+    try:
+        sizes, spoil = LAYOUTS[layout]
+        pdf = _table(803, seed=60 + sorted(LAYOUTS).index(layout))
+        pieces = _cut(pdf, sizes(len(pdf)))
+        if spoil is not None:
+            pieces = spoil(pieces)
+        whole = pd.concat(pieces, ignore_index=True)   # the frame's concat
+        stages, spy = (_tree_chain, _Spy) if chain == "tree_block" \
+            else (_formula_chain, _CompactSpy)
+
+        gathered, column = set(), cp.Pieces.column
+
+        def spying(self, col):
+            if len(self.parts) > 1:
+                gathered.add(col)
+            return column(self, col)
+
+        df = DataFrame.from_partitions(pieces, session=spark)
+        with monkeypatch.context() as m:
+            m.setattr(cp.Pieces, "column", spying)
+            prep, got, moved = _fit_frame(df, stages(), spy())
+        # a column is read piece by piece: only the label, and a column
+        # whose pieces disagree in storage, is gathered to one
+        assert gathered == ({"label"} | DISAGREE.get(layout, set())
+                            if len(pieces) > 1 else set())
+        one = DataFrame.from_partitions([whole], session=spark)
+        prep1, want, moved1 = _fit_frame(one, stages(), spy())
+
+        # the counters: the pieces read, and no table-wide concat but the
+        # one-piece frame's own (`toPandas` of one partition, as before)
+        assert moved.pop("plan.pieces") == len(pieces)
+        assert moved1.pop("plan.pieces") == 1
+        assert moved1.pop("collect.concats") == 1
+        if len(pieces) == 1:
+            assert moved.pop("collect.concats") == 1
+        else:
+            assert "collect.concats" not in moved and df._pdf_cache is None
+            # the estimator's frame lies over the same partitions
+            assert all(a is b for a, b in zip(got.frame._materialize(),
+                                              pieces))
+        assert moved == moved1 and moved["plan.fits"] == 1   # legacy too
+
+        label = "label"
+        assert _same_bits(np.asarray(got.labels[label]),
+                          np.asarray(want.labels[label]))
+        assert _same_bits(np.asarray(got.labels[label]),
+                          whole[label].to_numpy())
+        if chain == "tree_block":
+            _assert_same_fit((prep, got.seen), (prep1, want.seen))
+            with monkeypatch.context() as m:   # the sequential reference
+                m.setattr(fz, "try_fast_fit", lambda *a, **k: None)
+                gprep, generic, gmoved = _fit_frame(df, stages(), _Spy())
+            # (a frame of one piece holds its table since the first fit)
+            assert gmoved == ({"collect.concats": 1} if len(pieces) > 1
+                              else {})
+            _assert_same_fit((prep, got.seen), (gprep, generic.seen))
+            return
+        _assert_same_formula(prep[0], prep1[0])
+        mine, theirs = got.parts, want.parts
+        assert _same_bits(mine.num, theirs.num)
+        assert _same_bits(mine.codes, theirs.codes)
+        assert (mine.layout, mine.width) == (theirs.layout, theirs.width)
+        assert mine.keep is not None and _same_bits(mine.keep, theirs.keep)
+        # the sequential reference: the formula's own fit and transform
+        seq = stages()[0].fit(one)
+        _assert_same_formula(prep[0], seq)
+        from sml_tpu.ml.linalg import to_matrix
+        out = seq.transform(one).toPandas()
+        assert _same_bits(mine.expand_host(),
+                          np.ascontiguousarray(to_matrix(out["features"]),
+                                               dtype=np.float32))
+        assert 0 < len(out) == int(mine.keep.sum()) < len(whole)
+    finally:
+        GLOBAL_CONF.set("sml.linear.compactBytes", held)
+
+
+def test_a_frame_that_holds_its_concat_is_read_from_it(spark, monkeypatch,
+                                                        counters):
+    """A second fit of one frame (a grid, a cross-validation over one
+    split) reads the memo; a plan that declines makes the concat, once."""
+    monkeypatch.setattr(cp, "_INLINE_ROWS", 400)
+    pdf = _table(500, seed=70)
+    df = spark.createDataFrame(pdf, numPartitions=4)
+    _prep, spy, moved = _fit_frame(df, _tree_chain(), _Spy())
+    assert moved == {"plan.fits": 1, "plan.pieces": 4}
+    assert df._pdf_cache is None
+
+    declining = _tree_chain() + [StandardScaler(inputCol="features",
+                                                outputCol="scaled")]
+    tree = RandomForestRegressor(featuresCol="scaled", maxBins=8, maxDepth=2,
+                                 numTrees=2, seed=1)
+    _prep, _tree, moved = _fit_frame(df, declining, tree)
+    assert moved == {"plan.declined": 1, "collect.concats": 1}
+    memo = df._pdf_cache
+    assert memo is not None and len(memo) == 500
+    _prep, _tree, moved = _fit_frame(df, declining, tree)
+    assert moved == {"plan.declined": 1}   # the memo: no second concat
+
+    _prep, again, moved = _fit_frame(df, _tree_chain(), _Spy())
+    assert moved == {"plan.fits": 1, "plan.pieces": 1}
+    assert df._pdf_cache is memo
+    piece, = again.frame._materialize()
+    assert np.shares_memory(piece["w"].to_numpy(), memo["w"].to_numpy())
+    _assert_same_fit((_prep, again.seen), (_prep, spy.seen))
+
+
 # -- counters and spans -----------------------------------------------------
 def test_counters_say_which_fits_took_the_plan(spark, monkeypatch, counters):
     from sml_tpu.obs import taxonomy
-    for name in ("fits", "declined", "columns_legacy"):
+    for name in ("fits", "declined", "columns_legacy", "pieces"):
         assert taxonomy.is_registered("count", "featurize.plan." + name)
+    assert taxonomy.is_registered("count", "featurize.collect.concats")
     assert taxonomy.is_registered("emit", "featurize.plan.declined")
 
-    def moved(before):
+    def moved(before, prefix="featurize.plan."):
         now = counters.counters()
-        return {k[len("featurize.plan."):]: now[k] - before.get(k, 0)
-                for k in now if k.startswith("featurize.plan.")
+        return {k[len(prefix):]: now[k] - before.get(k, 0)
+                for k in now if k.startswith(prefix)
                 and now[k] != before.get(k, 0)}
 
     pdf = _table(300, seed=40)
     df = spark.createDataFrame(pdf)
     start = counters.counters()
     Pipeline(stages=_chain() + [_Spy()]).fit(df)
-    assert moved(start) == {"fits": 1}
+    assert moved(start) == {"fits": 1, "pieces": 1}   # a small table: its concat
+    assert moved(start, "featurize.collect.") == {"concats": 1}
 
     start = counters.counters()
     obj = _table(300, seed=41, strings="object")
     Pipeline(stages=_chain() + [_Spy()]).fit(spark.createDataFrame(obj))
-    assert moved(start) == {"fits": 1, "columns_legacy": 2}
+    assert moved(start) == {"fits": 1, "pieces": 1, "columns_legacy": 2}
 
     # a stage outside the chain: the generic sequential fit, and why
     start = counters.counters()

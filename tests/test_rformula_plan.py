@@ -182,3 +182,40 @@ def test_a_formula_beside_other_prep_stages_declines_and_says_why(spark, conf):
                if e.name == "featurize.plan.declined"]
     assert reasons[-1] == "a RFormula stage beside other prep stages"
     assert isinstance(model.stages[1], RFormulaModel)   # the generic path
+
+
+@pytest.mark.parametrize("compact", [False, True], ids=["block", "compact"])
+@pytest.mark.parametrize("formula", ["label ~ .", "log_price ~ . - price"])
+def test_a_formula_over_the_frames_partitions_makes_no_concat(
+        spark, conf, monkeypatch, formula, compact):
+    """The plan reads the eight partitions where they lie: the model is
+    the one-partition frame's to the bit, the label under another name
+    too, and the frame holds no table of its own afterwards."""
+    import sml_tpu.ml._column_plan as cp
+    monkeypatch.setattr(cp, "_INLINE_ROWS", 2048)   # under the table's rows
+    label, estimator = FORMULAS[formula]
+    pdf = _table(nulls=True)
+    conf.set("sml.linear.compactBytes", 0 if compact else 1 << 40)
+
+    def fit(df):
+        before = obs.RECORDER.counters()
+        model = Pipeline(stages=[
+            RFormula(formula=formula, handleInvalid="skip", labelCol="target"),
+            estimator(labelCol="target", maxIter=10)]).fit(df)
+        after = obs.RECORDER.counters()
+        return model, {k: after[k] - before.get(k, 0) for k in (
+            "featurize.plan.fits", "featurize.plan.pieces",
+            "featurize.collect.concats") if after.get(k, 0) != before.get(k, 0)}
+
+    df = spark.createDataFrame(pdf, numPartitions=8)
+    model, moved = fit(df)
+    assert moved == {"featurize.plan.fits": 1, "featurize.plan.pieces": 8}
+    assert df._pdf_cache is None
+    want, moved = fit(spark.createDataFrame(pdf, numPartitions=1))
+    assert moved == {"featurize.plan.fits": 1, "featurize.plan.pieces": 1,
+                     "featurize.collect.concats": 1}
+    assert model.stages[0].stages[0].labelsArray \
+        == want.stages[0].stages[0].labelsArray
+    np.testing.assert_array_equal(model.stages[-1].coefficients.toArray(),
+                                  want.stages[-1].coefficients.toArray())
+    assert model.stages[-1].intercept == want.stages[-1].intercept
