@@ -20,6 +20,7 @@ from .feature import _as_object_series
 from .linalg import DenseVector, vector_series
 from ._staging import extract_compact, extract_features, extract_xy
 from . import linear_impl
+from .featurizer import margin_jobs
 from ._tree_models import (DecisionTreeClassificationModel,
                            DecisionTreeClassifier, GBTClassificationModel,
                            GBTClassifier, RandomForestClassificationModel,
@@ -27,10 +28,12 @@ from ._tree_models import (DecisionTreeClassificationModel,
 
 
 class BinaryLogisticRegressionSummary:
-    """Training summary. On the compact fast path the margin and accuracy
-    are computed EAGERLY at fit time (two cheap O(n) sweeps, so the
-    summary closure need not pin the training block); only the
-    O(n log n) AUC sort stays lazy, materializing on first read."""
+    """Training summary. On the compact fast path the float64 margin and
+    the accuracy are computed EAGERLY at fit time, in ONE visit of the
+    rows, a job a block of them on the column plan's pool (span
+    `fit.summary`; `CompactParts.predict_affine_agreeing`), so the summary
+    closure need not pin the training block; only the O(n log n) AUC sort
+    stays lazy, materializing on first read."""
 
     def __init__(self, accuracy: float = None, areaUnderROC: float = None,
                  numInstances: int = 0, lazy_fn=None):
@@ -102,17 +105,26 @@ class LogisticRegression(Estimator):
                                             intercept=res.intercept)
             model._inherit_params(self)
 
-            # margin + accuracy run EAGERLY (two cheap O(n) sweeps) so the
-            # summary closure holds only two 1-D arrays — the previous
-            # closure pinned the full CompactParts block (hundreds of MB
-            # at the 8M-row scale this path is gated to) until the summary
-            # was read, or forever if it never was. Only the O(n log n)
-            # AUC sort stays lazy; all metrics are EXACT full-data values,
-            # and _force drops the arrays once reduced to floats.
-            with PROFILER.span("fit.summary", rows=len(y)):
-                margin = parts.predict_affine(res.coefficients,
-                                              res.intercept)
-                acc = float(np.mean(((margin > 0).astype(float)) == y))
+            # margin + accuracy run EAGERLY, so the summary closure holds
+            # only two 1-D arrays — the previous closure pinned the full
+            # CompactParts block (hundreds of MB at the 8M-row scale this
+            # path is gated to) until the summary was read, or forever if
+            # it never was. ONE visit of the rows, a job a block of them on
+            # the column plan's pool (`CompactParts.predict_affine_agreeing`,
+            # PERF.md section 6, PR 37): a job writes its block of the
+            # float64 margin and counts the rows whose side of 0 is their
+            # label, and the counts' sum over the rows is the mean of the
+            # 0/1 agreements to the bit (NaN for no rows, as that mean is).
+            # Only the O(n log n) AUC sort stays lazy; all metrics are
+            # EXACT full-data values, and _force drops the arrays once
+            # reduced to floats.
+            n = len(y)
+            workers, blocks = margin_jobs(n)
+            with PROFILER.span("fit.summary", rows=n, workers=workers,
+                               blocks=blocks):
+                margin, agreeing = parts.predict_affine_agreeing(
+                    res.coefficients, res.intercept, y)
+                acc = float(np.divide(agreeing, n))
 
             def lazy_metrics(margin=margin, y=y, acc=acc):
                 return acc, _fast_auc(margin, y)

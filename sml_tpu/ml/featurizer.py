@@ -22,6 +22,7 @@ VectorAssembler(handleInvalid in ("error", "keep")).
 from __future__ import annotations
 
 import itertools
+from functools import partial
 from typing import List, NamedTuple, Optional
 
 import numpy as np
@@ -29,6 +30,28 @@ import pandas as pd
 
 from ..obs._recorder import RECORDER as _OBS
 from ..utils.profiler import PROFILER
+
+
+#: rows of the block one job of the margin pass owns
+#: (`CompactParts.predict_affine_agreeing`): eight of the column plan's. A
+#: job is some seventy NumPy calls, each of which gives the interpreter
+#: lock up and takes it again, and on a busy pool a hand-over costs what a
+#: call on 65,536 values does, so smaller blocks run one after another.
+#: The course's 22 slots on the chip tool's one-chip host, 13 cores, pool,
+#: median ms of 9 by rows a block (PERF.md section 6, PR 37): 6.4 M rows
+#: 65,536 299 / 131,072 168 / 262,144 103 / 524,288 79 / 1,048,576 101
+#: (the whole-column pass 1,652; 65,536-row blocks inline 338); 1.6 M rows
+#: 75 / 44 / 27 / 33 / 54 (the whole-column pass 85)
+_MARGIN_BLOCK_ROWS = 524288
+
+
+def margin_jobs(rows: int):
+    """(workers, blocks) of a margin pass over `rows` rows, for the span
+    around it to note: the threads its jobs run on (1: the calling one,
+    `_column_plan.runs_inline`) and how many jobs there are."""
+    from . import _column_plan as cp
+    return (1 if cp.runs_inline(rows) else cp._cores(),
+            -(-rows // _MARGIN_BLOCK_ROWS))
 
 
 class CompactParts(NamedTuple):
@@ -91,22 +114,61 @@ class CompactParts(NamedTuple):
     def predict_affine(self, coef: np.ndarray, intercept: float) -> np.ndarray:
         """X @ coef + intercept without expanding: numeric dot + one
         embedding-table lookup per encoded column (w·onehot(i) == w[i])."""
+        return self.predict_affine_agreeing(coef, intercept, None)[0]
+
+    def predict_affine_agreeing(self, coef: np.ndarray, intercept: float,
+                                y: Optional[np.ndarray]):
+        """(margin, agreeing): `predict_affine`'s float64 margin and, for
+        0/1 labels `y`, the count of rows whose `margin > 0` is their
+        label (0 without `y`), from the same visit of the rows.
+
+        A job a BLOCK OF ROWS on the column plan's pool (`_column_plan`:
+        the one pool of the process, inline under its row threshold and on
+        a worker thread, the same result either way): a job fills its
+        slice of the margin with the intercept and walks the layout once,
+        in the layout's order, so its temporaries are a block long, not
+        a table, and a row's additions happen in the order of the one
+        whole-column pass, whose margin this is TO THE BIT
+        (`tests/test_logistic_summary.py` keeps that pass). Jobs open no
+        spans and bump no counters; this thread counts where they ran,
+        `linear.summary.pooled` / `.inline`."""
+        from . import _column_plan as cp
         coef = np.asarray(coef, dtype=np.float64)
-        acc = np.full(self.rows, float(intercept), dtype=np.float64)
-        lo = 0
-        for item in self.layout:
-            if item[0] == "num":
-                acc += coef[lo] * self.num[item[1]]   # float64 products
-                lo += 1
-            else:
-                _, j, width = item
-                idx = self.codes[j]
-                # a code past the width (the dropped last, a "keep"
-                # overflow) is a row of zeros: it reads the appended 0
-                table = np.append(coef[lo:lo + width], 0.0)
-                acc += table[np.where((idx >= 0) & (idx < width), idx, width)]
-                lo += width
-        return acc
+        intercept = float(intercept)
+        n = self.rows
+        margin = np.empty(n, dtype=np.float64)
+
+        def block(r0: int) -> int:
+            r1 = min(r0 + _MARGIN_BLOCK_ROWS, n)
+            acc = margin[r0:r1]
+            acc[:] = intercept
+            lo = 0
+            for item in self.layout:
+                if item[0] == "num":
+                    acc += coef[lo] * self.num[item[1], r0:r1]   # float64
+                    lo += 1
+                else:
+                    _, j, width = item
+                    idx = self.codes[j, r0:r1]
+                    # a code past the width (the dropped last, a "keep"
+                    # overflow) is a row of zeros: it reads the appended 0
+                    table = np.append(coef[lo:lo + width], 0.0)
+                    acc += table[np.where((idx >= 0) & (idx < width),
+                                          idx, width)]
+                    lo += width
+            if y is None:
+                return 0
+            return int(np.count_nonzero((acc > 0) == y[r0:r1]))
+
+        inline = cp.runs_inline(n)
+        agreeing = sum(cp.run_tasks(
+            [partial(block, r0) for r0 in range(0, n, _MARGIN_BLOCK_ROWS)],
+            inline))
+        if inline:
+            PROFILER.count("linear.summary.inline")
+        else:
+            PROFILER.count("linear.summary.pooled")
+        return margin, agreeing
 
 
 def _numeric(col) -> np.ndarray:

@@ -113,6 +113,11 @@ COUNTERS = {
     # run / Newton steps the device executed (the scan's length, whatever
     # converged) / steps that moved the coefficients (what a fit reports)
     "linear.irls.fits", "linear.irls.steps_run", "linear.irls.iterations",
+    # the compact form's margin pass (featurizer.CompactParts.predict_affine:
+    # a job a block of rows; the logistic summary inside `fit.summary`, a
+    # linear summary's MAE when it is read): a pass that ran its jobs on the
+    # column plan's pool / one that ran them on the caller
+    "linear.summary.pooled", "linear.summary.inline",
     # prewarm manifest (parallel/prewarm.py): recorded signatures,
     # replayed/failed first-dispatches, pool-size attribution
     "prewarm.*",
