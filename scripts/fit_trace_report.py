@@ -18,8 +18,22 @@ first), then, from the same process's recorder ring and the run's
    the end of the last device operation of the fit minus the end of its
    `fit.device_wait` (both on the profiler's clock);
 2. the phases of each fit of the window, from the spans that share its
-   trace id: fits 1-3 against the rest (which phase is still warming);
-3. what the trace says about each device operation: the plane's lines, and
+   trace id, children beside their parents (`fit.quantize.*`, the
+   `fit.featurize.*` four, the staging steps `stage.key` / `.pad` / `.put`)
+   and what a span notes in numbers as `<name> [<note>]` (the process's
+   `cpu_s`, the slowest column job's `longest_s`, a staging
+   step's `bytes`, `copied`, `hit`): fits 1-3 against the rest (which phase
+   is still warming);
+3. the one-clock check extended to the transfer: for every `fit.stage` of
+   the window, the end of the fit's last host-to-device event on the trace's
+   clock minus the end of its last `stage.put` span (positive: `device_put`
+   returned before the transfer was done), the same for the transfers that
+   started before the fit's first `fit.dispatch` (a tree program's dispatch
+   makes transfers of its own, so there this is the staging's; where the
+   runtime is still issuing the staged transfers when the dispatch begins,
+   as for cell 4's 0.6 GB, it reads negative and the first number is the
+   one to read), and which events of the trace were taken for transfers;
+4. what the trace says about each device operation: the plane's lines, and
    a sample of operations with the statistics kept with them (where the
    `jax.named_scope` of an operation is to be found).
 """
@@ -42,6 +56,9 @@ from sml_tpu.utils.profiler import now, wallclock  # noqa: E402
 T_START = now()
 
 WAIT = "fit.device_wait"
+#: what a host-to-device transfer may be called in a trace, letters only
+H2D_MARKS = ("h2d", "hosttodevice", "transfertodevice", "copytodevice",
+             "bufferfromhostbuffer", "transferliteraltodevice", "infeed")
 
 
 def _quartiles(values):
@@ -105,10 +122,70 @@ def clock_check(trace, profile, placed, window):
     return out
 
 
+def put_against_transfer(profile, placed, window):
+    """The end of a fit's last host-to-device event minus the end of its
+    last `stage.put` span, in microseconds, a `fit.stage` of the window. A
+    fit's transfers are the events named like one (`H2D_MARKS`, any plane
+    and line) that start between the start of its `fit.stage` and the end
+    of the `fit.device_wait` that follows; the staging's own are those of
+    them that start before the fit's first `fit.dispatch`. Where the trace
+    names no such event, the lines it has, for the next reader."""
+    lo, hi = window
+    found, transfers, lines = {}, [], {}
+    for plane in profile.planes:
+        for line in plane.lines:
+            label = f"{plane.name} / {line.name}"
+            for e in line.events:
+                lines[label] = lines.get(label, 0) + 1
+                if not lo <= e.start_ns <= hi:
+                    continue
+                letters = "".join(c for c in e.name.lower() if c.isalpha())
+                if any(mark in letters for mark in H2D_MARKS):
+                    key = f"{label} / {e.name[:80]}"
+                    found[key] = found.get(key, 0) + 1
+                    transfers.append((float(e.start_ns),
+                                      float(e.start_ns + e.duration_ns)))
+    out = {"events_taken_for_transfers": found}
+    if not transfers:
+        out["lines"] = lines
+        return out
+    transfers.sort()
+    starts = [a for a, _ in transfers]
+    inside = sorted((a, b, n) for n, a, b in placed if lo <= a and b <= hi
+                    and n in ("fit.stage", "stage.put", "fit.dispatch", WAIT))
+    gaps, staged, last_put, stage_at, dispatch_at = [], [], None, None, None
+    for a, b, name in inside:
+        if name == "fit.stage":
+            stage_at, last_put, dispatch_at = a, None, None
+        elif name == "stage.put" and stage_at is not None:
+            last_put = b
+        elif name == "fit.dispatch" and dispatch_at is None:
+            dispatch_at = a
+        elif name == WAIT and stage_at is not None and last_put is not None:
+            at = bisect.bisect_left(starts, stage_at)
+            mine = transfers[at:bisect.bisect_right(starts, b)]
+            if mine:
+                gaps.append((max(e for _, e in mine) - last_put) / 1e3)
+            # the staging's own: a program's dispatch transfers its scalars
+            # and a tree program its margin, so those that start before the
+            # fit's first dispatch are the ones `stage.put` asked for
+            mine = transfers[at:bisect.bisect_left(
+                starts, dispatch_at if dispatch_at is not None else b)]
+            if mine:
+                staged.append((max(e for _, e in mine) - last_put) / 1e3)
+            stage_at = None
+    out["last_transfer_end_minus_last_put_end_us"] = _quartiles(gaps)
+    out["last_transfer_started_before_dispatch"
+        "_end_minus_last_put_end_us"] = _quartiles(staged)
+    return out
+
+
 def fits_by_phase(events, since_s: float):
     """One row a fit whose root `fit` span started after `since_s` (the
     recorder's clock): seconds by span name, the root's own remainder as
-    `(unattributed)`; children of `fit.quantize` kept beside it."""
+    `(unattributed)`; every child (of `fit.quantize`, `fit.featurize`,
+    `fit.stage`) kept beside its parent, under its own name, and what a
+    span notes in numbers beside it as `<name> [<note>]`."""
     spans = [e for e in events if e.kind == "span" and "trace" in e.args]
     roots = sorted((e for e in spans if e.name == "fit"
                     and e.args.get("parent") is None and e.ts >= since_s),
@@ -119,6 +196,14 @@ def fits_by_phase(events, since_s: float):
         row = {"fit": root.dur}
         direct = 0.0
         for e in mine:
+            # what a span notes beside its seconds: the process's CPU
+            # seconds (`CPU_SPANS`), the slowest job and, for a staging
+            # step, its bytes and how often it had to copy the caller's
+            # array or found it cached
+            for note in ("cpu_s", "longest_s", "bytes", "copied", "hit"):
+                if note in e.args:
+                    key = f"{e.name} [{note}]"
+                    row[key] = row.get(key, 0.0) + e.args[note]
             if e is root:
                 continue
             row[e.name] = row.get(e.name, 0.0) + e.dur
@@ -233,6 +318,8 @@ def main() -> int:
         "ring": {"events": len(recorder.events()),
                  "dropped": recorder.dropped},
         "clock": clock_check(trace, profile, seen["labels"], window),
+        "put_against_transfer": put_against_transfer(
+            profile, seen["labels"], window),
         "fits": rows,
         "first_three_against_rest": warm_against_rest(rows),
         "device": device_metadata(profile, path),
@@ -242,7 +329,7 @@ def main() -> int:
     out = os.path.join(args.out, args.workload + ".json")
     with open(out, "w") as f:
         json.dump(report, f, indent=1)
-    summary = {k: report[k] for k in ("cell", "clock",
+    summary = {k: report[k] for k in ("cell", "clock", "put_against_transfer",
                                       "first_three_against_rest")}
     summary["device"] = {k: v for k, v in report["device"].items()
                          if not k.endswith("statistics")}

@@ -9,7 +9,13 @@ Set-up as the cell's kind does it (the seeded table, its warm fits, the
 recorder on). Then `--fits` timed fits, each on a `randomSplit` this process
 has not fitted, the recorder on and off in turn (on, off, off, on, ...). With
 the recorder on, the span totals give the phases of the untraced fit
-(`fit.baseline` is what the recorder itself adds to every fit). Writes
+(`fit.baseline` is what the recorder itself adds to every fit). Then what
+one `PROFILER.span` costs in this process (the runtime's and the pool's
+threads alive, as in a fit), median microseconds of 10,000: the recorder
+off, on for a plain span, on for a span that reads the process's CPU seconds
+(`taxonomy.CPU_SPANS`: a `time.process_time` at each end), the read alone,
+and the two spans again inside a profiler trace started as a traced run of
+the benchmark starts it (every span is a `TraceAnnotation` there). Writes
 `<out>/<cell>.json` and prints it.
 """
 
@@ -24,6 +30,49 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+
+def span_cost_us(trace_dir: str, calls: int = 10000) -> dict:
+    """Median microseconds of an empty `PROFILER.span`: off, on, and on
+    inside a profiler trace written under `trace_dir`."""
+    import time
+
+    import jax
+
+    from sml_tpu.conf import GLOBAL_CONF
+    from sml_tpu.utils.profiler import PROFILER, now
+
+    def median_us(fn):
+        walls = []
+        for _ in range(calls):
+            t = now()
+            fn()
+            walls.append(now() - t)
+        return statistics.median(walls) * 1e6
+
+    def span(name):
+        def enter_and_leave():
+            with PROFILER.span(name):
+                pass
+        return enter_and_leave
+
+    def both(state):
+        return {state + ".plain": median_us(span("fit.dispatch")),
+                state + ".cpu": median_us(span("fit.featurize"))}
+
+    out = {"process_time": median_us(time.process_time)}
+    for on in (False, True):
+        GLOBAL_CONF.set("sml.obs.enabled", on)
+        out.update(both("on" if on else "off"))
+    options = jax.profiler.ProfileOptions()    # as harness/runner.py's
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        out.update(both("traced"))
+    finally:
+        jax.profiler.stop_trace()
+    return out
 
 
 def main() -> int:
@@ -76,7 +125,7 @@ def main() -> int:
         after = program.counters()
         GLOBAL_CONF.set("sml.obs.enabled", True)
         for name, total in after.items():
-            if on and name.startswith("span_s.fit"):
+            if on and name.startswith(("span_s.fit", "span_s.stage.")):
                 phases.setdefault(name[7:], []).append(
                     total - before.get(name, 0.0))
         ctx.log(f"fit {i} recorder {'on' if on else 'off'}: "
@@ -91,6 +140,7 @@ def main() -> int:
         "on_over_off": mean_on / mean_off - 1.0,
         "phases_s_a_fit_recorder_on_untraced": {
             name: statistics.mean(v) for name, v in sorted(phases.items())},
+        "span_us": span_cost_us(os.path.join(ctx.workdir, "span_trace")),
     }
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, args.workload + ".json"), "w") as f:

@@ -10,6 +10,29 @@ MLOps glue (tracking/registry/feature store/AutoML) — single-process Python
 driver, no JVM, native C++ for host-side hot ops.
 """
 
+import os as _os
+import time as _time
+
+
+def _process_age_s():
+    """Seconds since the process started (`/proc/self/stat`'s start time
+    against the clock it is kept on, CLOCK_BOOTTIME); None where there is
+    no `/proc`."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return _time.clock_gettime(_time.CLOCK_BOOTTIME) \
+            - ticks / _os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+
+
+# two facts of the process that only the package can know, published once
+# the import is done (the recorder's gauges `process.age_at_import_s` and
+# `process.import_s`): a set-up's seconds before the program's first span
+_age_at_import_s = _process_age_s()
+_import_t0 = _time.monotonic()
+
 
 def _require_pandas_cow() -> None:
     """The frame layer's shallow-copy memoization (`toPandas` caching,
@@ -38,6 +61,17 @@ _ensure_compile_cache()
 from .conf import GLOBAL_CONF
 from .frame import DataFrame, Row, TpuSession, functions, get_session
 from .version import __version__
+
+
+def _note_process() -> None:
+    from .obs._recorder import RECORDER
+    facts = {"import_s": _time.monotonic() - _import_t0}
+    if _age_at_import_s is not None:
+        facts["age_at_import_s"] = _age_at_import_s
+    RECORDER.note_process(**facts)
+
+
+_note_process()
 
 
 def install_shims() -> None:

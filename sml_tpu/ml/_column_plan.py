@@ -39,7 +39,9 @@ float, object strings in one piece) is gathered to the ONE pandas column
 the concat would hold, and the job runs on that as on a table of one piece.
 
 Jobs open no spans and bump no counters: the caller's thread does both,
-so `span_s.*` stay wall seconds of one thread.
+so `span_s.*` stay wall seconds of one thread. What a job took is two
+clock reads around it, and the slowest job's seconds (`Plan.longest_s`)
+ride the caller's span `fit.featurize.plan.jobs`.
 """
 
 from __future__ import annotations
@@ -53,7 +55,7 @@ import numpy as np
 import pandas as pd
 
 from ..parallel.pipeline import mark_host_worker, on_host_worker
-from ..utils.profiler import PROFILER
+from ..utils.profiler import PROFILER, now
 from .feature import imputer_surrogate, indexer_labels, order_labels
 from .featurizer import _IndexSource, _numeric
 
@@ -382,7 +384,9 @@ class Plan:
     """The jobs of one fit, run over the table's pieces (each keeps its
     `result`), the scratch they wrote (row i is the assembler's input i,
     its rows in the pieces' order), `block()` to interleave it and
-    `compact()` to hand it over as it is."""
+    `compact()` to hand it over as it is. `longest_s` is the wall seconds
+    of the slowest job (two clock reads a job, on the thread that runs
+    it): the jobs' step cannot end before it."""
 
     def __init__(self, src: Pieces, jobs: list):
         self.rows = src.rows
@@ -392,12 +396,16 @@ class Plan:
         assembled = sum(j.row is not None for j in jobs)
         self.scratch = scratch = np.empty(
             (assembled, self.rows), dtype=np.float32)
-        results = run_tasks(
-            [lambda j=j: j.run(src, None if j.row is None
-                               else scratch[j.row]) for j in jobs],
-            self.inline)
-        for j, r in zip(jobs, results):
+
+        def timed(j):
+            t0 = now()
+            r = j.run(src, None if j.row is None else scratch[j.row])
+            return r, now() - t0
+        results = run_tasks([lambda j=j: timed(j) for j in jobs],
+                            self.inline)
+        for j, (r, _) in zip(jobs, results):
             j.result = r
+        self.longest_s = max((wall for _, wall in results), default=0.0)
 
     def _dropped(self) -> Optional[np.ndarray]:
         masks = [j.result.invalid for j in self.jobs

@@ -127,6 +127,57 @@ def _normalize(a) -> np.ndarray:
     return np.ascontiguousarray(np.asarray(a))
 
 
+#: an array of at least this many bytes has its staging steps recorded as
+#: the spans `stage.key` / `stage.pad` / `stage.put` (`staging_step`). Under
+#: it the three are microseconds and stay in the caller's own time: the
+#: serving path pays the comparison and a note into `_NO_SPAN`'s dict
+_SPAN_BYTES = 1 << 20
+_NO_SPAN = contextlib.nullcontext({})   # its notes are read by nobody
+
+
+def staging_step(step: str, nbytes: int):
+    """The span `stage.<step>` (key / pad / put) noting `bytes`, for a
+    step over an array of at least `_SPAN_BYTES`; under it `_NO_SPAN`.
+    The functions here are shared by fits, scoring and serving, so the
+    spans are named for the function: during a fit they lie inside
+    `fit.stage`."""
+    if nbytes < _SPAN_BYTES:
+        return _NO_SPAN
+    from ..utils.profiler import PROFILER
+    return PROFILER.span(f"stage.{step}", bytes=int(nbytes))
+
+
+def _keyed(a, tag: tuple, probe: Callable = _stage_cache.get):
+    """The `stage.key` step: (the normalized array, its cache key: the
+    content key and then `tag`, what `probe` finds cached under that key
+    or None). Notes `copied` where the caller's array was not the
+    C-contiguous ndarray the staging boundary takes as it is, and `hit`."""
+    with staging_step("key", getattr(a, "nbytes", 0)) as note:
+        given, a = a, _normalize(a)
+        if a is not given:
+            note["copied"] = True
+        key = (_memo_key(a),) + tag
+        hit = probe(key)
+        note["hit"] = hit is not None
+    return a, key, hit
+
+
+def _padded_rows(a: np.ndarray, rows: int, axis: int = 0) -> np.ndarray:
+    """The `stage.pad` step: `a` with zero rows appended along `axis` up
+    to `rows`, the bucketed count of its own (`a` itself, and no span,
+    where it has them already or has no row at all, as `mesh.pad_rows`
+    leaves such an array). Notes the `bytes` of the padded copy."""
+    pad = (-a.shape[axis]) % rows
+    if not pad:
+        return a
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, pad)
+    with staging_step("pad", a.nbytes) as note:
+        padded = np.pad(a, widths)
+        note["bytes"] = int(padded.nbytes)
+    return padded
+
+
 _CKSUM_CHUNK = 1 << 20  # words per block (8MB) — bounds the arange temp
 
 
@@ -239,9 +290,12 @@ def _bin_cache_budget() -> int:
     return GLOBAL_CONF.getInt("sml.tree.binCacheBytes")
 
 
+def _bin_tag(mesh) -> tuple:
+    return (id(mesh), "bins", meshlib.data_width(mesh))
+
+
 def _bin_cache_key(a: np.ndarray, mesh) -> tuple:
-    return (_memo_key(a), id(mesh), "bins",
-            meshlib.data_width(mesh))
+    return (_memo_key(a),) + _bin_tag(mesh)
 
 
 def _bin_cache_touch(key):
@@ -292,15 +346,14 @@ def stage_bins_cached(binned: np.ndarray) -> jax.Array:
     from ..utils.profiler import PROFILER
     mesh = meshlib.get_mesh()
     n_dev = meshlib.data_width(mesh)
-    a = _normalize(binned)
-    key = _bin_cache_key(a, mesh)
-    hit = _bin_cache_touch(key)
+    a, key, hit = _keyed(binned, _bin_tag(mesh), _bin_cache_touch)
     if hit is not None:
         PROFILER.count("staging.bin_cache_hit")
         PROFILER.count("staging.h2d_bytes_saved", a.nbytes)
         return hit
-    padded = meshlib.pad_rows(a, meshlib.bucket_rows(a.shape[0], n_dev))[0]
-    hit = jax.device_put(padded, meshlib.data_sharding(mesh, padded.ndim))
+    padded = _padded_rows(a, meshlib.bucket_rows(a.shape[0], n_dev))
+    with staging_step("put", padded.nbytes):
+        hit = jax.device_put(padded, meshlib.data_sharding(mesh, padded.ndim))
     _bin_cache_store(key, hit)
     PROFILER.count("staging.bin_cache_miss")
     PROFILER.count("staging.h2d_bytes", padded.nbytes)
@@ -408,23 +461,20 @@ def stage_rows_cached(a, pad_to_multiple: bool = True) -> jax.Array:
     mesh = meshlib.get_mesh()
     n_dev = meshlib.data_width(mesh)
     a, rows_last = _rows_axis(a)
-    a = _normalize(a)
-    key = (_memo_key(a), id(mesh), "arrT" if rows_last else "arr", n_dev)
-    hit = _stage_cache.get(key)
+    a, key, hit = _keyed(
+        a, (id(mesh), "arrT" if rows_last else "arr", n_dev))
     if hit is None:
         padded = a
-        if pad_to_multiple and rows_last:
-            pad = meshlib.bucket_rows(a.shape[-1], n_dev) - a.shape[-1]
-            if pad:
-                padded = np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-        elif pad_to_multiple:
-            padded = meshlib.pad_rows(
-                a, meshlib.bucket_rows(a.shape[0], n_dev))[0]
+        if pad_to_multiple:
+            axis = -1 if rows_last else 0
+            padded = _padded_rows(
+                a, meshlib.bucket_rows(a.shape[axis], n_dev), axis)
         sharding = meshlib.data_sharding(mesh, padded.ndim)
         if rows_last:
             sharding = NamedSharding(mesh, P(
                 *([None] * (padded.ndim - 1)), meshlib.row_spec_entry(mesh)))
-        hit = jax.device_put(padded, sharding)
+        with staging_step("put", padded.nbytes):
+            hit = jax.device_put(padded, sharding)
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", padded.nbytes)
@@ -442,14 +492,13 @@ def stage_stacked_cached(a: np.ndarray) -> jax.Array:
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = meshlib.get_mesh()
     n_dev = meshlib.data_width(mesh)
-    a = _normalize(a)
-    key = (_memo_key(a), id(mesh), "stack", n_dev)
-    hit = _stage_cache.get(key)
+    a, key, hit = _keyed(a, (id(mesh), "stack", n_dev))
     from ..utils.profiler import PROFILER
     if hit is None:
         spec = P(None, meshlib.row_spec_entry(mesh),
                  *([None] * (a.ndim - 2)))
-        hit = jax.device_put(a, NamedSharding(mesh, spec))
+        with staging_step("put", a.nbytes):
+            hit = jax.device_put(a, NamedSharding(mesh, spec))
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", a.nbytes)
@@ -467,15 +516,15 @@ def stage_trial_stacked_cached(a: np.ndarray, mesh) -> jax.Array:
     a multiple of the trial dim and axis 1 to a multiple of the FULL
     device count (so any data-axis width divides it)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    a = _normalize(a)
-    key = (_memo_key(a), id(mesh), "tstack",
-           mesh.shape[meshlib.TRIAL_AXIS], mesh.shape[meshlib.DATA_AXIS])
-    hit = _stage_cache.get(key)
+    a, key, hit = _keyed(
+        a, (id(mesh), "tstack", mesh.shape[meshlib.TRIAL_AXIS],
+            mesh.shape[meshlib.DATA_AXIS]))
     from ..utils.profiler import PROFILER
     if hit is None:
         spec = P(meshlib.TRIAL_AXIS, meshlib.DATA_AXIS,
                  *([None] * (a.ndim - 2)))
-        hit = jax.device_put(a, NamedSharding(mesh, spec))
+        with staging_step("put", a.nbytes):
+            hit = jax.device_put(a, NamedSharding(mesh, spec))
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", a.nbytes)
@@ -491,8 +540,11 @@ def stage_mask_cached(n_padded: int, n_true: int) -> jax.Array:
             meshlib.data_width(mesh))
     hit = _stage_cache.get(mkey)
     if hit is None:
-        hit = meshlib.row_mask(n_padded, n_true)
-        hit = jax.device_put(hit, meshlib.data_sharding(mesh, 1))
+        # the mask is made here, not handed in: its fill is its pad step
+        with staging_step("pad", 4 * n_padded):
+            mask = meshlib.row_mask(n_padded, n_true)
+        with staging_step("put", mask.nbytes):
+            hit = jax.device_put(mask, meshlib.data_sharding(mesh, 1))
         _cache_put(mkey, hit)
     return hit
 

@@ -264,7 +264,8 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
     else:
         if missing is not None and not np.isnan(missing):
             with PROFILER.span("fit.featurize", rows=int(X.shape[0]),
-                               bytes=int(X.nbytes)):
+                               bytes=int(X.nbytes)), \
+                    PROFILER.span("fit.featurize.missing"):
                 X = X.copy()
                 X[X == missing] = np.nan
         F = X.shape[1]
@@ -729,7 +730,8 @@ class _TreeEstimatorBase(Estimator, _TreeParams):
     _loss = "squared"
 
     def _extract(self, df):
-        with PROFILER.span("fit.featurize") as note:
+        with PROFILER.span("fit.featurize") as note, \
+                PROFILER.span("fit.featurize.extract"):
             X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
                                  self.getOrDefault("labelCol"))
             ok = np.isfinite(y)

@@ -762,7 +762,11 @@ def _try_fast_fit(stages, raw, make_frame):
 
     X = keep = parts = None
     with PROFILER.span("fit.featurize", rows=n, columns=len(jobs)) as note:
-        plan = cp.Plan(raw, jobs)
+        # the plan's two steps as children: the column jobs, submit to
+        # last result, and the scratch made the estimator's block
+        with PROFILER.span("fit.featurize.plan.jobs") as step:
+            plan = cp.Plan(raw, jobs)
+            step["longest_s"] = plan.longest_s
         note["workers"] = plan.workers
         # an encoder's width follows its indexer's labels
         onehot = [None if drop_last is None
@@ -771,11 +775,13 @@ def _try_fast_fit(stages, raw, make_frame):
         los = list(itertools.accumulate(
             (1 if w is None else w for w in onehot), initial=0))
         width = los[-1]
-        if compact_bytes is not None and n * width * 4 >= compact_bytes:
-            parts = plan.compact(onehot, invalid)
-        if parts is None:   # also: a NaN the expanded block would carry
-            X, keep = plan.block(onehot, invalid)
-            note["bytes"] = int(X.nbytes)
+        with PROFILER.span("fit.featurize.plan.block") as step:
+            if compact_bytes is not None and n * width * 4 >= compact_bytes:
+                parts = plan.compact(onehot, invalid)
+            if parts is None:   # also: a NaN the expanded block would carry
+                X, keep = plan.block(onehot, invalid)
+                note["bytes"] = int(X.nbytes)
+            step["compact"] = parts is not None
 
     with PROFILER.span("fit.prep", stages=len(plans)):
         fitted = []

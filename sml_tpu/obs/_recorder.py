@@ -77,6 +77,7 @@ class Recorder:
         self._ring: deque = deque(
             maxlen=max(int(GLOBAL_CONF.getInt("sml.obs.ringEvents")), 16))
         self._totals: Dict[str, float] = {}
+        self._process: Dict[str, float] = {}   # see note_process
         self._tids: Dict[int, int] = {}
         self._free_tids: List[int] = []
         self._next_tid = 0
@@ -110,6 +111,18 @@ class Recorder:
                 int(GLOBAL_CONF.getInt("sml.obs.sinkMaxBytes")), 0)
         self.enabled = GLOBAL_CONF.getBool("sml.obs.enabled")
 
+    def note_process(self, **facts: float) -> None:
+        """Facts of the PROCESS, not of a recorder epoch, as gauges
+        `process.<key>`: `sml_tpu/__init__.py` notes them once (the
+        process's age when the package started to import, and the
+        import's own wall seconds). Kept beside the totals, so no
+        `reset()` drops them and they are no event of a timeline: an
+        enabled recorder's `counters()` always carry them."""
+        with self._lock:
+            self._process.update(
+                ("process." + key, float(value))
+                for key, value in facts.items())
+
     # --------------------------------------------------------------- emit
     def emit(self, kind: str, name: str, dur: Optional[float] = None,
              ts: Optional[float] = None,
@@ -119,8 +132,11 @@ class Recorder:
         call to the running totals `span_s.<name>` / `span_n.<name>`
         (under the one lock taken here, no extra ring event): busy
         seconds and work done by span name, read through `counters()`
-        like every counter, whatever the ring still holds. Cheap no-op
-        when disabled."""
+        like every counter, whatever the ring still holds. A span that
+        read the process's CPU seconds between its two ends (`cpu_s`
+        among its args: `Profiler.span`, for `taxonomy.CPU_SPANS`) adds
+        them to `span_cpu_s.<name>` the same way. Cheap no-op when
+        disabled."""
         if not self.enabled:
             return
         at = (ts if ts is not None else time.perf_counter()) - self._epoch
@@ -141,6 +157,9 @@ class Recorder:
                     "span_n." + name
                 totals[busy] = totals.get(busy, 0.0) + (dur or 0.0)
                 totals[calls] = totals.get(calls, 0.0) + 1.0
+                if args and "cpu_s" in args:
+                    cpu = "span_cpu_s." + name
+                    totals[cpu] = totals.get(cpu, 0.0) + args["cpu_s"]
             sink = self._ensure_sink()
             if sink is not None:  # under the lock: lines must not interleave
                 self._write_sink(ev, sink)
@@ -255,14 +274,19 @@ class Recorder:
             return list(self._ring)
 
     def counters(self) -> Dict[str, float]:
+        """The running totals and the `process.*` gauges (`note_process`).
+        Off, the recorder answers with nothing at all, as its contract
+        has been (`tests/test_obs.py`): the gauges too."""
         with self._lock:
-            return dict(self._totals)
+            return dict(self._totals, **self._process) if self.enabled \
+                else dict(self._totals)
 
     def reset(self) -> None:
-        """Drop all events/totals and re-zero the epoch (enabled state and
-        sink configuration survive). An OPEN sink gets a fresh header
-        line: its previous epoch_unix anchor no longer describes the
-        re-zeroed timeline, and a postmortem reader re-anchors at the
+        """Drop all events/totals and re-zero the epoch (enabled state,
+        sink configuration and the `process.*` gauges survive: those are
+        facts of the process, not of an epoch). An OPEN sink gets a fresh
+        header line: its previous epoch_unix anchor no longer describes
+        the re-zeroed timeline, and a postmortem reader re-anchors at the
         newest header above each line."""
         with self._lock:
             self._ring.clear()

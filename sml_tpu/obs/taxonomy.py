@@ -35,8 +35,17 @@ SPANS = {
     # fit.dispatch / fit.device_wait / fit.readback / fit.unpack inside
     # the tree programs' spans and, but for fit.unpack, inside every
     # `_staging.run_data_parallel` program (the linear family's), and
-    # fit.summary (the logistic training summary's host pass)
+    # fit.summary (the logistic training summary's host pass); inside
+    # the `fit.featurize` spans their children fit.featurize.plan.jobs /
+    # .plan.block (the column plan), .extract and .missing (the two block
+    # copies of a tree fit)
     "fit", "fit.*",
+    # the staging functions' steps for an array of at least 1 MiB
+    # (ml/_staging.py `_SPAN_BYTES`; shared with scoring and serving, so
+    # named for the function): stage.key (normalize + content key + cache
+    # look-up) / stage.pad (the padded host copy) / stage.put (device_put
+    # until it returns); inside `fit.stage` during a fit
+    "stage.*",
     # serving layer: one coalesced device dispatch of the micro-batcher
     "serve.batch",
     # per-device straggler attribution (obs/_skew.py): skew.compute /
@@ -47,12 +56,24 @@ SPANS = {
     "ingest.*",
 }
 
+#: spans that read the PROCESS's CPU seconds at their two ends while the
+#: recorder is on (`Profiler.span`): the one host phase whose total a
+#: reader takes. Process-wide on purpose: the phase's work is on the column
+#: plan's pool, so `span_cpu_s.<name>` over `span_s.<name>` is the cores it
+#: kept busy. A name is added with the metric that reads it (a read is a
+#: system call, and the clock ticks in 10 ms steps on the chip's host:
+#: nothing for a span of a few milliseconds)
+CPU_SPANS = frozenset({"fit.featurize"})
+
 COUNTERS = {
     # running totals the recorder keeps for EVERY span name (no call
     # site): span_s.<name> seconds inside spans of that name,
     # span_n.<name> how many ended — read as deltas between two
     # `RECORDER.counters()` snapshots
     "span_s.*", "span_n.*",
+    # and for the spans of `CPU_SPANS` alone: CPU seconds (user + system,
+    # every thread) of the process between the span's two ends
+    "span_cpu_s.*",
     # stall watchdog (obs/_watchdog.py): flagged in-flight tickets
     "stall.*",
     # black-box postmortem (obs/blackbox.py): bundles written
@@ -209,6 +230,13 @@ COUNTERS = {
 
 GAUGES = {
     "hbm.*",              # hbm.<pool>_bytes / hbm.total_bytes
+    "process.*",          # facts of the process, which no reset()
+                          # drops: process.age_at_import_s (the
+                          # process's age when `sml_tpu` started to
+                          # import: the interpreter, the caller's own
+                          # imports, jax and the runtime's start where
+                          # they came first) / process.import_s (the
+                          # package's import, wall seconds)
     "serve.queue_rows",   # rows admitted but not yet dispatched
     "serve.flush_micros",  # the micro-batcher's LIVE flush deadline —
                           # conf-static unless sml.serve.flushAutoTune

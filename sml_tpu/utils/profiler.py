@@ -22,6 +22,7 @@ from ..conf import GLOBAL_CONF
 from ..obs import _audit as _obs_audit
 from ..obs import _context as _obs_ctx
 from ..obs._recorder import RECORDER as _OBS
+from ..obs.taxonomy import CPU_SPANS as _CPU_SPANS
 from ..obs._watchdog import WATCHDOG as _OBS_WATCHDOG
 
 
@@ -105,6 +106,12 @@ class Profiler:
         the active context inside the block, so spans nest as they ran.
         The span is also a `jax.profiler.TraceAnnotation`, so a profiler
         trace shows it on the host plane above the device ops it caused.
+        A span the taxonomy lists under `CPU_SPANS` (`fit.featurize`)
+        also reads the PROCESS's CPU seconds at its two ends while the
+        recorder is on (`time.process_time`: a system call, 6 us on the
+        chip's host): the delta rides its event as `cpu_s` and the
+        recorder's running total `span_cpu_s.<name>`. Process-wide on
+        purpose: a pooled phase's work is on the pool's threads.
         For spans carrying a dispatch `route`, it registers a
         stall-watchdog ticket (expected wall = the audit's prediction for
         this thread's pending decision) and feeds the measured wall time
@@ -143,11 +150,15 @@ class Profiler:
                 stack = tls.stack
                 child_acc = [0.0]
                 stack.append(child_acc)
+            cpu0 = time.process_time() \
+                if obs_on and name in _CPU_SPANS else None
             t0 = time.perf_counter()
             try:
                 yield meta
             finally:
                 dt = time.perf_counter() - t0
+                if cpu0 is not None:
+                    meta["cpu_s"] = time.process_time() - cpu0
                 if "rows" in meta:   # counted inside the block
                     rows = meta.pop("rows")
                 _OBS_WATCHDOG.close(ticket)

@@ -4,6 +4,7 @@ id, the running totals a span name, and the `tree.*` scopes inside the
 compiled tree program."""
 
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from sml_tpu.ml import tree_impl
 from sml_tpu.ml.feature import (Imputer, StandardScaler, StringIndexer,
                                 VectorAssembler)
 from sml_tpu.ml.regression import RandomForestRegressor
+from sml_tpu.parallel import collectives as coll
 from sml_tpu.xgboost import XgboostRegressor
 
 #: direct children of the root, and of the spans that have children
@@ -213,6 +215,222 @@ def test_recorder_off_no_event_and_no_total(spark):
     _pipeline("bagged").fit(_frame(spark, seed=7))
     assert obs.RECORDER.events() == []
     assert obs.RECORDER.counters() == {}
+
+
+# ------------------------------------------- the host path seen from inside
+STEPS = ["stage.key", "stage.pad", "stage.put"]
+
+
+def _column_sums(xb, mask):
+    return coll.psum(jnp.sum(xb * mask[:, None], axis=0))
+
+
+def _staged_once(recorder, rows, seed):
+    """One `run_data_parallel` over a float32 block of `rows` x 4 no test
+    has staged: its `fit.stage` span and the `stage.*` spans inside it."""
+    from sml_tpu.ml._staging import run_data_parallel
+    x = np.random.default_rng(np.random.SeedSequence([seed, 38])) \
+        .normal(size=(rows, 4)).astype(np.float32)
+    obs.reset()
+    got = run_data_parallel(_column_sums, x)
+    np.testing.assert_allclose(got, x.sum(axis=0, dtype=np.float64),
+                               rtol=1e-3, atol=0.5)
+    spans = _spans(recorder)
+    stage, = [e for e in spans if e.name == "fit.stage"]
+    steps = sorted((e for e in spans if e.name in STEPS), key=lambda e: e.ts)
+    return stage, steps
+
+
+def test_the_steps_of_a_large_staging_sum_to_fit_stage(recorder):
+    """An array over `_SPAN_BYTES` (64 MB, and its 16 MB mask): a key, a
+    pad and a put each, disjoint inside `fit.stage` on its thread, and
+    together all of it but the calls between them (0.1 ms a span once 64 MB
+    have gone through the caches). The best of three tables: a worker of a
+    loaded test host can lose its core between two spans."""
+    short = []
+    for seed in (1, 2, 3):
+        rows = 4_000_000 + seed         # a mask of its own each time
+        stage, steps = _staged_once(recorder, rows, seed)
+        assert [e.name for e in steps] == STEPS + ["stage.pad", "stage.put"]
+        assert {e.tid for e in steps} == {stage.tid}
+        _assert_disjoint_inside(steps, stage)
+        block = steps[:3]
+        assert block[0].args["bytes"] == rows * 16
+        assert block[0].args["hit"] is False and "copied" not in block[0].args
+        # padded to the bucketed row count, and that is what is put
+        assert block[1].args["bytes"] == block[2].args["bytes"] > rows * 16
+        assert block[2].args["bytes"] % (4 * 4 * 8) == 0
+        assert steps[3].args["bytes"] == block[2].args["bytes"] // 4
+        short.append(1.0 - sum(e.dur for e in steps) / stage.dur)
+        if short[-1] <= 0.02:
+            break
+    assert min(short) <= 0.02, short
+    totals = recorder.counters()
+    assert sum(totals["span_s." + n] for n in STEPS) == \
+        pytest.approx(sum(e.dur for e in steps), abs=1e-9)
+
+
+def test_a_small_staging_opens_no_step(recorder):
+    stage, steps = _staged_once(recorder, 20_000, seed=4)   # 320 KB
+    assert steps == [] and stage.dur > 0
+    assert not [k for k in recorder.counters() if ".stage." in k
+                and not k.endswith(".fit.stage")]
+
+
+def test_a_staging_cache_hit_opens_the_key_alone(recorder):
+    from sml_tpu.ml import _staging
+    x = np.random.default_rng(np.random.SeedSequence([5, 38])) \
+        .normal(size=(300_001, 2)).astype(np.float32)      # 2.4 MB
+    obs.reset()
+    first = _staging.stage_rows_cached(x)
+    assert [e.name for e in _spans(recorder)] == STEPS
+    obs.reset()
+    assert _staging.stage_rows_cached(x) is first
+    key, = _spans(recorder)
+    assert key.name == "stage.key" and key.args["hit"] is True
+    assert key.args["bytes"] == x.nbytes and "copied" not in key.args
+    # an array the staging boundary has to copy says so
+    obs.reset()
+    _staging.stage_rows_cached(x[::2])
+    assert _spans(recorder)[0].args["copied"] is True
+    # a bin matrix takes the bin cache through the same three steps
+    bins = np.random.default_rng(6).integers(
+        0, 200, size=(300_001, 5), dtype=np.uint8)
+    obs.reset()
+    _staging.stage_bins_cached(bins)
+    _staging.stage_bins_cached(bins)
+    assert [e.name for e in _spans(recorder)] == STEPS + ["stage.key"]
+
+
+def test_the_featurize_children_lie_inside_a_featurize_span(spark, recorder):
+    """`fit.featurize` keeps its name, its notes and its extent at every
+    site; what each site does is a child of it on the same thread, and the
+    root's children are what they were (`PHASES`)."""
+    df = _frame(spark, seed=8)
+    obs.reset()
+    _pipeline("boosted").fit(df)
+    spans = _spans(recorder)
+    by_id = {e.args["span"]: e for e in spans}
+    inside = [e for e in spans if e.name.startswith("fit.featurize.")]
+    assert sorted(e.name for e in inside) == [
+        "fit.featurize.extract", "fit.featurize.missing",
+        "fit.featurize.plan.block", "fit.featurize.plan.jobs"]
+    for e in inside:
+        parent = by_id[e.args["parent"]]
+        assert parent.name == "fit.featurize" and parent.tid == e.tid
+        _assert_disjoint_inside([e], parent)
+    jobs, = [e for e in inside if e.name.endswith("plan.jobs")]
+    assert 0.0 < jobs.args["longest_s"] <= jobs.dur
+    block, = [e for e in inside if e.name.endswith("plan.block")]
+    assert block.args["compact"] is False
+    plan = by_id[jobs.args["parent"]]
+    assert plan is by_id[block.args["parent"]]
+    assert plan.args["columns"] == 3 and plan.args["rows"] == 3000
+    _assert_disjoint_inside([jobs, block], plan)
+    root, = [e for e in spans if e.name == "fit"]
+    assert {e.name for e in _children(spans, root)} == PHASES
+    # the phases the eight `fit.host.*` read still fit in the root
+    totals = recorder.counters()
+    phases = ("fit.collect", "fit.prep", "fit.featurize", "fit.quantize",
+              "fit.stage", "fit.dispatch", "fit.device_wait", "fit.readback",
+              "fit.unpack", "fit.baseline")
+    assert sum(totals["span_s." + n] for n in phases) <= totals["span_s.fit"]
+    assert sum(totals["span_s." + e.name] for e in inside) <= \
+        totals["span_s.fit.featurize"]
+
+
+def test_cpu_seconds_grow_only_for_the_spans_that_ask(spark, recorder):
+    from sml_tpu.obs.taxonomy import CPU_SPANS
+    assert CPU_SPANS == {"fit.featurize"}
+    df = _frame(spark, seed=9)
+    obs.reset()
+    _pipeline("bagged").fit(df)
+    spans = _spans(recorder)
+    totals = recorder.counters()
+    assert [k for k in totals if k.startswith("span_cpu_s.")] == \
+        ["span_cpu_s.fit.featurize"]
+    for e in spans:
+        assert ("cpu_s" in e.args) == (e.name in CPU_SPANS), e.name
+    mine = [e.args["cpu_s"] for e in spans if e.name == "fit.featurize"]
+    assert len(mine) == 2 and min(mine) >= 0.0     # the plan, `_extract`
+    assert totals["span_cpu_s.fit.featurize"] == pytest.approx(
+        sum(mine), abs=1e-9)
+
+
+def test_cpu_seconds_are_the_process_s_every_thread(recorder):
+    """Process-wide on purpose: what a pooled phase's workers burn while
+    the span is open is in its `cpu_s`, more than the span's own wall."""
+    import threading
+    from sml_tpu.utils.profiler import PROFILER
+
+    def spin(seconds=0.2):
+        end = time.thread_time() + seconds
+        while time.thread_time() < end:
+            pass
+    workers = [threading.Thread(target=spin) for _ in range(3)]
+    with PROFILER.span("fit.featurize"):
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join()
+    with PROFILER.span("fit.stage"):
+        spin(0.01)
+    totals = recorder.counters()
+    assert totals["span_cpu_s.fit.featurize"] >= 0.55    # 3 x 0.2 s
+    assert "span_cpu_s.fit.stage" not in totals
+
+
+def test_recorder_off_reads_no_cpu_seconds(spark, monkeypatch):
+    """Off, a span that asks still early-outs: no event, no total, and not
+    one `process_time` call, the profiler on or not."""
+    from sml_tpu.utils import profiler
+    calls = []
+    real = time.process_time
+    monkeypatch.setattr(profiler.time, "process_time",
+                        lambda: calls.append(1) or real())
+    assert not obs.RECORDER.enabled
+    obs.reset()
+    GLOBAL_CONF.set("sml.profiler.enabled", True)
+    try:
+        with profiler.PROFILER.span("fit.featurize"):
+            pass
+        _pipeline("bagged").fit(_frame(spark, seed=10))
+    finally:
+        GLOBAL_CONF.set("sml.profiler.enabled", False)
+        profiler.PROFILER.reset()
+    assert calls == []
+    assert obs.RECORDER.events() == [] and obs.RECORDER.counters() == {}
+    GLOBAL_CONF.set("sml.obs.enabled", True)
+    try:
+        with profiler.PROFILER.span("fit.featurize"):
+            pass
+        with profiler.PROFILER.span("fit.stage"):
+            pass
+        assert len(calls) == 2
+    finally:
+        GLOBAL_CONF.set("sml.obs.enabled", False)
+        obs.reset()
+
+
+def test_reset_keeps_the_two_facts_of_the_process(recorder):
+    """`process.*` are facts of the process, not of an epoch: an enabled
+    recorder's counters carry them from its first snapshot on, whatever was
+    reset; they are no event of the timeline."""
+    first = recorder.counters()
+    assert first["process.import_s"] > 0.0
+    assert first["process.age_at_import_s"] >= 0.0   # this host has /proc
+    recorder.counter("staging.cache_hit")
+    obs.reset()
+    assert recorder.counters() == {
+        "process.import_s": first["process.import_s"],
+        "process.age_at_import_s": first["process.age_at_import_s"]}
+    assert recorder.events() == []
+    GLOBAL_CONF.set("sml.obs.enabled", False)
+    obs.reset()
+    assert recorder.counters() == {}                 # off: no total at all
+    GLOBAL_CONF.set("sml.obs.enabled", True)         # on again: seeded
+    assert set(recorder.counters()) == {"process.import_s",
+                                        "process.age_at_import_s"}
 
 
 # ------------------------------------------------------------ named scopes
