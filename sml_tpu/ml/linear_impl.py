@@ -347,13 +347,17 @@ def _compact_irls_fn(layout, maxIter: int, tol: float):
 
     def irls_compact(num_t, codes_t, yb, mask):
         """WHOLE-FIT fused IRLS: the expanded block stays resident in HBM
-        and all maxIter Newton steps — grad/Hessian psum, (d+1)² solve,
-        damping, convergence freeze — run in ONE dispatch. The host loop
-        pays a dispatch round trip and a device→host read per iteration; at
-        course-scale d that fixed cost IS the fit time. Semantics mirror
-        fit_logistic's lam=0 loop: step = solve(H + 1e-8 I, g), damp to
-        the midpoint when the log-likelihood drops by >1e3, freeze after
-        max|Δw| < tol (executed iterations are reported). The block is
+        and the Newton steps to convergence, at most maxIter — grad/Hessian
+        psum, (d+1)² solve, damping, the convergence test — run in ONE
+        dispatch. The host loop pays a dispatch round trip and a
+        device→host read per iteration; at course-scale d that fixed cost
+        IS the fit time. Semantics mirror fit_logistic's lam=0 loop: step =
+        solve(H + 1e-8 I, g), damp to the midpoint when the log-likelihood
+        drops by >1e3, stop after the step whose max|Δw| < tol (a
+        `while_loop` on `done`: every step it runs moves `w`, so the count
+        it returns is the steps executed AND the iterations a fit reports;
+        `done` comes from psum'd quantities, so every shard leaves at the
+        same step). The block is
         [Z 1]^T (`_expand_masked`): a product over the table's rows
         contracts its last axis. `w` stays in the standardized space for
         all the steps and is returned there with the shift and the scale;
@@ -363,8 +367,8 @@ def _compact_irls_fn(layout, maxIter: int, tol: float):
             d1 = Xa.shape[0]
             eye = jnp.eye(d1, dtype=jnp.float32)
 
-            def body(carry, _):
-                w, prev_ll, done, iters = carry
+            def body(carry):
+                w, prev_ll, _, iters = carry
                 with jax.named_scope("linear.irls.margin"):
                     eta = w @ Xa
                     p = jax.nn.sigmoid(eta)
@@ -381,17 +385,17 @@ def _compact_irls_fn(layout, maxIter: int, tol: float):
                     w_new = w - step
                     conv = jnp.max(jnp.abs(w_new - w)) < tol
                     damp = ll < prev_ll - 1e3
-                    w_next = jnp.where(
-                        done, w, jnp.where(damp, (w + w_new) / 2, w_new))
-                    iters = iters + jnp.where(done, 0, 1)
-                return (w_next, jnp.where(done, prev_ll, ll),
-                        done | conv, iters), None
+                    w_next = jnp.where(damp, (w + w_new) / 2, w_new)
+                return w_next, ll, conv, iters + 1
+
+            def unfinished(carry):
+                _, _, done, iters = carry
+                return (iters < maxIter) & ~done
 
             init = (jnp.zeros((d1,), jnp.float32), jnp.float32(-jnp.inf),
                     jnp.bool_(False), jnp.int32(0))
             with jax.named_scope("linear.irls"):
-                (w, _, _, iters), _ = jax.lax.scan(body, init, None,
-                                                   length=maxIter)
+                w, _, _, iters = jax.lax.while_loop(unfinished, body, init)
         return w, shift, scale, iters
 
     irls_compact.__name__ = \
@@ -407,8 +411,9 @@ def fit_logistic_compact(parts, y: np.ndarray, *, maxIter: int = 100,
     need the materialized block (prox shrinkage on raw coefficients);
     callers route those through parts.expand_host() + fit_logistic.
     Counters: `linear.irls.fits`, `linear.irls.steps_run` (the steps the
-    device executed: the scan's length, whatever converged) and
-    `linear.irls.iterations` (the steps that moved `w`)."""
+    device executed: the loop's own count, read back with the fit) and
+    `linear.irls.iterations` (the steps that moved `w`: every step the
+    loop runs does, so the two grow together)."""
     from ..utils.profiler import PROFILER
     n_rows, d = parts.rows, parts.width
     z, shift, scale, iters = run_data_parallel(
@@ -417,11 +422,12 @@ def fit_logistic_compact(parts, y: np.ndarray, *, maxIter: int = 100,
         np.asarray(y, np.float32),
         work=WorkHint(flops=3.0 * maxIter * n_rows * (d + 1) ** 2,
                       kind="blas"))
+    steps = int(iters)
     PROFILER.count("linear.irls.fits")
-    PROFILER.count("linear.irls.steps_run", int(maxIter))
-    PROFILER.count("linear.irls.iterations", int(iters))
+    PROFILER.count("linear.irls.steps_run", steps)
+    PROFILER.count("linear.irls.iterations", steps)
     w = np.linalg.solve(_raw_map(shift, scale).T, np.asarray(z, np.float64))
-    return LinearFit(w[:d], float(w[d]), int(iters))
+    return LinearFit(w[:d], float(w[d]), steps)
 
 
 def _newton_pass(Xb, yb, mask, wb, shift, scale):

@@ -131,8 +131,9 @@ COUNTERS = {
     # (few rows, or the caller is itself a pool worker)
     "quantize.plan.fits", "quantize.plan.inline",
     # the fused logistic fit (linear_impl.fit_logistic_compact): programs
-    # run / Newton steps the device executed (the scan's length, whatever
-    # converged) / steps that moved the coefficients (what a fit reports)
+    # run / Newton steps the device executed (the loop's own count: it
+    # stops at convergence, at most maxIter) / steps that moved the
+    # coefficients (what a fit reports; every executed step does)
     "linear.irls.fits", "linear.irls.steps_run", "linear.irls.iterations",
     # the compact form's margin pass (featurizer.CompactParts.predict_affine:
     # a job a block of rows; the logistic summary inside `fit.summary`, a

@@ -295,6 +295,36 @@ def test_the_fused_logistic_fit_fits_a_v5e_at_the_cells_size(one_chip_mesh):
         assert scope in hlo, scope
 
 
+def test_the_fused_logistic_fit_leaves_its_loop_on_done(one_chip_mesh):
+    """The loop over Newton steps stops where the fit has converged
+    (ISSUE 39): compiled for the chip, the condition of the `linear.irls`
+    loop reads a `pred[]` of the carry (`done`) beside the step count. A
+    refactor back to a fixed length (a `scan`, whose condition is a bare
+    trip count) fails here, before a chip run reads `steps_per_fit` 100."""
+    from sml_tpu.ml import linear_impl
+    layout = (("oh", 0, 3), ("num", 0), ("num", 1))
+    fn = linear_impl._compact_irls_fn(layout, 100, 1e-6)
+    last, flat = P(None, D), P(D)
+    mapped = meshlib.shard_map_compat(
+        fn, mesh=one_chip_mesh, in_specs=(last, last, flat, flat),
+        out_specs=P())
+    shapes = [jax.ShapeDtypeStruct(shape, dtype,
+                                   sharding=NamedSharding(one_chip_mesh, s))
+              for shape, dtype, s in (((2, AOT_ROWS), jnp.float32, last),
+                                      ((1, AOT_ROWS), jnp.int32, last),
+                                      ((AOT_ROWS,), jnp.float32, flat),
+                                      ((AOT_ROWS,), jnp.float32, flat))]
+    hlo = jax.jit(mapped).lower(*shapes).compile().as_text()
+    loops = [ln for ln in hlo.splitlines()
+             if re.search(r"\swhile\(", ln) and "linear.irls/while" in ln]
+    assert len(loops) == 1, loops
+    name = re.search(r"condition=%?([\w.\-]+)", loops[0]).group(1)
+    condition = re.search(
+        rf"^%?{re.escape(name)} \(.*?^}}", hlo, re.M | re.S).group(0)
+    assert re.search(r"pred\[\]\S* get-tuple-element\(", condition), condition
+    assert "s32[]" in condition and "constant(100)" in condition, condition
+
+
 def test_ops_in_loop_bodies_follows_calls():
     hlo = """HloModule m
 %fused (p: s32[4]) -> s32[4] {
