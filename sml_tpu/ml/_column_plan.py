@@ -116,16 +116,30 @@ def runs_inline(rows: int) -> bool:
     return rows < _INLINE_ROWS or on_host_worker()
 
 
-def run_tasks(tasks: list, inline: bool) -> list:
-    """Every task's result, in the tasks' order. All of them end before
-    an error is raised, on the caller's thread: the first in the tasks'
-    order, as the sequential pass would have met it."""
+def start_tasks(tasks: list, inline: bool):
+    """The tasks started (on the pool; run here at once where `inline`),
+    and a call that gives every task's result, in the tasks' order. All
+    of them end before an error is raised, on the caller's thread: the
+    first in the tasks' order, as the sequential pass would have met it.
+    Between the start and the call the caller does what it likes (a
+    validator dispatches its next fold while the last one's margins are
+    ranked)."""
     if inline:
-        return [t() for t in tasks]
+        results = [t() for t in tasks]
+        return lambda: results
     pool = _executor()
     futures = [pool.submit(t) for t in tasks]
-    wait(futures)
-    return [f.result() for f in futures]
+
+    def results():
+        wait(futures)
+        return [f.result() for f in futures]
+    return results
+
+
+def run_tasks(tasks: list, inline: bool) -> list:
+    """Every task's result, in the tasks' order (`start_tasks`, waited
+    for)."""
+    return start_tasks(tasks, inline)()
 
 
 class Pieces:

@@ -633,22 +633,33 @@ def _is_bin_matrix(a: np.ndarray) -> bool:
 
 
 @contextlib.contextmanager
+def shared_keys():
+    """The per-thread key memo (`_memo_key`) for the length of the block:
+    every array this thread stages or probes inside it is hashed once.
+    `routed_for` opens one a program; a caller that dispatches several
+    programs over the same arrays (a validator's folds and its refit)
+    opens one round them all, and the inner ones then leave it be."""
+    had_memo = getattr(_tls_keys, "memo", None)
+    if had_memo is None:
+        _tls_keys.memo = {}
+    try:
+        yield
+    finally:
+        if had_memo is None:
+            _tls_keys.memo = None
+
+
+@contextlib.contextmanager
 def routed_for(hint, *arrays, stacked: bool = False):
     """Context manager binding the stage-aware dispatch decision as the
     thread's active mesh (see _route_mesh). Also installs the per-thread
     key memo so the probe's fingerprints are reused by the stage.
     `stacked=True` prices/promotes fold-stacked (folds, rows, ...) arrays
     in their axis-1-sharded layout."""
-    had_memo = getattr(_tls_keys, "memo", None)
-    if had_memo is None:
-        _tls_keys.memo = {}
-    try:
+    with shared_keys():
         mesh, _ = _route_mesh(hint, arrays, stacked=stacked)
         with meshlib.use_mesh_local(mesh):
             yield mesh
-    finally:
-        if had_memo is None:
-            _tls_keys.memo = None
 
 
 def route_for_arrays(hint, *arrays) -> Tuple[object, str]:

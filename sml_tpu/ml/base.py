@@ -144,6 +144,14 @@ class Estimator(Params, Saveable):
     def _fit(self, df):
         raise NotImplementedError
 
+    def _block_reader(self):
+        """(the estimator a `Pipeline.fit`'s column plan makes the feature
+        block for when this is the last stage, whether that block must be
+        the compact form): this estimator and no, for one that reads its
+        own block. A stage that fits ANOTHER estimator over the block (a
+        validator) names that one; None where it needs the generic path."""
+        return self, False
+
 
 class Model(Transformer):
     """A fitted Transformer (MLlib: Model[M] extends Transformer)."""
@@ -289,6 +297,9 @@ class Pipeline(Estimator):
                 fast = None
             if fast is not None:
                 fitted_prep, shim = fast
+                # the frame whose rows, partition by partition, are the
+                # block's: a validator's folds are `randomSplit`'s of it
+                shim._row_source = cur
                 return PipelineModel(fitted_prep + [stages[-1].fit(shim)])
             if len(raw.parts) > 1:
                 # the plan declined: the generic sequential fit reads the

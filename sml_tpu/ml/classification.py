@@ -1,20 +1,25 @@
 """Classification estimators.
 
 `LogisticRegression` (`SML/Solutions/ML Electives/MLE 03` answer path) fits
-by IRLS Newton steps whose X^T W X reduction is a mesh psum
-(`linear_impl.fit_logistic`); transform appends `rawPrediction`,
-`probability`, and `prediction` columns like MLlib. Tree classifiers ride
-`tree_impl`.
+by IRLS Newton steps whose X^T W X reduction is a mesh psum: over a compact
+block the fused program, one dispatch a fit, the elastic-net penalty in it
+(`linear_impl.fit_logistic_compact`), and `linear_impl.fit_logistic`'s loop
+where there is no such block; a `CrossValidator` over it reads its folds
+off the one staged block (`_fold_metrics`). Transform appends
+`rawPrediction`, `probability`, and `prediction` columns like MLlib. Tree
+classifiers ride `tree_impl`.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import numpy as np
 import pandas as pd
 
 from ..utils.profiler import PROFILER
+from . import _column_plan as cp
 from .base import Estimator, Model, load_arrays, save_arrays
 from .feature import _as_object_series
 from .linalg import DenseVector, vector_series
@@ -90,17 +95,20 @@ class LogisticRegression(Estimator):
 
     def _fit(self, df) -> "LogisticRegressionModel":
         lam = float(self.getOrDefault("regParam"))
+        alpha = float(self.getOrDefault("elasticNetParam"))
         maxIter = int(self.getOrDefault("maxIter"))
         tol = float(self.getOrDefault("tol"))
         fit_int = bool(self.getOrDefault("fitIntercept"))
         compact = extract_compact(df, self.getOrDefault("featuresCol"),
                                   self.getOrDefault("labelCol"))
-        if compact is not None and lam == 0.0 and fit_int:
+        if compact is not None and fit_int:
             # fused-IRLS device program: the whole Newton loop in one
-            # dispatch, one-hot slots expanded on-chip (linear_impl)
+            # dispatch, one-hot slots expanded on-chip, the elastic-net
+            # penalty in it where there is one (linear_impl)
             parts, y = compact
-            res = linear_impl.fit_logistic_compact(parts, y,
-                                                   maxIter=maxIter, tol=tol)
+            res = linear_impl.fit_logistic_compact(
+                parts, y, regParam=lam, elasticNetParam=alpha,
+                maxIter=maxIter, tol=tol)
             model = LogisticRegressionModel(coefficients=res.coefficients,
                                             intercept=res.intercept)
             model._inherit_params(self)
@@ -132,22 +140,19 @@ class LogisticRegression(Estimator):
             model._summary = BinaryLogisticRegressionSummary(
                 accuracy=acc, numInstances=len(y), lazy_fn=lazy_metrics)
             return model
+        if compact is not None:
+            # no intercept: the materialized block and the host loop
+            parts, y = compact
+            X = parts.expand_host()
         else:
-            if compact is not None:
-                # penalized config needs the materialized block (prox on
-                # raw coefficients); expand host-side and take the loop
-                parts, y = compact
-                X = parts.expand_host()
-            else:
-                X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
-                                     self.getOrDefault("labelCol"))
-                ok = np.isfinite(y)
-                X, y = X[ok], y[ok]
-            res = linear_impl.fit_logistic(
-                X, y, regParam=lam,
-                elasticNetParam=float(self.getOrDefault("elasticNetParam")),
-                fitIntercept=fit_int, maxIter=maxIter, tol=tol)
-            margin = X @ res.coefficients + res.intercept
+            X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
+                                 self.getOrDefault("labelCol"))
+            ok = np.isfinite(y)
+            X, y = X[ok], y[ok]
+        res = linear_impl.fit_logistic(
+            X, y, regParam=lam, elasticNetParam=alpha,
+            fitIntercept=fit_int, maxIter=maxIter, tol=tol)
+        margin = X @ res.coefficients + res.intercept
         model = LogisticRegressionModel(coefficients=res.coefficients,
                                         intercept=res.intercept)
         model._inherit_params(self)
@@ -156,6 +161,88 @@ class LogisticRegression(Estimator):
             accuracy=float(np.mean(pred == y)),
             areaUnderROC=_fast_auc(margin, y), numInstances=len(y))
         return model
+
+    #: the grid parameters `_fold_metrics` hands the fused program as
+    #: numbers: any other one changes the program or the rows it reads
+    _FOLD_GRID = frozenset({"regParam", "elasticNetParam"})
+
+    def _folds_on_block(self, grid, evaluator) -> bool:
+        """Whether a cross-validation of this estimator over `grid` under
+        `evaluator` can read its folds off the one staged block
+        (`_fold_metrics`): the grid moves the penalty alone, the fit has
+        its intercept, and the metric is the area under the ROC curve of
+        this estimator's own margin against its own label."""
+        from .evaluation import BinaryClassificationEvaluator
+        return (bool(self.getOrDefault("fitIntercept"))
+                and all(p.parent == self.uid and p.name in self._FOLD_GRID
+                        for pmap in grid for p in pmap)
+                and type(evaluator) is BinaryClassificationEvaluator
+                and evaluator.getOrDefault("metricName") == "areaUnderROC"
+                and evaluator.getOrDefault("labelCol")
+                == self.getOrDefault("labelCol")
+                and evaluator.getOrDefault("rawPredictionCol")
+                == self.getOrDefault("rawPredictionCol"))
+
+    def _fold_metrics(self, df, grid, k: int, fold_ids):
+        """The (grid point, fold) areas under the ROC curve of a k-fold
+        cross-validation whose grid and evaluator `_folds_on_block` has
+        accepted, read off the frame's compact block where it lies on the
+        chip: a dispatch a fold fits every grid point on the rows outside
+        the fold (the fused penalized program, the fold a mask) and makes
+        every row's margin there (`linear_impl._compact_enet_fn`); the
+        fold's own are ranked on the host pool (`_midrank_auc`, exact).
+        No fold frame, no transform and no second H2D of the features.
+        `fold_ids(keep)` gives a fold id a row the block keeps. None
+        where the frame carries no compact block: the validator then
+        makes its fold frames. Counters `cv.fits` and `cv.evals`; span
+        `fit.cv.eval` holds what of the rankings the chip's work did not
+        hide."""
+        compact = extract_compact(df, self.getOrDefault("featuresCol"),
+                                  self.getOrDefault("labelCol"))
+        if compact is None:
+            return None
+        parts, y = compact
+        fold = fold_ids(parts.keep)
+        points = []
+        for pmap in grid:
+            at = self.copy(pmap)
+            points.append((float(at.getOrDefault("regParam")),
+                           float(at.getOrDefault("elasticNetParam"))))
+        inline = cp.runs_inline(parts.rows)
+        ranked = []
+        for held in range(k):
+            fits = linear_impl.fit_logistic_folds(
+                parts, y, fold, held, points,
+                maxIter=int(self.getOrDefault("maxIter")),
+                tol=float(self.getOrDefault("tol")))
+            PROFILER.count("cv.fits", len(points))
+            # the fold's margins are ranked on the host pool while the
+            # next fold is on the chip
+            rows = np.flatnonzero(fold == held)
+            positive = y[rows] > 0.5
+            ranked.append(cp.start_tasks(
+                [partial(_midrank_auc, margin, rows, positive)
+                 for _, _, margin in fits], inline))
+        with PROFILER.span("fit.cv.eval", evaluations=k * len(points)):
+            metrics = np.array([r() for r in ranked], dtype=np.float64).T
+        PROFILER.count("cv.evals", k * len(points))
+        return metrics
+
+
+def _midrank_auc(score: np.ndarray, rows: np.ndarray,
+                 positive: np.ndarray) -> float:
+    """The exact area under the ROC curve of `score[rows]`, ties by
+    midrank: every positive row's count of negatives scored under it plus
+    half of those scored as it, in whole numbers, over positives x
+    negatives. Two sorts of a class each and two binary searches: no
+    argsort of the whole, and integers until the last division."""
+    s = score[rows]
+    pos, neg = np.sort(s[positive]), np.sort(s[~positive])
+    if not len(pos) or not len(neg):
+        return float("nan")
+    twice = int(np.searchsorted(neg, pos, side="left").sum(dtype=np.int64)) \
+        + int(np.searchsorted(neg, pos, side="right").sum(dtype=np.int64))
+    return twice / (2 * len(pos) * len(neg))
 
 
 def _fast_auc(score: np.ndarray, label: np.ndarray) -> float:

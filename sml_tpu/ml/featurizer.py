@@ -661,6 +661,14 @@ def _try_fast_fit(stages, raw, make_frame):
     *prep, est = stages
     if not isinstance(est, Estimator):
         return _decline("the last stage is no estimator")
+    # the last stage says whose block this is (`Estimator._block_reader`):
+    # its own, or, for a validator that reads its folds off one staged
+    # block, its estimator's, in the compact form the folds are masks
+    # over; the caller fits the last stage on the block either way
+    reader = est._block_reader()
+    if reader is None:
+        return _decline("a validator whose folds need their frames")
+    est, compact_only = reader
     if not (est.hasParam("featuresCol") and est.hasParam("labelCol")):
         return _decline("the estimator reads no featuresCol and labelCol")
     # a formula IS an indexer, an encoder and an assembler over raw
@@ -758,7 +766,8 @@ def _try_fast_fit(stages, raw, make_frame):
     compact_bytes = None
     if type(est).__name__ in ("LinearRegression", "LogisticRegression"):
         from ..conf import GLOBAL_CONF
-        compact_bytes = GLOBAL_CONF.getInt("sml.linear.compactBytes")
+        compact_bytes = 0 if compact_only else \
+            GLOBAL_CONF.getInt("sml.linear.compactBytes")
 
     X = keep = parts = None
     with PROFILER.span("fit.featurize", rows=n, columns=len(jobs)) as note:
@@ -778,6 +787,8 @@ def _try_fast_fit(stages, raw, make_frame):
         with PROFILER.span("fit.featurize.plan.block") as step:
             if compact_bytes is not None and n * width * 4 >= compact_bytes:
                 parts = plan.compact(onehot, invalid)
+            if parts is None and compact_only:
+                return _decline("a NaN the compact block would carry")
             if parts is None:   # also: a NaN the expanded block would carry
                 X, keep = plan.block(onehot, invalid)
                 note["bytes"] = int(X.nbytes)

@@ -13,16 +13,27 @@ different chips instead of serializing device programs on one shared mesh.
 This is the TPU form of the reference's driver thread pool + executor
 tasks (`ML 07:120-130`) — the task-parallel model-selection strategy
 SURVEY §2.2 P6.
+
+Folds without fold frames: where the estimator takes its folds as a mask
+over ONE staged block (`LogisticRegression._fold_metrics`: a validator that
+is a `Pipeline`'s last stage gets the column plan's compact block,
+`CrossValidator._block_estimator`), `CrossValidator` makes a fold id a row
+(`_fold_ids`: `randomSplit`'s own membership) and no split, union or cache;
+a fold is one dispatch over the whole mesh, and they run one after another
+whatever `parallelism` allows.
 """
 
 from __future__ import annotations
 
 import itertools
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from ..parallel.mesh import run_placed_trials
+from ..utils.profiler import PROFILER
+from ._staging import shared_keys
 from .base import Estimator, Model, Saveable
 from .param import Param
 
@@ -64,7 +75,45 @@ class _ValidatorParams:
 
 def _fit_and_eval(est: Estimator, pmap, train, val, evaluator) -> float:
     model = est.copy(pmap).fit(train)
+    PROFILER.count("cv.fits")
+    PROFILER.count("cv.evals")
     return evaluator.evaluate(model.transform(val))
+
+
+#: the most folds a fold id a row holds (int8, what is staged beside the
+#: block); a validator with more makes its fold frames
+_FOLD_IDS_MAX = 127
+
+
+def _fold_ids(frame, k: int, seed: int, keep) -> np.ndarray:
+    """The fold `frame.randomSplit([1 / k] * k, seed)` puts each row in,
+    int8, in the frame's own row order (partition after partition): the
+    same draws on the same pre-split order (`frame/sampling.py`), with no
+    fold frame made. `keep` masks the rows a featurizer dropped before
+    the split saw them (None: none), and the ids are of the kept rows. A
+    job a partition on the column plan's pool: the sort is most of it."""
+    from ..frame.sampling import partition_uniforms, presplit_order
+    from . import _column_plan as cp
+    parts = frame._materialize()
+    bounds = np.cumsum([1.0 / k] * k)
+    starts = np.cumsum([0] + [len(p) for p in parts])
+
+    def ids(i: int) -> np.ndarray:
+        pdf = parts[i]
+        if keep is not None and not keep[starts[i]:starts[i + 1]].all():
+            pdf = pdf[keep[starts[i]:starts[i + 1]]]
+        order = presplit_order(pdf)
+        cell = np.minimum(np.searchsorted(bounds, partition_uniforms(
+            seed, i, len(pdf)), side="right"), k - 1).astype(np.int8)
+        if order is None:
+            return cell
+        out = np.empty(len(pdf), dtype=np.int8)
+        out[order] = cell       # row j of the sorted partition is order[j]
+        return out
+
+    return np.concatenate(cp.run_tasks(
+        [partial(ids, i) for i in range(len(parts))],
+        cp.runs_inline(int(starts[-1]))))
 
 
 def _batched_fold_metrics(est, grid, fold_pairs, evaluator):
@@ -197,7 +246,30 @@ class CrossValidator(Estimator, _ValidatorParams):
                   evaluator=evaluator, numFolds=numFolds, seed=seed,
                   parallelism=parallelism, collectSubModels=collectSubModels)
 
+    def _block_estimator(self):
+        """The estimator whose folds this validator reads off ONE staged
+        block (`_fit`), for `Pipeline.fit`'s column plan to featurize
+        for (`_block_reader`); None where the folds need their frames."""
+        est = self.getOrDefault("estimator")
+        on_block = getattr(est, "_folds_on_block", None)
+        if on_block is None \
+                or int(self.getOrDefault("numFolds")) > _FOLD_IDS_MAX \
+                or not on_block(self.getOrDefault("estimatorParamMaps"),
+                                self.getOrDefault("evaluator")):
+            return None
+        return est
+
+    def _block_reader(self):
+        est = self._block_estimator()
+        return None if est is None else (est, True)
+
     def _fit(self, df) -> "CrossValidatorModel":
+        # the content keys of the frame's arrays are made once for every
+        # dispatch of this fit (`_staging.shared_keys`)
+        with PROFILER.span("fit.cv"), shared_keys():
+            return self._fit_folds(df)
+
+    def _fit_folds(self, df) -> "CrossValidatorModel":
         est = self.getOrDefault("estimator")
         grid = self.getOrDefault("estimatorParamMaps")
         evaluator = self.getOrDefault("evaluator")
@@ -206,6 +278,39 @@ class CrossValidator(Estimator, _ValidatorParams):
         seed = int(seed) if seed is not None else 42
         par = max(1, int(self.getOrDefault("parallelism")))
 
+        # an estimator that takes its folds as a mask over the one staged
+        # block (`LogisticRegression._fold_metrics`): a fold id a row,
+        # `randomSplit`'s own membership on the frame the user fitted
+        # (`_row_source`: the frame whose rows, partition by partition,
+        # are this one's), no fold frame, union or cache. Its dispatches
+        # run one after another whatever `parallelism` says: each holds
+        # the whole mesh and one fit's temporaries, and `parallelism` is
+        # a bound on how many run at once
+        metrics = None
+        if self._block_estimator() is not None:
+            def fold_ids(keep):
+                with PROFILER.span("fit.cv.folds", folds=k):
+                    return _fold_ids(getattr(df, "_row_source", df), k,
+                                     seed, keep)
+            metrics = est._fold_metrics(df, grid, k, fold_ids)
+        if metrics is None:
+            metrics = self._frame_metrics(df, est, grid, evaluator, k, seed,
+                                          par)
+
+        avg = metrics.mean(axis=1)
+        best_idx = int(np.argmax(avg) if evaluator.isLargerBetter()
+                       else np.argmin(avg))
+        with PROFILER.span("fit.cv.refit", point=best_idx):
+            best_model = est.copy(grid[best_idx]).fit(df)
+        PROFILER.count("cv.fits")
+        cvm = CrossValidatorModel(bestModel=best_model, avgMetrics=list(avg))
+        cvm._inherit_params(self)
+        return cvm
+
+    @staticmethod
+    def _frame_metrics(df, est, grid, evaluator, k, seed, par):
+        """The (grid point, fold) metrics from fold FRAMES: k splits, k
+        unions, each cached (counter `cv.fold_frames`)."""
         # seeded per-partition fold assignment — same contract class as
         # randomSplit (`ML 02:38-52`): deterministic given (seed, layout)
         folds = df.randomSplit([1.0 / k] * k, seed=seed)
@@ -221,6 +326,7 @@ class CrossValidator(Estimator, _ValidatorParams):
                 train = train.union(r)
             train.cache()
             fold_pairs.append((train, val))
+        PROFILER.count("cv.fold_frames", 2 * k)
 
         metrics = _batched_fold_metrics(est, grid, fold_pairs, evaluator)
         if metrics is None:
@@ -237,14 +343,7 @@ class CrossValidator(Estimator, _ValidatorParams):
             results = run_placed_trials(jobs, run, par)
             for gi, fi, m in results:
                 metrics[gi, fi] = m
-
-        avg = metrics.mean(axis=1)
-        best_idx = int(np.argmax(avg) if evaluator.isLargerBetter()
-                       else np.argmin(avg))
-        best_model = est.copy(grid[best_idx]).fit(df)
-        cvm = CrossValidatorModel(bestModel=best_model, avgMetrics=list(avg))
-        cvm._inherit_params(self)
-        return cvm
+        return metrics
 
 
 class CrossValidatorModel(Model, _ValidatorParams):

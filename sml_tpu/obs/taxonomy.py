@@ -89,6 +89,11 @@ COUNTERS = {
     "staging.evict_bytes", "staging.bin_evict_bytes",
     "shuffle.rows", "shuffle.bytes",
     "cv.batchFolds.fallback",
+    # a validator's fit (ml/tuning.py): estimator fits it made (the grid's
+    # over the folds and the refit) / validation metrics it took / fold
+    # frames it made (splits and unions; 0 where the folds are a mask over
+    # one staged block)
+    "cv.fits", "cv.evals", "cv.fold_frames",
     # Pallas launches of the traversal kernel (native/traverse_kernel.py,
     # docs/KERNELS.md): TRACE-TIME statics (counted once per program
     # trace, like collective.*: launches per execution = the count ×
@@ -135,6 +140,14 @@ COUNTERS = {
     # stops at convergence, at most maxIter) / steps that moved the
     # coefficients (what a fit reports; every executed step does)
     "linear.irls.fits", "linear.irls.steps_run", "linear.irls.iterations",
+    # the penalized fused fit (linear_impl._compact_enet_fn): inner
+    # coordinate sweeps of its proximal steps / fits that ran maxIter
+    # steps without converging / fits whose steps stopped shrinking at
+    # float32's floor before one was under tol (linear_impl._stalled:
+    # ended there, not converged); and fits that took the host loop, a
+    # dispatch a step (linear_impl.fit_logistic: no compact block)
+    "linear.irls.prox_sweeps", "linear.irls.unconverged",
+    "linear.irls.floor_ended", "linear.host_loops",
     # the compact form's margin pass (featurizer.CompactParts.predict_affine:
     # a job a block of rows; the logistic summary inside `fit.summary`, a
     # linear summary's MAE when it is read): a pass that ran its jobs on the

@@ -190,6 +190,7 @@ def pmin(x, axis=DATA_AXIS):
 
 
 def all_gather(x, axis: str = DATA_AXIS, *, tiled: bool = False):
+    axis = _resolve_row_axis(axis)
     _note("all_gather", x)
     return lax.all_gather(x, axis_name=axis, tiled=tiled)
 

@@ -295,6 +295,45 @@ def test_the_fused_logistic_fit_fits_a_v5e_at_the_cells_size(one_chip_mesh):
         assert scope in hlo, scope
 
 
+def test_a_folds_penalized_fits_fit_a_v5e_at_the_cells_size(one_chip_mesh):
+    """`mle03_logreg_cv.fit_cv`'s fold program at its own shapes (six grid
+    points over 6,815,744 padded rows with a fold id a row), compiled for
+    the chip: ONE fit's temporaries whatever the grid's width (the points
+    run one after another: the block and one weighted copy, not six), the
+    new scopes are in the metadata, and nothing in it sorts (a `lax.sort`
+    of the margins took 141 s to compile for the v5e, ISSUE 40: the
+    ranking is the host's)."""
+    from sml_tpu.ml import linear_impl
+    rows, points = 6_815_744, 6
+    layout = tuple(("oh", j, w) for j, w in enumerate((1, 35, 5, 2, 2))) \
+        + tuple(("num", i) for i in range(17))
+    fn = linear_impl._compact_enet_fn(layout, 100, 1e-6, True)
+    last, flat, whole = P(None, D), P(D), P()
+    args = (((17, rows), jnp.float32, last), ((5, rows), jnp.int32, last),
+            ((rows,), jnp.float32, flat), ((rows,), jnp.int8, flat),
+            ((rows,), jnp.float32, flat), ((), jnp.float32, whole),
+            ((points,), jnp.float32, whole), ((points,), jnp.float32, whole))
+    with meshlib.use_mesh_local(one_chip_mesh):
+        mapped = meshlib.shard_map_compat(
+            fn, mesh=one_chip_mesh, in_specs=tuple(s for _, _, s in args),
+            out_specs=P())
+        compiled = jax.jit(mapped).lower(*[
+            jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(one_chip_mesh, s))
+            for shape, dtype, s in args]).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3.7e9, \
+        f"{memory.temp_size_in_bytes / 1e9:.2f} GB of temporaries"
+    assert memory.argument_size_in_bytes < 1.0e9
+    hlo = compiled.as_text()
+    assert f"f32[63,{rows}]" in hlo and f"f32[{rows},63]" not in hlo
+    assert f"f32[{points},63,{rows}]" not in hlo
+    assert not re.search(r"\ssort\(", hlo)
+    for scope in ("linear.expand", "linear.irls/", "linear.irls.hess",
+                  "linear.irls.solve", "linear.irls.prox", "cv.eval"):
+        assert scope in hlo, scope
+
+
 def test_the_fused_logistic_fit_leaves_its_loop_on_done(one_chip_mesh):
     """The loop over Newton steps stops where the fit has converged
     (ISSUE 39): compiled for the chip, the condition of the `linear.irls`
