@@ -370,8 +370,23 @@ def _mesh_platform(mesh=None) -> str:
 
 def _hist_dtype():
     """bf16 histogram operands on TPU (exact one-hot, f32 accumulation on
-    the MXU); f32 elsewhere — XLA:CPU has no bf16xbf16=f32 dot."""
+    the MXU); f32 elsewhere — XLA:CPU has no bf16xbf16=f32 dot. The type
+    the dot MULTIPLIES in; what the one-hot side is STORED as between
+    dots is `_operand_dtype`'s."""
     return jnp.bfloat16 if _mesh_platform() == "tpu" else jnp.float32
+
+
+def _operand_dtype(hist_dtype):
+    """The type the loop-invariant one-hot `B1t` is STORED in, decided
+    here and nowhere else: int8 where the histogram dot multiplies in
+    bf16 (the TPU, `_hist_dtype`), else `hist_dtype` itself. 0 and 1 are
+    exact in both, so the stored type changes no number; it halves what
+    every histogram dot reads from HBM and what stays resident for a
+    dispatch. XLA:TPU fuses the widening into the dot's operand read (the
+    compiled program holds no bf16 copy, tests/test_tree_operand.py);
+    XLA:CPU would write the widened copy at every level, so there the
+    operand is the float32 one-hot itself."""
+    return jnp.int8 if hist_dtype == jnp.bfloat16 else hist_dtype
 
 
 def _hist_subtract() -> bool:
@@ -490,9 +505,12 @@ def _make_tree_builder(spec: TreeSpec, hist_dtype=jnp.float32,
                 # dispatch and held outside the loop over rounds by its
                 # optimization_barrier (without it XLA's fusible sinking
                 # rebuilds it every round); each dot reads all of it
-                # from HBM
+                # from HBM, in the one byte an element it is stored at
+                # on the chip (`_operand_dtype`): the widening here is
+                # the only read of B1t in `build`, and it fuses into the
+                # dot (a no-op where stored and histogram type agree)
                 part = jax.lax.dot_general(
-                    B1t, ns, (((1,), (0,)), ((), ())),
+                    B1t.astype(hist_dtype), ns, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 hist = _psum_merge(part)
                 if subtract and level > 0:
@@ -686,18 +704,21 @@ def _sliced_draw(n: int, data_width: int, draw, axes=None):
 def _tree_operand(binned_c, n_bins: int, hist_dtype, barrier: bool = True):
     """The histogram operand of every tree program, built ONCE a
     dispatch: `(binned, B1t)` = the compact bins widened to int32 and
-    their one-hot, `(F·B, n)` in `hist_dtype`, pre-transposed for the
-    histogram dot (a `.T` at the dot would re-materialize a gigabyte
-    transpose every level of every tree).
+    their one-hot, `(F·B, n)`, pre-transposed for the histogram dot (a
+    `.T` at the dot would re-materialize a gigabyte transpose every
+    level of every tree). It is STORED in `_operand_dtype(hist_dtype)`:
+    int8 on the chip, where the dot widens it to bf16 as it reads (one
+    byte an element from HBM and not two: 1.09 GB a dot at 1.7 M rows ×
+    640 columns); the float32 one-hot itself elsewhere.
 
     The `optimization_barrier` is what keeps "once" true. The one-hot is
     loop-invariant and cheap to express (broadcast, compare, convert), and
     XLA:TPU's fusible-sinking pass moves exactly such producers INTO a
     while loop that consumes them, trading recomputation for live memory
     (the body's name then ends `…sunk`): though written outside the
-    `lax.scan` over rounds it is then rebuilt every round, 4.4 GB read
-    and 2.2 GB written at 1.7 M rows × 640 columns, longer than the
-    round's four histogram dots take (PERF.md §6, PR 27). Behind the
+    `lax.scan` over rounds it is then rebuilt every round (as bf16, 4.4 GB
+    read and 2.2 GB written at 1.7 M rows × 640 columns, longer than the
+    round's four histogram dots took: PERF.md §6, PR 27). Behind the
     barrier it is an opaque buffer the loop carries as an operand; the
     barrier is the identity on its value. `barrier=False` is for a
     program with NO loop to sink into (`_build_tree_program`): there it
@@ -707,7 +728,8 @@ def _tree_operand(binned_c, n_bins: int, hist_dtype, barrier: bool = True):
         # the 4x-smaller staged matrix), never on the host/H2D path
         binned = binned_c.astype(jnp.int32)
         n, F = binned.shape
-        B1t = jax.nn.one_hot(binned, n_bins, dtype=hist_dtype) \
+        B1t = jax.nn.one_hot(binned, n_bins,
+                             dtype=_operand_dtype(hist_dtype)) \
             .reshape(n, F * n_bins).T
         return binned, (jax.lax.optimization_barrier(B1t) if barrier
                         else B1t)
@@ -1101,18 +1123,19 @@ def _ensemble_compiled(es: EnsembleSpec):
 
 
 def _onehot_bytes(spec: TreeSpec, rows: int) -> int:
-    """HBM bytes of the one-hot resident (`B1t`: rows × F ×
-    bins in hist_dtype) — the dominant transient the ledger charges for
+    """HBM bytes of the one-hot resident (`B1t`: rows × F × bins in the
+    type it is STORED in, `_operand_dtype`: one byte an element on the
+    chip) — the dominant transient the ledger charges for
     the duration of a tree-fit dispatch (every tree program shape,
     fit_tree included). Dispatch-long BY CONSTRUCTION: `_tree_operand`
     builds it once and its optimization_barrier makes it a buffer the
     loop over rounds carries (left to itself the compiler rebuilds it
-    every round: as many bytes alive, a round at a time). While it is built the
-    int32 broadcast it is compared from (4 bytes an element, twice these
-    bytes) is alive beside it; that is not charged here (PERF.md §3,
-    `memory_peak_bytes`)."""
+    every round: as many bytes alive, a round at a time). While it is
+    built the int32 broadcast it is compared from (4 bytes an element,
+    four times the chip's one-byte operand) is alive beside it; that is
+    not charged here (PERF.md §3, `memory_peak_bytes`)."""
     return int(rows) * spec.n_features * spec.n_bins \
-        * np.dtype(_hist_dtype()).itemsize
+        * np.dtype(_operand_dtype(_hist_dtype())).itemsize
 
 
 def _run_and_read(compiled, *args):

@@ -1,15 +1,22 @@
-"""The histogram operand is built once a dispatch (ISSUE 27).
+"""The histogram operand is built once a dispatch (ISSUE 27) and stored at
+one byte an element on the chip (ISSUE 41).
 
 `tree_impl._tree_operand` is the one place that widens the bins and builds
-the bf16 one-hot `B1t`, and it ends in an `optimization_barrier`: without
+the one-hot `B1t`, and it ends in an `optimization_barrier`: without
 it XLA:TPU's fusible sinking rebuilds the one-hot inside the loop over
-rounds. Three things are held here, none of which needs the chip:
+rounds. `tree_impl._operand_dtype` is the one place that says what it is
+stored as: int8 where the histogram dot multiplies in bf16 (the chip), the
+dot's own type elsewhere. Four things are held here, none of which needs the
+chip:
 
   (a) the jaxpr of every looping program has the barrier once, outside the
       scan, and the histogram dot reads it as a scan constant;
   (b) compiled for a described v5e chip, no `tree.operand` instruction is
-      inside the while loop, and the loop carries the one-hot as an operand;
-  (c) the barrier is the identity: the fitted packs are bit-equal without it.
+      inside the while loop, the loop carries the one-hot as ONE int8
+      operand, and no widened copy of it exists anywhere in the program;
+  (c) the barrier is the identity: the fitted packs are bit-equal without it;
+  (d) so is the stored type: the packs are bit-equal with the operand stored
+      as int8 and as the histogram's own type.
 """
 
 import re
@@ -103,8 +110,8 @@ def test_one_barrier_outside_the_scan_feeds_the_histogram_dot(which, boosting):
     assert not inside, "the barrier lies outside the scan over rounds"
     assert "tree.operand" in str(barrier.source_info.name_stack)
     (b1t,) = barrier.outvars
-    assert (b1t.aval.shape, b1t.aval.dtype) == ((F * B, 64),
-                                                tree_impl._hist_dtype())
+    assert (b1t.aval.shape, b1t.aval.dtype) == (
+        (F * B, 64), tree_impl._operand_dtype(tree_impl._hist_dtype()))
 
     scans = [e for e, _ in eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
@@ -117,8 +124,12 @@ def test_one_barrier_outside_the_scan_feeds_the_histogram_dot(which, boosting):
             and "tree.hist" in str(e.source_info.name_stack)
             and e.invars[0].aval.shape == (F * B, 64)]
     assert len(dots) == 2, "one histogram dot a level, in the scan body"
+    widened = {e.outvars[0]: e.invars[0] for e, _ in _walk(body)
+               if e.primitive.name == "convert_element_type"}
     for dot in dots:
-        lhs = dot.invars[0]
+        # (where the stored type is not the dot's, the widening at the call
+        # is all that lies between the two)
+        lhs = widened.get(dot.invars[0], dot.invars[0])
         assert lhs in consts, "the dot's operand is computed in the body"
         assert scan.invars[consts.index(lhs)] is b1t, \
             "the dot reads another array than the barrier's"
@@ -137,7 +148,8 @@ def test_the_single_tree_program_builds_it_the_same_way_without_a_barrier():
     names = [e.primitive.name for e in eqns]
     assert "optimization_barrier" not in names and "scan" not in names
     assert any("tree.operand" in str(e.source_info.name_stack)
-               and e.outvars[0].aval.shape == (F * B, 64) for e in eqns)
+               and e.outvars[0].aval.shape == (F * B, 64)
+               and e.outvars[0].aval.dtype == jnp.int8 for e in eqns)
 
 
 # ------------------------------------- what the operand's type and size hang on
@@ -167,12 +179,29 @@ def test_mesh_platform_memo_and_invalidation(spark):
         tree_impl._platform_memo.clear()
 
 
-def test_onehot_ledger_reads_the_operand_for_a_fit_and_zero_after(spark):
+def test_the_operand_is_stored_at_one_byte_where_the_dot_is_bf16():
+    """`_operand_dtype` is the one place that decides: int8 for the chip's
+    bf16 dot, and the float32 one-hot as it was elsewhere (XLA:CPU would
+    write the widened copy at every level)."""
+    assert tree_impl._operand_dtype(jnp.bfloat16) == jnp.int8
+    assert tree_impl._operand_dtype(jnp.float32) == jnp.float32
+
+
+@pytest.mark.parametrize("hist_dtype,itemsize", [
+    (jnp.float32, 4), (jnp.bfloat16, 1)], ids=["cpu-f32", "tpu-bf16"])
+def test_onehot_ledger_reads_the_operand_for_a_fit_and_zero_after(
+        spark, monkeypatch, hist_dtype, itemsize):
     """The HBM ledger charges the one-hot resident under `hist_onehot` for
     as long as a fit's dispatch lasts: rows (as staged, padding included) x
-    F x bins x itemsize at its peak, nothing once the fit has returned."""
+    F x bins x the STORED type's itemsize at its peak (`_onehot_bytes`: four
+    bytes for this platform's float32 one-hot, one for the chip's int8
+    behind a bf16 dot), nothing once the fit has returned."""
     from sml_tpu.ml._staging import stage_sharded
     from sml_tpu.obs import LEDGER
+    # (the program cache is not keyed by the operand's type, which a
+    # platform fixes for a process: give the other type a cache of its own)
+    monkeypatch.setattr(tree_impl, "_hist_dtype", lambda: hist_dtype)
+    monkeypatch.setattr(tree_impl, "_ensemble_cache", {})
     binned, y, _, _ = _rows(3000, seed=5)
     b_dev, mask_dev, _ = stage_sharded(binned)
     y_dev = tree_impl.stage_aligned(y, b_dev.shape[0])
@@ -181,8 +210,9 @@ def test_onehot_ledger_reads_the_operand_for_a_fit_and_zero_after(spark):
                                      seed=7)
     pool = LEDGER.snapshot()["hist_onehot"]
     assert pool["allocs"] == 1 and pool["frees"] == 1
-    assert pool["peak"] == b_dev.shape[0] * F * B \
-        * np.dtype(tree_impl._hist_dtype()).itemsize
+    assert pool["peak"] == tree_impl._onehot_bytes(_es(True).tree,
+                                                   b_dev.shape[0]) \
+        == b_dev.shape[0] * F * B * itemsize
     assert pool["live"] == 0
 
 
@@ -224,15 +254,30 @@ def one_chip_mesh():
 AOT_ROWS = 8192
 
 
-def _compiled_for(mesh, es, which: str = "ensemble") -> str:
-    """A program's optimized HLO for `mesh`'s chip, at 8,192 rows."""
+def _compile_for(mesh, es, which: str = "ensemble", rows: int = AOT_ROWS):
+    """A program compiled for `mesh`'s chip at `rows` rows of the spec's
+    own width (the arguments' types are `_program`'s)."""
     program, args, by_row = _program(which, es)
     mapped, specs = _sharded(program, args, by_row, mesh)
     shapes = [jax.ShapeDtypeStruct(
-        (AOT_ROWS,) + np.shape(a)[1:] if r else np.shape(a),
+        ((rows,) + (es.tree.n_features,) * (np.ndim(a) - 1)) if r
+        else np.shape(a),
         np.asarray(a).dtype, sharding=NamedSharding(mesh, s))
         for a, r, s in zip(args, by_row, specs)]
-    return jax.jit(mapped).lower(*shapes).compile().as_text()
+    return jax.jit(mapped).lower(*shapes).compile()
+
+
+def _compiled_for(mesh, es, which: str = "ensemble") -> str:
+    """A program's optimized HLO for `mesh`'s chip, at 8,192 rows."""
+    return _compile_for(mesh, es, which).as_text()
+
+
+def _wider_copies(hlo: str, columns: int, rows: int) -> list:
+    """The types in which a bf16 or f32 copy of the (columns, rows) one-hot
+    appears in a program: the dot widens as it reads, so a copy hoisted out
+    of the loop or written inside it is exactly what ISSUE 41 took away."""
+    return [wide for wide in ("bf16", "f32")
+            if f"{wide}[{columns},{rows}]" in hlo]
 
 
 @pytest.mark.parametrize("which,boosting", [
@@ -248,8 +293,9 @@ def test_compiled_for_v5e_the_operand_is_outside_the_loop(one_chip_mesh,
     # the loop over rounds (the forest's Poisson draw is a loop too)
     carried = [ln.split(" while(")[0] for ln in hlo.splitlines()
                if re.search(r"\swhile\(", ln)]
-    assert sum(f"bf16[{F * B},{AOT_ROWS}]" in c for c in carried) == 1, \
-        "the loop over rounds carries the one-hot as an operand"
+    assert sum(c.count(f"s8[{F * B},{AOT_ROWS}]") for c in carried) == 1, \
+        "the loop over rounds carries the one-hot as ONE int8 operand"
+    assert _wider_copies(hlo, F * B, AOT_ROWS) == []
 
 
 def test_the_check_sees_an_operand_that_was_sunk(one_chip_mesh, monkeypatch):
@@ -259,6 +305,27 @@ def test_the_check_sees_an_operand_that_was_sunk(one_chip_mesh, monkeypatch):
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
     hlo = _compiled_for(one_chip_mesh, _es(True, n_trees=3))
     assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand")
+
+
+def test_the_boosted_fit_holds_a_one_byte_operand_at_the_cells_size(
+        one_chip_mesh):
+    """`ml11_xgb.fit`'s program at its own shapes (1,703,936 padded rows x
+    10 features, 64 bins, depth 4, 100 rounds), compiled for the chip:
+    5.58 GB of temporaries with the one-hot resident as s8[640, rows]
+    (6.67 GB with it in bf16, ISSUE 41), and no wider copy of it."""
+    rows, feats, bins = 1_703_936, 10, 64
+    spec = tree_impl.TreeSpec(
+        max_depth=4, n_bins=bins, n_features=feats, feature_k=feats,
+        min_instances=1, min_info_gain=0.0, reg_lambda=1.0, gamma=0.0)
+    es = tree_impl.EnsembleSpec(
+        tree=spec, n_trees=100, loss="squared", boosting=True,
+        bootstrap=False, subsample=1.0, step_size=0.1)
+    compiled = _compile_for(one_chip_mesh, es, rows=rows)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 5.8e9, f"{temporaries / 1e9:.2f} GB of temporaries"
+    hlo = compiled.as_text()
+    assert f"s8[{feats * bins},{rows}]" in hlo
+    assert _wider_copies(hlo, feats * bins, rows) == []
 
 
 def test_the_fused_logistic_fit_fits_a_v5e_at_the_cells_size(one_chip_mesh):
@@ -394,22 +461,44 @@ ENTRY %main (a: s32[4]) -> s32[4] {
 
 
 # --------------------------------------------- (c) the barrier is the identity
+def _fit_on_cpu(es):
+    """(packs, base, the traced program's text) of the ensemble program as
+    it is built NOW, on 512 rows and one CPU device."""
+    data = _rows(512, seed=3)
+    program = tree_impl._make_ensemble_program(es, 1, (D,), 0)
+    mapped, _ = _sharded(program, data, (True, True, True, False), _cpu_mesh())
+    packs, base = jax.jit(mapped)(*data)
+    return np.asarray(packs), float(base), str(jax.make_jaxpr(mapped)(*data))
+
+
 @pytest.mark.parametrize("boosting", [True, False], ids=["boosted", "bagged"])
 def test_fitted_packs_are_bit_equal_without_the_barrier(boosting, monkeypatch):
     es = _es(boosting, n_trees=4, depth=3)
-    binned, y, mask, rng = _rows(512, seed=3)
-
-    def fit():
-        program = tree_impl._make_ensemble_program(es, 1, (D,), 0)
-        mapped, _ = _sharded(program, (binned, y, mask, rng),
-                             (True, True, True, False), _cpu_mesh())
-        packs, base = jax.jit(mapped)(binned, y, mask, rng)
-        return np.asarray(packs), float(base)
-
-    with_barrier = fit()
+    with_barrier = _fit_on_cpu(es)
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
-    without = fit()
+    without = _fit_on_cpu(es)
     assert with_barrier[0].shape == (4, 5, 15)
     assert (with_barrier[0][:, 0] >= 0).any(), "the trees split"
     np.testing.assert_array_equal(with_barrier[0], without[0])
     assert with_barrier[1] == without[1]
+
+
+# ----------------------------------------- (d) and so is the stored type
+@pytest.mark.parametrize("boosting", [True, False], ids=["boosted", "bagged"])
+def test_fitted_packs_are_bit_equal_with_the_operand_stored_as_int8(
+        boosting, monkeypatch):
+    """0 and 1 are exact in int8 and in the histogram's type, and the dot
+    multiplies in the histogram's type either way: on this platform the
+    program with an int8 `B1t` fits the very trees of the one with the
+    float32 `B1t`."""
+    es = _es(boosting, n_trees=4, depth=3)
+    monkeypatch.setattr(tree_impl, "_operand_dtype", lambda hist: hist)
+    as_hist = _fit_on_cpu(es)
+    monkeypatch.setattr(tree_impl, "_operand_dtype", lambda hist: jnp.int8)
+    as_int8 = _fit_on_cpu(es)
+    stored = f"i8[{F * B},512]"
+    assert stored in as_int8[2] and stored not in as_hist[2], \
+        "the patch reaches the operand"
+    assert (as_hist[0][:, 0] >= 0).any(), "the trees split"
+    np.testing.assert_array_equal(as_hist[0], as_int8[0])
+    assert as_hist[1] == as_int8[1]
