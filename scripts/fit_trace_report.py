@@ -56,6 +56,10 @@ from sml_tpu.utils.profiler import now, wallclock  # noqa: E402
 T_START = now()
 
 WAIT = "fit.device_wait"
+#: the programs' `jax.named_scope` families (docs/OBSERVABILITY.md): the
+#: sample of operations shown with their statistics prefers those that name
+#: one
+SCOPE_FAMILIES = ("tree.", "linear.", "cv.", "als.")
 #: what a host-to-device transfer may be called in a trace, letters only
 H2D_MARKS = ("h2d", "hosttodevice", "transfertodevice", "copytodevice",
              "bufferfromhostbuffer", "transferliteraltodevice", "infeed")
@@ -251,9 +255,10 @@ def device_metadata(profile, path, sample: int = 40):
     scoped = _fit_scopes.scopes_of_file(path)
     in_name = sum(1 for op in meta if _fit_scopes.scope_in(op))
     picked = list(meta.items())
-    picked = picked[:sample // 2] + [kv for kv in picked[sample // 2:]
-                                     if "tree." in json.dumps(kv[1])
-                                     ][:sample // 2]
+    picked = picked[:sample // 2] + [
+        kv for kv in picked[sample // 2:]
+        if any(family in json.dumps(kv[1]) for family in SCOPE_FAMILIES)
+    ][:sample // 2]
     return {"lines": lines, "operations": len(meta),
             "operations_with_a_scope_in_their_statistics": len(scoped),
             "operations_with_a_scope_in_their_name": in_name,

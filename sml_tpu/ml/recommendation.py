@@ -7,16 +7,23 @@ users/items across executors and shuffles factor blocks; here the WHOLE
 alternating fit is ONE jitted shard_map program (`fori_loop` over
 iterations), each half-step inside it:
 
-    per chip:  segment-sum of (f_i ⊗ f_i, r·f_i) by user  → (U, r, r), (U, r)
+    per chip:  the normal equations by ROW BLOCKS of the entity-sorted
+               order: gather the other side's factor rows of a block,
+               form its statistics (the upper triangle of f_i ⊗ f_i, and
+               r·f_i), sum them by segment in a scan that begins anew at
+               each entity, add a block's sums into the accumulators
     psum       over ICI (the factor-block exchange)
-    batched    solve of all U normal systems on-device
+    batched    Cholesky solve of all the normal systems on-device
 
 with ALS-WR regularization (λ·n_u, Spark's scheme). Ratings AND factors stay
-in HBM for the entire fit: one dispatch, one packed factor download."""
+in HBM for the entire fit: one dispatch, one packed factor download. No
+array of ratings x statistics exists: a block's is the largest
+(`_block_rows`), so the table's size is bounded by its four row arrays and
+not by rank² times them (docs/KERNELS.md "The blocked normal equations")."""
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache
 from typing import Optional
 
 import jax
@@ -26,15 +33,129 @@ import pandas as pd
 
 from ..parallel import collectives as coll
 from .base import Estimator, Model, load_arrays, save_arrays
-from ._staging import data_parallel
+
+#: a dispatch's block temporaries may take this share of the device's
+#: memory (`_block_rows`): the block's statistics and the copies of them
+#: that the log-depth scan keeps alive, `_BLOCK_COPIES` arrays of a row's
+#: statistics padded to the chip's 128 lanes (read from the compiled
+#: program's memory analysis at rank 12: PERF.md section 4)
+_BLOCK_SHARE = 0.25
+_BLOCK_COPIES = 3
+_LANES = 128
+#: the memory of a device that reports none (the CPU test mesh): a v5e's
+_DEVICE_BYTES = 16 << 30
 
 
-from functools import lru_cache
+def _stat_width(rank: int) -> int:
+    """Columns of a rating's statistics: the upper triangle of f ⊗ f, then
+    r·f."""
+    return rank * (rank + 1) // 2 + rank
+
+
+def _block_bytes(rank: int, rows: int) -> int:
+    """Bytes of a block's temporaries: `_BLOCK_COPIES` float32 arrays of
+    its rows' statistics, the columns padded to whole lane tiles."""
+    return 4 * _BLOCK_COPIES * rows * _LANES * -(-_stat_width(rank) // _LANES)
+
+
+def _block_rows(rank: int) -> int:
+    """Rows of one block of the blocked build: the largest power of two
+    whose temporaries fit `_BLOCK_SHARE` of the active mesh's first
+    device."""
+    from ..parallel import mesh as meshlib
+    stats = meshlib.get_mesh().devices.flat[0].memory_stats() or {}
+    budget = _BLOCK_SHARE * float(stats.get("bytes_limit", _DEVICE_BYTES))
+    return 1 << max(int(budget // _block_bytes(rank, 1)).bit_length() - 1, 0)
+
+
+def _stat_operands(f, rat):
+    """The operands of a block's statistics, as they enter the products:
+    the gathered factor rows and the ratings. The identity; the seam where
+    `benchmark/tools_als.py` rounds them for the lower-precision control."""
+    return f, rat
+
+
+#: rows of a tile of `_segment_sums`: the chip's sublanes
+_TILE = 8
+
+
+def _segment_sums(stats, begins):
+    """Inclusive running sums of `stats` down axis 0 that begin anew at
+    every row `begins` flags: the row a segment ends on holds the
+    segment's sum. No prefix ever spans two segments, so a sum carries the
+    rounding of its own rows alone (a difference of two table-long
+    prefixes carried ~4 % median error at MovieLens-25M scale in plain
+    float32 — r4 review — and took a double-single pair of them to mend).
+
+    Two levels: the `_TILE` rows of a tile are summed one after another
+    (each step a whole plane of one row a tile), a log-depth associative
+    scan carries the tiles' sums, an eighth of the rows, and a tile's rows
+    before its first flagged one take what the tiles before it carry."""
+
+    def combine(a, b):
+        fa, va = a
+        fb, vb = b
+        return fa | fb, jnp.where(fb, vb, va + vb)
+
+    rows, width = stats.shape
+    if rows % _TILE:
+        return jax.lax.associative_scan(
+            combine, (begins[:, None], stats), axis=0)[1]
+    x = stats.reshape(rows // _TILE, _TILE, width)
+    b = begins.reshape(rows // _TILE, _TILE)
+    run, seen = [x[:, 0]], [b[:, 0]]
+    for j in range(1, _TILE):
+        run.append(jnp.where(b[:, j, None], x[:, j], run[-1] + x[:, j]))
+        seen.append(seen[-1] | b[:, j])
+    _, carried = jax.lax.associative_scan(
+        combine, (seen[-1][:, None], run[-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(carried[:1]), carried[:-1]])
+    out = [jnp.where(f[:, None], r, r + before) for f, r in zip(seen, run)]
+    return jnp.stack(out, axis=1).reshape(rows, width)
+
+
+def _block_sums(stats, begins, s, t):
+    """Every entity's sum of `stats` over its rows [s, t) of one block
+    (none where t == s): the running sum at the last of them. The seam
+    where `benchmark/tools_als.py` puts a plain float32 prefix and its
+    boundary difference for the control."""
+    run = _segment_sums(stats, begins)
+    return jnp.where((t > s)[:, None], run[jnp.maximum(t - 1, 0)], 0.0)
+
+
+def _cholesky_solve(A, b):
+    """x of A x = b for symmetric positive-definite systems laid out
+    BATCH-LAST: A (rank, rank, n), b (rank, n). A column Cholesky and the
+    two triangular solves as `fori_loop`s of elementwise steps over whole
+    (rank, n) planes, so the batch runs along the lanes and no (n, rank,
+    rank) tile of 12 x 12 padded to 16 x 128 is ever made; float32
+    throughout, no matrix unit."""
+    rank = A.shape[0]
+    rows = jnp.arange(rank)
+
+    def factor(j, carry):
+        A, L = carry
+        col = A[:, j, :] * jax.lax.rsqrt(A[j, j, :])[None, :]
+        col = jnp.where((rows >= j)[:, None], col, 0.0)
+        return A - col[:, None, :] * col[None, :, :], L.at[:, j, :].set(col)
+
+    _, L = jax.lax.fori_loop(0, rank, factor, (A, jnp.zeros_like(A)))
+
+    def forward(j, y):     # L y = b: y_j from the y_k, k < j, set so far
+        return y.at[j].set((b[j] - (L[j] * y).sum(0)) / L[j, j])
+
+    y = jax.lax.fori_loop(0, rank, forward, jnp.zeros_like(b))
+
+    def backward(k, x):    # L' x = y, from the last row up
+        j = rank - 1 - k
+        return x.at[j].set((y[j] - (L[:, j] * x).sum(0)) / L[j, j])
+
+    return jax.lax.fori_loop(0, rank, backward, jnp.zeros_like(b))
 
 
 @lru_cache(maxsize=64)
 def _als_fit_program(n_users: int, n_items: int, rank: int, reg: float,
-                     max_iter: int, nonneg: bool):
+                     max_iter: int, nonneg: bool, block_rows: int = 0):
     """The WHOLE alternating fit as one XLA program: `fori_loop` over
     iterations, both half-steps inside, factors living on-device for the
     entire fit. One dispatch per fit instead of 2·maxIter — the per-launch
@@ -44,16 +165,19 @@ def _als_fit_program(n_users: int, n_items: int, rank: int, reg: float,
 
     SORTED-SEGMENT normal equations, no scatters: `segment_sum` lowers to
     a serialized HBM read-modify-write scatter on TPU and made the
-    half-steps ~3x slower than this formulation (measured 1.9s → 0.6s for
-    a 10-iteration MovieLens-1M-scale fit). The rating triples are sorted
-    by entity ON HOST once per fit (ids are static across iterations, so
-    the permutation is too); each shard holds a contiguous slice of the
-    sorted order plus its clipped local [start, end) bounds per entity,
-    accumulates per-segment sums as cumsum boundary differences (a
-    log-depth associative scan that streams at full HBM bandwidth), and
-    `psum` merges the per-shard partial normal equations — segments that
-    span a shard boundary add up across shards. Padding rows sit past
-    every real segment's end, so bounds clipping makes them inert.
+    half-steps ~3x slower than a sorted formulation (an old chip reading:
+    1.9s → 0.6s for a 10-iteration MovieLens-1M-scale fit). The rating
+    triples are sorted by entity ON HOST once per fit (ids are static
+    across iterations, so the permutation is too); each shard holds a
+    contiguous slice of the sorted order plus its clipped local
+    [start, end) bounds per entity, and walks its slice in blocks of
+    `block_rows` rows (0: the whole slice is one block). A block gathers
+    the other side's factor rows, forms its rows' statistics, sums them by
+    segment (`_segment_sums`) and adds, for every entity, the running sum
+    at the last of its rows that the block holds: a segment that spans a
+    block's end adds up across blocks exactly as one that spans a shard's
+    end adds up under `psum`. Padding rows sit past every real segment's
+    end, so no entity's bounds reach them.
 
     Program args (leading axis row-sharded unless noted):
       ius     item ids in user-sorted order     (rows,)
@@ -63,86 +187,208 @@ def _als_fit_program(n_users: int, n_items: int, rank: int, reg: float,
       ub      per-shard user bounds             (1, 2, n_users) per shard
       ib      per-shard item bounds             (1, 2, n_items) per shard
       uf0/if0 replicated factor inits
-    (No mask arg: padding rows sit past every real segment's end, so the
-    clipped bounds already exclude them.)
+    Returns (user factors, item factors, half-steps run).
     """
+    tri_i, tri_j = np.triu_indices(rank)
+    n_tri, width = len(tri_i), _stat_width(rank)
+    # stats = (f @ left) * ([f r] @ right): 0/1 selections, so the matrix
+    # unit only routes columns (exact at HIGHEST) and every statistic is
+    # one float32 product, with the rows on the sublanes all the way
+    left = np.zeros((rank, width), np.float32)
+    right = np.zeros((rank + 1, width), np.float32)
+    left[tri_i, np.arange(n_tri)] = 1.0
+    right[tri_j, np.arange(n_tri)] = 1.0
+    left[np.arange(rank), n_tri + np.arange(rank)] = 1.0
+    right[rank, n_tri:] = 1.0
+    # where A[i, j] lies among the statistics
+    tri_of = np.zeros((rank, rank), np.int32)
+    tri_of[tri_i, tri_j] = tri_of[tri_j, tri_i] = np.arange(n_tri)
+    select = jax.lax.Precision.HIGHEST
 
-    def half(other_sorted, rat_sorted, bounds, n_out):
-        f = other_sorted
-        stats = jnp.concatenate(
-            [(f[:, :, None] * f[:, None, :]).reshape(f.shape[0],
-                                                     rank * rank),
-             f * rat_sorted[:, None]], axis=1)
-        hi, lo = _ds_cumsum(stats)
-        zero = jnp.zeros((1, stats.shape[1]), stats.dtype)
-        hi = jnp.concatenate([zero, hi], axis=0)
-        lo = jnp.concatenate([zero, lo], axis=0)
+    def side(ids, rat, bounds):
+        """A side's row arrays as the blocks read them: padded to whole
+        blocks, with the rows that begin a segment flagged (once a fit:
+        the order is static across iterations)."""
+        rows = ids.shape[0]
+        block = min(block_rows or rows, rows)
+        pad = -rows % block
+        begins = jnp.zeros(rows + pad, bool).at[bounds[0]].set(
+            True, mode="drop")
+        return (jnp.pad(ids, (0, pad)), jnp.pad(rat, (0, pad)), begins,
+                block)
+
+    def half(other, ids, rat, begins, block, bounds, n_out):
         starts, ends = bounds[0], bounds[1]
-        # difference in double-single: the hi parts cancel exactly (both
-        # exactly representable); the residual lives in lo
-        seg = coll.psum((hi[ends] - hi[starts]) + (lo[ends] - lo[starts]))
-        cnt = coll.psum((ends - starts).astype(jnp.float32))
-        A = seg[:, :rank * rank].reshape(n_out, rank, rank)
-        b = seg[:, rank * rank:]
-        lam = reg * jnp.maximum(cnt, 1.0)
-        A = A + lam[:, None, None] * jnp.eye(rank, dtype=A.dtype)[None]
-        sol = jnp.linalg.solve(A, b[:, :, None])[:, :, 0]
-        sol = jnp.where(cnt[:, None] > 0, sol, 0.0)
-        return jnp.maximum(sol, 0.0) if nonneg else sol
+
+        def one_block(k, acc):
+            lo = k * block
+            with jax.named_scope("als.gather"):
+                f = other[jax.lax.dynamic_slice(ids, (lo,), (block,))]
+            with jax.named_scope("als.normal"):
+                f, r = _stat_operands(
+                    f, jax.lax.dynamic_slice(rat, (lo,), (block,)))
+                stats = jnp.dot(f, left, precision=select) * jnp.dot(
+                    jnp.concatenate([f, r[:, None]], axis=1), right,
+                    precision=select)
+                return acc + _block_sums(
+                    stats, jax.lax.dynamic_slice(begins, (lo,), (block,)),
+                    jnp.clip(starts - lo, 0, block),
+                    jnp.clip(ends - lo, 0, block))
+
+        acc = jax.lax.fori_loop(0, ids.shape[0] // block, one_block,
+                                jnp.zeros((n_out, width), jnp.float32))
+        with jax.named_scope("als.normal"):
+            with jax.named_scope("als.normal.allreduce"):
+                acc = coll.psum(acc)
+                cnt = coll.psum((ends - starts).astype(jnp.float32))
+        with jax.named_scope("als.solve"):
+            seg = acc.T                              # (width, n_out)
+            lam = reg * jnp.maximum(cnt, 1.0)
+            A = seg[tri_of] + lam[None, None, :] * jnp.eye(
+                rank, dtype=seg.dtype)[:, :, None]
+            sol = _cholesky_solve(A, seg[n_tri:]).T
+            sol = jnp.where(cnt[:, None] > 0, sol, 0.0)
+            return jnp.maximum(sol, 0.0) if nonneg else sol
 
     def program(ius, usi, rat_u, rat_i, ub, ib, uf0, if0):
         ub2 = ub[0]  # (2, n_users): this shard's local bounds
         ib2 = ib[0]
+        by_user = side(ius, rat_u, ub2)
+        by_item = side(usi, rat_i, ib2)
 
         def body(_, carry):
-            uf, itf = carry
-            uf = half(itf[ius], rat_u, ub2, n_users)
-            itf = half(uf[usi], rat_i, ib2, n_items)
-            return uf, itf
+            uf, itf, steps = carry
+            uf = half(itf, *by_user, ub2, n_users)
+            itf = half(uf, *by_item, ib2, n_items)
+            return uf, itf, steps + 2
 
-        return jax.lax.fori_loop(0, max_iter, body, (uf0, if0))
+        return jax.lax.fori_loop(0, max_iter, body,
+                                 (uf0, if0, jnp.int32(0)))
 
     return program
 
 
-def _ds_cumsum(x):
-    """Double-single (compensated) inclusive cumsum along axis 0: a
-    TwoSum-combine associative scan carrying (sum, error) float32 pairs,
-    ~float64-precision prefixes from float32 storage. A plain f32 prefix
-    loses the tiny per-segment sums to cancellation once the prefix
-    magnitude dwarfs them (at MovieLens-25M scale the boundary difference
-    carried ~4% median error — r4 review); the compensated scan's
-    residual keeps the difference exact to ~2^-45 of the prefix."""
+#: an integer id column whose values span at most this many times its rows
+#: (and never fewer than 2^16 values) takes the presence table of
+#: `dense_ids`; a wider one, and any other type, takes `np.unique`
+_TABLE_SPAN = 8
 
-    def two_sum(a, b):
-        s = a + b
-        bb = s - a
-        err = (a - (s - bb)) + (b - bb)
-        return s, err
 
-    def combine(c1, c2):
-        hi1, lo1 = c1
-        hi2, lo2 = c2
-        s, e = two_sum(hi1, hi2)
-        return s, e + lo1 + lo2
+def _row_chunks(n: int):
+    """[lo, hi) row ranges a task each, about one a core of the column
+    plan's pool (one range where the work runs inline)."""
+    from ._column_plan import _cores, runs_inline
+    parts = 1 if runs_inline(n) else _cores()
+    edges = np.linspace(0, n, parts + 1).astype(np.int64)
+    return [(int(a), int(b)) for a, b in zip(edges[:-1], edges[1:]) if b > a]
 
-    return jax.lax.associative_scan(
-        combine, (x, jnp.zeros_like(x)), axis=0)
+
+def _pooled(fn, n: int) -> list:
+    """`fn(lo, hi)` over the row chunks of `n`, on the column plan's pool
+    from `_INLINE_ROWS` rows on; the results in the chunks' order."""
+    from ._column_plan import run_tasks, runs_inline
+    return run_tasks([(lambda lo=lo, hi=hi: fn(lo, hi))
+                      for lo, hi in _row_chunks(n)], runs_inline(n))
+
+
+def dense_ids(raw: np.ndarray):
+    """(the distinct ids in order, each row's place among them as int32):
+    `np.unique(raw, return_inverse=True)` to the bit, without its sort
+    where the ids are integers of a bounded range: a presence table over
+    [min, max] and its running count, O(rows), marked and looked up a
+    chunk of rows a task."""
+    raw = np.asarray(raw)
+    n = raw.size
+    if n and raw.dtype.kind in "iu":
+        lo, hi = int(raw.min()), int(raw.max())
+        if hi - lo < max(_TABLE_SPAN * n, 1 << 16) and hi < 1 << 63:
+            off = raw.astype(np.int64, copy=False)
+            present = np.zeros(hi - lo + 1, bool)
+
+            def mark(a, b):    # every task writes the same True: no race
+                present[off[a:b] - lo] = True
+            _pooled(mark, n)
+            place = np.cumsum(present, dtype=np.int32) - 1
+            index = np.empty(n, np.int32)
+
+            def look(a, b):
+                np.take(place, off[a:b] - lo, out=index[a:b], mode="clip")
+            _pooled(look, n)
+            ids = (np.flatnonzero(present) + lo).astype(raw.dtype)
+            return ids, index
+    ids, index = np.unique(raw, return_inverse=True)
+    return ids, index.astype(np.int32)
+
+
+def stable_order(dense: np.ndarray, n_out: int) -> np.ndarray:
+    """`np.argsort(dense, kind="stable")` to the bit for dense ids in
+    [0, n_out), as a histogram and a scatter by chunks of rows: a chunk
+    sorts its rows' keys (id, row) — unique, so any sort of them is the
+    stable order — and counts its ids; a chunk's run of an id then lies in
+    the whole order after that id's runs of the chunks before it, and its
+    rows are scattered there. The chunks run on the column plan's pool."""
+    n = len(dense)
+    chunks = _row_chunks(n)
+    if len(chunks) < 2:
+        return np.argsort(dense, kind="stable")
+
+    def local(lo, hi):
+        key = dense[lo:hi].astype(np.uint64) << np.uint64(32)
+        key |= np.arange(lo, hi, dtype=np.uint64)
+        key.sort()
+        ids = (key >> np.uint64(32)).astype(np.int32)
+        key &= np.uint64(0xFFFFFFFF)
+        return key.astype(np.int64), ids, np.bincount(ids, minlength=n_out)
+
+    sorted_chunks = _pooled(local, n)
+    counts = np.stack([c for _, _, c in sorted_chunks])      # (chunks, ids)
+    total = counts.sum(axis=0)
+    # where a chunk's run of an id begins: in the whole order, and in the
+    # chunk's own
+    whole = (np.cumsum(total) - total)[None, :] \
+        + np.cumsum(counts, axis=0) - counts
+    own = np.cumsum(counts, axis=1) - counts
+    order = np.empty(n, np.int64)
+
+    def place(c):
+        rows, ids, _ = sorted_chunks[c]
+        order[np.arange(len(rows)) + (whole[c] - own[c])[ids]] = rows
+
+    from ._column_plan import run_tasks
+    run_tasks([(lambda c=c: place(c)) for c in range(len(chunks))], False)
+    return order
+
+
+def segment_bounds(dense: np.ndarray, n_out: int):
+    """([start, end) of every id's rows in the id-sorted order, int64):
+    `np.searchsorted(sorted ids, arange(n_out))` and `(.. + 1)` to the
+    bit, from the ids' counts."""
+    counts = np.bincount(dense, minlength=n_out)
+    ends = np.cumsum(counts, dtype=np.int64)
+    return ends - counts, ends
 
 
 def sort_als_triples(u32: np.ndarray, i32: np.ndarray, ratings: np.ndarray):
     """Per-side stable sort of the rating triples (host, once per fit —
     ids are static across iterations). Returns the four row arrays the
-    program will actually consume; callers pass THESE to the router so
+    program will actually consume, which callers pass to the router so
     residency probes and background promotion see the staged arrays, not
-    the unsorted originals."""
-    u_order = np.argsort(u32, kind="stable")
-    i_order = np.argsort(i32, kind="stable")
-    return {
-        "u_sorted": u32[u_order], "i_sorted": i32[i_order],
-        "ius": i32[u_order], "usi": u32[i_order],
-        "rat_u": ratings[u_order], "rat_i": ratings[i_order],
-    }
+    the unsorted originals, and each side's segment bounds in its order."""
+    n_users = int(u32.max()) + 1 if len(u32) else 0
+    n_items = int(i32.max()) + 1 if len(i32) else 0
+    u_order = stable_order(u32, n_users)
+    i_order = stable_order(i32, n_items)
+    gathers = (("ius", i32, u_order), ("rat_u", ratings, u_order),
+               ("usi", u32, i_order), ("rat_i", ratings, i_order))
+    out = {name: np.empty(len(ratings), a.dtype) for name, a, _ in gathers}
+
+    def gather(lo, hi):
+        for name, a, order in gathers:
+            np.take(a, order[lo:hi], out=out[name][lo:hi], mode="clip")
+    _pooled(gather, len(ratings))
+    out["u_bounds"] = segment_bounds(u32, n_users)
+    out["i_bounds"] = segment_bounds(i32, n_items)
+    return out
 
 
 def stage_als_sorted(prep: dict, n_users: int, n_items: int):
@@ -158,19 +404,19 @@ def stage_als_sorted(prep: dict, n_users: int, n_items: int):
     n_padded = meshlib.bucket_rows(n, n_dev)
     blk = n_padded // n_dev
 
-    def bounds_for(ids_sorted, n_out):
-        g_starts = np.searchsorted(ids_sorted, np.arange(n_out)) \
-            .astype(np.int64)
-        g_ends = np.searchsorted(ids_sorted, np.arange(n_out) + 1) \
-            .astype(np.int64)
+    def bounds_for(bounds, n_out):
+        # an id past the last one rated (the bounds stop at the largest
+        # id seen) has no row: [n, n)
+        g_starts, g_ends = (np.concatenate(
+            [b, np.full(n_out - len(b), n, np.int64)]) for b in bounds)
         lo = (np.arange(n_dev) * blk)[:, None]
         hi = lo + blk
         st = np.clip(g_starts[None, :], lo, hi) - lo
         en = np.clip(g_ends[None, :], lo, hi) - lo
         return np.stack([st, en], axis=1).astype(np.int32)  # (n_dev,2,n_out)
 
-    ub = bounds_for(prep["u_sorted"], n_users)
-    ib = bounds_for(prep["i_sorted"], n_items)
+    ub = bounds_for(prep["u_bounds"], n_users)
+    ib = bounds_for(prep["i_bounds"], n_items)
     return (stage_rows_cached(prep["ius"]),
             stage_rows_cached(prep["usi"]),
             stage_rows_cached(prep["rat_u"]),
@@ -214,7 +460,18 @@ class ALS(Estimator):
         return self.getOrDefault("itemCol")
 
     def _fit(self, df) -> "ALSModel":
-        pdf = df.toPandas()
+        """The fit's standard children under the root `fit`: `fit.collect`
+        (the three columns, from the frame's partitions where they lie),
+        `fit.featurize` (`.als.index`: raw ids to dense ids; `.als.sort`:
+        the two orders, the sorted row arrays and the bounds; then the
+        factors' init), and inside `program.als_fit` the four of every
+        program: `fit.stage`, `fit.dispatch`, `fit.device_wait`,
+        `fit.readback`."""
+        from ..parallel import dispatch
+        from ..parallel import mesh as meshlib
+        from ..utils.profiler import PROFILER
+        from ._column_plan import Pieces
+        from ._staging import cached_data_parallel, routed_for, transient_hbm
         uc, ic, rc = (self.getOrDefault("userCol"), self.getOrDefault("itemCol"),
                       self.getOrDefault("ratingCol"))
         rank = int(self.getOrDefault("rank"))
@@ -222,32 +479,23 @@ class ALS(Estimator):
         reg = float(self.getOrDefault("regParam"))
         seed = self.getOrDefault("seed")
         rng = np.random.default_rng(int(seed) if seed is not None else 0)
-
-        users_raw = np.asarray(pdf[uc])
-        items_raw = np.asarray(pdf[ic])
-        ratings = np.asarray(pdf[rc], dtype=np.float32)
-        u_ids, u_index = np.unique(users_raw, return_inverse=True)
-        i_ids, i_index = np.unique(items_raw, return_inverse=True)
-        U, I = len(u_ids), len(i_ids)
-
-        # stage rating triples sharded by row; normal-equation accumulation
-        # is nnz·rank² per half-step plus (U+I)·rank³ Cholesky solves
-        from ..parallel import dispatch
-        from ._staging import routed_for
-        u32 = u_index.astype(np.int32)
-        i32 = i_index.astype(np.int32)
-        _hint = dispatch.WorkHint(
-            flops=2.0 * max_iter * (len(ratings) * rank * rank
-                                    + (U + I) * rank ** 3),
-            kind="segment")
         nonneg = bool(self.getOrDefault("nonnegative"))
-        from ..utils.profiler import PROFILER
-        from ._staging import cached_data_parallel
-        prep = sort_als_triples(u32, i32, ratings)
-        with routed_for(_hint, prep["ius"], prep["usi"], prep["rat_u"],
-                        prep["rat_i"]) as _mesh:
-            staged = stage_als_sorted(prep, U, I)
 
+        with PROFILER.span("fit.collect") as note:
+            src = Pieces.of(df)
+            note["pieces"] = len(src.parts)
+            # these three columns alone are gathered: the frame's others
+            # never
+            users_raw, items_raw = (np.asarray(src.column(c))
+                                    for c in (uc, ic))
+            ratings = np.asarray(src.column(rc), dtype=np.float32)
+        with PROFILER.span("fit.featurize", rows=len(ratings)):
+            with PROFILER.span("fit.featurize.als.index"):
+                u_ids, u32 = dense_ids(users_raw)
+                i_ids, i32 = dense_ids(items_raw)
+            U, I = len(u_ids), len(i_ids)
+            with PROFILER.span("fit.featurize.als.sort"):
+                prep = sort_als_triples(u32, i32, ratings)
             # MLlib-style init: |N(0,1)| rows normalized to unit norm
             # (ALS.scala initialize). r4's small signed init (0.1·N) sat
             # near the zero saddle: on ~25% of course-scale subsets the
@@ -258,18 +506,51 @@ class ALS(Estimator):
             if0 = np.abs(rng.standard_normal((I, rank))).astype(np.float32)
             uf0 /= np.linalg.norm(uf0, axis=1, keepdims=True) + 1e-12
             if0 /= np.linalg.norm(if0, axis=1, keepdims=True) + 1e-12
+            # the program's shapes are the entity counts on `bucket_rows`'
+            # grid (at most an eighth more, rows of zeros that no rating
+            # names and whose solution is 0): a new split of the same
+            # table that rates a few items more or fewer is the SAME
+            # compiled program
+            U_pad, I_pad = (meshlib.bucket_rows(k, 1) for k in (U, I))
+            uf0 = np.pad(uf0, ((0, U_pad - U), (0, 0)))
+            if0 = np.pad(if0, ((0, I_pad - I), (0, 0)))
 
-            fit = cached_data_parallel(
-                _als_fit_program(U, I, rank, reg, max_iter, nonneg),
-                replicated_argnums=(6, 7))
+        # rating triples staged sharded by row; the build is nnz·rank² a
+        # half-step and the (U + I) Cholesky solves rank³ / 3 each
+        _hint = dispatch.WorkHint(
+            flops=2.0 * max_iter * (len(ratings) * rank * rank
+                                    + (U + I) * rank ** 3 / 3.0),
+            kind="segment")
+        rows = (prep["ius"], prep["usi"], prep["rat_u"], prep["rat_i"])
+        with routed_for(_hint, *rows) as _mesh:
             _route = "host" if dispatch.is_host_mesh(_mesh) else "device"
             with PROFILER.span("program.als_fit", rows=len(ratings),
                                route=_route):
-                # ONE dispatch for the whole alternating fit; one batched
-                # device→host transfer for both factor matrices
-                uf_h, itf_h = jax.device_get(fit(*staged, uf0, if0))
+                with PROFILER.span("fit.stage", rows=len(ratings)):
+                    staged = stage_als_sorted(prep, U_pad, I_pad)
+                shard = staged[0].shape[0] // meshlib.data_width(_mesh)
+                block = min(_block_rows(rank), shard)
+                blocks = -(-shard // block)
+                with transient_hbm("als_block", _block_bytes(rank, block)):
+                    # ONE dispatch for the whole alternating fit; one
+                    # batched device→host transfer for both factor matrices
+                    with PROFILER.span("fit.dispatch"):
+                        fit = cached_data_parallel(
+                            _als_fit_program(U_pad, I_pad, rank, reg,
+                                             max_iter, nonneg, block),
+                            replicated_argnums=(6, 7))
+                        out = fit(*staged, uf0, if0)
+                    with PROFILER.span("fit.device_wait"):
+                        out = jax.block_until_ready(out)
+                with PROFILER.span("fit.readback"):
+                    uf_h, itf_h, steps = jax.device_get(out)
+        PROFILER.count("staging.d2h_bytes", uf_h.nbytes + itf_h.nbytes)
+        PROFILER.count("als.fits")
+        PROFILER.count("als.half_steps", int(steps))
+        PROFILER.count("als.blocks", blocks)
+        PROFILER.count("als.ratings", len(ratings))
         m = ALSModel(user_ids=u_ids, item_ids=i_ids,
-                     user_factors=uf_h, item_factors=itf_h)
+                     user_factors=uf_h[:U], item_factors=itf_h[:I])
         m._inherit_params(self)
         return m
 
@@ -317,6 +598,7 @@ class ALSModel(Model):
         return idx, known
 
     def _transform(self, df):
+        from ..utils.profiler import PROFILER
         uc, ic = self.getOrDefault("userCol"), self.getOrDefault("itemCol")
         oc = self.getOrDefault("predictionCol")
         cold = self.getOrDefault("coldStartStrategy")
@@ -326,13 +608,17 @@ class ALSModel(Model):
             if len(out) == 0:
                 out[oc] = pd.Series(dtype=float)
                 return out
-            ui, u_ok = self._lookup(np.asarray(out[uc]), self._user_ids, self._uf)
-            ii, i_ok = self._lookup(np.asarray(out[ic]), self._item_ids, self._if)
-            pred = np.einsum("ij,ij->i", self._uf[ui], self._if[ii])
-            pred = np.where(u_ok & i_ok, pred, np.nan)
+            with PROFILER.span("transform.als.lookup", rows=len(out)):
+                ui, u_ok = self._lookup(np.asarray(out[uc]), self._user_ids, self._uf)
+                ii, i_ok = self._lookup(np.asarray(out[ic]), self._item_ids, self._if)
+                pred = np.einsum("ij,ij->i", self._uf[ui], self._if[ii])
+                pred = np.where(u_ok & i_ok, pred, np.nan)
             out[oc] = pred.astype(np.float64)
             if cold == "drop":
-                out = out[np.isfinite(out[oc])].reset_index(drop=True)
+                keep = np.isfinite(out[oc])
+                PROFILER.count("als.cold_start.dropped",
+                               int(len(out) - keep.sum()))
+                out = out[keep].reset_index(drop=True)
             return out
 
         return df._derive(fn)

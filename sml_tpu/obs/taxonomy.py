@@ -38,8 +38,12 @@ SPANS = {
     # fit.summary (the logistic training summary's host pass); inside
     # the `fit.featurize` spans their children fit.featurize.plan.jobs /
     # .plan.block (the column plan), .extract and .missing (the two block
-    # copies of a tree fit)
+    # copies of a tree fit), .als.index and .als.sort (a factorization's
+    # dense ids, and its two sorted orders with their bounds)
     "fit", "fit.*",
+    # ALSModel.transform's look-up of a partition's users and movies and
+    # their factors' dot products (ml/recommendation.py)
+    "transform.als.lookup",
     # the staging functions' steps for an array of at least 1 MiB
     # (ml/_staging.py `_SPAN_BYTES`; shared with scoring and serving, so
     # named for the function): stage.key (normalize + content key + cache
@@ -94,6 +98,15 @@ COUNTERS = {
     # frames it made (splits and unions; 0 where the folds are a mask over
     # one staged block)
     "cv.fits", "cv.evals", "cv.fold_frames",
+    # a factorization's fit (ml/recommendation.py ALS._fit): fits /
+    # half-steps the ONE dispatch ran, read back with the factors (2 x
+    # maxIter) / blocks of rows a half-step walks a shard's sorted order
+    # in (`_block_rows`) / training ratings; and rows ALSModel.transform
+    # dropped under coldStartStrategy="drop". On the device the
+    # `jax.named_scope`s als.gather / als.normal (with
+    # als.normal.allreduce inside it) / als.solve
+    "als.fits", "als.half_steps", "als.blocks", "als.ratings",
+    "als.cold_start.dropped",
     # Pallas launches of the traversal kernel (native/traverse_kernel.py,
     # docs/KERNELS.md): TRACE-TIME statics (counted once per program
     # trace, like collective.*: launches per execution = the count ×
