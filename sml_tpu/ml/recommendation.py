@@ -10,8 +10,10 @@ iterations), each half-step inside it:
     per chip:  the normal equations by ROW BLOCKS of the entity-sorted
                order: gather the other side's factor rows of a block,
                form its statistics (the upper triangle of f_i ⊗ f_i, and
-               r·f_i), sum them by segment in a scan that begins anew at
-               each entity, add a block's sums into the accumulators
+               r·f_i), sum them by segment, sums that begin anew at each
+               entity: ONE masked matrix product a tile of 128 rows, the
+               tiles' last rows summed the same way a level up
+               (`_segment_sums`); add a block's sums into the accumulators
     psum       over ICI (the factor-block exchange)
     batched    Cholesky solve of all the normal systems on-device
 
@@ -35,10 +37,14 @@ from ..parallel import collectives as coll
 from .base import Estimator, Model, load_arrays, save_arrays
 
 #: a dispatch's block temporaries may take this share of the device's
-#: memory (`_block_rows`): the block's statistics and the copies of them
-#: that the log-depth scan keeps alive, `_BLOCK_COPIES` arrays of a row's
-#: statistics padded to the chip's 128 lanes (read from the compiled
-#: program's memory analysis at rank 12: PERF.md section 4)
+#: memory (`_block_rows`): the block's statistics and their running sums
+#: (`_segment_sums`), counted as `_BLOCK_COPIES` arrays of a row's
+#: statistics padded to the chip's 128 lanes. Read from the compiled
+#: program's memory analysis at rank 12 and blocks of 2^21 rows: 2.21 GB,
+#: 2.06 such arrays (the statistics a tile at a time with the rows along
+#: the lanes, 0.81 GB; their running sums row by row, 1.07 GB; the
+#: gathered factor rows), rounded up: the scan this replaced held 3.46 GB
+#: (PERF.md sections 4 and 6)
 _BLOCK_SHARE = 0.25
 _BLOCK_COPIES = 3
 _LANES = 128
@@ -75,52 +81,110 @@ def _stat_operands(f, rat):
     return f, rat
 
 
-#: rows of a tile of `_segment_sums`: the chip's sublanes
-_TILE = 8
+#: rows of a tile of `_segment_sums`: a side of the chip's matrix unit (and
+#: the lanes of a vector register: a tile's mask is whole registers)
+_TILE = 128
+
+
+def _tiles(stats, begins):
+    """`stats` (rows, width) and `begins` (rows,) as tiles of `_TILE` rows
+    (one tile of a block no longer than that): (tiles, tile, width) and
+    (tiles, tile), the last tile filled with rows of zeros that begin
+    nothing, past every row an entity's bounds reach."""
+    rows, width = stats.shape
+    tile = min(_TILE, rows)
+    pad = -rows % tile
+    if pad:
+        stats = jnp.pad(stats, ((0, pad), (0, 0)))
+        begins = jnp.pad(begins, (0, pad))
+    return stats.reshape(-1, tile, width), begins.reshape(-1, tile)
+
+
+def _tile_sums(x, flags):
+    """(run, open) of tiles `x` (tiles, tile, width) whose rows `flags`
+    (tiles, tile) begin a segment: `run[n, r]` the sum of the tile's rows
+    from the last flagged one at or before r (the tile's first where
+    `open[n, r]`: none is) to r. ONE masked product a tile: with `seg` the
+    running count of the flags down a tile, `M[n, r, c]` is 1 for the rows
+    c of r's own segment up to r and 0 for every other, so a sum holds its
+    own rows alone (a 0 multiplies the rest: no prefix spans two segments,
+    and no difference of prefixes is taken), the mask is exact in every
+    pass of the matrix unit and the accumulator is float32. The mask is
+    made from the flags where the product reads it and is never kept."""
+    at = jnp.arange(x.shape[1])
+    seg = jnp.cumsum(flags, axis=1, dtype=jnp.int32)
+    mask = (seg[:, :, None] == seg[:, None, :]) & (at <= at[:, None])
+    run = jnp.einsum("nrc,ncw->nrw", mask.astype(x.dtype), x,
+                     precision=jax.lax.Precision.HIGHEST)
+    return run, seg == 0
+
+
+def _carries(run, flags):
+    """What the tiles before it hand every tile, (tiles, width): the sum
+    from the last flagged row before the tile (the first row where none
+    is) to the tile's first row, 0 for the first tile. A tile hands on its
+    LAST row's running sum and whether it holds a flag: a row a tile, so
+    the level above is the same sums over 1/`_TILE` of the rows. Those rows
+    are gathered: a strided slice of them reads the whole level."""
+    tiles, tile, width = run.shape
+    last = run.reshape(-1, width)[(jnp.arange(tiles) + 1) * tile - 1]
+    held = _running_sums(last, flags.any(axis=1))
+    return jnp.concatenate([jnp.zeros_like(held[:1]), held[:-1]])
+
+
+def _running_sums(stats, begins):
+    """Inclusive running sums of `stats` down axis 0 that begin anew at
+    every row `begins` flags, every row whole: a tile's rows before its
+    first flagged one take what the tiles before it carry. For the levels
+    above the first, where a pass over every row costs nothing."""
+    rows, width = stats.shape
+    x, flags = _tiles(stats, begins)
+    run, open_ = _tile_sums(x, flags)
+    if x.shape[0] > 1:
+        run = run + jnp.where(open_[:, :, None],
+                              _carries(run, flags)[:, None, :], 0.0)
+    return run.reshape(-1, width)[:rows]
 
 
 def _segment_sums(stats, begins):
-    """Inclusive running sums of `stats` down axis 0 that begin anew at
-    every row `begins` flags: the row a segment ends on holds the
-    segment's sum. No prefix ever spans two segments, so a sum carries the
+    """(run, carry) of a block's `stats` (rows, width) whose rows `begins`
+    flags begin an entity's segment: the running sums in levels of radix
+    `_TILE`. `run[i]` is row i's sum WITHIN ITS TILE (`_tile_sums`) and
+    `carry[n]` what the rows before tile n hand it (`_carries`), so the
+    sum from a segment's first row s to its row i is `run[i]`, plus
+    `carry[i // tile]` where s lies before i's tile: the row a segment ends
+    on holds the segment's sum, and no pass fixes up every row of the
+    first level. No prefix ever spans two segments, so a sum carries the
     rounding of its own rows alone (a difference of two table-long
     prefixes carried ~4 % median error at MovieLens-25M scale in plain
     float32 — r4 review — and took a double-single pair of them to mend).
-
-    Two levels: the `_TILE` rows of a tile are summed one after another
-    (each step a whole plane of one row a tile), a log-depth associative
-    scan carries the tiles' sums, an eighth of the rows, and a tile's rows
-    before its first flagged one take what the tiles before it carry."""
-
-    def combine(a, b):
-        fa, va = a
-        fb, vb = b
-        return fa | fb, jnp.where(fb, vb, va + vb)
-
-    rows, width = stats.shape
-    if rows % _TILE:
-        return jax.lax.associative_scan(
-            combine, (begins[:, None], stats), axis=0)[1]
-    x = stats.reshape(rows // _TILE, _TILE, width)
-    b = begins.reshape(rows // _TILE, _TILE)
-    run, seen = [x[:, 0]], [b[:, 0]]
-    for j in range(1, _TILE):
-        run.append(jnp.where(b[:, j, None], x[:, j], run[-1] + x[:, j]))
-        seen.append(seen[-1] | b[:, j])
-    _, carried = jax.lax.associative_scan(
-        combine, (seen[-1][:, None], run[-1]), axis=0)
-    before = jnp.concatenate([jnp.zeros_like(carried[:1]), carried[:-1]])
-    out = [jnp.where(f[:, None], r, r + before) for f, r in zip(seen, run)]
-    return jnp.stack(out, axis=1).reshape(rows, width)
+    `run` holds whole tiles: `rows` rounded up."""
+    width = stats.shape[1]
+    with jax.named_scope("als.normal.tiles"):
+        x, flags = _tiles(stats, begins)
+        run, _ = _tile_sums(x, flags)
+        flat = run.reshape(-1, width)
+    with jax.named_scope("als.normal.carry"):
+        carry = _carries(run, flags) if x.shape[0] > 1 \
+            else jnp.zeros((1, width), run.dtype)
+    return flat, carry
 
 
 def _block_sums(stats, begins, s, t):
     """Every entity's sum of `stats` over its rows [s, t) of one block
-    (none where t == s): the running sum at the last of them. The seam
-    where `benchmark/tools_als.py` puts a plain float32 prefix and its
-    boundary difference for the control."""
-    run = _segment_sums(stats, begins)
-    return jnp.where((t > s)[:, None], run[jnp.maximum(t - 1, 0)], 0.0)
+    (none where t == s): the running sum at the last of them, with what
+    the tiles before that row's tile carry where the segment began before
+    it; gathers of `entities` rows. The seam where
+    `benchmark/tools_als.py` puts a plain float32 prefix and its boundary
+    difference for the control."""
+    run, carry = _segment_sums(stats, begins)
+    tile = run.shape[0] // carry.shape[0]
+    with jax.named_scope("als.normal.carry"):
+        last = jnp.maximum(t - 1, 0)
+        at = last // tile                   # the tile of an entity's last row
+        total = run[last] + jnp.where((s < at * tile)[:, None],
+                                      carry[at], 0.0)
+        return jnp.where((t > s)[:, None], total, 0.0)
 
 
 def _cholesky_solve(A, b):
@@ -220,18 +284,29 @@ def _als_fit_program(n_users: int, n_items: int, rank: int, reg: float,
     def half(other, ids, rat, begins, block, bounds, n_out):
         starts, ends = bounds[0], bounds[1]
 
+        tile = min(_TILE, block)
+
+        def rows_of(a, lo):
+            """A block's rows of `a`, filled to whole tiles."""
+            return jnp.pad(jax.lax.dynamic_slice(a, (lo,), (block,)),
+                           (0, -block % tile))
+
         def one_block(k, acc):
             lo = k * block
             with jax.named_scope("als.gather"):
-                f = other[jax.lax.dynamic_slice(ids, (lo,), (block,))]
+                f = other[rows_of(ids, lo)]
             with jax.named_scope("als.normal"):
-                f, r = _stat_operands(
-                    f, jax.lax.dynamic_slice(rat, (lo,), (block,)))
-                stats = jnp.dot(f, left, precision=select) * jnp.dot(
-                    jnp.concatenate([f, r[:, None]], axis=1), right,
-                    precision=select)
+                f, r = _stat_operands(f, rows_of(rat, lo))
+                # a tile at a time, as `_segment_sums` reads them: the
+                # compiler then lays a tile's statistics out once, for the
+                # two selections and the tile's product alike
+                f = f.reshape(-1, tile, rank)
+                fr = jnp.concatenate(
+                    [f, r.reshape(-1, tile, 1)], axis=2)
+                stats = jnp.einsum("nck,kw->ncw", f, left, precision=select) \
+                    * jnp.einsum("nck,kw->ncw", fr, right, precision=select)
                 return acc + _block_sums(
-                    stats, jax.lax.dynamic_slice(begins, (lo,), (block,)),
+                    stats.reshape(-1, width), rows_of(begins, lo),
                     jnp.clip(starts - lo, 0, block),
                     jnp.clip(ends - lo, 0, block))
 

@@ -103,8 +103,10 @@ COUNTERS = {
     # maxIter) / blocks of rows a half-step walks a shard's sorted order
     # in (`_block_rows`) / training ratings; and rows ALSModel.transform
     # dropped under coldStartStrategy="drop". On the device the
-    # `jax.named_scope`s als.gather / als.normal (with
-    # als.normal.allreduce inside it) / als.solve
+    # `jax.named_scope`s als.gather / als.normal (inside it
+    # als.normal.tiles: a block's masked product a tile;
+    # als.normal.carry: the levels above and the entities' gathers;
+    # als.normal.allreduce) / als.solve
     "als.fits", "als.half_steps", "als.blocks", "als.ratings",
     "als.cold_start.dropped",
     # Pallas launches of the traversal kernel (native/traverse_kernel.py,

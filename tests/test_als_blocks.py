@@ -194,6 +194,87 @@ def test_the_blocks_rows_follow_the_devices_memory(monkeypatch):
     assert R._block_rows(16) == 1 << 16
 
 
+# ------------------------------------------- the segment sums, alone
+T = R._TILE
+
+
+def _lengths(rows, rng):
+    """Skewed segment lengths that fill `rows` rows: ones beside
+    thousands."""
+    out = []
+    while rows:
+        out.append(min(rows, int(rng.zipf(1.3)) % 3000 + 1))
+        rows -= out[-1]
+    return out
+
+
+#: name -> (rows of the block, rows before the first flagged one: a
+#: segment an earlier block began, the segments' lengths; None: skewed)
+SEGMENTS = {
+    "every_row_its_own": (1000, 0, [1] * 1000),
+    "one_segment_the_whole_block": (2 * T * T + T, 0, [2 * T * T + T]),
+    "one_segment_of_a_short_block": (96, 0, [96]),
+    "begins_mid_segment": (T * T + 5 * T, T + 3, [3, T, T * T - 1, 7]),
+    "begins_mid_segment_past_a_level": (2 * T * T, T * T + 1, [T * T - 1]),
+    "ends_on_and_past_a_tiles_end": (
+        6 * T, 0, [T, T + 1, T - 1, T, 1, 2 * T - 1]),
+    "ends_on_and_past_a_second_level_tiles_end": (
+        2 * T * T + 2 * T, 0, [T * T, T * T + 1, T - 1, T]),
+    "ends_one_row_short_of_a_second_level_tile": (
+        T * T + T, 0, [T * T - 1, 1, T]),
+    "rows_64": (64, 0, None), "rows_96": (96, 0, None),
+    "rows_1000": (1000, 0, None), "rows_16512": ((1 << 14) + 128, 0, None),
+    "rows_64_mid_segment": (64, 9, None),
+    "rows_1000_mid_segment": (1000, 130, None),
+    "rows_16512_mid_segment": ((1 << 14) + 128, 300, None),
+}
+
+
+@pytest.mark.parametrize("name", list(SEGMENTS))
+def test_the_segment_sums_are_a_float64_loops(name):
+    """`_block_sums` (every entity's sum) and `_segment_sums` (every ROW's
+    running sum, read as `_block_sums` reads it) against a float64 loop
+    over the segments, rows of magnitudes four decades apart: the gap
+    under 1e-6 of the segment's OWN magnitude sum, which a prefix that
+    spans segments cannot hold (the controls below)."""
+    rows, lead, lens = SEGMENTS[name]
+    rng = np.random.default_rng(len(name) + rows)
+    lens = lens or _lengths(rows - lead, rng)
+    assert lead + sum(lens) <= rows
+    s = lead + np.cumsum([0] + lens[:-1])
+    t = s + np.asarray(lens)
+    x = (rng.standard_normal((rows, 7))
+         * np.exp(2 * rng.standard_normal((rows, 1)))).astype(np.float32)
+    begins = np.zeros(rows, bool)
+    begins[s] = True
+    if lead:                      # the segment an earlier block began
+        s, t = np.concatenate([[0], s]), np.concatenate([[lead], t])
+    # two entities more with no row in the block, before and after
+    s, t = (np.concatenate([[0], b, [rows]]) for b in (s, t))
+    s[0] = t[0] = 0
+    got = np.asarray(jax.jit(R._block_sums)(
+        x, begins, s.astype(np.int32), t.astype(np.int32)))
+    assert not got[0].any() and not got[-1].any()
+    x64 = x.astype(np.float64)
+    for a, b, row in zip(s[1:-1], t[1:-1], got[1:-1]):
+        gap = np.abs(row - x64[a:b].sum(0)) / np.abs(x64[a:b]).sum(0)
+        assert gap.max() < 1e-6, (name, a, b, gap.max())
+    # every row: its running sum from its segment's first row
+    run, carry = map(np.asarray, jax.jit(R._segment_sums)(x, begins))
+    tile = min(T, rows)
+    assert run.shape == (-(-rows // tile) * tile, 7)
+    assert carry.shape == (-(-rows // tile), 7) and not carry[0].any()
+    first = np.repeat(s[1:-1], t[1:-1] - s[1:-1])       # a row's segment's
+    at = np.arange(first.size) + s[1]
+    whole = run[at] + np.where((first < at // tile * tile)[:, None],
+                               carry[at // tile], 0.0)
+    want = np.cumsum(x64[s[1]:], axis=0)[:first.size]
+    want -= np.concatenate([np.zeros((1, 7)), want])[first - s[1]]
+    size = np.cumsum(np.abs(x64[s[1]:]), axis=0)[:first.size]
+    size -= np.concatenate([np.zeros((1, 7)), size])[first - s[1]]
+    assert (np.abs(whole - want) / size).max() < 1e-6, name
+
+
 # ------------------------------------------------------------ the controls
 @pytest.fixture(scope="module")
 def tools():

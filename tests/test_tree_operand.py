@@ -431,6 +431,57 @@ def test_the_fused_logistic_fit_leaves_its_loop_on_done(one_chip_mesh):
     assert "s32[]" in condition and "constant(100)" in condition, condition
 
 
+def test_a_blocks_segment_sums_are_a_tile_product_on_a_v5e(one_chip_mesh):
+    """The factorization's fit program (ISSUE 43) compiled for the chip at
+    blocks of 2^18 rows, rank 12: the loop over blocks holds a matrix
+    product under `als.normal.tiles`, no array is shaped (tiles, 1, width)
+    (the one-sublane layout of the scan it replaced), no loop is longer
+    than a block's tiles, and the temporaries are no more than the
+    program's before it at the same block (306,711,040 B: commit 8156e75
+    compiled the same way). In this file because one worker holds libtpu
+    (the fixture above); the sums themselves are held on the CPU in
+    `tests/test_als_blocks.py`."""
+    from sml_tpu.ml import recommendation
+    rows, block, rank, users, items = 1 << 20, 1 << 18, 12, 4096, 2048
+    width = recommendation._stat_width(rank)
+    fn = recommendation._als_fit_program(users, items, rank, 0.1, 2, False,
+                                         block)
+    row, bounds = P(D), P(D, None, None)
+    specs = (row, row, row, row, bounds, bounds, P(), P())
+    mapped = meshlib.shard_map_compat(fn, mesh=one_chip_mesh, in_specs=specs,
+                                      out_specs=P())
+    shapes = [jax.ShapeDtypeStruct(shape, dtype,
+                                   sharding=NamedSharding(one_chip_mesh, sp))
+              for (shape, dtype), sp in zip(
+                  (((rows,), jnp.int32), ((rows,), jnp.int32),
+                   ((rows,), jnp.float32), ((rows,), jnp.float32),
+                   ((1, 2, users), jnp.int32), ((1, 2, items), jnp.int32),
+                   ((users, rank), jnp.float32),
+                   ((items, rank), jnp.float32)), specs)]
+    # every loop of the program is a scan of a static length: the
+    # alternations, a side's blocks, the Cholesky's columns
+    eqns = [e for e, _ in _walk(jax.make_jaxpr(mapped)(*shapes).jaxpr)]
+    assert not [e for e in eqns if e.primitive.name == "while"]
+    lengths = [e.params["length"] for e in eqns if e.primitive.name == "scan"]
+    assert rows // block in lengths
+    assert max(lengths) <= block // recommendation._TILE, lengths
+    compiled = jax.jit(mapped).lower(*shapes).compile()
+    hlo = compiled.as_text()
+    in_loops = set(tree_impl.ops_in_loop_bodies(hlo, "als.normal.tiles"))
+    products = [ln.split(" = ", 1)[0].strip().removeprefix("ROOT ")
+                for ln in hlo.splitlines() if "als.normal.tiles" in ln
+                and re.search(r"\s(convolution|dot)\(", ln)]
+    assert products and set(products) <= in_loops, products
+    assert f"f32[{block // recommendation._TILE},{recommendation._TILE}," \
+        f"{width}]" in hlo
+    assert not re.search(rf"f32\[\d+,1,{width}\]", hlo)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= 306_711_040, temporaries
+    for scope in ("als.gather", "als.normal.tiles", "als.normal.carry",
+                  "als.normal.allreduce", "als.solve"):
+        assert scope in hlo, scope
+
+
 def test_ops_in_loop_bodies_follows_calls():
     hlo = """HloModule m
 %fused (p: s32[4]) -> s32[4] {
