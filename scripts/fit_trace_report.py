@@ -22,8 +22,9 @@ first), then, from the same process's recorder ring and the run's
    `fit.featurize.*` four, the staging steps `stage.key` / `.pad` / `.put`)
    and what a span notes in numbers as `<name> [<note>]` (the process's
    `cpu_s`, the slowest column job's `longest_s`, a staging
-   step's `bytes`, `copied`, `hit`): fits 1-3 against the rest (which phase
-   is still warming);
+   step's `bytes`, `copied`, `hit`, and `stage.pad [warm share]`: the share
+   of a fit's pad steps written into the pad pool's warm pages): fits 1-3
+   against the rest (which phase is still warming);
 3. the one-clock check extended to the transfer: for every `fit.stage` of
    the window, the end of the fit's last host-to-device event on the trace's
    clock minus the end of its last `stage.put` span (positive: `device_put`
@@ -199,15 +200,20 @@ def fits_by_phase(events, since_s: float):
         mine = [e for e in spans if e.args["trace"] == root.args["trace"]]
         row = {"fit": root.dur}
         direct = 0.0
+        pads = sum(1 for e in mine if "warm" in e.args)
         for e in mine:
             # what a span notes beside its seconds: the process's CPU
             # seconds (`CPU_SPANS`), the slowest job and, for a staging
-            # step, its bytes and how often it had to copy the caller's
-            # array or found it cached
+            # step, its bytes, how often it had to copy the caller's
+            # array or found it cached and, of the pads, the share written
+            # into warm pages
             for note in ("cpu_s", "longest_s", "bytes", "copied", "hit"):
                 if note in e.args:
                     key = f"{e.name} [{note}]"
                     row[key] = row.get(key, 0.0) + e.args[note]
+            if e.args.get("warm"):
+                key = f"{e.name} [warm share]"
+                row[key] = row.get(key, 0.0) + 1.0 / pads
             if e is root:
                 continue
             row[e.name] = row.get(e.name, 0.0) + e.dur

@@ -12,6 +12,8 @@ replacement for Spark's executor→driver `treeAggregate`
 from __future__ import annotations
 
 import contextlib
+import math
+import weakref
 from functools import partial
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -162,20 +164,158 @@ def _keyed(a, tag: tuple, probe: Callable = _stage_cache.get):
     return a, key, hit
 
 
-def _padded_rows(a: np.ndarray, rows: int, axis: int = 0) -> np.ndarray:
-    """The `stage.pad` step: `a` with zero rows appended along `axis` up
-    to `rows`, the bucketed count of its own (`a` itself, and no span,
-    where it has them already or has no row at all, as `mesh.pad_rows`
-    leaves such an array). Notes the `bytes` of the padded copy."""
+#: host bytes of FREE warm pad buffers the pool may keep: one fit's set of
+#: the largest deployment (0.63 GB: a compact block of 8 M rows, its
+#: numeric columns, labels and mask) and a second buffer of its smaller sizes
+_PAD_POOL_MAX_BYTES = 1 << 30
+
+
+def _nothing_placed():
+    return None
+
+
+class _PadPool:
+    """Host buffers a padded copy is written into, kept from one staging to
+    the next, because the price of a pad is not the copy but the first
+    touch of the pages of a fresh allocation (0.92 GB/s into untouched
+    pages against 11.4 GB/s into pages written before, PERF.md). Keyed by
+    bytes alone: a buffer is a flat uint8 array that `view` gives the
+    shape and dtype of the hour, so every split of a table (one bucketed
+    shape, `mesh.bucket_rows`) finds the buffers of the split before.
+
+    It holds FREE buffers only, least recently used first, each with a weak
+    reference to the array last put from it: `device_put` returns before
+    the host buffer has been read, so a buffer is handed out again only
+    once that array is ready (or gone). A buffer taken and never given back
+    (a put that raised) is the garbage collector's."""
+
+    def __init__(self, max_bytes: int):
+        self.max_bytes = max_bytes
+        self._free: list = []     # (flat buffer, () -> the array placed | None)
+        self._bytes = 0
+
+    def take(self, nbytes: int) -> Tuple[np.ndarray, bool]:
+        """(a flat buffer of `nbytes`, whether its pages are warm). The
+        longest-free buffer of that size whose transfer is over; where the
+        only ones are still being read, a fresh one if the pool has room to
+        keep both (two arrays of one shape staged back to back), else the
+        oldest, after its transfer."""
+        with _stage_lock:
+            sized = [(i, placed()) for i, (buf, placed)
+                     in enumerate(self._free) if buf.nbytes == nbytes]
+            at, placed = next((s for s in sized
+                               if s[1] is None or s[1].is_ready()),
+                              (None, None))
+            if at is None and sized \
+                    and self._bytes + nbytes > self.max_bytes:
+                at, placed = sized[0]
+            if at is None:
+                return np.empty(nbytes, np.uint8), False
+            buf = self._free.pop(at)[0]
+            self._bytes -= nbytes
+        if placed is not None:
+            # graftlint: disable=host-sync-in-hot-path -- the host buffer may be written only once the transfer out of it is over; by the next fit the array is ready and this returns at once
+            placed.block_until_ready()
+        return buf, True
+
+    def give_back(self, buf: np.ndarray, placed=None) -> None:
+        """`buf` is free for the next pad of its size once `placed`, the
+        array put from it (None: nothing was), is ready. Over the bound the
+        least recently used go; their pages live as long as a transfer
+        reads them (the runtime holds the array it was given)."""
+        if buf.nbytes > self.max_bytes:
+            return
+        ref = _nothing_placed if placed is None else weakref.ref(placed)
+        with _stage_lock:
+            self._free.append((buf, ref))
+            self._bytes += buf.nbytes
+            while self._bytes > self.max_bytes:
+                self._bytes -= self._free.pop(0)[0].nbytes
+
+    def stats(self) -> dict:
+        """(buffers, bytes) snapshot: test/debug surface."""
+        with _stage_lock:
+            return {"buffers": len(self._free), "bytes": self._bytes}
+
+    def clear(self) -> None:
+        with _stage_lock:
+            self._free.clear()
+            self._bytes = 0
+
+
+_PAD_POOL = _PadPool(_PAD_POOL_MAX_BYTES)
+
+
+def _aliases_host(mesh) -> bool:
+    """Whether an array placed on `mesh` may BE the host buffer it was put
+    from. The CPU client takes an aligned NumPy array (and each aligned
+    shard of one) without a copy: the "device" array the staging cache then
+    keeps is the host memory, and a pad written over it at the next staging
+    would change a cached array under its content key. So a padded copy for
+    such a mesh (the tests' virtual one, the host route's) is never
+    pooled."""
+    return mesh.devices.flat[0].platform == "cpu"
+
+
+def _zero_tailed(shape: tuple, dtype, axis: int, lead, n_lead: int,
+                 nbytes_in: int, mesh):
+    """The `stage.pad` step: (an array of `shape` that is `lead` in its
+    first `n_lead` entries along `axis` and zero after them, the pad pool's
+    buffer it lies in, for `_put` to give back). A fresh allocation and
+    None, as `np.pad` makes it, where the step is over less than
+    `_SPAN_BYTES` (scoring and serving stage small batches on many threads:
+    no fresh-page cost worth a buffer, and nothing to queue on) or the
+    array placed on `mesh` may alias the host. The tail is always written:
+    a pooled buffer held another split's rows before. Notes the `bytes` of
+    the array and whether its pages were `warm`."""
+    from ..utils.profiler import PROFILER
+    nbytes = math.prod(shape) * np.dtype(dtype).itemsize
+    spanned = nbytes_in >= _SPAN_BYTES
+    with staging_step("pad", nbytes_in) as note:
+        buf, warm = _PAD_POOL.take(nbytes) \
+            if spanned and not _aliases_host(mesh) else (None, False)
+        out = np.empty(shape, dtype) if buf is None \
+            else buf.view(dtype).reshape(shape)
+        at = [slice(None)] * len(shape)
+        at[axis] = slice(0, n_lead)
+        out[tuple(at)] = lead
+        at[axis] = slice(n_lead, None)
+        out[tuple(at)] = 0
+        note["bytes"] = nbytes
+        note["warm"] = warm
+    if spanned and warm:
+        PROFILER.count("staging.pad_warm")
+    elif spanned:
+        PROFILER.count("staging.pad_fresh")
+    return out, buf
+
+
+def _padded_rows(a: np.ndarray, rows: int, mesh, axis: int = 0, dtype=None):
+    """(`a`, as `dtype` where one is given, with zero rows appended along
+    `axis` up to `rows`, the bucketed count of its own; the pad pool's
+    buffer or None): `_zero_tailed`'s pad step. `a` itself, and no span,
+    where it has the rows and the type already or has no row at all, as
+    `mesh.pad_rows` leaves such an array."""
     pad = (-a.shape[axis]) % rows
-    if not pad:
-        return a
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, pad)
-    with staging_step("pad", a.nbytes) as note:
-        padded = np.pad(a, widths)
-        note["bytes"] = int(padded.nbytes)
-    return padded
+    dtype = np.dtype(dtype or a.dtype)
+    if not pad and dtype == a.dtype:
+        return a, None
+    shape = list(a.shape)
+    shape[axis] += pad
+    return _zero_tailed(tuple(shape), dtype, axis, a, a.shape[axis],
+                        a.nbytes, mesh)
+
+
+def _put(padded: np.ndarray, sharding, buf: Optional[np.ndarray] = None):
+    """The `stage.put` step: `padded` placed as `sharding` says. The call
+    returns before the host memory has been read, so the pad pool's buffer
+    `padded` lies in (`buf`) goes back with the array to wait for."""
+    with staging_step("put", padded.nbytes):
+        # graftlint: disable=unsharded-device-put -- `sharding` is the caller's NamedSharding over the active mesh, every site's
+        hit = jax.device_put(padded, sharding)
+    if buf is not None:
+        _PAD_POOL.give_back(buf, hit)
+    return hit
 
 
 _CKSUM_CHUNK = 1 << 20  # words per block (8MB) — bounds the arange temp
@@ -351,9 +491,8 @@ def stage_bins_cached(binned: np.ndarray) -> jax.Array:
         PROFILER.count("staging.bin_cache_hit")
         PROFILER.count("staging.h2d_bytes_saved", a.nbytes)
         return hit
-    padded = _padded_rows(a, meshlib.bucket_rows(a.shape[0], n_dev))
-    with staging_step("put", padded.nbytes):
-        hit = jax.device_put(padded, meshlib.data_sharding(mesh, padded.ndim))
+    padded, buf = _padded_rows(a, meshlib.bucket_rows(a.shape[0], n_dev), mesh)
+    hit = _put(padded, meshlib.data_sharding(mesh, padded.ndim), buf)
     _bin_cache_store(key, hit)
     PROFILER.count("staging.bin_cache_miss")
     PROFILER.count("staging.h2d_bytes", padded.nbytes)
@@ -457,6 +596,21 @@ def transient_hbm(pool: str, nbytes: int):
 def stage_rows_cached(a, pad_to_multiple: bool = True) -> jax.Array:
     """device_put a row-sharded array through the content cache; of a
     `RowsLast` block the last axis is the one padded and sharded."""
+    return _stage_rows(a, pad_to_multiple)
+
+
+def stage_aligned_cached(arr: np.ndarray, n_padded: int) -> jax.Array:
+    """device_put a per-row array as float32 with zero rows up to
+    `n_padded`, the rows of a block staged before, through the content
+    cache under the key of the PADDED array."""
+    padded, buf = _padded_rows(arr, n_padded, meshlib.get_mesh(),
+                               dtype=np.float32)
+    return _stage_rows(padded, False, buf)
+
+
+def _stage_rows(a, pad_to_multiple: bool, buf: Optional[np.ndarray] = None):
+    """`stage_rows_cached`; `buf` is the pad pool's buffer that an array
+    the caller padded itself lies in."""
     from ..utils.profiler import PROFILER
     mesh = meshlib.get_mesh()
     n_dev = meshlib.data_width(mesh)
@@ -467,18 +621,19 @@ def stage_rows_cached(a, pad_to_multiple: bool = True) -> jax.Array:
         padded = a
         if pad_to_multiple:
             axis = -1 if rows_last else 0
-            padded = _padded_rows(
-                a, meshlib.bucket_rows(a.shape[axis], n_dev), axis)
+            padded, buf = _padded_rows(
+                a, meshlib.bucket_rows(a.shape[axis], n_dev), mesh, axis)
         sharding = meshlib.data_sharding(mesh, padded.ndim)
         if rows_last:
             sharding = NamedSharding(mesh, P(
                 *([None] * (padded.ndim - 1)), meshlib.row_spec_entry(mesh)))
-        with staging_step("put", padded.nbytes):
-            hit = jax.device_put(padded, sharding)
+        hit = _put(padded, sharding, buf)
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", padded.nbytes)
     else:
+        if buf is not None:
+            _PAD_POOL.give_back(buf)
         PROFILER.count("staging.cache_hit")
         PROFILER.count("staging.h2d_bytes_saved", a.nbytes)
     return hit
@@ -497,8 +652,7 @@ def stage_stacked_cached(a: np.ndarray) -> jax.Array:
     if hit is None:
         spec = P(None, meshlib.row_spec_entry(mesh),
                  *([None] * (a.ndim - 2)))
-        with staging_step("put", a.nbytes):
-            hit = jax.device_put(a, NamedSharding(mesh, spec))
+        hit = _put(a, NamedSharding(mesh, spec))
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", a.nbytes)
@@ -523,8 +677,7 @@ def stage_trial_stacked_cached(a: np.ndarray, mesh) -> jax.Array:
     if hit is None:
         spec = P(meshlib.TRIAL_AXIS, meshlib.DATA_AXIS,
                  *([None] * (a.ndim - 2)))
-        with staging_step("put", a.nbytes):
-            hit = jax.device_put(a, NamedSharding(mesh, spec))
+        hit = _put(a, NamedSharding(mesh, spec))
         _cache_put(key, hit)
         PROFILER.count("staging.cache_miss")
         PROFILER.count("staging.h2d_bytes", a.nbytes)
@@ -541,10 +694,9 @@ def stage_mask_cached(n_padded: int, n_true: int) -> jax.Array:
     hit = _stage_cache.get(mkey)
     if hit is None:
         # the mask is made here, not handed in: its fill is its pad step
-        with staging_step("pad", 4 * n_padded):
-            mask = meshlib.row_mask(n_padded, n_true)
-        with staging_step("put", mask.nbytes):
-            hit = jax.device_put(mask, meshlib.data_sharding(mesh, 1))
+        mask, buf = _zero_tailed((n_padded,), np.float32, 0, 1.0, n_true,
+                                 4 * n_padded, mesh)
+        hit = _put(mask, meshlib.data_sharding(mesh, 1), buf)
         _cache_put(mkey, hit)
     return hit
 

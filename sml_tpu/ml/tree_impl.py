@@ -1778,9 +1778,5 @@ def stage_tree_data(X: np.ndarray, y: np.ndarray, max_bins: int,
 
 def stage_aligned(arr: np.ndarray, n_padded: int):
     """Shard a per-row array aligned with previously staged binned data."""
-    from ._staging import stage_rows_cached, staging_step
-    with staging_step("pad", arr.nbytes) as note:
-        padded = np.zeros((n_padded,) + arr.shape[1:], dtype=np.float32)
-        padded[:arr.shape[0]] = arr
-        note["bytes"] = int(padded.nbytes)
-    return stage_rows_cached(padded, pad_to_multiple=False)
+    from ._staging import stage_aligned_cached
+    return stage_aligned_cached(arr, n_padded)

@@ -91,6 +91,11 @@ COUNTERS = {
     "staging.bin_cache_hit", "staging.bin_cache_miss",
     "staging.h2d_bytes", "staging.d2h_bytes", "staging.h2d_bytes_saved",
     "staging.evict_bytes", "staging.bin_evict_bytes",
+    # pad steps over `_SPAN_BYTES` (ml/_staging.py `_zero_tailed`): written
+    # into a retained buffer whose pages are warm / into a fresh allocation
+    # (the first of a size, and every one where a placed array may alias
+    # the host: the CPU backend)
+    "staging.pad_warm", "staging.pad_fresh",
     "shuffle.rows", "shuffle.bytes",
     "cv.batchFolds.fallback",
     # a validator's fit (ml/tuning.py): estimator fits it made (the grid's

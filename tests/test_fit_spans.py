@@ -261,6 +261,9 @@ def test_the_steps_of_a_large_staging_sum_to_fit_stage(recorder):
         assert block[1].args["bytes"] == block[2].args["bytes"] > rows * 16
         assert block[2].args["bytes"] % (4 * 4 * 8) == 0
         assert steps[3].args["bytes"] == block[2].args["bytes"] // 4
+        # this backend may take the host buffer for the placed array, so
+        # neither pad is pooled: fresh pages both (tests/test_staging_pad_pool)
+        assert block[1].args["warm"] is False is steps[3].args["warm"]
         short.append(1.0 - sum(e.dur for e in steps) / stage.dur)
         if short[-1] <= 0.02:
             break
@@ -268,6 +271,8 @@ def test_the_steps_of_a_large_staging_sum_to_fit_stage(recorder):
     totals = recorder.counters()
     assert sum(totals["span_s." + n] for n in STEPS) == \
         pytest.approx(sum(e.dur for e in steps), abs=1e-9)
+    assert totals["staging.pad_fresh"] == 2.0      # the block's, the mask's
+    assert "staging.pad_warm" not in totals
 
 
 def test_a_small_staging_opens_no_step(recorder):
@@ -275,6 +280,7 @@ def test_a_small_staging_opens_no_step(recorder):
     assert steps == [] and stage.dur > 0
     assert not [k for k in recorder.counters() if ".stage." in k
                 and not k.endswith(".fit.stage")]
+    assert not [k for k in recorder.counters() if "staging.pad_" in k]
 
 
 def test_a_staging_cache_hit_opens_the_key_alone(recorder):
