@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """blackbox_view — render a black-box postmortem bundle offline.
 
-A bundle (written by `obs.dump_blackbox()` / `install_blackbox()` /
-`bench.py --blackbox-on-fail` — see sml_tpu/obs/blackbox.py) is a
+A bundle (written by `obs.dump_blackbox()` / `install_blackbox()` — see
+sml_tpu/obs/blackbox.py) is a
 directory of JSON artifacts from a crashed or stalled process. This
 script turns it back into something a human debugs with:
 

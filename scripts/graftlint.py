@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""graftlint runner: engine-invariant static analysis over sml_tpu/,
-bench.py, and scripts/.
+"""graftlint runner: engine-invariant static analysis over sml_tpu/ and
+scripts/.
 
 Loads the framework in `sml_tpu/lint/` STANDALONE (importlib by path,
 package name "graftlint") so a lint run never imports the sml_tpu
@@ -8,8 +8,8 @@ package — and therefore never imports jax: CI can gate on this from a
 cold interpreter in well under a second (asserted by
 tests/test_lint_clean.py).
 
-Exit-code CONTRACT (relied on by `bench.py --lint` and CI — do not
-reuse these codes for anything else):
+Exit-code CONTRACT (relied on by CI, held by tests/test_lint_clean.py — do
+not reuse these codes for anything else):
 
     0  clean: no unsuppressed violations (also: --list-rules,
        --update-baseline success)

@@ -73,7 +73,7 @@ _register("sml.tree.hierarchicalAllreduce", "auto", str,
           "(mesh.host_mesh); 'true' = same, but error-prone on flat "
           "meshes so it still requires the host axes; 'false' = always "
           "the flat single-hop psum. Per-hop launches/bytes land in "
-          "collective.psum[_bytes].ici/.dcn (docs/PERF.md)")
+          "collective.psum[_bytes].ici/.dcn (docs/OBSERVABILITY.md)")
 _register("sml.mesh.hostGroups", 0, int,
           "Default host-group count for mesh.host_mesh() when called "
           "without an explicit `hosts`: 0 = auto (jax.process_count() "
@@ -206,19 +206,9 @@ _register("sml.infer.kernelBlockRows", 2048, int,
           "hardware (bounds the VMEM per-level one-hot tile to "
           "~blockRows*(n_nodes+F) elements; the actual block is the "
           "largest 32-row-aligned divisor of the per-chip padded rows at "
-          "or under this). The hand-set default the --kernelbench autotuner "
-          "exists to beat: a tuned spec from the prewarm manifest "
-          "overrides this per (model shape, batch width) when "
-          "sml.infer.autotune is on. Interpret mode always runs ONE "
+          "or under this). Interpret mode always runs ONE "
           "block (the traversal has no cross-row reduction, so blocking "
           "never changes results — bit-parity either way)")
-_register("sml.infer.autotune", True, _to_bool,
-          "Consult the prewarm manifest's autotuned traversal-kernel "
-          "specs (persisted by bench.py --kernelbench) when resolving "
-          "the scoring kernel: a recorded winner for this (model shape, "
-          "maxBins, batch width) on this mesh overrides sml.infer.kernel"
-          "/kernelBlockRows, so replicas and replays pick the tuned "
-          "spec without re-sweeping. Off = conf-resolved spec only")
 _register("sml.infer.prefetchBatches", 4, int,
           "DeviceScorer.score_batches lookahead: batches dispatched ahead "
           "of the drain point so batch i+1's prep + H2D staging overlaps "
@@ -229,9 +219,8 @@ _register("sml.cv.batchFolds", True, _to_bool,
           "programs. With sml.cv.maxFusedTrials > 1 the GRID axis fuses "
           "too (per-trial hyperparameters pad to the grid maxima as "
           "traced scalars), so a G-point grid over k folds costs "
-          "ceil(G*k/maxFusedTrials) tree-fit dispatches — the r01 bench's "
-          "ml07_cv/ml08 legs were dominated by dispatch COUNT, not "
-          "kernel time. Metrics match the placed-trials path within "
+          "ceil(G*k/maxFusedTrials) tree-fit dispatches (a grid is "
+          "dominated by dispatch COUNT, not kernel time). Metrics match the placed-trials path within "
           "float tolerance (below-max-depth trials derive terminal-level "
           "stats from the level histograms rather than the dedicated "
           "leaf pass); false forces placed trials")

@@ -208,7 +208,7 @@ class ReplicaPool:
 class Autoscaler:
     """Occupancy- and burn-rate-banded replica count control.
 
-    `step()` evaluates the bands once (the bench and tests drive it
+    `step()` evaluates the bands once (tests drive it
     deterministically); `start()` runs it on an interval. Signals: the
     router's MEAN observed occupancy since the last step (arrival-
     weighted — a quiet instant between bursts cannot fake a quiet

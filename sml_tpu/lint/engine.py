@@ -1,9 +1,8 @@
 """The graftlint driver: parse once, run every rule, apply pragma and
 baseline suppression, report.
 
-`run()` is the single entry used by `scripts/graftlint.py`, the
-`bench.py --lint` gate, and tests/test_graftlint.py (which feeds it
-in-memory fixture projects).
+`run()` is the single entry used by `scripts/graftlint.py` and
+tests/test_graftlint.py (which feeds it in-memory fixture projects).
 """
 
 from __future__ import annotations

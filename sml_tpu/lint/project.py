@@ -18,9 +18,9 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from .core import SourceFile
 
-#: what the runner lints: the engine package, the bench harness, and the
-#: repo's scripts (the lint package dogfoods itself via sml_tpu/lint/).
-DEFAULT_LINT_TARGETS = ("sml_tpu", "bench.py", "scripts")
+#: what the runner lints: the engine package and the repo's scripts (the
+#: lint package dogfoods itself via sml_tpu/lint/).
+DEFAULT_LINT_TARGETS = ("sml_tpu", "scripts")
 #: parsed for conf-key call-site evidence only, never linted
 DEFAULT_EXTRA_TARGETS = ("tests",)
 

@@ -19,9 +19,8 @@ Also flagged: direct invocation of the traversal kernel entry,
 `forest_traverse(...)` / `traverse_kernel.forest_traverse(...)`, outside
 the `score_block` dispatch glue (`ml/inference.py`'s
 `_forest_margin_path`). A bypassing
-call skips `resolve_infer_kernel`, so the VMEM demotion guard, the
-autotuned-spec lookup, and the `infer.kernel.*` counters never see the
-launch.
+call skips `resolve_infer_kernel`, so the VMEM demotion guard and the
+`infer.kernel.*` counters never see the launch.
 
 Suppression is an explicit ALLOWLIST of (file, enclosing function)
 pairs — or a directory prefix ending in "/" — each carrying its
@@ -64,8 +63,8 @@ ALLOWLIST: Dict[str, Dict[str, str]] = {
         "_forest_margin_path": "THE sanctioned traversal-kernel "
                                "invocation site: every forest_traverse "
                                "launch is resolved by "
-                               "resolve_infer_kernel (VMEM guard, tuned "
-                               "specs, infer.kernel.* counters) before "
+                               "resolve_infer_kernel (VMEM guard, "
+                               "infer.kernel.* counters) before "
                                "reaching it",
     },
     "sml_tpu/ml/_staging.py": {
@@ -170,8 +169,8 @@ def check(project: Project) -> List[Violation]:
                     "dispatch-bypass", f.rel, node.lineno,
                     f"direct traversal-kernel invocation `{label}` in "
                     f"`{qual}` bypasses the score_block dispatch path "
-                    f"(resolve_infer_kernel's VMEM guard, autotuned "
-                    f"specs, and infer.kernel.* counters never see the "
+                    f"(resolve_infer_kernel's VMEM guard and "
+                    f"infer.kernel.* counters never see the "
                     f"launch) — score through DeviceScorer/"
                     f"predict_forest_sharded (ml.inference."
                     f"_forest_margin_path is the one sanctioned call "

@@ -15,17 +15,17 @@ modules that emit them and the report/exporter/autologger that read them.
   (the recorder itself forwards names that originated at checked call
   sites; everyone else must write literals).
 
-`scripts/check_obs_taxonomy.py` is now a thin deprecation shim over the
-helpers here (`check_file` / `check_tree` / `load_taxonomy` / `cli_main`
-keep the original tuple-based API so tests/test_obs_taxonomy.py runs
-unchanged).
+The reverse direction is `unemitted_patterns`: a registered pattern that
+no call site of the package can emit is dead registry, found by the same
+walk (tests/test_obs_taxonomy.py holds both directions).
 """
 
 from __future__ import annotations
 
 import ast
 import os
-from typing import List, Tuple
+import re
+from typing import Iterator, List, Tuple
 
 from ..core import Violation, rule
 from ..project import Project
@@ -36,6 +36,9 @@ TARGETS = {
     "RECORDER": {"emit": (1, "emit"), "counter": (0, "counter"),
                  "gauge": (0, "gauge")},
     "_OBS": {"emit": (1, "emit"), "counter": (0, "counter"),
+             "gauge": (0, "gauge")},
+    # the recorder as obs/'s own classes hold it (`self._rec`)
+    "_rec": {"emit": (1, "emit"), "counter": (0, "counter"),
              "gauge": (0, "gauge")},
     # streaming-metrics histograms (obs/_metrics.py): observed names are
     # part of the same taxonomy (METRICS_NAMES, kind "observe")
@@ -77,13 +80,11 @@ def _is_obs_internal(rel: str) -> bool:
     return "/obs/" in f"/{rel}" or rel.endswith("utils/profiler.py")
 
 
-def check_source(text: str, rel: str, taxonomy,
-                 in_obs: bool) -> List[Tuple[str, int, str]]:
-    try:
-        tree = ast.parse(text, filename=rel)
-    except SyntaxError as e:
-        return [(rel, e.lineno or 0, f"syntax error: {e.msg}")]
-    out: List[Tuple[str, int, str]] = []
+def _name_sites(tree: ast.AST) -> Iterator[Tuple[int, str, str, str]]:
+    """Every name argument of a TARGETS call → (line, kind, form, text):
+    form "literal" (text = the name), "prefix" (an f-string; text = its
+    literal prefix) or "computed" (text = ""; an f-string that begins
+    with an interpolation is one)."""
     for node in ast.walk(tree):
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)):
@@ -96,18 +97,33 @@ def check_source(text: str, rel: str, taxonomy,
             continue  # name passed by keyword — obs-internal style only
         arg = node.args[arg_idx]
         if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-            if not taxonomy.is_registered(kind, arg.value):
-                out.append((rel, node.lineno,
-                            f"unregistered {kind} name {arg.value!r}"))
-        elif isinstance(arg, ast.JoinedStr):
-            prefix = _joined_prefix(arg)
-            if not taxonomy.prefix_registered(kind, prefix):
-                out.append((rel, node.lineno,
+            yield node.lineno, kind, "literal", arg.value
+        elif isinstance(arg, ast.JoinedStr) and _joined_prefix(arg):
+            yield node.lineno, kind, "prefix", _joined_prefix(arg)
+        else:
+            yield node.lineno, kind, "computed", ""
+
+
+def check_source(text: str, rel: str, taxonomy,
+                 in_obs: bool) -> List[Tuple[str, int, str]]:
+    try:
+        tree = ast.parse(text, filename=rel)
+    except SyntaxError as e:
+        return [(rel, e.lineno or 0, f"syntax error: {e.msg}")]
+    out: List[Tuple[str, int, str]] = []
+    for lineno, kind, form, name in _name_sites(tree):
+        if form == "literal":
+            if not taxonomy.is_registered(kind, name):
+                out.append((rel, lineno,
+                            f"unregistered {kind} name {name!r}"))
+        elif form == "prefix":
+            if not taxonomy.prefix_registered(kind, name):
+                out.append((rel, lineno,
                             f"unregistered dynamic {kind} family "
-                            f"(literal prefix {prefix!r} matches no "
+                            f"(literal prefix {name!r} matches no "
                             f"wildcard entry)"))
         elif not in_obs:
-            out.append((rel, node.lineno,
+            out.append((rel, lineno,
                         f"computed {kind} name (only literals/f-strings "
                         f"are lintable; computed names are reserved to "
                         f"sml_tpu/obs/)"))
@@ -126,6 +142,13 @@ def load_taxonomy(repo: str = REPO):
     return mod
 
 
+def _py_files(root: str) -> Iterator[str]:
+    for dirpath, _dirs, files in os.walk(root):
+        for f in sorted(files):
+            if f.endswith(".py"):
+                yield os.path.join(dirpath, f)
+
+
 def check_file(path: str, taxonomy) -> List[Tuple[str, int, str]]:
     rel = os.path.relpath(path, REPO)
     in_obs = (os.sep + "obs" + os.sep in path
@@ -137,25 +160,61 @@ def check_file(path: str, taxonomy) -> List[Tuple[str, int, str]]:
 def check_tree(root: str = PKG) -> List[Tuple[str, int, str]]:
     taxonomy = load_taxonomy()
     violations: List[Tuple[str, int, str]] = []
-    for dirpath, _dirs, files in os.walk(root):
-        for f in sorted(files):
-            if f.endswith(".py"):
-                violations.extend(
-                    check_file(os.path.join(dirpath, f), taxonomy))
+    for path in _py_files(root):
+        violations.extend(check_file(path, taxonomy))
     return violations
 
 
-def cli_main() -> int:
-    """The original check_obs_taxonomy.py CLI behavior, kept for the shim."""
-    violations = check_tree()
-    for rel, line, msg in violations:
-        print(f"{rel}:{line}: {msg}")
-    if violations:
-        print(f"{len(violations)} taxonomy violation(s); register the "
-              f"name in sml_tpu/obs/taxonomy.py or fix the call site")
-        return 1
-    print("obs taxonomy clean")
-    return 0
+#: a string literal that can be a family or a dotted prefix of names
+_FAMILY = re.compile(r"[a-z_]+(\.[a-z_]+)*\.?")
+
+#: registry of taxonomy.py -> the call-site kinds that feed it
+_REGISTRY_KINDS = {"SPANS": ("span",), "COUNTERS": ("count", "counter"),
+                   "GAUGES": ("gauge",), "EVENTS": ("emit",),
+                   "METRICS_NAMES": ("observe",)}
+
+
+def unemitted_patterns(root: str = PKG) -> List[Tuple[str, str]]:
+    """(registry, pattern) for every registered pattern that nothing
+    under `root` can emit. An emitter is a call site of the forward
+    walk whose literal name (or f-string prefix) the pattern matches,
+    or, for the names the forward check lets sml_tpu/obs/ COMPUTE
+    (`"span_s." + name`, `SkewTracker("ingest")`), a string literal of
+    an obs-internal file (the registry itself apart) that is the
+    pattern's family or lies under it."""
+    taxonomy = load_taxonomy()
+    sites = set()      # (kind, form, text) of lintable call sites
+    families = set()   # string literals of obs-internal files
+    for path in _py_files(root):
+        with open(path, encoding="utf-8") as fh:
+            tree = ast.parse(fh.read(), filename=path)
+        sites.update((kind, form, name)
+                     for _, kind, form, name in _name_sites(tree)
+                     if form != "computed")
+        rel = os.path.relpath(path, root)
+        if _is_obs_internal(rel) and rel != os.path.join("obs", "taxonomy.py"):
+            families.update(
+                n.value for n in ast.walk(tree)
+                if isinstance(n, ast.Constant) and isinstance(n.value, str)
+                and _FAMILY.fullmatch(n.value))
+
+    def emitted(pattern: str, kinds) -> bool:
+        wild = pattern.endswith("*")
+        stem = pattern[:-1] if wild else pattern
+        for kind, form, name in sites:
+            if kind not in kinds:
+                continue
+            if name == stem or (wild and name.startswith(stem)) \
+                    or (form == "prefix" and stem.startswith(name)):
+                return True
+        return wild and any(
+            lit == stem.rstrip(".") or lit.startswith(stem)
+            for lit in families)
+
+    return [(registry, pattern)
+            for registry, kinds in sorted(_REGISTRY_KINDS.items())
+            for pattern in sorted(getattr(taxonomy, registry))
+            if not emitted(pattern, kinds)]
 
 
 @rule("obs-taxonomy",

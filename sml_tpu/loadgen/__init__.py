@@ -1,10 +1,8 @@
 """sml_tpu.loadgen — open-loop, trace-driven load harness.
 
-Every load number the repo had before this package came from
-closed-loop synthetic clients (`bench.py --fleet` / `--serving`):
-clients that wait for each response before sending the next, and
-therefore SLOW THEIR OWN ARRIVAL RATE the moment the system queues —
-the classic coordinated-omission trap. The percentiles such a client
+Closed-loop synthetic clients wait for each response before sending the
+next, and therefore SLOW THEIR OWN ARRIVAL RATE the moment the system
+queues — the classic coordinated-omission trap. The percentiles such a client
 reports describe the workload the system degraded its clients into,
 not the workload the users offered. This package measures the offered
 workload honestly:
@@ -28,9 +26,7 @@ workload honestly:
   so measured phases hit warm per-bucket programs.
 
 The last completed driver's report is the `load` block of
-`obs.engine_health()` (`load_report()`), and `bench.py --load` commits
-the same shape as the sidecar `load` block that `obs/regress.py`
-judges. See docs/LOADGEN.md for the trace grammar, the open-loop
+`obs.engine_health()` (`load_report()`). See docs/LOADGEN.md for the trace grammar, the open-loop
 semantics, and the tail-engineering ladder this harness motivates
 (`sml.serve.flushAutoTune`, `sml.fleet.burstSlope*`).
 """
@@ -52,14 +48,8 @@ _register("sml.load.overrunMicros", 5000, int,
           "Open-loop honesty tolerance: a request picked up this many "
           "microseconds after its SCHEDULED arrival instant counts "
           "load.overrun (the schedule outran the driver's pool). "
-          "Overruns flag in the bench sidecar and regress — a load "
-          "report with overruns indicts the harness, not the system")
-_register("sml.load.resultTimeoutSec", 30.0, float,
-          "Bounded wait the load harness places on each request's "
-          "result (FleetFuture/ScoreFuture.result(timeout=)); expiry "
-          "raises the typed RequestTimeout, counted serve.timeout + "
-          "load.timeout — an open-loop driver must never hang on one "
-          "lost future")
+          "A load report with overruns indicts the harness, not the "
+          "system")
 
 from ._driver import OpenLoopDriver, closed_loop_probe  # noqa: E402
 from ._spec import PhaseSpec, Request, TraceSpec  # noqa: E402

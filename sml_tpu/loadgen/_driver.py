@@ -27,8 +27,8 @@ Honesty guarantees:
 
 `closed_loop_probe` is the deliberately-wrong control: the same
 schedule driven closed-loop, latency stamped from send time. Its only
-job is the omission proof in tests and the sidecar's like-for-like
-annotation — never report its numbers as load results.
+job is the omission proof in tests — never report its numbers as load
+results.
 """
 
 from __future__ import annotations
@@ -127,7 +127,7 @@ class OpenLoopDriver:
         lag = now() - sched
         if lag > self._overrun_s:
             # the schedule outran the pool: the driver itself delayed
-            # this fire. NEVER silent — it flags in report()/regress
+            # this fire. NEVER silent — it flags in report()
             with self._lock:
                 self._counts["overrun"] += 1
             PROFILER.count("load.overrun")
@@ -193,8 +193,7 @@ class OpenLoopDriver:
     def report(self) -> Dict[str, object]:
         """The honest-tail block: totals, overruns, and per-phase
         per-class p50/p99/p99.9 with the worst request's latency and
-        trace exemplar. Shapes match the bench sidecar's `load` block
-        so regress can diff them directly."""
+        trace exemplar."""
         with self._lock:
             counts = dict(self._counts)
             samples = {k: list(v) for k, v in self._samples.items()}

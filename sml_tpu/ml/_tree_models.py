@@ -120,8 +120,7 @@ class _EnsembleSpec:
                 from .inference import predict_forest_sharded
                 sf, sb, lv, w = self.stacked()
                 return predict_forest_sharded(
-                    binned, sf, sb, lv, w, self.depth, base=self.base,
-                    n_bins=self.binning.edges.shape[1] + 1)
+                    binned, sf, sb, lv, w, self.depth, base=self.base)
             import jax
             with dispatch.observe_host("traverse", hint.flops), \
                     jax.default_device(list(mesh.devices.flat)[0]):
@@ -649,10 +648,9 @@ def fused_reg_stats_from_matrix(spec, X: np.ndarray, lab: np.ndarray,
             return None  # host route: ordinary path is cheaper
         from .inference import forest_eval_fn, resolve_infer_kernel
         sf, sb, lv, w = spec.stacked()
-        kernel, block_rows, _ = resolve_infer_kernel(
-            n_trees=sf.shape[0], depth=spec.depth, n_nodes=sf.shape[1],
-            n_feat=binned_q.shape[1],
-            n_bins=spec.binning.edges.shape[1] + 1, n_rows=n)
+        kernel, block_rows = resolve_infer_kernel(
+            n_trees=sf.shape[0], n_nodes=sf.shape[1],
+            n_feat=binned_q.shape[1])
         stats = run_data_parallel(
             forest_eval_fn(spec.depth, link, kernel, block_rows),
             binned_q, l32, f32,

@@ -91,7 +91,7 @@ def _forest_margin(binned_b, sf, sb, lv, weights, depth: int):
 # -------------------------------------------------- traversal-kernel choice
 #: last resolved traversal spec + fallback/demotion counts — the
 #: `infer_kernel` block of obs.engine_health() (kernel_report below)
-_KERNEL_STATE: dict = {"kernel": None, "block_rows": 0, "tuned": False,
+_KERNEL_STATE: dict = {"kernel": None, "block_rows": 0,
                        "resolutions": 0, "fallbacks": 0, "demotions": 0}
 
 
@@ -100,33 +100,17 @@ def _kernel_fallback() -> None:
     _KERNEL_STATE["fallbacks"] += 1
 
 
-def infer_spec_key(n_trees: int, depth: int, n_feat: int, n_bins: int,
-                   n_rows: int) -> dict:
-    """The autotuner's lookup key: (model shape, maxBins, batch width).
-    `rows` is the BUCKETED padded batch width — the shape the staged
-    program actually compiles for, so near-size batches share one tuned
-    spec exactly as they share one executable."""
-    mesh = meshlib.get_mesh()
-    n_dev = meshlib.data_width(mesh)
-    return {"trees": int(n_trees), "depth": int(depth),
-            "features": int(n_feat), "bins": int(n_bins),
-            "rows": int(meshlib.bucket_rows(n_rows, n_dev))}
-
-
-def _note_spec(kernel: str, block_rows: int, tuned: bool) -> None:
+def _note_spec(kernel: str, block_rows: int) -> None:
     changed = (_KERNEL_STATE["kernel"] != kernel
-               or _KERNEL_STATE["block_rows"] != block_rows
-               or _KERNEL_STATE["tuned"] != tuned)
-    _KERNEL_STATE.update(kernel=kernel, block_rows=int(block_rows),
-                         tuned=bool(tuned))
+               or _KERNEL_STATE["block_rows"] != block_rows)
+    _KERNEL_STATE.update(kernel=kernel, block_rows=int(block_rows))
     _KERNEL_STATE["resolutions"] += 1
     PROFILER.count(f"infer.kernel.{kernel}")
     if changed:
         from ..obs._recorder import RECORDER
         if RECORDER.enabled:
             RECORDER.emit("infer", "infer.kernel.spec", args={
-                "kernel": kernel, "block_rows": int(block_rows),
-                "tuned": bool(tuned)})
+                "kernel": kernel, "block_rows": int(block_rows)})
 
 
 def _vmem_guard(block_rows: int, n_trees: int, n_nodes: int,
@@ -148,50 +132,17 @@ def _vmem_guard(block_rows: int, n_trees: int, n_nodes: int,
     return min(block_rows, mb), False
 
 
-def resolve_infer_kernel(n_trees: int, depth: int, n_nodes: int,
-                         n_feat: int, n_bins: int, n_rows: int):
-    """Per-dispatch traversal-spec resolution → (kernel, block_rows,
-    tuned). `tuned` is the provenance of THIS resolution (returned, not
-    re-read from shared state — concurrent scorers resolve interleaved).
-
-    Order: (1) an AUTOTUNED spec from the prewarm manifest
-    (`sml.infer.autotune`, recorded by `bench.py --kernelbench`) wins for
-    its exact (model shape, maxBins, batch width) on this mesh — replicas
-    and replays pick the tuned kernel without re-sweeping; (2) otherwise
+def resolve_infer_kernel(n_trees: int, n_nodes: int, n_feat: int):
+    """Per-dispatch traversal-spec resolution → (kernel, block_rows):
     the conf ladder (`sml.infer.kernel` + `sml.infer.kernelBlockRows`).
-    EVERY pallas candidate — tuned or conf — passes the real-TPU VMEM
-    guard (`_vmem_guard`): the block clamps to the budget, and an
-    unfittable spec falls back to xla with `infer.kernel.fallback` +
-    demotion counts instead of failing to lower mid-trace. The resolved
-    pair keys the program cache and the prewarm signature, so a change
-    compiles fresh."""
+    A pallas candidate passes the real-TPU VMEM guard (`_vmem_guard`):
+    the block clamps to the budget, and an unfittable spec falls back to
+    xla with `infer.kernel.fallback` + demotion counts instead of
+    failing to lower mid-trace. The resolved pair keys the program cache
+    and the prewarm signature, so a change compiles fresh."""
     from ..conf import GLOBAL_CONF
     from ..native import traverse_kernel as _tk
     from .tree_impl import _mesh_platform
-    if GLOBAL_CONF.getBool("sml.infer.autotune"):
-        from ..parallel import prewarm as _prewarm
-        key = infer_spec_key(n_trees, depth, n_feat, n_bins, n_rows)
-        spec = _prewarm.tuned_spec("infer_kernel", key)
-        if spec is not None:
-            kernel = str(spec.get("kernel", "xla"))
-            block_rows = int(spec.get("block_rows", 0))
-            tuned = True
-            if kernel == "pallas":
-                if _tk.probe(interpret=_mesh_platform() != "tpu"):
-                    _kernel_fallback()
-                    kernel, block_rows, tuned = "xla", 0, False
-                else:
-                    block_rows, demoted = _vmem_guard(
-                        block_rows, n_trees, n_nodes, n_feat)
-                    if demoted:
-                        # a tuned spec recorded on a roomier mesh (or a
-                        # changed budget) must not lower over-budget on
-                        # the serving hot path: same ladder as conf
-                        _kernel_fallback()
-                        _KERNEL_STATE["demotions"] += 1
-                        kernel, block_rows, tuned = "xla", 0, False
-            _note_spec(kernel, block_rows, tuned=tuned)
-            return kernel, block_rows, tuned
     # `sml.infer.kernel` for the ACTIVE mesh; `auto` on a TPU whose
     # toolchain probe fails is the one fallback, and it is counted
     kernel, fell_back = _tk.resolve_mode(
@@ -199,26 +150,25 @@ def resolve_infer_kernel(n_trees: int, depth: int, n_nodes: int,
     if fell_back:
         _kernel_fallback()
     if kernel != "pallas":
-        _note_spec("xla", 0, tuned=False)
-        return "xla", 0, False
+        _note_spec("xla", 0)
+        return "xla", 0
     block_rows, demoted = _vmem_guard(
         GLOBAL_CONF.getInt("sml.infer.kernelBlockRows"),
         n_trees, n_nodes, n_feat)
     if demoted:
         _kernel_fallback()
         _KERNEL_STATE["demotions"] += 1
-        _note_spec("xla", 0, tuned=False)
-        return "xla", 0, False
-    _note_spec("pallas", block_rows, tuned=False)
-    return "pallas", int(block_rows), False
+        _note_spec("xla", 0)
+        return "xla", 0
+    _note_spec("pallas", block_rows)
+    return "pallas", int(block_rows)
 
 
 def kernel_report() -> dict:
     """The `infer_kernel` block of `obs.engine_health()`: the last
-    resolved traversal spec (kernel, block rows, whether it came from
-    the autotuned manifest) and the cumulative fallback/demotion
-    counts — a replica silently scoring off the tuned path shows up
-    here, not just in the counters."""
+    resolved traversal spec (kernel, block rows) and the cumulative
+    fallback/demotion counts — a replica silently scoring off the
+    compiled kernel shows up here, not just in the counters."""
     return dict(_KERNEL_STATE)
 
 
@@ -253,7 +203,7 @@ def _make_forest_forward(depth: int, kernel: str = "xla",
     on fn IDENTITY — a fresh closure per call would compile a parallel
     universe of executables instead of warming the live ones. The
     resolved traversal spec is part of the identity (and the `_prewarm`
-    meta) so a tuned-spec change compiles fresh and replay rebuilds the
+    meta) so a spec change compiles fresh and replay rebuilds the
     RECORDED spec regardless of live conf."""
     key = (depth, kernel, block_rows)
     fn = _forest_forwards.get(key)
@@ -360,39 +310,7 @@ def _register_prewarm_factories() -> None:
                                  int(m.get("block_rows", 0))))
 
 
-def _replay_infer_kernel(meta: dict) -> None:
-    """Prewarm rebuilder for autotuned traversal specs ("infer_kernel"
-    manifest entries): rebuild the forward program for the RECORDED
-    (model shape, batch width, spec) and first-dispatch it on
-    zero-filled operands — replica spin-up (`ServingEndpoint.__init__`'s
-    `maybe_prewarm`) lands on the tuned kernel already compiled, without
-    a sweep and without waiting for first traffic."""
-    from .tree_impl import bin_dtype
-    key, spec = meta["key"], meta["spec"]
-    depth = int(key["depth"])
-    T, F = int(key["trees"]), int(key["features"])
-    rows = int(key["rows"])
-    n_nodes = 2 ** (depth + 1) - 1
-    prog = _forest_program(depth, str(spec.get("kernel", "xla")),
-                           int(spec.get("block_rows", 0)))
-    mesh = meshlib.get_mesh()
-    Bd = jax.device_put(
-        np.zeros((rows, F), dtype=bin_dtype(int(key["bins"]))),
-        meshlib.data_sharding(mesh, 2))
-    mask = jax.device_put(np.zeros((rows,), np.float32),
-                          meshlib.data_sharding(mesh, 1))
-    jax.device_get(prog(
-        Bd, mask, jnp.asarray(np.full((T, n_nodes), -1, np.int32)),
-        jnp.asarray(np.zeros((T, n_nodes), np.int32)),
-        jnp.asarray(np.zeros((T, n_nodes), np.float32)),
-        jnp.asarray(np.zeros((T,), np.float32))))
-
-
 _register_prewarm_factories()
-
-from ..parallel import prewarm as _prewarm_mod
-
-_prewarm_mod.register_rebuilder("infer_kernel", _replay_infer_kernel)
 
 
 def _stage_rows(X: np.ndarray):
@@ -423,23 +341,16 @@ def predict_linear_sharded(X: np.ndarray, w: np.ndarray, b: float,
 def predict_forest_sharded(binned: np.ndarray, sf: np.ndarray,
                            sb: np.ndarray, lv: np.ndarray,
                            weights: np.ndarray, depth: int,
-                           base: float = 0.0,
-                           n_bins: Optional[int] = None) -> np.ndarray:
+                           base: float = 0.0) -> np.ndarray:
     """Stacked-ensemble traversal: rows sharded over the mesh, tree tensors
     replicated (they are KB-scale), one fused program for the whole forest.
     `binned` keeps its compact quantized dtype end-to-end (the program
     widens on-device). The traversal implementation (XLA where-sums vs
     the fused `native/traverse_kernel.py` launch) resolves per dispatch
-    through `resolve_infer_kernel`; `n_bins` feeds the autotuned-spec
-    key (absent, the compact dtype's capacity stands in — same model,
-    same stand-in, so lookups stay consistent)."""
+    through `resolve_infer_kernel`."""
     binned = np.ascontiguousarray(binned)
-    if n_bins is None:
-        n_bins = int(np.iinfo(binned.dtype).max) + 1 \
-            if binned.dtype.kind in "ui" else 0
-    kernel, block_rows, _ = resolve_infer_kernel(
-        n_trees=sf.shape[0], depth=depth, n_nodes=sf.shape[1],
-        n_feat=binned.shape[1], n_bins=n_bins, n_rows=binned.shape[0])
+    kernel, block_rows = resolve_infer_kernel(
+        n_trees=sf.shape[0], n_nodes=sf.shape[1], n_feat=binned.shape[1])
     Bd, mask, n = _stage_rows(binned)
     prog = _forest_program(depth, kernel, block_rows)
     out = prog(Bd, mask, np.asarray(sf), np.asarray(sb),
@@ -555,12 +466,10 @@ class DeviceScorer:
                                         spec.tree_weights)
             return margin, n, finalize
         binned = np.ascontiguousarray(binned)
-        kernel, block_rows, tuned = resolve_infer_kernel(
-            n_trees=sf.shape[0], depth=spec.depth, n_nodes=sf.shape[1],
-            n_feat=binned.shape[1],
-            n_bins=spec.binning.edges.shape[1] + 1, n_rows=n)
-        self._kernel_spec = {"kernel": kernel, "block_rows": block_rows,
-                             "tuned": tuned}
+        kernel, block_rows = resolve_infer_kernel(
+            n_trees=sf.shape[0], n_nodes=sf.shape[1],
+            n_feat=binned.shape[1])
+        self._kernel_spec = {"kernel": kernel, "block_rows": block_rows}
         Bd, mask, n = _stage_rows(binned)
         prog = _forest_program(spec.depth, kernel, block_rows)
         # replicated operands go in as host arrays: the program's own
@@ -609,7 +518,7 @@ class DeviceScorer:
 
     def kernel_spec(self) -> Optional[dict]:
         """The traversal spec this scorer's most recent device-routed
-        forest dispatch resolved to ({kernel, block_rows, tuned}), or
+        forest dispatch resolved to ({kernel, block_rows}), or
         None (linear model / no device dispatch yet). Snapshot first:
         a concurrent `_dispatch` (prefetch/serving threads) rebinds
         `_kernel_spec` between a check and a `dict()` of it."""

@@ -28,9 +28,6 @@ built on ONE structured event bus:
 - `SKEW` / `straggler_report()` (`_skew`): per-device compute vs
   collective-wait attribution of fused mesh programs, rendered as
   per-chip lanes in the Chrome trace.
-- `regress` (stdlib-only, also loadable standalone by
-  `scripts/bench_diff.py`): noise-aware comparison of two bench sidecars
-  — the machine-checkable perf-regression gate.
 - `TraceContext` / `current_trace` (`_context`): causal request tracing
   — a context minted at serving admission rides contextvars (with
   explicit cross-thread handoff) through micro-batch coalescing, the
@@ -88,7 +85,7 @@ __all__ = ["RECORDER", "Event", "LEDGER", "METRICS", "SKEW", "INGEST_SKEW",
            "LogHistogram", "merge_snapshots", "export_chrome_trace",
            "audit_report", "audit_records", "memory_report",
            "engine_metrics", "engine_health", "straggler_report",
-           "skew_report_from_trace", "annotate_regressions", "reset",
+           "skew_report_from_trace", "reset",
            "enabled", "note_compile", "autolog_fit"]
 
 
@@ -128,9 +125,9 @@ def note_pipeline(family: str, phase: str, key: str, index: int) -> None:
 def note_compile(name: str) -> None:
     """Mark a program-cache miss (= a fresh trace + XLA compile/replay):
     bumps the `compile.programs` total AND the per-name
-    `compile.program.<name>` counter (bench legs derive their
-    distinct-program / first-dispatch attribution from the per-name
-    deltas), and records a compile event."""
+    `compile.program.<name>` counter (distinct-program / first-dispatch
+    attribution reads the per-name deltas), and records a compile
+    event."""
     from ..utils.profiler import PROFILER
     PROFILER.count("compile.programs")
     PROFILER.count(f"compile.program.{name}")
@@ -272,9 +269,9 @@ def engine_health(window_s: Optional[float] = None) -> Dict[str, object]:
         # None until a monitor registers (a model carrying a baseline)
         "drift": drift.DRIFT.report(),
         # scoring traversal-kernel resolution (ml/inference.py): the
-        # last resolved spec (kernel / block_rows / tuned provenance)
-        # and cumulative fallback+demotion counts. Read lazily off
-        # sys.modules so a health poll never drags jax in — None until
+        # last resolved spec (kernel / block_rows) and cumulative
+        # fallback+demotion counts. Read lazily off sys.modules so a
+        # health poll never drags jax in — None until
         # the inference module has loaded (nothing scored yet)
         "infer_kernel": _infer_kernel_report(),
         # serving load-shed attribution (serving/_batcher.py): every
@@ -305,20 +302,6 @@ def engine_health(window_s: Optional[float] = None) -> Dict[str, object]:
             "audit_decisions": health["audit"]["decisions"],
             "slo_burn_rate": health["slo"]["burn_rate"]})
     return health
-
-
-def annotate_regressions(findings) -> int:
-    """Land `obs.regress` / `scripts/bench_diff.py` verdicts in the
-    flight recorder as `regress.verdict` events, so an exported Chrome
-    trace pins each regression on the timeline next to the engine
-    activity it indicts. Returns the number of events emitted."""
-    if not RECORDER.enabled:
-        return 0
-    n = 0
-    for f in findings:
-        RECORDER.emit("regress", "regress.verdict", args=dict(f))
-        n += 1
-    return n
 
 
 _fit_depth = threading.local()

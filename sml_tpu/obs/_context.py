@@ -54,7 +54,7 @@ def _next_id() -> int:
 
 
 def hex_id(ident: Optional[int]) -> Optional[str]:
-    """Display form of a trace/span id (reports, bench sidecar)."""
+    """Display form of a trace/span id (reports)."""
     return None if ident is None else f"0x{ident:013x}"
 
 

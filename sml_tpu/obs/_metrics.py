@@ -1,8 +1,8 @@
 """Streaming metrics core: log-bucketed latency/size histograms.
 
-The PR-2 recorder stores raw EVENTS; quantiles over them meant keeping
-raw sample lists and sorting at read time (`bench.py` did exactly that
-for `serve_p50_ms`). This module is the HDR-histogram-shaped fix: values
+The recorder stores raw EVENTS; quantiles over them would mean keeping
+raw sample lists and sorting at read time. This module is the
+HDR-histogram-shaped alternative: values
 land in geometric buckets (8 per octave, so one bucket spans a ~9%
 relative range), counts are all that is retained, and p50/p99/rates fall
 out of a merge — O(buckets) memory regardless of traffic, snapshots from
@@ -173,8 +173,8 @@ class LogHistogram:
 
     def worst(self) -> tuple:
         """(max observed value, its exemplar trace id or None) — the
-        literal worst request the histogram saw, for engine_health() and
-        the bench sidecar to name."""
+        literal worst request the histogram saw, for engine_health() to
+        name."""
         with self._lock:
             return (self.max, self._max_exemplar)
 

@@ -115,7 +115,7 @@ def to_trace_dicts(records: Iterable[dict]) -> List[dict]:
                             "args": {"value": args.get("total", 0.0)}})
         else:
             # every other typed event (dispatch, cache, collective,
-            # compile, serve, infer, skew, health, regress, stall,
+            # compile, serve, infer, skew, health, stall,
             # blackbox, ...) renders as an instant marker: a visible pin
             # without a lane
             out.append({"ph": "i", "s": "t", "pid": PID_HOST,

@@ -11,8 +11,7 @@ bundle to `sml.obs.blackboxDir`, triggered three ways:
   `threading.excepthook` (the prior hooks still run);
 - **on a hard stall** — `install()` registers a once-per-process
   `WATCHDOG.on_stall` hook, so the first flagged ticket dumps the
-  bundle while the hang is still live (`bench.py --blackbox-on-fail`
-  wires all of this into the bench driver).
+  bundle while the hang is still live.
 
 Bundle layout (all best-effort: a failing section is skipped, never
 fatal — the dump path must work in a dying process):
@@ -49,8 +48,7 @@ from ._watchdog import WATCHDOG, all_thread_stacks
 _register("sml.obs.blackboxDir", "blackbox", str,
           "Directory black-box forensics bundles are written under "
           "(obs.dump_blackbox / unhandled exceptions / hard stalls once "
-          "obs.blackbox.install() armed them; bench.py "
-          "--blackbox-on-fail). Each dump creates one "
+          "obs.blackbox.install() armed them). Each dump creates one "
           "blackbox-<utc>-<pid> bundle inside it")
 
 BUNDLE_VERSION = 1

@@ -25,7 +25,7 @@ machinery the engine already owns:
   in exact mode and bucket-approximate in compressed mode; categorical
   frequency PSI from the streamed `_cat_cnt` tables
   (`categorical_psi`); the prediction sketch judged like a feature.
-- **Noise-aware thresholds** (the `obs/regress.py` discipline): the
+- **Noise-aware thresholds**: the
   flag floor is the SELF-DISTANCE of the baseline — resample n_live
   values from the baseline's own stream, measure the distance of that
   iid sample against the baseline, repeat, and take the max. An iid
@@ -717,7 +717,7 @@ class DriftMonitor:
 def evaluate_block(baseline: DriftBaseline, X: np.ndarray,
                    preds: Optional[np.ndarray] = None,
                    name: str = "adhoc") -> Dict[str, object]:
-    """One-shot drift verdict for a materialized block (the bench and
+    """One-shot drift verdict for a materialized block (the
     batch-validation shape): a throwaway monitor observes the block and
     reports. Requires the recorder enabled (observation is gated)."""
     mon = DriftMonitor(baseline, name=name)

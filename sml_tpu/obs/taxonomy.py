@@ -1,14 +1,14 @@
 """Registered dotted-name taxonomy for spans, counters, and events.
 
 The profiler report, the Chrome-trace exporter, the engine-metrics
-autologger, and the bench's per-leg counter snapshots all key off these
+autologger, and the benchmark's per-layer readers all key off these
 names; a call site inventing `staging.h2dBytes` next to
 `staging.h2d_bytes` silently splits a metric in two. Every
 `PROFILER.span`/`PROFILER.count` and `RECORDER.emit/counter/gauge` call
 site is AST-linted against this registry (graftlint rule `obs-taxonomy`
-in sml_tpu/lint/rules/taxonomy.py — `scripts/check_obs_taxonomy.py` is
-now a shim — enforced by tests/test_obs_taxonomy.py and
-tests/test_lint_clean.py).
+in sml_tpu/lint/rules/taxonomy.py, enforced by tests/test_obs_taxonomy.py
+and tests/test_lint_clean.py), and every entry here has a call site that
+can emit it (the same rule's `unemitted_patterns`).
 
 Entries are exact names or `prefix.*` wildcards (wildcards cover the
 f-string sites whose suffix is runtime data: the op behind a
@@ -125,14 +125,11 @@ COUNTERS = {
     # fused traversal kernel on the SCORING path (native/traverse_kernel
     # + ml/inference.py resolution): infer.kernel.pallas / infer.kernel.xla
     # count spec resolutions landing on each path; infer.kernel.fallback
-    # counts dispatches that `auto` (or a tuned spec) wanted on pallas
-    # but that demoted to XLA — obs/regress.py flags any growth;
-    # infer.kernel.autotune_s accumulates --kernelbench
-    # sweep seconds (the cost the persisted manifest spec amortizes away)
+    # counts dispatches that `auto` wanted on pallas but that demoted
+    # to XLA
     "infer.kernel.*",
     "compile.programs",
-    "compile.program.*",  # per-name program-cache-miss counts (bench
-                          # derives distinct-programs-per-leg from these)
+    "compile.program.*",  # per-name program-cache-miss counts
     "tree.fit_dispatch",  # device launches of tree-fit programs (the
                           # grid-fused CV dispatch-count contract)
     # the layout a tree fit staged its bin matrix on
@@ -252,14 +249,6 @@ COUNTERS = {
     # schedule outran the pool; never silent, the committed gate requires
     # zero)
     "load.*",
-    # graftlint gate receipts (bench.py --lint): lint.runs /
-    # lint.violations (unsuppressed — 0 on any recorded run, the gate
-    # refuses otherwise) / lint.suppressed_pragma /
-    # lint.suppressed_baseline / lint.rules (active rule count) /
-    # lint.rule.<name> per-rule live-violation counts — obs/regress.py
-    # flags a violation-count increase or a rule-count decrease between
-    # committed sidecars
-    "lint.*",
 }
 
 GAUGES = {
@@ -301,7 +290,7 @@ EVENTS = {
     "infer.*",            # infer.dispatch / infer.drain (batch pipelining)
                           # + infer.kernel.spec (a scoring dispatch's
                           # resolved traversal spec CHANGED: kernel,
-                          # block_rows, tuned-or-conf provenance)
+                          # block_rows)
     "ingest.*",           # ingest.dispatch / ingest.drain (chunk-i+1
                           # H2D overlapping chunk-i device work — the
                           # double-buffered prefetch proof) + ingest.note
@@ -312,7 +301,6 @@ EVENTS = {
                           # lanes emitted as kind="span" through the raw
                           # RECORDER.emit path
     "health.*",           # health.snapshot (engine_health() receipts)
-    "regress.*",          # regress.verdict (bench_diff annotations)
     # causal tracing (obs/_context.py): trace.request admission spans
     # (emitted as kind="span" so the exporter lands them on the
     # admitting thread's lane — the flow arrows' source anchor). Trace

@@ -29,7 +29,7 @@ This module turns that serial sum into an overlapped pool:
 
 Every replay emits `prewarm.*` counters/events through the flight
 recorder, so the overlap is visible in the trace and assertable in
-tests. See docs/PERF.md ("Dispatch economics").
+tests. See docs/DESIGN_NOTES.md ("Dispatch economics").
 """
 
 from __future__ import annotations
@@ -200,59 +200,6 @@ def record(kind: str, meta: dict) -> None:
         entries[key] = entry
     PROFILER.count("prewarm.recorded")
     _flush(path)
-
-
-# ------------------------------------------------------- autotuned specs
-# The kernel autotuner (`bench.py --kernelbench`) persists its winning
-# traversal specs HERE, next to the program signatures they tune: the
-# manifest already rides the compile-cache directory to every replica
-# and replay, so a tuned (model shape, maxBins, batch width) → (kernel,
-# block_rows) decision survives process restarts and replica spin-up
-# without re-sweeping. Unlike ordinary `record` entries (idempotent,
-# append-only), tuned entries live at a STABLE key derived from
-# (kind, key, mesh) so a re-tune REPLACES the old winner.
-
-
-def _tuned_entry_key(kind: str, key: dict) -> Optional[str]:
-    try:
-        blob = json.dumps({"kind": kind, "key": key, "mesh": _mesh_sig()},
-                          sort_keys=True, default=str)
-    except (TypeError, ValueError):
-        return None
-    return "tuned-" + hashlib.sha1(blob.encode()).hexdigest()[:20]
-
-
-def record_tuned(kind: str, key: dict, spec: dict) -> None:
-    """Persist (or replace) one autotuned spec for `key` on the live
-    mesh. Best-effort like `record`: never fails a bench or a fit."""
-    if getattr(_tls, "replaying", False):
-        return
-    path = manifest_path()
-    ekey = _tuned_entry_key(kind, key)
-    if ekey is None:
-        return
-    entry = {"kind": kind, "meta": {"key": dict(key), "spec": dict(spec)},
-             "mesh": _mesh_sig()}
-    entries = _load(path)
-    with _lock:
-        if entries.get(ekey) == entry:
-            return
-        entries[ekey] = entry
-    PROFILER.count("prewarm.tuned")
-    _flush(path)
-
-
-def tuned_spec(kind: str, key: dict) -> Optional[dict]:
-    """The persisted autotuned spec for `key` on the live mesh, or None.
-    One canonical-JSON hash + a dict lookup against the cached manifest —
-    cheap enough for per-dispatch resolution on the scoring path."""
-    ekey = _tuned_entry_key(kind, key)
-    if ekey is None:
-        return None
-    entry = _load(manifest_path()).get(ekey)
-    if entry is None or entry.get("mesh") != _mesh_sig():
-        return None
-    return dict(entry["meta"]["spec"])
 
 
 def _replay_one(entry: dict, stats: dict, stats_lock) -> None:

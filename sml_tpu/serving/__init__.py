@@ -34,8 +34,8 @@ Three layers, composable separately:
 
 Observability: `serve.*` spans/counters/gauges (queue depth, batch
 occupancy, shed counts, hot-swaps — registered in `obs/taxonomy.py`);
-per-request latencies are the caller's to time (`bench.py --help`,
-`serving` leg). See docs/SERVING.md for the architecture, the knobs,
+per-request latencies land in the `serve.request_ms` histogram
+(`obs.METRICS`). See docs/SERVING.md for the architecture, the knobs,
 and the degradation ladder.
 """
 

@@ -452,7 +452,7 @@ class MicroBatcher:
                 # per-request latency (admission -> result) into the
                 # streaming metrics core: serve percentiles and the SLO
                 # burn-rate come from this histogram, never from raw
-                # sample lists (bench.py's sort path is gone). The
+                # sample lists. The
                 # request's OWN trace id is the observation's exemplar
                 # (no bleed from batch mates) — the worst histogram
                 # bucket names a literal request

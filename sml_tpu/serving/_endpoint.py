@@ -373,7 +373,7 @@ class ServingEndpoint:
             # THIS replica's resolved traversal spec (None until a
             # device-routed forest dispatch) — next to the engine-wide
             # `infer_kernel` block, so a replica silently off the
-            # autotuned kernel is attributable to the endpoint
+            # compiled kernel is attributable to the endpoint
             "kernel": (scorer.kernel_spec()
                        if hasattr(scorer, "kernel_spec") else None),
         }

@@ -342,48 +342,6 @@ def test_pipeline_abandonment_releases_tickets_and_drains(recorder_on):
 
 
 # -------------------------------------------------------- regression sentry
-def test_regress_scale_block_rules():
-    """obs/regress.py: a vanished `scale` block is coverage regression
-    (sidecar candidates only — driver records are exempt), rows/s drops
-    flag at the capped tolerance, and a lost overlap-event proof flags."""
-    from sml_tpu.obs import regress
-
-    def sidecar(scale):
-        return regress.normalize({"legs": {}, "metrics": {},
-                                  "scale": scale})
-
-    base_block = {
-        "rows": 10_000_000, "ingest_rows_per_s": 300_000.0,
-        "predict_rows_per_s": 400_000.0,
-        "prefetch": {"events_ok": True},
-    }
-    base = sidecar(base_block)
-    # identical candidate: clean
-    assert regress.compare(base, sidecar(dict(base_block)))["ok"]
-    # block vanished from a sidecar: coverage regression
-    res = regress.compare(base, sidecar(None))
-    assert not res["ok"]
-    assert any(f["kind"] == "missing-scale-block"
-               for f in res["regressions"])
-    # driver records can never carry the block: exempt
-    rec = regress.normalize({"parsed": {}, "tail": ""})
-    assert regress.compare(base, rec)["ok"]
-    # ingest throughput dropped 30% (> capped 18% tolerance): flags
-    slow = dict(base_block, ingest_rows_per_s=210_000.0)
-    res = regress.compare(base, sidecar(slow))
-    assert any(f["kind"] == "scale-throughput"
-               and f["key"] == "ingest_rows_per_s"
-               for f in res["regressions"])
-    # overlap proof vanished: the double buffer degraded to serial
-    serial = dict(base_block, prefetch={"events_ok": False})
-    res = regress.compare(base, sidecar(serial))
-    assert any(f["kind"] == "scale-overlap" for f in res["regressions"])
-    # different row counts are not comparable: no throughput judgment
-    other = dict(base_block, rows=1_000_000,
-                 ingest_rows_per_s=100_000.0)
-    assert regress.compare(base, sidecar(other))["ok"]
-
-
 # ------------------------------------------------------------- 1M-row smoke
 def test_scale_smoke_1m_rows():
     """Tier-1-safe 1M-row synthetic smoke: chunked ingest + fit +

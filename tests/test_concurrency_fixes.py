@@ -207,7 +207,7 @@ def test_kernel_spec_snapshot_race():
         i = 0
         while not stop.is_set():
             sc._kernel_spec = None if i % 2 else \
-                {"kernel": "pallas", "block_rows": 256, "tuned": True}
+                {"kernel": "pallas", "block_rows": 256}
             i += 1
 
     t = threading.Thread(target=flipper, daemon=True)

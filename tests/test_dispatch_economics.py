@@ -1,6 +1,6 @@
 """PR 5 dispatch economics: grid-fused trial batching, fused-TPE
-generations, and the mapInPandas routing hint (docs/PERF.md § Dispatch
-economics).
+generations, and the mapInPandas routing hint (docs/DESIGN_NOTES.md
+§ Dispatch economics).
 
 The fusion contract: a G-point tree-regressor grid over k folds executes
 its fold-fits in <= ceil(G*k / sml.cv.maxFusedTrials) tree-fit device

@@ -9,9 +9,8 @@ and `tracking.log_model` (reloaded-vs-self distance exactly zero); the
 serving micro-batch path populates `engine_health()["drift"]` /
 `health_report()` with worst-request trace exemplars; the chunked ingest
 judges per-chunk drift (the refit-trigger signal); every drift
-observation site honors the disabled-overhead contract; the regress
-sentry guards the sidecar `drift` block's proofs; and a dead canary
-shadow is counted instead of silently reporting zero divergence.
+observation site honors the disabled-overhead contract; and a dead
+canary shadow is counted instead of silently reporting zero divergence.
 """
 
 import json
@@ -26,12 +25,11 @@ from sml_tpu import obs
 from sml_tpu.conf import GLOBAL_CONF
 from sml_tpu.frame._chunks import (ArrayChunkSource, DatasetSketch,
                                    FeatureSketch)
-from sml_tpu.ml import Pipeline
+from sml_tpu.ml import DeviceScorer, Pipeline
 from sml_tpu.ml.base import Saveable
 from sml_tpu.ml.feature import VectorAssembler
 from sml_tpu.ml.regression import LinearRegression, RandomForestRegressor
 from sml_tpu.obs import drift
-from sml_tpu.obs import regress
 from sml_tpu.serving import ServingEndpoint
 from sml_tpu.utils.profiler import PROFILER
 
@@ -276,6 +274,16 @@ def test_chunked_fit_reuses_ingest_sketch(obs_on):
 
 
 # ----------------------------------------------------- serving + ingest
+# The traffic below is 64 requests of 8 rows, and a request still queued
+# `sml.serve.requestTimeoutMillis` (250 ms) after admission is shed. A
+# flush at a width the process has not run compiles `forest_forward` on
+# the serving thread (0.4 s alone), so whenever a busy machine split the
+# 64 over two flushes, the second lot expired behind the first's compile.
+# The set-up makes the width ONE and warm: a full batch of 512 rows
+# flushes at once, and `drift_serving` has run that program already.
+_ONE_FLUSH = dict(max_batch_rows=512, flush_micros=100_000)
+
+
 @pytest.fixture()
 def drift_serving(spark, tmp_path):
     mlflow.set_tracking_uri(str(tmp_path / "runs"))
@@ -290,6 +298,7 @@ def drift_serving(spark, tmp_path):
                                registered_model_name="drift-serve")
     mlflow.MlflowClient().transition_model_version_stage(
         "drift-serve", 1, stage="Production")
+    DeviceScorer(model).score_block(X[:_ONE_FLUSH["max_batch_rows"]])
     yield model
     for k, v in prev.items():
         GLOBAL_CONF.set(k, v)
@@ -297,8 +306,7 @@ def drift_serving(spark, tmp_path):
 
 def test_serving_drift_block_and_exemplars(drift_serving):
     Xs, _ = make_xy(512, seed=91, shift=True)
-    with ServingEndpoint("drift-serve", "Production",
-                         flush_micros=500) as ep:
+    with ServingEndpoint("drift-serve", "Production", **_ONE_FLUSH) as ep:
         futs = [ep.submit(Xs[lo:lo + 8]) for lo in range(0, 512, 8)]
         for f in futs:
             f.result(timeout=30)
@@ -325,8 +333,7 @@ def test_serving_drift_block_and_exemplars(drift_serving):
 
 def test_serving_iid_traffic_stays_clean(drift_serving):
     Xi, _ = make_xy(512, seed=92)
-    with ServingEndpoint("drift-serve", "Production",
-                         flush_micros=500) as ep:
+    with ServingEndpoint("drift-serve", "Production", **_ONE_FLUSH) as ep:
         futs = [ep.submit(Xi[lo:lo + 8]) for lo in range(0, 512, 8)]
         for f in futs:
             f.result(timeout=30)
@@ -387,55 +394,6 @@ def test_disabled_overhead_drift_observation_sites():
     # stamps NO baseline (and pays no sketch/traversal)
     assert drift.capture_fit_baseline(
         np.zeros((10, F)), np.zeros(10), None, object()) is None
-
-
-# ------------------------------------------------------- regress sentry
-def _sidecar(drift_block):
-    return {"legs": {}, "value": 1.0, "metrics": {}, "drift": drift_block}
-
-
-def _drift_block(shift_flagged=True, named_ok=True, iid_flagged=False,
-                 bit_compat=True):
-    return {
-        "baseline": {"reload_bit_compat": bit_compat},
-        "iid": {"flagged": iid_flagged, "n_flagged": int(iid_flagged),
-                "max_severity": 0.4},
-        "shift": {"flagged": shift_flagged, "named_ok": named_ok,
-                  "n_flagged": 3},
-    }
-
-
-def test_regress_guards_drift_proofs():
-    base = regress.normalize(_sidecar(_drift_block()))
-    # null self-compare: clean
-    assert regress.compare(base, base)["ok"]
-    # vanished block = coverage regression (sidecar candidates only)
-    gone = regress.normalize({"legs": {}, "value": 1.0, "metrics": {}})
-    r = regress.compare(base, gone)
-    assert not r["ok"]
-    assert any(f["kind"] == "missing-drift-block"
-               for f in r["regressions"])
-    # detection lost
-    blind = regress.normalize(_sidecar(_drift_block(shift_flagged=False)))
-    r = regress.compare(base, blind)
-    assert any(f["kind"] == "drift-detection" for f in r["regressions"])
-    # features no longer named
-    unnamed = regress.normalize(_sidecar(_drift_block(named_ok=False)))
-    r = regress.compare(base, unnamed)
-    assert any(f["key"] == "shift.named_ok" for f in r["regressions"])
-    # iid no-false-positive proof lost
-    crying = regress.normalize(_sidecar(_drift_block(iid_flagged=True)))
-    r = regress.compare(base, crying)
-    assert any(f["kind"] == "drift-false-positive"
-               for f in r["regressions"])
-    # baseline round trip no longer bit-compatible
-    drifted = regress.normalize(_sidecar(_drift_block(bit_compat=False)))
-    r = regress.compare(base, drifted)
-    assert any(f["kind"] == "drift-roundtrip" for f in r["regressions"])
-    # the committed sidecar's drift block self-compares clean
-    committed = regress.load("bench_legs.json")
-    assert committed.get("drift") is not None
-    assert regress.compare(committed, committed)["ok"]
 
 
 # ------------------------------------------------------ canary satellites

@@ -1,27 +1,17 @@
 """Engine health layer (ISSUE 7): streaming metrics core, per-device
-straggler attribution, engine_health() snapshot, and the bench_diff
-perf-regression sentry CI gate.
+straggler attribution and the engine_health() snapshot.
 
 Acceptance:
 - log-bucketed p50/p99 land within ONE BUCKET WIDTH (2**(1/8)) of the
-  exact sorted-sample computation at the same rank (the contract that
-  let bench.py's raw-sort path be deleted);
+  exact sorted-sample computation at the same rank;
 - an injected 8-device skewed timing profile names the slow chip, the
   skew ratio matches the injected imbalance, and the report survives a
   Chrome-trace export round-trip;
 - `engine_health()` is populated (metric quantiles, audit, ledger, SLO
-  burn-rate) after a serving-shaped load;
-- `scripts/bench_diff.py` self-compare on the committed artifacts exits
-  0 with zero findings; a >=20% injected wall regression on any leg is
-  flagged and exits non-zero.
+  burn-rate) after a serving-shaped load.
 """
 
-import json
 import math
-import subprocess
-import sys
-import os
-import re
 import time
 
 import numpy as np
@@ -31,10 +21,6 @@ from sml_tpu import obs
 from sml_tpu.conf import GLOBAL_CONF
 from sml_tpu.obs._metrics import BUCKET_GROWTH, LogHistogram
 from sml_tpu.obs._trace import PID_SKEW, to_trace_events
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
-BENCH_DIFF = os.path.join(REPO, "scripts", "bench_diff.py")
 
 
 @pytest.fixture()
@@ -59,9 +45,9 @@ def test_histogram_percentile_parity_with_exact_sort():
     """Satellite: the log-bucketed p50/p99 over a serving-leg-shaped
     latency sample lands within one bucket width of the exact
     sorted-sample quantile at the same rank — the precision contract
-    that replaced bench.py's raw-sort percentile path."""
+    of reading percentiles from buckets and not from kept samples."""
     rng = np.random.default_rng(42)
-    # the bench serving leg's shape: ~2000 lognormal request latencies ms
+    # a serving run's shape: ~2000 lognormal request latencies ms
     samples = np.exp(rng.normal(1.2, 0.9, 2000))
     h = LogHistogram()
     for s in samples:
@@ -192,7 +178,7 @@ def test_trace_renders_one_lane_per_device(recorder):
 
 
 def test_skew_note_honors_real_device_ids(recorder):
-    """The bench probe passes jax.Device.ids: the report and the trace
+    """A caller may pass jax.Device.ids: the report and the trace
     lanes must indict the REAL chip, not the shard's row-order
     position (they differ on non-identity device assignments)."""
     attr = obs.SKEW.note("fit", [0.01, 0.09, 0.02], devices=[12, 7, 30])
@@ -283,186 +269,3 @@ def test_endpoint_latency_flows_into_dispatch_histograms(recorder):
     h = obs.METRICS.histogram("dispatch.host_ms")
     assert h is not None and h.count >= 1
     assert h.quantile(0.5) >= 1.0  # >= ~2ms measured, one-bucket exact
-
-
-# -------------------------------------------------------- regression sentry
-def _run_diff(*args):
-    return subprocess.run(
-        [sys.executable, BENCH_DIFF, *args],
-        capture_output=True, text=True, timeout=120, cwd=REPO)
-
-
-def _driver_record() -> dict:
-    """A document in the driver's record shape (`{n, cmd, rc, tail,
-    parsed}`: the captured end of a `python bench.py` run plus its parsed
-    headline), built inline with made-up walls — the format
-    `obs/regress.py` must keep reading, without keeping any particular
-    run of it in the tree."""
-    legs = {"ml02_lr": 4.0, "ml06_dt": 2.5, "ml07_rf": 2.5,
-            "ml11_xgb": 3.5, "ml12_mapinpandas": 0.4,
-            "ml13_applyinpandas": 0.05}
-    headline = {"metric": "ml02-ml13 suite wall-clock (synthetic record)",
-                "value": round(sum(legs.values()), 3), "unit": "seconds",
-                "vs_baseline": 2.0}
-    tail = "devices: [synthetic]\nwarmup (incl. compiles): 100.0s\n" \
-        + "".join(f"  {k:<24s}{v:>6.2f}s\n" for k, v in legs.items()) \
-        + "  rmse_xgb                   65.000\n" \
-        + json.dumps(headline) + "\n"
-    return {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": tail,
-            "parsed": headline}
-
-
-def test_bench_diff_self_compare_committed_artifacts(tmp_path):
-    """Satellite/acceptance: a driver-shaped record and the committed
-    sidecar each self-compare to ZERO findings, exit 0 — and the gate
-    runs jax-free (it is a tier-1 CI test)."""
-    record = tmp_path / "driver_record.json"
-    record.write_text(json.dumps(_driver_record()))
-    for artifact in (str(record), os.path.join(REPO, "bench_legs.json")):
-        proc = _run_diff(artifact, "--json")
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        result = json.loads(proc.stdout)
-        assert result["ok"] is True
-        assert result["regressions"] == []
-        assert result["checked"] > 0
-
-
-def test_bench_diff_flags_injected_sidecar_regression(tmp_path):
-    """Acceptance: a >=20% injected wall regression on any sidecar leg is
-    flagged and exits non-zero; engine-counter growth is flagged too."""
-    with open(os.path.join(REPO, "bench_legs.json")) as f:
-        doc = json.load(f)
-    leg = doc["legs"]["ml07_cv"]
-    leg["seconds"] = round(leg["seconds"] * 1.25, 3)
-    leg["seconds_per_pass"] = [round(x * 1.25, 3)
-                               for x in leg["seconds_per_pass"]]
-    cand = tmp_path / "cand.json"
-    cand.write_text(json.dumps(doc))
-    proc = _run_diff(os.path.join(REPO, "bench_legs.json"), str(cand),
-                     "--json")
-    assert proc.returncode == 1, proc.stdout
-    result = json.loads(proc.stdout)
-    keys = {f["key"] for f in result["regressions"]}
-    assert "ml07_cv" in keys
-
-
-def test_bench_diff_flags_injected_bench_record_regression(tmp_path):
-    """The driver-record format is diffable too: a 30% slower leg in
-    the tail flags."""
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(_driver_record()))
-    doc = _driver_record()
-    doc["tail"] = re.sub(
-        r"ml11_xgb(\s+)([0-9.]+)s",
-        lambda m: f"ml11_xgb{m.group(1)}{float(m.group(2)) * 1.3:.2f}s",
-        doc["tail"])
-    cand = tmp_path / "cand.json"
-    cand.write_text(json.dumps(doc))
-    proc = _run_diff(str(base), str(cand), "--json")
-    assert proc.returncode == 1, proc.stdout
-    result = json.loads(proc.stdout)
-    assert any(f["key"] == "ml11_xgb" and f["kind"] == "leg-wall"
-               for f in result["regressions"])
-
-
-def test_bench_diff_counter_and_collective_and_coverage_rules(tmp_path):
-    """The non-wall rules: a leg vanishing, a dispatch-count growth, and
-    a multichip psum-payload growth each flag independently."""
-    from sml_tpu.obs import regress
-    base = regress.load(os.path.join(REPO, "bench_legs.json"))
-    # a leg disappears -> coverage regression
-    import copy
-    cand = copy.deepcopy(base)
-    cand["legs"].pop("ml06_dt")
-    res = regress.compare(base, cand)
-    assert any(f["kind"] == "missing-leg" and f["key"] == "ml06_dt"
-               for f in res["regressions"])
-    # tree-fit dispatch count grows -> fusion-contract regression (the
-    # committed sidecar predates per-leg counters, so pin them on both
-    # sides and grow the candidate's)
-    base2 = copy.deepcopy(base)
-    base2["legs"]["ml07_cv"]["counters"]["tree.fit_dispatch"] = 4.0
-    cand = copy.deepcopy(base2)
-    cand["legs"]["ml07_cv"]["counters"]["tree.fit_dispatch"] = 13.0
-    res = regress.compare(base2, cand)
-    assert any(f["kind"] == "leg-counter"
-               and f["key"].endswith("tree.fit_dispatch")
-               for f in res["regressions"])
-    # multichip psum payload grows 10% -> collective-static regression
-    with open(os.path.join(REPO, "bench_legs.json")) as f:
-        raw = json.load(f)
-    if raw.get("multichip"):
-        cand_raw = copy.deepcopy(raw)
-        for e in cand_raw["multichip"]["widths"]:
-            e["collective_psum_bytes"] *= 1.10
-        res = regress.compare(regress.normalize(raw),
-                              regress.normalize(cand_raw))
-        assert any(f["kind"] == "multichip-collective"
-                   for f in res["regressions"])
-    # kernel.fallback growth -> EXACT rule: growth by even 1 flags, and
-    # a key ABSENT from the base leg counts as 0 (legs only record
-    # counters that fired, so the realistic regression is 0 -> N with no
-    # base key at all)
-    cand = copy.deepcopy(base)
-    assert "kernel.fallback" not in cand["legs"]["ml07_rf"]["counters"]
-    cand["legs"]["ml07_rf"]["counters"]["kernel.fallback"] = 1.0
-    res = regress.compare(base, cand)
-    assert any(f["kind"] == "leg-counter"
-               and f["key"].endswith("kernel.fallback")
-               for f in res["regressions"])
-    if raw.get("kernel"):
-        cand_raw = copy.deepcopy(raw)
-        for e in cand_raw["kernel"]["legs"]:
-            e["kernel_counters"]["kernel.fallback"] += 1.0
-        res = regress.compare(regress.normalize(raw),
-                              regress.normalize(cand_raw))
-        assert any(f["kind"] == "kernel-fallback"
-                   for f in res["regressions"])
-        # the kernelbench gate vanishing (or one sweep leg) is coverage
-        # loss, same as an ordinary leg going missing
-        cand_raw = copy.deepcopy(raw)
-        cand_raw.pop("kernel")
-        res = regress.compare(regress.normalize(raw),
-                              regress.normalize(cand_raw))
-        assert any(f["kind"] == "missing-kernel-block"
-                   for f in res["regressions"])
-        cand_raw = copy.deepcopy(raw)
-        cand_raw["kernel"]["legs"] = cand_raw["kernel"]["legs"][1:]
-        res = regress.compare(regress.normalize(raw),
-                              regress.normalize(cand_raw))
-        assert any(f["kind"] == "missing-kernel-leg"
-                   for f in res["regressions"])
-        # and the committed kernel block self-compares clean
-        res0 = regress.compare(regress.normalize(raw),
-                               regress.normalize(raw))
-        assert res0["ok"]
-
-
-def test_regress_verdicts_annotate_the_trace(recorder, tmp_path):
-    """Verdicts land in the flight recorder as regress.verdict events
-    and render as instant markers in the exported trace; bench_diff
-    --trace writes the standalone marker file."""
-    from sml_tpu.obs import regress
-    base = regress.load(os.path.join(REPO, "bench_legs.json"))
-    import copy
-    cand = copy.deepcopy(base)
-    cand["legs"]["ml02_lr"]["seconds"] *= 1.5
-    cand["legs"]["ml02_lr"]["passes"] = [
-        x * 1.5 for x in cand["legs"]["ml02_lr"]["passes"]]
-    res = regress.compare(base, cand)
-    assert not res["ok"]
-    n = obs.annotate_regressions(res["regressions"])
-    assert n == len(res["regressions"]) >= 1
-    trace = to_trace_events(obs.RECORDER.events())
-    marks = [e for e in trace if e.get("ph") == "i"
-             and e["name"] == "regress.verdict"]
-    assert len(marks) >= 1
-    assert marks[0]["args"]["key"] == "ml02_lr"
-    # the CLI's standalone trace file
-    out = tmp_path / "verdicts.json"
-    proc = _run_diff(os.path.join(REPO, "bench_legs.json"),
-                     os.path.join(REPO, "bench_legs.json"),
-                     "--trace", str(out))
-    assert proc.returncode == 0
-    doc = json.loads(out.read_text())
-    assert "traceEvents" in doc

@@ -475,71 +475,6 @@ def test_ct_trainer_promotes_through_fleet(spark, tmp_path, obs_on):
     assert trainer.stats()["promotions"] == 1
 
 
-# ----------------------------------------------------- regress guard
-def _fleet_block(hung=0, up_ok=True, down_ok=True, clean=True,
-                 rolled_back=True, bb=True, order=True, fanin=True,
-                 low_shed=0.6, low_p99=50.0):
-    return {
-        "requests": 10_000,
-        "hung_futures": hung,
-        "priority_order_ok": order,
-        "priority": {
-            "high": {"p99_ms": 20.0, "shed_rate": 0.0},
-            "normal": {"p99_ms": 30.0, "shed_rate": 0.2},
-            "low": {"p99_ms": low_p99, "shed_rate": low_shed},
-        },
-        "scale": {"up_ok": up_ok, "down_ok": down_ok},
-        "rollout": {"clean": {"passed": clean},
-                    "rollback": {"rolled_back": rolled_back,
-                                 "blackbox_on_disk": bb}},
-        "trace": {"fanin_ok": fanin},
-    }
-
-
-def _sidecar(block):
-    doc = {"legs": {}, "value": 1.0, "metrics": {}}
-    if block is not None:
-        doc["fleet"] = block
-    return doc
-
-
-def test_regress_guards_fleet_proofs():
-    from sml_tpu.obs import regress
-    base = regress.normalize(_sidecar(_fleet_block()))
-    assert regress.compare(base, base)["ok"]
-    # vanished block = coverage regression (sidecar candidates only)
-    r = regress.compare(base, regress.normalize(_sidecar(None)))
-    assert any(f["kind"] == "missing-fleet-block"
-               for f in r["regressions"])
-
-    def bad(**kw):
-        return regress.compare(
-            base, regress.normalize(_sidecar(_fleet_block(**kw))))
-
-    assert any(f["kind"] == "fleet-liveness"
-               for f in bad(hung=3)["regressions"])
-    for kw, key in ((dict(rolled_back=False),
-                     "rollout.rollback.rolled_back"),
-                    (dict(bb=False), "rollout.rollback.blackbox_on_disk"),
-                    (dict(clean=False), "rollout.clean.passed"),
-                    (dict(up_ok=False), "scale.up_ok"),
-                    (dict(down_ok=False), "scale.down_ok"),
-                    (dict(order=False), "priority_order_ok"),
-                    (dict(fanin=False), "trace.fanin_ok")):
-        r = bad(**kw)
-        assert any(f["kind"] == "fleet-proof" and f["key"] == key
-                   for f in r["regressions"]), key
-    # load numbers: p99 at the serving tolerance, shed rate noise-aware
-    assert any(f["kind"] == "fleet-latency"
-               for f in bad(low_p99=200.0)["regressions"])
-    assert any(f["kind"] == "fleet-shed-rate"
-               for f in bad(low_shed=0.95)["regressions"])
-    # the committed sidecar's fleet block self-compares clean
-    committed = regress.load("bench_legs.json")
-    assert committed.get("fleet") is not None
-    assert regress.compare(committed, committed)["ok"]
-
-
 # --------------------------------------------------- shed reason tags
 def test_deadline_shed_counts_reason(spark):
     """The deadline shed path is reason-tagged next to the total."""

@@ -203,8 +203,6 @@ def _regen(only=()):
     """Re-pin every metric, or the ones named in `only` alone."""
     import jax
     import jaxlib
-    # preserve foreign top-level blocks (bench_metrics_1m is written by
-    # `python bench.py --pin-goldens`, not by this regen)
     doc = {}
     if os.path.exists(GOLDEN_PATH):
         with open(GOLDEN_PATH) as f:
@@ -217,10 +215,9 @@ def _regen(only=()):
     else:
         doc.update({"n_rows": N_ROWS, "seed": 42,
                     "environment": "virtual 8-device CPU mesh (f32 "
-                                   "histograms); the TPU bench uses bf16 "
-                                   "histogram operands and reports its own "
-                                   "metric values in BENCH_r*.json; pinned "
-                                   f"on {on}",
+                                   "histograms); the chip's bf16 "
+                                   "histogram operands read other digits; "
+                                   f"pinned on {on}",
                     "metrics": got})
     with open(GOLDEN_PATH, "w") as f:
         json.dump(doc, f, indent=1)

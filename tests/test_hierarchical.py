@@ -11,7 +11,7 @@ Contracts:
   {1x8, 2x4, 4x2}, and its per-hop byte counters obey
   dcn = ici / ici_size exactly — the cross-host hop carries only the
   inter-group fraction of the flat allreduce payload (the acceptance
-  bound, also recorded in the committed `multihost` bench block).
+  bound).
 - HOST-SHAPE INVARIANCE: DT/RF/xgboost fits and CV avgMetrics on host
   meshes match the 1-host-group fit at every tested shape (sampling is
   layout-invariant; remaining drift is float reduction order, the same
@@ -24,9 +24,7 @@ Contracts:
   checkpoint boundary) resumes from the round-level checkpoint on the
   surviving groups and finishes the same model as the uninterrupted
   fit, counting `elastic.resume`/`elastic.repartition`.
-- Straggler attribution grows HOST lanes (`skew.host.*`), and the
-  regression sentry judges the `multihost` sidecar block (vanished
-  block, DCN-byte growth, lost parity, lost skew table).
+- Straggler attribution grows HOST lanes (`skew.host.*`).
 """
 
 import os
@@ -661,65 +659,3 @@ def test_skew_tracker_host_lanes_and_report(recording):
     assert "slowest_host" not in tracker.straggler_report()
     with pytest.raises(ValueError):
         tracker.note("bad", [1.0, 2.0], hosts=[0])
-
-
-# ------------------------------------------------- regression-sentry judge
-def _mh_entry(**over):
-    e = {"hosts": 2, "per_host": 4, "seconds": 1.0, "psum_ici": 5,
-         "psum_dcn": 5, "psum_bytes_ici": 9408.0, "psum_bytes_dcn": 2352.0,
-         "parity_ok": True, "slowest_host": 0,
-         "host_skew": [{"host": 0, "compute_ms": 1.0},
-                       {"host": 1, "compute_ms": 1.2}]}
-    e.update(over)
-    return e
-
-
-def _sidecar(entry=None, block=True):
-    doc = {"legs": {}}
-    if block:
-        doc["multihost"] = {"shapes": [entry or _mh_entry()]}
-    return doc
-
-
-def test_regress_judges_multihost_block():
-    """obs/regress.py judges the `multihost` sidecar block: a vanished
-    block or shape, DCN-byte growth past the 1% static tolerance, a
-    flipped parity proof, and a lost host-skew table are regressions;
-    an identical candidate and a BENCH_r0x driver record are clean."""
-    from sml_tpu.obs import regress
-
-    base = regress.normalize(_sidecar())
-    ok = regress.compare(base, regress.normalize(_sidecar()))
-    assert ok["ok"]
-
-    res = regress.compare(base, regress.normalize(_sidecar(block=False)))
-    assert not res["ok"]
-    assert any(f["kind"] == "missing-multihost-block"
-               for f in res["regressions"])
-    # driver records can never carry the block: exempt
-    rec = regress.normalize({"parsed": {}, "tail": ""})
-    assert rec["shape"] == "record"
-    assert regress.compare(base, rec)["ok"]
-
-    grew = regress.normalize(_sidecar(_mh_entry(psum_bytes_dcn=9408.0)))
-    res = regress.compare(base, grew)
-    assert not res["ok"]
-    assert any(f["kind"] == "multihost-collective"
-               and "psum_bytes_dcn" in f["key"] for f in res["regressions"])
-
-    flipped = regress.normalize(_sidecar(_mh_entry(parity_ok=False)))
-    res = regress.compare(base, flipped)
-    assert not res["ok"]
-    assert any(f["kind"] == "multihost-parity" for f in res["regressions"])
-
-    skewless = regress.normalize(_sidecar(_mh_entry(host_skew=None)))
-    res = regress.compare(base, skewless)
-    assert not res["ok"]
-    assert any(f["kind"] == "multihost-skew" for f in res["regressions"])
-
-    reshaped = regress.normalize(
-        {"legs": {}, "multihost": {"shapes": [_mh_entry(hosts=4)]}})
-    res = regress.compare(base, reshaped)
-    assert not res["ok"]
-    assert any(f["kind"] == "missing-multihost-shape"
-               for f in res["regressions"])

@@ -1,6 +1,6 @@
-"""CI enforcement (PR 3): the committed tree must pass graftlint, the
-linter must run jax-free from a cold interpreter, and the bench harness
-must refuse to record from a dirty tree (`bench.py --lint`)."""
+"""CI enforcement (PR 3): the committed tree must pass graftlint, and the
+linter must run jax-free from a cold interpreter and keep its exit-code
+contract."""
 
 import json
 import os
@@ -68,8 +68,7 @@ def test_update_baseline_preserves_reviewed_entries(tmp_path):
     for d in ("sml_tpu", "scripts"):
         shutil.copytree(os.path.join(REPO, d), tmp_path / d,
                         ignore=shutil.ignore_patterns("__pycache__"))
-    for f in ("bench.py", ".graftlint-baseline.json"):
-        shutil.copy(os.path.join(REPO, f), tmp_path / f)
+    shutil.copy(os.path.join(REPO, ".graftlint-baseline.json"), tmp_path)
     os.makedirs(tmp_path / "tests")
     out = subprocess.run(
         [sys.executable, str(tmp_path / "scripts" / "graftlint.py"),
@@ -101,8 +100,8 @@ def test_graftlint_json_reports_suppressions():
 
 def test_exit_code_contract(tmp_path, capsys):
     """The documented contract (scripts/graftlint.py docstring): 0
-    clean, 1 violations, 2 usage/internal error — relied on by the
-    bench gate and CI. All three legs drive main() itself."""
+    clean, 1 violations, 2 usage/internal error — relied on by CI. All
+    three legs drive main() itself."""
     import importlib.util
     spec = importlib.util.spec_from_file_location("_g_contract", RUNNER)
     m = importlib.util.module_from_spec(spec)
@@ -189,88 +188,3 @@ def test_changed_only_filters_to_changed_files(tmp_path):
     part_paths = {v["path"] for v in json.loads(part.stdout)["violations"]}
     assert "sml_tpu/new.py" in part_paths
     assert "sml_tpu/old.py" not in part_paths
-
-
-def test_regress_flags_lint_block_loss_and_violation_growth():
-    """obs/regress.py judges the sidecar `lint` block: a vanished block
-    (sidecar candidates), an unsuppressed-violation increase, or an
-    active-rule-count decrease each flag as a regression."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_regress_lint", os.path.join(REPO, "sml_tpu", "obs",
-                                      "regress.py"))
-    regress = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(regress)
-    lint_block = {"rules": 14, "files": 119, "violations": 0,
-                  "suppressed_pragma": 88, "suppressed_baseline": 3}
-    base = regress.normalize({"legs": {}, "lint": dict(lint_block)})
-    same = regress.normalize({"legs": {}, "lint": dict(lint_block)})
-    assert regress.compare(base, same)["ok"]
-    gone = regress.normalize({"legs": {}})
-    res = regress.compare(base, gone)
-    assert not res["ok"]
-    assert any(f["kind"] == "missing-lint-block"
-               for f in res["regressions"])
-    dirty = regress.normalize({"legs": {},
-                               "lint": dict(lint_block, violations=2)})
-    res2 = regress.compare(base, dirty)
-    assert any(f["kind"] == "lint-violations" for f in res2["regressions"])
-    shrunk = regress.normalize({"legs": {},
-                                "lint": dict(lint_block, rules=9)})
-    res3 = regress.compare(base, shrunk)
-    assert any(f["kind"] == "lint-rules" for f in res3["regressions"])
-    # driver records can never carry the block: exempt from coverage
-    rec = regress.normalize({"parsed": {}, "tail": ""})
-    assert regress.compare(base, rec)["ok"]
-    # absolute >=14-rule floor, judged even against a pre-PR-18 base
-    # record that carried fewer rules
-    old_base = regress.normalize({"legs": {},
-                                  "lint": dict(lint_block, rules=10)})
-    below = regress.normalize({"legs": {},
-                               "lint": dict(lint_block, rules=13)})
-    res4 = regress.compare(old_base, below)
-    assert any(f["kind"] == "lint-rule-floor" for f in res4["regressions"])
-    # untracked-compile-input is exact-mode: ONE occurrence regresses,
-    # even when the total violation count did not grow vs base
-    uci = regress.normalize({"legs": {}, "lint": dict(
-        lint_block, violations=0,
-        violations_by_rule={"untracked-compile-input": 1})})
-    res5 = regress.compare(base, uci)
-    assert any(f["kind"] == "lint-compile-input"
-               for f in res5["regressions"])
-    clean_by_rule = regress.normalize({"legs": {}, "lint": dict(
-        lint_block, violations_by_rule={})})
-    assert regress.compare(base, clean_by_rule)["ok"]
-
-
-def test_bench_lint_gate_refuses_dirty_tree(tmp_path):
-    """Copy the lintable surface, inject a violation, and check
-    `bench.py --lint` exits 1 with the refusal message BEFORE doing any
-    bench work (bench imports only numpy at module level, so this is a
-    sub-second subprocess)."""
-    for d in ("sml_tpu", "scripts"):
-        shutil.copytree(os.path.join(REPO, d), tmp_path / d,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    for f in ("bench.py", ".graftlint-baseline.json"):
-        shutil.copy(os.path.join(REPO, f), tmp_path / f)
-    os.makedirs(tmp_path / "tests")
-    rogue = tmp_path / "sml_tpu" / "rogue.py"
-    rogue.write_text("import time\nT0 = time.time()\n")
-    out = subprocess.run([sys.executable, "bench.py", "--lint"],
-                         cwd=tmp_path, capture_output=True, text=True,
-                         timeout=120)
-    assert out.returncode == 1, out.stdout + out.stderr
-    assert "refusing to record" in out.stderr
-    assert "rogue.py" in out.stdout
-    # and the same tree passes once the violation is gone
-    rogue.unlink()
-    probe = (
-        "import importlib.util, sys\n"
-        "spec = importlib.util.spec_from_file_location('_g', "
-        "'scripts/graftlint.py')\n"
-        "m = importlib.util.module_from_spec(spec)\n"
-        "spec.loader.exec_module(m)\n"
-        "sys.exit(m.main([]))\n")
-    out2 = subprocess.run([sys.executable, "-c", probe], cwd=tmp_path,
-                          capture_output=True, text=True, timeout=120)
-    assert out2.returncode == 0, out2.stdout + out2.stderr

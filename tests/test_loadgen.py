@@ -4,19 +4,10 @@ Acceptance covered here: deterministic trace compilation, the
 coordinated-omission proof (open- vs closed-loop tails diverge on a
 stalled scorer), explicit overrun accounting (never silent), the typed
 bounded-wait `RequestTimeout`, the tail-engineering ladder (flush
-auto-tune bounds, burn-slope admission pre-tightening), per-phase
-worst-request exemplar recovery through the flight-recorder ring, the
-sidecar `load`-block regress rules (positive and negative), the
-closed-loop annotation guards, the committed-sidecar self-compare, and
-the `bench.py --load` dirty-tree refusal.
+auto-tune bounds, burn-slope admission pre-tightening) and per-phase
+worst-request exemplar recovery through the flight-recorder ring.
 """
 
-import importlib.util
-import json
-import os
-import shutil
-import subprocess
-import sys
 import threading
 import time
 
@@ -29,9 +20,6 @@ from sml_tpu.loadgen import (OpenLoopDriver, PhaseSpec, TraceSpec,
                              closed_loop_probe)
 from sml_tpu.serving import MicroBatcher, RequestTimeout
 from sml_tpu.utils.profiler import PROFILER, now
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 
 @pytest.fixture()
@@ -50,16 +38,6 @@ def obs_on():
     yield
     GLOBAL_CONF.set("sml.obs.enabled", old)
     obs.reset()
-
-
-def _regress():
-    """Load obs/regress.py standalone (jax-free), same as bench_diff."""
-    spec = importlib.util.spec_from_file_location(
-        "_regress_load", os.path.join(REPO, "sml_tpu", "obs",
-                                      "regress.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ---------------------------------------------------------------- spec
@@ -307,150 +285,3 @@ def test_burn_slope_tightens_admission_before_breach(profiler_on):
     finally:
         for k, v in prev.items():
             GLOBAL_CONF.set(k, v)
-
-
-# ------------------------------------------------------- regress rules
-def _load_block():
-    return {
-        "requests": 500, "served": 480, "shed": 15, "timeout": 5,
-        "errors": 0, "overrun": 0, "shed_rate": 0.03,
-        "timeout_rate": 0.01,
-        "engineering": {"win": True, "off": {"p999_ms": 40.0},
-                        "on": {"p999_ms": 20.0}},
-        "phases": {
-            "steady": {"p50_ms": 2.0, "p99_ms": 8.0, "p999_ms": 12.0,
-                       "requests": 250, "worst_ms": 14.0,
-                       "worst_trace": "0x0000000000abc",
-                       "classes": {"high": {"p99_ms": 6.0,
-                                            "count": 50}}},
-            "burst": {"p50_ms": 3.0, "p99_ms": 15.0, "p999_ms": 25.0,
-                      "requests": 250, "worst_ms": 30.0,
-                      "worst_trace": "0x0000000000def",
-                      "classes": {}}}}
-
-
-def test_regress_load_rules_positive_and_negative():
-    """obs/regress.py judges the sidecar `load` block: vanished block,
-    overrun growth (exact-mode), lost engineering win, vanished phase,
-    >LOAD_TOL tail growth (per phase and per class), and lost worst-
-    request exemplars each flag; within-tolerance noise does not."""
-    regress = _regress()
-
-    def norm(block):
-        doc = {"legs": {}}
-        if block is not None:
-            doc["load"] = block
-        return regress.normalize(doc)
-
-    def kinds(cand):
-        return {f["kind"]
-                for f in regress.compare(base, cand)["regressions"]}
-
-    base = norm(_load_block())
-    assert regress.compare(base, norm(_load_block()))["ok"]
-    assert "missing-load-block" in kinds(norm(None))
-    # driver records can never carry the block: exempt from coverage
-    assert regress.compare(
-        base, regress.normalize({"parsed": {}, "tail": ""}))["ok"]
-    b = _load_block()
-    b["overrun"] = 2
-    assert "load-overrun" in kinds(norm(b))
-    b = _load_block()
-    b["engineering"]["win"] = False
-    assert "load-engineering" in kinds(norm(b))
-    b = _load_block()
-    del b["phases"]["burst"]
-    assert "missing-load-phase" in kinds(norm(b))
-    b = _load_block()
-    b["phases"]["steady"]["p999_ms"] *= 2.5  # past LOAD_TOL (2x)
-    assert "load-tail" in kinds(norm(b))
-    b = _load_block()
-    b["phases"]["steady"]["p999_ms"] *= 1.5  # open-loop weather
-    assert regress.compare(base, norm(b))["ok"]
-    b = _load_block()
-    b["phases"]["steady"]["classes"]["high"]["p99_ms"] *= 2.5
-    assert "load-tail" in kinds(norm(b))
-    b = _load_block()
-    b["phases"]["steady"]["worst_trace"] = None
-    assert "load-exemplar" in kinds(norm(b))
-
-
-def test_regress_closed_loop_annotation_guards():
-    """Closed- and open-loop percentiles are never compared
-    like-for-like: serving percentiles are judged only when both
-    records carry the same serve_closed_loop annotation, fleet
-    per-class p99 only when both blocks' closed_loop flags agree."""
-    regress = _regress()
-    base = regress.normalize(
-        {"legs": {}, "metrics": {"serve_p99_ms": 10.0}})
-    # annotation mismatch: a 10x "regression" is NOT judged
-    cand = regress.normalize(
-        {"legs": {}, "metrics": {"serve_p99_ms": 100.0,
-                                 "serve_closed_loop": 1.0}})
-    assert regress.compare(base, cand)["ok"]
-    # matched annotations: judged as before
-    cand2 = regress.normalize(
-        {"legs": {}, "metrics": {"serve_p99_ms": 100.0}})
-    res = regress.compare(base, cand2)
-    assert any(f["kind"] == "serve-latency"
-               for f in res["regressions"])
-
-    def fleet_doc(p99, closed_loop=None):
-        fl = {"hung_futures": 0,
-              "priority": {"high": {"p99_ms": p99, "shed_rate": 0.0}}}
-        if closed_loop is not None:
-            fl["closed_loop"] = closed_loop
-        return regress.normalize({"legs": {}, "fleet": fl})
-
-    basef = fleet_doc(10.0)
-    assert regress.compare(basef, fleet_doc(100.0,
-                                            closed_loop=True))["ok"]
-    res2 = regress.compare(basef, fleet_doc(100.0))
-    assert any(f["kind"] == "fleet-latency"
-               for f in res2["regressions"])
-
-
-def test_committed_sidecar_self_compare_and_injected_regression(
-        tmp_path):
-    """The committed bench sidecar self-compares clean (exit 0), and an
-    injected burst-tail regression past LOAD_TOL flips the verdict
-    (exit 1) — scripts/bench_diff.py is the jury, as in CI."""
-    legs = os.path.join(REPO, "bench_legs.json")
-    with open(legs) as f:
-        doc = json.load(f)
-    assert doc.get("load"), "committed sidecar lost its load block"
-    assert int(doc["load"]["overrun"]) == 0
-    assert doc["load"]["engineering"]["win"] is True
-    diff = os.path.join(REPO, "scripts", "bench_diff.py")
-    ok = subprocess.run([sys.executable, diff, legs, legs],
-                        capture_output=True, text=True, timeout=120)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    doc["load"]["phases"]["burst"]["p999_ms"] = \
-        float(doc["load"]["phases"]["burst"]["p999_ms"]) * 3.0
-    bad = tmp_path / "bad_legs.json"
-    bad.write_text(json.dumps(doc))
-    res = subprocess.run([sys.executable, diff, legs, str(bad)],
-                         capture_output=True, text=True, timeout=120)
-    assert res.returncode == 1, res.stdout + res.stderr
-    assert "load-tail" in res.stdout
-
-
-def test_bench_load_gate_refuses_dirty_tree(tmp_path):
-    """`bench.py --load` shares `--lint`'s gate: a tree with a lint
-    violation refuses to record BEFORE any load work (bench imports
-    only numpy at module level, so the refusal is a sub-second
-    subprocess)."""
-    for d in ("sml_tpu", "scripts"):
-        shutil.copytree(os.path.join(REPO, d), tmp_path / d,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    for f in ("bench.py", ".graftlint-baseline.json"):
-        shutil.copy(os.path.join(REPO, f), tmp_path / f)
-    os.makedirs(tmp_path / "tests")
-    rogue = tmp_path / "sml_tpu" / "rogue.py"
-    rogue.write_text("import time\nT0 = time.time()\n")
-    out = subprocess.run([sys.executable, "bench.py", "--load"],
-                         cwd=tmp_path, capture_output=True, text=True,
-                         timeout=120)
-    assert out.returncode == 1, out.stdout + out.stderr
-    assert "refusing to record" in out.stderr
-    assert "rogue.py" in out.stdout

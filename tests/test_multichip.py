@@ -16,7 +16,7 @@ Contracts:
   subtraction, and renders on the trace exporter's counter tracks.
 - CROSS-CHIP TRIAL PARALLELISM: `sml.cv.trialAxisDevices` shards fused
   (grid x fold) elements over a second mesh axis with unchanged metrics.
-- The 8-simulated-device dryrun subprocess exits 0 (the MULTICHIP_r01
+- The 8-simulated-device dryrun subprocess exits 0 (the first dryrun's
   crash class can never regress silently), and a foreign-mesh prewarm
   manifest is skipped, not replayed onto the 8-device mesh.
 """
@@ -402,7 +402,7 @@ def test_prewarm_foreign_manifest_skipped_on_8dev_mesh(spark, xy,
 
 # ------------------------------------------------------ dryrun regression
 def test_dryrun_8dev_subprocess_exits_zero():
-    """The CI gate for the MULTICHIP_r01 crash class: the 8-simulated-
+    """The CI gate for the first dryrun's crash class: the 8-simulated-
     device dryrun runs end-to-end in a clean subprocess and exits 0 —
     mesh sizing from materialized devices, sharded staging, histogram
     trees, eval pushdown, ALS, KMeans, scorer forward, compact linear."""

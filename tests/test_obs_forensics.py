@@ -326,25 +326,6 @@ def test_exception_block_shapes():
 
 
 # ---------------------------------------------------------- sentry tolerance
-def test_bench_diff_ignores_trace_annotation_fields():
-    """Satellite: the regression sentry must neither crash on nor flag
-    the non-perf sidecar annotations PR 8 added (the serve_worst_trace
-    trace-id exemplar is a string, not a load number)."""
-    from sml_tpu.obs import regress
-    doc = {"value": 1.0, "timed_pass_walls": [1.0],
-           "legs": {"serving": {"seconds": 1.0,
-                                "seconds_per_pass": [1.0]}},
-           "metrics": {"serve_p50_ms": 2.0,
-                       "serve_worst_trace": "0x21bd608200001"}}
-    base = regress.normalize(doc)
-    assert "serve_worst_trace" not in base["metrics"]
-    assert base["metrics"]["serve_p50_ms"] == 2.0
-    cand = json.loads(json.dumps(doc))
-    cand["metrics"]["serve_worst_trace"] = "0xdeadbeef00000"  # changed id
-    res = regress.compare(base, regress.normalize(cand))
-    assert res["ok"], res["regressions"]
-
-
 # ------------------------------------------------------- wall-clock anchoring
 def test_sink_header_and_trace_carry_epoch_anchor(recorder, tmp_path):
     """Satellite: the JSONL sink's header line and the exported trace's

@@ -1,35 +1,34 @@
-"""Name-taxonomy lint (scripts/check_obs_taxonomy.py): every
-PROFILER.span/count and RECORDER.emit/counter/gauge call site in the
-package must use a name registered in sml_tpu/obs/taxonomy.py, so
-counter/span names cannot silently drift between the modules that emit
-them and the report/exporter/autologger that read them (PR 2 satellite).
+"""Name-taxonomy lint (graftlint rule `obs-taxonomy`,
+sml_tpu/lint/rules/taxonomy.py): every PROFILER.span/count and
+RECORDER.emit/counter/gauge call site in the package must use a name
+registered in sml_tpu/obs/taxonomy.py, so counter/span names cannot
+silently drift between the modules that emit them and the
+report/exporter/autologger that read them (PR 2 satellite); and every
+registered pattern must have a call site that can emit it.
 """
 
 import importlib.util
 import os
 
-import pytest
+from sml_tpu.lint.rules import taxonomy as checker
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 
 
-@pytest.fixture(scope="module")
-def checker():
-    path = os.path.join(REPO, "scripts", "check_obs_taxonomy.py")
-    spec = importlib.util.spec_from_file_location("check_obs_taxonomy", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_package_is_taxonomy_clean(checker):
+def test_package_is_taxonomy_clean():
     violations = checker.check_tree()
     assert violations == [], "\n".join(
         f"{f}:{ln}: {msg}" for f, ln, msg in violations)
 
 
-def test_checker_catches_rogue_names(checker, tmp_path):
+def test_every_pattern_has_an_emitter():
+    """The reverse of the check above: a pattern that no call site can
+    emit is registry left behind by code that went."""
+    assert checker.unemitted_patterns() == []
+
+
+def test_checker_catches_rogue_names(tmp_path):
     """The lint actually detects drift: unregistered literals, dynamic
     families outside any wildcard, and computed names outside obs/."""
     bad = tmp_path / "rogue.py"
@@ -39,7 +38,7 @@ def test_checker_catches_rogue_names(checker, tmp_path):
         "with PROFILER.span(f'mystery.{x}'):\n    pass\n"   # rogue family
         "RECORDER.emit('cache', name_var)\n"                # computed name
         "RECORDER.gauge('hbm.bin_cache_bytes', 1)\n")       # registered: ok
-    taxonomy = checker._load_taxonomy()
+    taxonomy = checker.load_taxonomy()
     violations = checker.check_file(str(bad), taxonomy)
     msgs = "\n".join(m for _, _, m in violations)
     assert len(violations) == 3, msgs
@@ -48,8 +47,8 @@ def test_checker_catches_rogue_names(checker, tmp_path):
     assert "computed" in msgs
 
 
-def test_wildcards_and_exact_names(checker):
-    t = checker._load_taxonomy()
+def test_wildcards_and_exact_names():
+    t = checker.load_taxonomy()
     assert t.is_registered("span", "shuffle.partition")
     assert t.is_registered("span", "program.tree_ensemble")
     assert t.is_registered("count", "staging.h2d_bytes")
@@ -61,6 +60,10 @@ def test_wildcards_and_exact_names(checker):
     assert not t.prefix_registered("span", "mystery.")
 
 
-def test_script_cli_exits_clean(checker):
+def test_script_cli_exits_clean():
     """The committed tree passes the lint via the CLI entry too."""
-    assert checker.main() == 0
+    path = os.path.join(REPO, "scripts", "graftlint.py")
+    spec = importlib.util.spec_from_file_location("_graftlint_cli", path)
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    assert runner.main(["--rule", "obs-taxonomy"]) == 0

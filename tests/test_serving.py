@@ -174,6 +174,12 @@ def test_promote_while_serving_race(registered_pair):
     m1, m2, X = registered_pair
     exp1 = DeviceScorer(m1).score_block(X)
     exp2 = DeviceScorer(m2).score_block(X)
+    # three clients coalesce to one, two or three blocks a flush: run the
+    # other two widths here, or the first flush at each compiles on the
+    # serving thread and, on a busy machine, the requests queued behind
+    # it pass `sml.serve.requestTimeoutMillis` and are shed
+    for k in (2, 3):
+        DeviceScorer(m1).score_block(np.tile(X, (k, 1)))
     errors, torn = [], []
     stop = threading.Event()
 

@@ -1,37 +1,30 @@
-"""Pallas fused tree-traversal inference kernel + autotuner (ISSUE 12).
+"""Pallas fused tree-traversal inference kernel (ISSUE 12).
 
 The contract (docs/KERNELS.md): with `sml.infer.kernel=pallas` on a
 non-TPU backend the traversal kernel runs in INTERPRET mode, op-for-op
 `_forest_margin`'s math — kernel-path predictions must be BIT-IDENTICAL
 to the XLA traversal for DT/RF/boosted ensembles across bin dtypes, NaN
 rows, and the logistic finalize; 'auto' never emulates on CPU; the
-resolved (kernel, block_rows) spec keys the program cache; autotuned
-specs round-trip through the prewarm manifest; the VMEM guard demotes
-oversized (block_rows × trees) specs on real TPU; and the fallback /
-spec surface shows in `engine_health()["infer_kernel"]` and the
-`obs/regress.py` kernel_infer rules.
+resolved (kernel, block_rows) spec keys the program cache; the VMEM
+guard demotes oversized (block_rows × trees) specs on real TPU; and the
+fallback / spec surface shows in `engine_health()["infer_kernel"]`.
 """
 
-import json
-import os
 import types
 
 import numpy as np
 import pytest
 
 from sml_tpu.conf import GLOBAL_CONF
-from sml_tpu.utils.profiler import PROFILER
 
 
 @pytest.fixture()
 def infer_conf():
     """Restore scoring-kernel knobs after each test."""
     keys = ("sml.infer.kernel", "sml.infer.kernelBlockRows",
-            "sml.infer.autotune", "sml.profiler.enabled",
-            "sml.dispatch.mode")
+            "sml.profiler.enabled", "sml.dispatch.mode")
     prev = {k: GLOBAL_CONF.get(k) for k in keys}
     GLOBAL_CONF.set("sml.profiler.enabled", True)
-    GLOBAL_CONF.set("sml.infer.autotune", False)
     yield
     for k, v in prev.items():
         GLOBAL_CONF.set(k, v)
@@ -72,8 +65,7 @@ def _margins(spec, binned, kernel):
     GLOBAL_CONF.set("sml.infer.kernel", kernel)
     sf, sb, lv, w = spec.stacked()
     return inference.predict_forest_sharded(
-        binned, sf, sb, lv, w, spec.depth, base=spec.base,
-        n_bins=spec.binning.edges.shape[1] + 1)
+        binned, sf, sb, lv, w, spec.depth, base=spec.base)
 
 
 # ------------------------------------------------------------ bit parity
@@ -183,15 +175,14 @@ def test_auto_never_selects_pallas_on_cpu(spark, infer_conf):
     from sml_tpu.ml import inference
     GLOBAL_CONF.set("sml.infer.kernel", "auto")
     f0 = inference._KERNEL_STATE["fallbacks"]
-    k, br, tuned = inference.resolve_infer_kernel(
-        n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
-    assert (k, br, tuned) == ("xla", 0, False)
+    k, br = inference.resolve_infer_kernel(
+        n_trees=5, n_nodes=31, n_feat=8)
+    assert (k, br) == ("xla", 0)
     assert inference._KERNEL_STATE["fallbacks"] == f0
     GLOBAL_CONF.set("sml.infer.kernel", "bogus")
     with pytest.raises(ValueError, match="sml.infer.kernel"):
         inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32,
-            n_rows=4096)
+            n_trees=5, n_nodes=31, n_feat=8)
 
 
 def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
@@ -208,7 +199,7 @@ def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
     f0 = inference._KERNEL_STATE["fallbacks"]
     with pytest.raises(RuntimeError, match="Boom: no pallas here"):
         inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
+            n_trees=5, n_nodes=31, n_feat=8)
     assert inference._KERNEL_STATE["fallbacks"] == f0
     X, y = _toy(n=2000)
     spec = _fit_kind("dt", X, y, 32)
@@ -222,8 +213,8 @@ def test_explicit_pallas_raises_when_kernel_unavailable(spark, infer_conf,
     mesh = meshlib.get_mesh()
     tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")
     try:
-        k, br, _ = inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=8, n_bins=32, n_rows=4096)
+        k, br = inference.resolve_infer_kernel(
+            n_trees=5, n_nodes=31, n_feat=8)
     finally:
         tree_impl._platform_memo.clear()
     assert (k, br) == ("xla", 0)
@@ -243,137 +234,27 @@ def test_vmem_guard_demotes_oversized_specs_on_tpu(spark, infer_conf,
     monkeypatch.setitem(traverse_kernel._avail, False, None)
     GLOBAL_CONF.set("sml.infer.kernel", "pallas")
     GLOBAL_CONF.set("sml.infer.kernelBlockRows", 10 ** 6)
-    k, br, _ = inference.resolve_infer_kernel(
-        n_trees=8, depth=5, n_nodes=63, n_feat=10, n_bins=32,
-        n_rows=4096)
+    k, br = inference.resolve_infer_kernel(
+        n_trees=8, n_nodes=63, n_feat=10)
     assert (k, br) == ("pallas", 10 ** 6)  # CPU: conf taken verbatim
     mesh = meshlib.get_mesh()
     tree_impl._platform_memo[id(mesh)] = (mesh, "tpu")  # simulate TPU
     try:
-        k, br, _ = inference.resolve_infer_kernel(
-            n_trees=8, depth=5, n_nodes=63, n_feat=10, n_bins=32,
-            n_rows=4096)
+        k, br = inference.resolve_infer_kernel(
+            n_trees=8, n_nodes=63, n_feat=10)
         assert k == "pallas" and 32 <= br < 10 ** 6  # clamped to budget
         from sml_tpu.native import traverse_kernel as _tk
         assert br == _tk.max_block_rows(8, 63, 10)  # ONE arithmetic
         f0 = inference._KERNEL_STATE["fallbacks"]
         d0 = inference._KERNEL_STATE["demotions"]
-        k, br, _ = inference.resolve_infer_kernel(
-            n_trees=2000, depth=10, n_nodes=2047, n_feat=10, n_bins=32,
-            n_rows=4096)  # 2000×2047 node tables >> the VMEM budget
+        k, br = inference.resolve_infer_kernel(
+            n_trees=2000, n_nodes=2047,
+            n_feat=10)  # 2000×2047 node tables >> the VMEM budget
         assert (k, br) == ("xla", 0)
         assert inference._KERNEL_STATE["fallbacks"] == f0 + 1
         assert inference._KERNEL_STATE["demotions"] == d0 + 1
     finally:
         tree_impl._platform_memo.clear()
-
-
-# ------------------------------------------------- autotuned spec roundtrip
-def test_tuned_spec_roundtrip_through_prewarm_manifest(spark, infer_conf,
-                                                       tmp_path):
-    """record_tuned → manifest entry → resolver picks the tuned spec
-    (overriding conf) without a sweep; re-tuning REPLACES the entry; a
-    different batch width misses; the infer_kernel rebuilder replays the
-    recorded program into the live caches."""
-    from sml_tpu.ml import inference
-    from sml_tpu.parallel import mesh as meshlib, prewarm
-    prev_dir = GLOBAL_CONF.get("sml.compile.cacheDir")
-    GLOBAL_CONF.set("sml.compile.cacheDir", str(tmp_path))
-    try:
-        GLOBAL_CONF.set("sml.infer.autotune", True)
-        GLOBAL_CONF.set("sml.infer.kernel", "xla")  # tuned spec must win
-        key = inference.infer_spec_key(5, 4, 10, 32, 4096)
-        assert prewarm.tuned_spec("infer_kernel", key) is None
-        prewarm.record_tuned("infer_kernel", key,
-                             {"kernel": "pallas", "block_rows": 512})
-        assert prewarm.tuned_spec("infer_kernel", key) \
-            == {"kernel": "pallas", "block_rows": 512}
-        k, br, tuned = inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=10, n_bins=32,
-            n_rows=4096)
-        assert (k, br, tuned) == ("pallas", 512, True)
-        assert inference.kernel_report()["tuned"] is True
-        # re-tune REPLACES (stable manifest key), never accumulates
-        prewarm.record_tuned("infer_kernel", key,
-                             {"kernel": "xla", "block_rows": 0})
-        assert prewarm.tuned_spec("infer_kernel", key) \
-            == {"kernel": "xla", "block_rows": 0}
-        mpath = os.path.join(str(tmp_path), "prewarm_manifest.json")
-        with open(mpath) as f:
-            entries = json.load(f)["entries"]
-        tuned = [e for e in entries.values()
-                 if e["kind"] == "infer_kernel"]
-        assert len(tuned) == 1
-        # a different batch width is a different key: conf path resolves
-        k2, br2, tuned2 = inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=10, n_bins=32,
-            n_rows=262144)
-        assert (k2, br2, tuned2) == ("xla", 0, False)
-        assert inference.kernel_report()["tuned"] is False
-        # autotune off: the manifest is ignored entirely
-        prewarm.record_tuned("infer_kernel", key,
-                             {"kernel": "pallas", "block_rows": 512})
-        GLOBAL_CONF.set("sml.infer.autotune", False)
-        k3, _, _ = inference.resolve_infer_kernel(
-            n_trees=5, depth=4, n_nodes=31, n_feat=10, n_bins=32,
-            n_rows=4096)
-        assert k3 == "xla"
-        # the prewarm rebuilder replays the tuned program into the SAME
-        # cache the live score path hits (replica spin-up's warm start)
-        inference._replay_infer_kernel(
-            {"key": key, "spec": {"kernel": "pallas", "block_rows": 512}})
-        mesh = meshlib.get_mesh()
-        assert (4, id(mesh), "pallas", 512) in inference._forest_programs
-    finally:
-        GLOBAL_CONF.set("sml.compile.cacheDir", prev_dir or "")
-
-
-# --------------------------------------------------------- regress rules
-def test_regress_kernel_infer_rules(spark):
-    """obs/regress.py: a vanished kernel_infer sidecar block, fallback
-    growth, or a lost beats-default/replay proof is a regression;
-    driver-shaped records are exempt from the coverage rule."""
-    from sml_tpu.obs import regress
-    block = {"fallbacks": 0.0, "tuned_beats_default": True,
-             "replay_ok": True, "legs": []}
-    base = regress.normalize({"legs": {}, "kernel_infer": dict(block)})
-    ok = regress.compare(base, regress.normalize(
-        {"legs": {}, "kernel_infer": dict(block)}))
-    assert ok["ok"]
-    gone = regress.compare(base, regress.normalize({"legs": {}}))
-    assert not gone["ok"]
-    assert any(f["kind"] == "missing-kernel-infer-block"
-               for f in gone["regressions"])
-    # driver records can never carry the block: exempt
-    rec = regress.compare(base, regress.normalize(
-        {"parsed": {}, "tail": ""}))
-    assert not any(f["kind"] == "missing-kernel-infer-block"
-                   for f in rec["regressions"])
-    fell = regress.compare(base, regress.normalize(
-        {"legs": {}, "kernel_infer": dict(block, fallbacks=2.0)}))
-    assert any(f["kind"] == "infer-kernel-fallback"
-               for f in fell["regressions"])
-    lost = regress.compare(base, regress.normalize(
-        {"legs": {},
-         "kernel_infer": dict(block, tuned_beats_default=False)}))
-    assert any(f["key"] == "tuned_beats_default"
-               for f in lost["regressions"])
-    lost2 = regress.compare(base, regress.normalize(
-        {"legs": {}, "kernel_infer": dict(block, replay_ok=False)}))
-    assert any(f["key"] == "replay_ok" for f in lost2["regressions"])
-    # interpret-mode runs: every pallas block_rows candidate is the
-    # identical single-block program, so beats-default is timer noise —
-    # NOT judged as a proof (replay_ok still is)
-    ib = dict(block, interpret=True)
-    base_i = regress.normalize({"legs": {}, "kernel_infer": dict(ib)})
-    lost_i = regress.compare(base_i, regress.normalize(
-        {"legs": {},
-         "kernel_infer": dict(ib, tuned_beats_default=False)}))
-    assert not any(f["key"] == "tuned_beats_default"
-                   for f in lost_i["regressions"])
-    lost_i2 = regress.compare(base_i, regress.normalize(
-        {"legs": {}, "kernel_infer": dict(ib, replay_ok=False)}))
-    assert any(f["key"] == "replay_ok" for f in lost_i2["regressions"])
 
 
 def test_block_plan_never_reads_conf_at_trace_time():
