@@ -60,7 +60,7 @@ WAIT = "fit.device_wait"
 #: the programs' `jax.named_scope` families (docs/OBSERVABILITY.md): the
 #: sample of operations shown with their statistics prefers those that name
 #: one
-SCOPE_FAMILIES = ("tree.", "linear.", "cv.", "als.")
+SCOPE_FAMILIES = ("tree.", "linear.", "cv.", "als.", "kmeans.")
 #: what a host-to-device transfer may be called in a trace, letters only
 H2D_MARKS = ("h2d", "hosttodevice", "transfertodevice", "copytodevice",
              "bufferfromhostbuffer", "transferliteraltodevice", "infeed")

@@ -165,9 +165,12 @@ def _keyed(a, tag: tuple, probe: Callable = _stage_cache.get):
 
 
 #: host bytes of FREE warm pad buffers the pool may keep: one fit's set of
-#: the largest deployment (0.63 GB: a compact block of 8 M rows, its
-#: numeric columns, labels and mask) and a second buffer of its smaller sizes
-_PAD_POOL_MAX_BYTES = 1 << 30
+#: the largest deployment (1.17 GB: a clustering's feature-major float32
+#: block of 6.8 M padded rows x 42 columns and its mask; a buffer larger
+#: than the bound is never kept, and its pad is paid in fresh pages every
+#: fit) and the sets of the smaller ones (0.63 GB: a compact linear block
+#: of 8 M rows, its numeric columns, labels and mask)
+_PAD_POOL_MAX_BYTES = 2 << 30
 
 
 def _nothing_placed():

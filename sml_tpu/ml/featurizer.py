@@ -580,8 +580,10 @@ def try_fast_fit(stages, raw, make_frame):
     slot metadata is reconstructed analytically, and the estimator gets a
     frame carrying the assembled block: NO transform chain ever
     materializes, and that frame lies over the same pieces (`make_frame`
-    makes it from a list of them). Returns (fitted_prep_stages,
-    estimator_input_frame), or
+    makes it from a list of them). An estimator that reads a featuresCol
+    and NO label (a clustering) is handed the scratch as it was written,
+    feature-major, where every assembled column is numeric. Returns
+    (fitted_prep_stages, estimator_input_frame), or
     None where the plan declines (counter `featurize.plan.declined`, the
     reason on its event): the caller falls back to the generic sequential
     fit, which is always correct. The caller runs the estimator fit itself
@@ -669,8 +671,11 @@ def _try_fast_fit(stages, raw, make_frame):
     if reader is None:
         return _decline("a validator whose folds need their frames")
     est, compact_only = reader
-    if not (est.hasParam("featuresCol") and est.hasParam("labelCol")):
-        return _decline("the estimator reads no featuresCol and labelCol")
+    if not est.hasParam("featuresCol"):
+        return _decline("the estimator reads no featuresCol")
+    # an estimator with no label (a clustering) reads the block alone, and
+    # reads it FEATURE-MAJOR, as the scratch is written: no interleave
+    unlabelled = not est.hasParam("labelCol")
     # a formula IS an indexer, an encoder and an assembler over raw
     # columns (`RFormula._chain`): its jobs are theirs, and its model is
     # made from their models at the end. `renamed` is the formula's label
@@ -695,7 +700,8 @@ def _try_fast_fit(stages, raw, make_frame):
     assembler = prep[-1]
     if est.getOrDefault("featuresCol") != assembler.getOrDefault("outputCol"):
         return _decline("the estimator does not read the assembler's output")
-    if renamed is None and est.getOrDefault("labelCol") not in raw.columns:
+    if renamed is None and not unlabelled \
+            and est.getOrDefault("labelCol") not in raw.columns:
         return _decline("labelCol is no raw column")
     if prep_overwrites_label(prep[:-1], est):
         return _decline("a prep stage rewrites the label")
@@ -785,7 +791,9 @@ def _try_fast_fit(stages, raw, make_frame):
             (1 if w is None else w for w in onehot), initial=0))
         width = los[-1]
         with PROFILER.span("fit.featurize.plan.block") as step:
-            if compact_bytes is not None and n * width * 4 >= compact_bytes:
+            if (compact_bytes is not None
+                    and n * width * 4 >= compact_bytes) \
+                    or (unlabelled and all(w is None for w in onehot)):
                 parts = plan.compact(onehot, invalid)
             if parts is None and compact_only:
                 return _decline("a NaN the compact block would carry")

@@ -44,6 +44,10 @@ SPANS = {
     # ALSModel.transform's look-up of a partition's users and movies and
     # their factors' dot products (ml/recommendation.py)
     "transform.als.lookup",
+    # what of a clustering's seeding runs on the host (ml/clustering.py
+    # KMeans._fit: the draws' key, the rows of initMode="random"; the
+    # rounds of k-means|| are inside the fit's ONE dispatch)
+    "kmeans.init.local",
     # the staging functions' steps for an array of at least 1 MiB
     # (ml/_staging.py `_SPAN_BYTES`; shared with scoring and serving, so
     # named for the function): stage.key (normalize + content key + cache
@@ -114,6 +118,21 @@ COUNTERS = {
     # als.normal.allreduce) / als.solve
     "als.fits", "als.half_steps", "als.blocks", "als.ratings",
     "als.cold_start.dropped",
+    # a clustering's fit (ml/clustering.py KMeans._fit), every count
+    # read back with the centers of the ONE dispatch: fits / Lloyd steps
+    # run / fits that `tol` ended (not `maxIter`) / rounds of k-means||,
+    # each over all rows / candidates the rounds kept (the first center
+    # among them) / blocks of rows a step walks a shard in
+    # (`_block_rows`) / rows assigned, summed over the steps (rows x
+    # iterations) / clusters the last step left empty (each kept its
+    # center). On the device the `jax.named_scope`s kmeans.init (the
+    # seeding's passes, the candidates' weights and the weighted
+    # k-means++) / kmeans.assign (a block's distance product and arg-min)
+    # / kmeans.update (the 0/1 product, the all-reduce, the new centers)
+    # / kmeans.cost (the pass at the returned centers)
+    "kmeans.fits", "kmeans.iterations", "kmeans.converged",
+    "kmeans.init.rounds", "kmeans.init.candidates", "kmeans.blocks",
+    "kmeans.rows", "kmeans.empty_clusters",
     # Pallas launches of the traversal kernel (native/traverse_kernel.py,
     # docs/KERNELS.md): TRACE-TIME statics (counted once per program
     # trace, like collective.*: launches per execution = the count ×

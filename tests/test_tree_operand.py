@@ -553,3 +553,47 @@ def test_fitted_packs_are_bit_equal_with_the_operand_stored_as_int8(
     assert (as_hist[0][:, 0] >= 0).any(), "the trees split"
     np.testing.assert_array_equal(as_hist[0], as_int8[0])
     assert as_hist[1] == as_int8[1]
+
+
+# ----------------------------------- the clustering's blocked Lloyd step
+def test_the_clustering_fit_holds_no_rows_by_k_array_at_the_cells_width(
+        one_chip_mesh, monkeypatch):
+    """`mle02_kmeans.fit_kmeans`'s program at its own widths (42 columns,
+    k = 1000, two rounds of k-means||) over 262,144 rows in blocks of 8,192,
+    compiled for the chip: the seeding, the Lloyd loop and the cost are
+    there, the largest arrays are the table and a block's (k, block) tile,
+    and nothing has rows x k elements (at the cell's size: 27 GB). The
+    compile goes here, where the others are: one worker loads libtpu."""
+    from sml_tpu.ml import clustering
+    rows, d, k, block = 262_144, 42, 1000, 8192
+    monkeypatch.setattr(clustering, "_block_rows", lambda width: block)
+    clustering.forget_programs()
+    mesh = one_chip_mesh
+    program = clustering._fit_program(k, "k-means||", 2)
+    specs = (P(None, D), P(D), P(), P(), P(), P())
+    shapes = [jax.ShapeDtypeStruct(shape, dtype,
+                                   sharding=NamedSharding(mesh, spec))
+              for (shape, dtype), spec in zip((
+                  ((d, rows), jnp.float32), ((rows,), jnp.float32),
+                  ((2,), jnp.uint32), ((), jnp.int32), ((), jnp.float32),
+                  ((k,), jnp.int32)), specs)]
+    with meshlib.use_mesh_local(mesh):
+        mapped = jax.shard_map(program, mesh=mesh, in_specs=specs,
+                               out_specs=P(), check_vma=False)
+        compiled = jax.jit(mapped).lower(*shapes).compile()
+    clustering.forget_programs()
+    hlo = compiled.as_text()
+    for scope in ("kmeans.init", "kmeans.assign", "kmeans.update",
+                  "kmeans.cost"):
+        assert scope in hlo, scope
+    sizes = {}
+    for kind, dims in re.findall(r"\b(f32|bf16|s32|u32|s8|pred)\[([0-9,]+)\]",
+                                 hlo):
+        elements = int(np.prod([int(x) for x in dims.split(",")]))
+        sizes[f"{kind}[{dims}]"] = elements
+    assert f"f32[{d},{rows}]" in sizes                   # the table
+    assert f"f32[{k},{block}]" in sizes                  # a block's tile
+    slots = clustering._candidate_slots(k)
+    assert max(sizes.values()) <= max(d * rows, (128 + 2 * slots) * block)
+    assert not [s for s, n in sizes.items() if n >= rows * k]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
