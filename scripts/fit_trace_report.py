@@ -19,7 +19,7 @@ first), then, from the same process's recorder ring and the run's
    `fit.device_wait` (both on the profiler's clock);
 2. the phases of each fit of the window, from the spans that share its
    trace id, children beside their parents (`fit.quantize.*`, the
-   `fit.featurize.*` four, the staging steps `stage.key` / `.pad` / `.put`)
+   `fit.featurize.*` children, the staging steps `stage.key` / `.pad` / `.put`)
    and what a span notes in numbers as `<name> [<note>]` (the process's
    `cpu_s`, the slowest column job's `longest_s`, a staging
    step's `bytes`, `copied`, `hit`, and `stage.pad [warm share]`: the share

@@ -186,19 +186,22 @@ _bins_lock = _threading.Lock()  # parallel tuning trials bin concurrently
 _BINS_CACHE_MAX_BYTES = 1 << 30
 
 
-def _cached_bins(X, y32, max_bins, categorical):
-    """make_bins memoized by (content fingerprint, bins, categorical):
-    CV folds and tuning trials re-fit trees on IDENTICAL matrices once per
-    parameter set — re-quantizing 1M rows per fit was ~0.3s apiece.
+def _cached_bins(X, y32, max_bins, categorical, missing=None):
+    """make_bins memoized by (content fingerprint, bins, categorical,
+    missing): CV folds and tuning trials re-fit trees on IDENTICAL matrices
+    once per parameter set — re-quantizing 1M rows per fit was ~0.3s
+    apiece. One block read under two `missing` values is two entries.
     Byte-budgeted and locked like the staging cache (same concurrent
     TpuTrials path, same multi-100MB operands)."""
     from ._staging import _content_key, _normalize
-    from .tree_impl import make_bins
+    from .tree_impl import _missing_value, make_bins
     with PROFILER.span("fit.quantize", rows=int(X.shape[0])) as note:
         with PROFILER.span("fit.quantize.key"):
             Xc = _normalize(X)
+            missing = _missing_value(missing)
             key = (_content_key(Xc), _content_key(_normalize(y32)),
-                   int(max_bins), tuple(sorted((categorical or {}).items())))
+                   int(max_bins), tuple(sorted((categorical or {}).items())),
+                   missing)
         while True:
             with _bins_lock:
                 hit = _bins_cache.get(key)
@@ -215,7 +218,8 @@ def _cached_bins(X, y32, max_bins, categorical):
             waiter.wait()
         note["hit"] = False
         try:
-            hit = make_bins(Xc, y32, max_bins, categorical)  # its own span
+            hit = make_bins(Xc, y32, max_bins, categorical,
+                            missing=missing)  # its own span
             cost = hit[0].nbytes
             with _bins_lock:
                 _bins_cache[key] = hit
@@ -254,24 +258,24 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
     the bin cache, so X may be None — the raw float data never existed
     whole. Everything downstream is the SAME code path as the monolithic
     fit, which makes chunked-vs-monolithic bit-parity a structural
-    property rather than a numerical accident."""
+    property rather than a numerical accident.
+
+    X is read and never written (it may be the block a frame's
+    `_featurized` memo holds), nor copied: a `missing` that is a number
+    (xgboost's) goes to the two readers that took the NaNs a copy used to
+    carry, the quantizer's jobs and the drift baseline's sample."""
     from ._staging import routed_for
     y32 = np.asarray(y, np.float32)
     if prebinned is not None:
         binned, binning = prebinned
         F = binned.shape[1]
     else:
-        if missing is not None and not np.isnan(missing):
-            with PROFILER.span("fit.featurize", rows=int(X.shape[0]),
-                               bytes=int(X.nbytes)), \
-                    PROFILER.span("fit.featurize.missing"):
-                X = X.copy()
-                X[X == missing] = np.nan
         F = X.shape[1]
         # bin on host FIRST so the dispatcher can probe the staging cache
         # with the actual device operand; histogram builds dominate the
         # program: trees x levels x (n x F x bins) one-hot accumulations
-        binned, binning = _cached_bins(X, y32, max_bins, categorical)
+        binned, binning = _cached_bins(X, y32, max_bins, categorical,
+                                       missing)
     # measured host-mesh rate for this program is ~1.2e9 ops/s (one-hot
     # expansion defeats CPU BLAS) — scatter-class, not blas
     hint = dispatch.WorkHint(
@@ -311,18 +315,20 @@ def _fit_ensemble(X: np.ndarray, y: np.ndarray, *, categorical: Dict[int, int],
     # passes its full-data ingest sketch instead). Host-side numpy only
     # — capture must not perturb the fit's program/dispatch counters
     spec.baseline = _capture_baseline(X, y32, categorical, spec, binned,
-                                      baseline_sketch)
+                                      baseline_sketch, missing)
     return spec
 
 
-def _capture_baseline(X, y32, categorical, spec, binned, sketch):
+def _capture_baseline(X, y32, categorical, spec, binned, sketch,
+                      missing=None):
     """`drift.capture_fit_baseline` under its span: with the recorder on
     it is a host pass inside every fit (a sketch of the strided rows and
     a NumPy descent of them through every tree)."""
     from ..obs import drift as _drift
     with PROFILER.span("fit.baseline", trees=len(spec.trees)) as note:
         baseline = _drift.capture_fit_baseline(
-            X, y32, categorical, spec, binned=binned, sketch=sketch)
+            X, y32, categorical, spec, binned=binned, sketch=sketch,
+            missing=missing)
         note["rows"] = None if baseline is None else baseline.sampled_rows
     return baseline
 
@@ -733,7 +739,12 @@ class _TreeEstimatorBase(Estimator, _TreeParams):
             X, y, _ = extract_xy(df, self.getOrDefault("featuresCol"),
                                  self.getOrDefault("labelCol"))
             ok = np.isfinite(y)
-            X, y = X[ok], y[ok]
+            if ok.all():
+                # X IS the block it was handed (the column plan's)
+                PROFILER.count("featurize.extract.whole")
+            else:
+                X, y = X[ok], y[ok]
+                PROFILER.count("featurize.extract.gathered")
             note["rows"], note["bytes"] = int(X.shape[0]), int(X.nbytes)
         return X, y, _categorical_slots(df, self.getOrDefault("featuresCol"))
 

@@ -142,12 +142,24 @@ def finalize_binning(F: int, max_bins: int,
     return Binning(edges=edges, cat_remap=remaps), edge_list, bin_dtype(need)
 
 
+def _missing_value(missing) -> Optional[float]:
+    """A fit's `missing` as the quantizer takes it: the number whose equals
+    read NaN, or None where there is none to look for (None, NaN)."""
+    if missing is None or np.isnan(missing):
+        return None
+    return float(missing)
+
+
 def make_bins(X: np.ndarray, y: np.ndarray, max_bins: int,
               categorical: Optional[Dict[int, int]] = None,
-              max_categories_error: bool = True) -> Tuple[np.ndarray, Binning]:
+              max_categories_error: bool = True,
+              missing: Optional[float] = None) -> Tuple[np.ndarray, Binning]:
     """Host-side discretization. Continuous features: quantile edges.
     Categorical slots: identity bins ordered by mean label; cardinality must
     fit in max_bins, reproducing Spark's maxBins error (`ML 06:91-126`).
+    A value equal to `missing` (xgboost's: a number, not None or NaN) is
+    read as NaN by both phases, in every slot: the bins and the `Binning`
+    are those of a copy of X with NaN written there, and X is not written.
 
     A plan of two phases on the column plan's pool (`_column_plan`: the one
     pool of the process, inline under its row threshold and on a worker
@@ -161,6 +173,7 @@ def make_bins(X: np.ndarray, y: np.ndarray, max_bins: int,
     from . import _column_plan as cp
     n, F = X.shape
     categorical = categorical or {}
+    missing = _missing_value(missing)
     y = None if y is None else np.asarray(y)
     inline = cp.runs_inline(n)
     with PROFILER.span("fit.quantize.bins",
@@ -170,15 +183,16 @@ def make_bins(X: np.ndarray, y: np.ndarray, max_bins: int,
         with PROFILER.span("fit.quantize.stats"):
             probs = np.linspace(0, 1, max_bins + 1)[1:-1]
             stats = cp.run_tasks(
-                [partial(_column_stats, X, y, f, categorical.get(f), probs)
-                 for f in range(F)], inline)
+                [partial(_column_stats, X, y, f, categorical.get(f), probs,
+                         missing) for f in range(F)], inline)
             binning, edge_list, out_dtype = finalize_binning(
                 F, max_bins, categorical,
                 {f: q for f, q in enumerate(stats) if f not in categorical},
                 {f: stats[f] for f in categorical},
                 max_categories_error=max_categories_error)
         with PROFILER.span("fit.quantize.digitize"):
-            binned = _bin_columns(X, edge_list, binning.cat_remap, out_dtype)
+            binned = _bin_columns(X, edge_list, binning.cat_remap, out_dtype,
+                                  missing)
     if inline:
         PROFILER.count("quantize.plan.inline")
     else:
@@ -187,14 +201,23 @@ def make_bins(X: np.ndarray, y: np.ndarray, max_bins: int,
 
 
 def _column_stats(X: np.ndarray, y: Optional[np.ndarray], f: int,
-                  card: Optional[int], probs: np.ndarray):
+                  card: Optional[int], probs: np.ndarray,
+                  missing: Optional[float] = None):
     """The bin statistic of column f from ONE visit: the job copies its
     column out of the block (contiguous; every further pass walks 1/F of
     the block) and returns the raw `np.quantile` values of a continuous
     slot (None where nothing is finite), or a categorical slot's
     per-category mean labels (inf for absent categories): what
-    `finalize_binning` takes."""
-    col = np.ascontiguousarray(X[:, f])
+    `finalize_binning` takes. The values equal to `missing` are NaN in
+    the job's copy, before anything reads it."""
+    if missing is None:
+        col = np.ascontiguousarray(X[:, f])
+    else:
+        # ONE strided pass, then the compare on the contiguous copy (a
+        # `where` over the view walks the block twice); `np.array`: the
+        # job's own even where the block is one column
+        col = np.array(X[:, f])
+        np.putmask(col, col == missing, np.nan)
     if card is None:
         finite = col[np.isfinite(col)]
         if len(finite) == 0:
@@ -239,13 +262,15 @@ def _group_labels(col: np.ndarray, card: int, y: Optional[np.ndarray]):
 
 
 def _bin_columns(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
-                 out_dtype=np.int32) -> np.ndarray:
+                 out_dtype=np.int32,
+                 missing: Optional[float] = None) -> np.ndarray:
     """Discretization against known edges/remaps, a job a block of rows:
     every block is binned for all F columns and written once, as
     contiguous rows of the result in `out_dtype`, by the C++ kernel
     (`native/binning.cc`) when available, NumPy otherwise — identical
     semantics (searchsorted 'left'; non-finite → bin 0; a categorical
-    slot's rank looked up in the same pass). The blocks run on the
+    slot's rank looked up in the same pass; a value equal to `missing`,
+    where a fit gives one, is NaN as it is read). The blocks run on the
     column plan's pool, or inline for few rows (a serving batch) and on
     a worker thread (`ml/_chunked.py` calls this a chunk a worker).
     `out_dtype` is the quantized engine's compact storage dtype (see
@@ -255,12 +280,12 @@ def _bin_columns(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
     from . import _column_plan as cp
     n, F = X.shape
     binned = np.empty((n, F), dtype=out_dtype)
-    native = _native_binning.row_binner(edge_list, remaps)
+    native = _native_binning.row_binner(edge_list, remaps, missing)
 
     def block(r0: int) -> None:
         rows, out = X[r0:r0 + cp._BLOCK_ROWS], binned[r0:r0 + cp._BLOCK_ROWS]
         if native is None or not native(rows, out):
-            _bin_rows_numpy(rows, edge_list, remaps, out)
+            _bin_rows_numpy(rows, edge_list, remaps, out, missing)
 
     cp.run_tasks([partial(block, r0) for r0 in range(0, n, cp._BLOCK_ROWS)],
                  cp.runs_inline(n))
@@ -268,11 +293,13 @@ def _bin_columns(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
 
 
 def _bin_rows_numpy(X: np.ndarray, edge_list, remaps: Dict[int, np.ndarray],
-                    out: np.ndarray) -> None:
+                    out: np.ndarray, missing: Optional[float] = None) -> None:
     """`native/binning.cc`'s `bin_rows` in NumPy: the rows of X into the
     rows of `out`, every column."""
     for f, qs in enumerate(edge_list):
         col = X[:, f]
+        if missing is not None:
+            col = np.where(col == missing, col.dtype.type(np.nan), col)
         rank = remaps.get(f)
         if rank is not None:
             out[:, f] = rank[np.clip(col.astype(np.int64), 0, len(rank) - 1)]

@@ -7,7 +7,8 @@
 // kernel. Semantics mirror the NumPy path exactly: searchsorted(edges, x,
 // 'left') for finite x, bin 0 for any non-finite value, and for a
 // categorical slot rank[clip(int64(x), 0, card - 1)] (tree_impl
-// ._bin_rows_numpy).
+// ._bin_rows_numpy). A value equal to the fit's `missing` is read as NaN
+// in either kind of slot (a NaN `missing` equals nothing: there is none).
 //
 // One call bins ROWS [0, n) of the matrix it is handed, all F columns,
 // and writes them once, as contiguous rows of the result in its final
@@ -58,19 +59,21 @@ static inline int64_t category(double x, int64_t card) {
 // they are). Feature f is continuous where cards[f] == 0, with edge row
 // edges[f * max_edges .. + n_edges[f]], ascending, then +inf up to
 // max_edges, a multiple of LANES; else categorical, with rank table
-// ranks[rank_lo[f] .. + cards[f]].
+// ranks[rank_lo[f] .. + cards[f]]. `missing` is compared in X's own type,
+// as NumPy's `X == missing` is.
 template <typename T, typename O>
 static void bin_rows_impl(const T* X, int64_t n, int32_t F,
                           int64_t row_stride, int64_t col_stride,
                           const float* edges, const int32_t* n_edges,
                           int32_t max_edges, const int32_t* ranks,
                           const int64_t* rank_lo, const int64_t* cards,
-                          O* out) {
+                          T missing, O* out) {
     for (int64_t i = 0; i < n; ++i) {
         const T* row = X + i * row_stride;
         O* dst = out + i * F;
         for (int32_t f = 0; f < F; ++f) {
-            const T x = row[f * col_stride];
+            const T raw = row[f * col_stride];
+            const T x = raw == missing ? static_cast<T>(NAN) : raw;
             if (cards[f] > 0) {
                 dst[f] = static_cast<O>(ranks[rank_lo[f] + category(
                     static_cast<double>(x), cards[f])]);
@@ -89,22 +92,24 @@ static int bin_rows_out(const T* X, int64_t n, int32_t F, int64_t row_stride,
                         int64_t col_stride, const float* edges,
                         const int32_t* n_edges, int32_t max_edges,
                         const int32_t* ranks, const int64_t* rank_lo,
-                        const int64_t* cards, void* out, int32_t out_bytes) {
+                        const int64_t* cards, double missing, void* out,
+                        int32_t out_bytes) {
+    const T miss = static_cast<T>(missing);
     switch (out_bytes) {
     case 1:
         bin_rows_impl<T, uint8_t>(X, n, F, row_stride, col_stride, edges,
                                   n_edges, max_edges, ranks, rank_lo, cards,
-                                  static_cast<uint8_t*>(out));
+                                  miss, static_cast<uint8_t*>(out));
         return 0;
     case 2:
         bin_rows_impl<T, uint16_t>(X, n, F, row_stride, col_stride, edges,
                                    n_edges, max_edges, ranks, rank_lo, cards,
-                                   static_cast<uint16_t*>(out));
+                                   miss, static_cast<uint16_t*>(out));
         return 0;
     case 4:
         bin_rows_impl<T, int32_t>(X, n, F, row_stride, col_stride, edges,
                                   n_edges, max_edges, ranks, rank_lo, cards,
-                                  static_cast<int32_t*>(out));
+                                  miss, static_cast<int32_t*>(out));
         return 0;
     }
     return 1;
@@ -163,21 +168,22 @@ int group_labels(const void* col, int32_t x_bytes, int64_t n, int64_t card,
 }
 
 // x_bytes: 4 = float32, 8 = float64; out_bytes: 1 = uint8, 2 = uint16,
-// 4 = int32 (tree_impl.bin_dtype's three). Returns 0, or 1 for a width it
-// has no instantiation of (nothing written).
+// 4 = int32 (tree_impl.bin_dtype's three); missing: the value read as NaN
+// (NaN: none). Returns 0, or 1 for a width it has no instantiation of
+// (nothing written).
 int bin_rows(const void* X, int32_t x_bytes, int64_t n, int32_t F,
              int64_t row_stride, int64_t col_stride, const float* edges,
              const int32_t* n_edges, int32_t max_edges, const int32_t* ranks,
-             const int64_t* rank_lo, const int64_t* cards, void* out,
-             int32_t out_bytes) {
+             const int64_t* rank_lo, const int64_t* cards, double missing,
+             void* out, int32_t out_bytes) {
     if (x_bytes == 4)
         return bin_rows_out(static_cast<const float*>(X), n, F, row_stride,
                             col_stride, edges, n_edges, max_edges, ranks,
-                            rank_lo, cards, out, out_bytes);
+                            rank_lo, cards, missing, out, out_bytes);
     if (x_bytes == 8)
         return bin_rows_out(static_cast<const double*>(X), n, F, row_stride,
                             col_stride, edges, n_edges, max_edges, ranks,
-                            rank_lo, cards, out, out_bytes);
+                            rank_lo, cards, missing, out, out_bytes);
     return 1;
 }
 

@@ -8,6 +8,7 @@ the NumPy expressions
     np.searchsorted(edges_f, X[:, f], side="left")  # then non-finite → 0
     rank_f[np.clip(X[:, f].astype(np.int64), 0, len(rank_f) - 1)]
 
+(on a block whose values equal to `missing`, where a fit has one, read NaN)
 of `ml.tree_impl._bin_rows_numpy`, which runs where no compiler built the
 library; parity tests pin the two implementations against each other. The
 kernel starts no thread: `tree_impl._bin_columns` gives each block to one
@@ -36,7 +37,7 @@ def _lib() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int32]
+            ctypes.c_double, ctypes.c_void_p, ctypes.c_int32]
         lib.bin_rows.restype = ctypes.c_int
         lib.group_labels.argtypes = [
             ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
@@ -51,14 +52,18 @@ class RowBinner:
     rows padded with +inf into an (F, width) float32 block (width a
     multiple of the kernel's 16 lanes) with their lengths, the rank tables
     end to end with each slot's offset and cardinality (0 marks a
-    continuous slot). The arrays live as long as the binner, so a call
-    may hand their addresses to the kernel."""
+    continuous slot), and the value the kernel reads as NaN (`missing`;
+    NaN, which equals nothing, where there is none). The arrays live as
+    long as the binner, so a call may hand their addresses to the
+    kernel."""
 
     def __init__(self, lib: ctypes.CDLL, edges_list: List[np.ndarray],
-                 remaps: Dict[int, np.ndarray]):
+                 remaps: Dict[int, np.ndarray],
+                 missing: Optional[float] = None):
         F = len(edges_list)
         self._fn = lib.bin_rows
         self.F = F
+        self.missing = float("nan") if missing is None else float(missing)
         width = max((len(e) for e in edges_list), default=0)
         self.edges = np.full((F, max(-(-width // 16), 1) * 16), np.inf,
                              dtype=np.float32)
@@ -99,18 +104,18 @@ class RowBinner:
             X.strides[0] // size, X.strides[1] // size,
             self.edges.ctypes.data, self.n_edges.ctypes.data,
             self.edges.shape[1], self.ranks.ctypes.data,
-            self.rank_lo.ctypes.data, self.cards.ctypes.data,
+            self.rank_lo.ctypes.data, self.cards.ctypes.data, self.missing,
             out.ctypes.data, out.dtype.itemsize) == 0
 
 
-def row_binner(edges_list: List[np.ndarray],
-               remaps: Dict[int, np.ndarray]) -> Optional[RowBinner]:
+def row_binner(edges_list: List[np.ndarray], remaps: Dict[int, np.ndarray],
+               missing: Optional[float] = None) -> Optional[RowBinner]:
     """The native kernel over these tables, or None when the library is
     unavailable (the caller uses the NumPy path)."""
     lib = _lib()
     if lib is None or any(len(r) == 0 for r in remaps.values()):
         return None
-    return RowBinner(lib, edges_list, remaps)
+    return RowBinner(lib, edges_list, remaps, missing)
 
 
 def group_labels(col: np.ndarray, card: int, y: Optional[np.ndarray]):

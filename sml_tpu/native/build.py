@@ -16,6 +16,7 @@ with the compiler's output, counted (``native.build_failed``) and kept in
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import glob
 import hashlib
@@ -73,7 +74,11 @@ def load_library(name: str) -> Optional[ctypes.CDLL]:
                 os.makedirs(_BUILD_DIR, exist_ok=True)
                 for stale in glob.glob(
                         os.path.join(_BUILD_DIR, f"lib{name}-*.so")):
-                    os.unlink(stale)
+                    # `so` itself: a racing process's, just finished; one
+                    # already gone: a racing process removed it first
+                    if stale != so:
+                        with contextlib.suppress(FileNotFoundError):
+                            os.unlink(stale)
                 tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(_CXX + [src, "-o", tmp], check=True,
                                capture_output=True, text=True, timeout=120)

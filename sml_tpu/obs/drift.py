@@ -337,7 +337,8 @@ def _bounded_sketch_copy(dsk, cap: int):
 def capture_fit_baseline(X: Optional[np.ndarray], y: np.ndarray,
                          categorical: Optional[Dict[int, int]], spec, *,
                          binned: Optional[np.ndarray] = None,
-                         sketch=None) -> Optional[DriftBaseline]:
+                         sketch=None, missing: Optional[float] = None
+                         ) -> Optional[DriftBaseline]:
     """Build the baseline `_fit_ensemble` stamps into a fitted spec —
     ONLY with the recorder enabled (the PR-2 kill-switch: an obs-off
     fit pays one attribute load, not a sketch pass; train with
@@ -347,7 +348,9 @@ def capture_fit_baseline(X: Optional[np.ndarray], y: np.ndarray,
     regardless of n, and persisted sketches compress to the
     `sml.data.sketchBuckets` centroid budget. The chunked path passes
     its ingest pass-1 `sketch` (the FULL-data summary, already paid
-    for) instead of raw X."""
+    for) instead of raw X. A fit's `missing` (a number: xgboost's) is NaN
+    in the sample of X that is sketched, as it is in the fit's bins; X is
+    not written."""
     if not RECORDER.enabled:
         return None
     cap = GLOBAL_CONF.getInt("sml.obs.driftBaselineRows")
@@ -362,7 +365,11 @@ def capture_fit_baseline(X: Optional[np.ndarray], y: np.ndarray,
         sampled = getattr(sketch, "n_rows", n)
     elif X is not None:
         features = DatasetSketch(X.shape[1], categorical)
-        features.update(np.asarray(X)[::stride], np.asarray(y)[::stride])
+        sample = np.asarray(X)[::stride]
+        if missing is not None:  # a NaN `missing` equals nothing
+            sample = np.where(sample == missing,
+                              sample.dtype.type(np.nan), sample)
+        features.update(sample, np.asarray(y)[::stride])
         sampled = features.n_rows
         features = _bounded_sketch_copy(features, persist_cap)
     else:

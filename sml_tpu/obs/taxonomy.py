@@ -37,8 +37,10 @@ SPANS = {
     # `_staging.run_data_parallel` program (the linear family's), and
     # fit.summary (the logistic training summary's host pass); inside
     # the `fit.featurize` spans their children fit.featurize.plan.jobs /
-    # .plan.block (the column plan), .extract and .missing (the two block
-    # copies of a tree fit), .als.index and .als.sort (a factorization's
+    # .plan.block (the column plan), .extract (a tree fit's label column
+    # and its test for a label that is not finite; the block is gathered
+    # only where one is: a fit's `missing` is a compare of the quantizer's
+    # jobs and opens no span), .als.index and .als.sort (a factorization's
     # dense ids, and its two sorted orders with their bounds)
     "fit", "fit.*",
     # ALSModel.transform's look-up of a partition's users and movies and
@@ -166,6 +168,10 @@ COUNTERS = {
     "featurize.plan.fits", "featurize.plan.declined",
     "featurize.plan.columns_legacy", "featurize.plan.pieces",
     "featurize.collect.concats",
+    # a tree fit's `_extract`: every label finite, so X is the block it was
+    # handed (the column plan's, not copied) / a label was not, so the
+    # rows with a finite one were gathered into a new block
+    "featurize.extract.whole", "featurize.extract.gathered",
     # the quantize plan (tree_impl.make_bins: a job a column for the bin
     # statistics, a job a block of rows for the bins): a make_bins that ran
     # its jobs on the column plan's pool / one that ran them on the caller

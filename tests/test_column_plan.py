@@ -602,11 +602,14 @@ def test_a_frame_that_holds_its_concat_is_read_from_it(spark, monkeypatch,
     tree = RandomForestRegressor(featuresCol="scaled", maxBins=8, maxDepth=2,
                                  numTrees=2, seed=1)
     _prep, _tree, moved = _fit_frame(df, declining, tree)
-    assert moved == {"plan.declined": 1, "collect.concats": 1}
+    # (the forest's own `_extract` handed its block on whole, as it was)
+    assert moved == {"plan.declined": 1, "collect.concats": 1,
+                     "extract.whole": 1}
     memo = df._pdf_cache
     assert memo is not None and len(memo) == 500
     _prep, _tree, moved = _fit_frame(df, declining, tree)
-    assert moved == {"plan.declined": 1}   # the memo: no second concat
+    # the memo: no second concat
+    assert moved == {"plan.declined": 1, "extract.whole": 1}
 
     _prep, again, moved = _fit_frame(df, _tree_chain(), _Spy())
     assert moved == {"plan.fits": 1, "plan.pieces": 1}

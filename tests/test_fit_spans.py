@@ -319,7 +319,7 @@ def test_the_featurize_children_lie_inside_a_featurize_span(spark, recorder):
     by_id = {e.args["span"]: e for e in spans}
     inside = [e for e in spans if e.name.startswith("fit.featurize.")]
     assert sorted(e.name for e in inside) == [
-        "fit.featurize.extract", "fit.featurize.missing",
+        "fit.featurize.extract",
         "fit.featurize.plan.block", "fit.featurize.plan.jobs"]
     for e in inside:
         parent = by_id[e.args["parent"]]
