@@ -12,8 +12,9 @@ or `maxIter`) and the cost at the returned centers, with ONE psum of the
 
 Every pass walks the rows in BLOCKS (`_walk`, `_block_rows`): a block's
 distances to all k centers are one matrix product on the MXU about the
-column means, its arg-min (the lowest index wins a tie, MLlib's rule) and
-its sums the product of the 0/1 assignment matrix with the block. What
+column means (float32 as its six bfloat16 products along ONE contraction
+of 6d, `_nearest`), its arg-min (the lowest index wins a tie, MLlib's rule)
+and its sums the product of the 0/1 assignment matrix with the block. What
 lives at once is one block's (k, block) tile: no array of rows x k
 elements exists, so the table's size is bounded by its own block and not
 by k times it (docs/KERNELS.md "The blocked Lloyd step").
@@ -92,33 +93,74 @@ def _first_min(score):
     return jnp.argmin(score, axis=0).astype(jnp.int32)
 
 
-def _nearest(xb, centers, valid=None):
-    """(index of the nearest of `centers` (k, d), squared distance to it)
-    for the columns of `xb` (d, rows), both about the same origin:
-    |c|² − 2c·x + |x|², the product float32 on the MXU (six bfloat16
-    passes) and the norms float32. Centers where `valid` is false are
-    nobody's nearest."""
-    c, x = _product_operand(centers), _product_operand(xb)
-    cn = jnp.sum(c * c, axis=1)
-    if valid is not None:
-        cn = jnp.where(valid, cn, jnp.inf)
-    score = cn[:, None] - 2.0 * jnp.dot(c, x, precision=_PRECISE)
-    idx = _first_min(score)
-    d2 = jnp.min(score, axis=0) + jnp.sum(x * x, axis=0)
-    return idx, jnp.maximum(d2, 0.0)
-
-
 def _three_bfloat16(x):
     """`x` (float32) as three bfloat16 arrays that add up to it (8 + 8 + 8
     bits of mantissa): what keeps a float32 operand's accuracy through a
-    bfloat16 product whose other side is exact. `reduce_precision`, not a
-    cast there and back, which the compiler may drop."""
+    bfloat16 product. `reduce_precision`, not a cast there and back, which
+    the compiler may drop."""
     parts = []
     for _ in range(3):
         head = jax.lax.reduce_precision(x, 8, 7)
         parts.append(head.astype(jnp.bfloat16))
         x = x - head
     return parts
+
+
+def _block_parts(xb):
+    """A block (d, rows) as the products read it: the three bfloat16 parts
+    of `_product_operand(xb)`."""
+    return _three_bfloat16(_product_operand(xb))
+
+
+#: (part of -2c, part of x) of the six products that make a float32 product
+#: of bfloat16 parts (`Precision.HIGHEST`'s six), in the order they lie
+#: along the contraction: the SMALLEST first (2^-16 of c1x1, then 2^-8) and
+#: c1x1 last. The MXU adds along the contraction in that order, and a small
+#: term added to a sum that already holds c1x1 is rounded at c1x1's grain:
+#: c1x1 first read 1.5-1.7 x the library product's error on the chip, this
+#: order reads the library's (PERF.md section 6, PR 48)
+_PRODUCTS = ((2, 0), (0, 2), (1, 1), (1, 0), (0, 1), (0, 0))
+
+
+def _stacked_rows(parts):
+    """The block's side of `_nearest`'s product, (6d, rows): the part of x
+    of each of `_PRODUCTS` in turn, each padded with zeros to its row
+    group and the six ADDED (in any place one is not zero: exact). A sum
+    and not a `concatenate`: the chip's compiler writes a concatenate of
+    repeated operands out before the product (33 MB and 0.18 ms a block of
+    65,536 rows, as long as the product itself) and builds the sum INSIDE
+    the product's fusion as it reads its operand (docs/KERNELS.md "The
+    blocked Lloyd step")."""
+    d = parts[0].shape[0]
+    placed = [jnp.pad(parts[j], ((group * d, (5 - group) * d), (0, 0)))
+              for group, (_, j) in enumerate(_PRODUCTS)]
+    return sum(placed[1:], placed[0])
+
+
+def _nearest(xb, centers, valid=None, parts=None):
+    """(index of the nearest of `centers` (k, d), squared distance to it)
+    for the columns of `xb` (d, rows), both about the same origin:
+    |c|² − 2c·x + |x|², the norms float32 and the product float32 as this
+    chip makes one: the six products of the operands' bfloat16 parts
+    (`_PRODUCTS`: `Precision.HIGHEST`'s six, not `HIGH`'s three), here
+    stacked along ONE contraction of 6d and accumulated in float32: the
+    parts of -2c side by side (k, 6d) against `_stacked_rows` (6d, rows),
+    two filled passes of the MXU at d = 42 where the library makes six
+    over a contraction of d. `parts` is `_block_parts(xb)` where the caller
+    has it. Centers where `valid` is false are nobody's nearest."""
+    c, x = _product_operand(centers), _product_operand(xb)
+    cn = jnp.sum(c * c, axis=1)
+    if valid is not None:
+        cn = jnp.where(valid, cn, jnp.inf)
+    c_parts = _three_bfloat16(-2.0 * c)
+    product = jax.lax.dot_general(
+        jnp.concatenate([c_parts[i] for i, _ in _PRODUCTS], axis=1),
+        _stacked_rows(_block_parts(xb) if parts is None else parts),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    score = cn[:, None] + product
+    idx = _first_min(score)
+    d2 = jnp.min(score, axis=0) + jnp.sum(x * x, axis=0)
+    return idx, jnp.maximum(d2, 0.0)
 
 
 def _assigned(idx, w, k: int):
@@ -128,16 +170,16 @@ def _assigned(idx, w, k: int):
         & w[None, :]
 
 
-def _block_sums(xb, idx, w, k: int):
-    """(sums (k, d) float32, counts (k,) int32) of the columns of `xb`
-    (d, rows) by their center `idx`, rows where `w` is false left out: ONE
-    bfloat16 product of the 0/1 assignment matrix (exact) with the block
-    as three bfloat16 parts and a row of ones, accumulated in float32."""
-    d = xb.shape[0]
-    x = _product_operand(xb)
+def _block_sums(parts, idx, w, k: int):
+    """(sums (k, d) float32, counts (k,) int32) of the columns of a block
+    (d, rows), given as its `_block_parts`, by their center `idx`, rows
+    where `w` is false left out: ONE bfloat16 product of the 0/1 assignment
+    matrix (exact) with the three parts and a row of ones, accumulated in
+    float32."""
+    d, rows = parts[0].shape
     onehot = _assigned(idx, w, k).astype(jnp.bfloat16)
     rhs = jnp.concatenate(
-        _three_bfloat16(x) + [jnp.ones((1, x.shape[1]), jnp.bfloat16)])
+        list(parts) + [jnp.ones((1, rows), jnp.bfloat16)])
     out = jax.lax.dot_general(onehot, rhs, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     sums = (out[:, :d] + out[:, d:2 * d]) + out[:, 2 * d:3 * d]
@@ -175,9 +217,11 @@ def _lloyd_pass(Xt, live, origin, centers, block: int):
     def body(lo, xb, fresh, carry):
         sums, counts = carry
         with jax.named_scope("kmeans.assign"):
-            idx, _ = _nearest(xb, centers)
+            parts = _block_parts(xb)
+            idx, _ = _nearest(xb, centers, parts=parts)
         with jax.named_scope("kmeans.update"):
-            s, c = _block_sums(xb, idx, fresh & _rows_of(live, lo, block), k)
+            s, c = _block_sums(parts, idx,
+                               fresh & _rows_of(live, lo, block), k)
             return sums + s, counts + c
 
     sums, counts = _walk(Xt, origin, block, body, (

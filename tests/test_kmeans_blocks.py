@@ -174,6 +174,154 @@ def test_three_bfloat16_parts_add_up_to_the_float32():
     np.testing.assert_array_equal(total.astype(np.float32), np.asarray(x))
 
 
+# ------------------------------------------- the distance product alone
+def _distance_case(d, k, invalid, seed=11, rows=1280):
+    """(block (d, rows), centers (k, d), valid (k,) or None), all about the
+    rows' mean: columns of 1 to 1e5 as the cell's table has them, centers
+    that are rows of the block a little moved."""
+    rng = np.random.default_rng(seed)
+    scale = np.exp(rng.uniform(0.0, 11.5, (d, 1)))
+    x = rng.normal(0, 1, (d, rows)) * scale
+    x -= x.mean(axis=1, keepdims=True)
+    c = x[:, rng.choice(rows, k, replace=False)].T \
+        + rng.normal(0, 1e-3, (k, d)) * scale.T
+    valid = None
+    if invalid:
+        valid = np.ones(k, bool)
+        valid[rng.choice(k, invalid, replace=False)] = False
+    return jnp.asarray(x, jnp.float32), jnp.asarray(c, jnp.float32), valid
+
+
+def _float64_distances(x, c):
+    x, c = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    return (c ** 2).sum(axis=1)[:, None] - 2.0 * c @ x \
+        + (x ** 2).sum(axis=0)[None, :]
+
+
+def _expanded(xb, centers, valid, product):
+    """`_nearest`'s expansion around another `product` (standing for
+    -2 c·x)."""
+    cn = jnp.sum(centers * centers, axis=1)
+    if valid is not None:
+        cn = jnp.where(valid, cn, jnp.inf)
+    score = cn[:, None] + product
+    return clustering._first_min(score), jnp.maximum(
+        jnp.min(score, axis=0) + jnp.sum(xb * xb, axis=0), 0.0)
+
+
+def _first_parts_nearest(xb, centers, valid=None):
+    """The control: the expansion with ONE bfloat16 product, the first
+    parts' (what `Precision.DEFAULT` makes of a float32 product)."""
+    lone = lambda a: jax.lax.reduce_precision(a, 8, 7).astype(jnp.bfloat16)
+    return _expanded(xb, centers, valid, jax.lax.dot_general(
+        lone(-2.0 * centers), lone(xb), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))
+
+
+def _highest_nearest(xb, centers, valid=None):
+    """What `_nearest` was: the library's float32 product."""
+    return _expanded(xb, centers, valid, -2.0 * jnp.dot(
+        centers, xb, precision=jax.lax.Precision.HIGHEST))
+
+
+@pytest.mark.parametrize("what", [
+    "float32-grain", "first-parts-control", "ties", "invalid",
+    "rounded-operand"])
+@pytest.mark.parametrize("d,k,invalid", [
+    (42, 1000, 0), (42, 37, 9), (13, 1000, 0), (13, 37, 9)],
+    ids=["d42-k1000", "d42-k37-invalid", "d13-k1000", "d13-k37-invalid"])
+def test_the_stacked_product_is_a_float32_product(d, k, invalid, what,
+                                                  monkeypatch):
+    """`_nearest` alone: its six bfloat16 products along one contraction of
+    6d against float64, beside the library's `highest` product (the bound
+    it met) and a product of the first parts alone (the control)."""
+    x, c, valid = _distance_case(d, k, invalid)
+    want = _float64_distances(x, c)
+    if valid is not None:
+        want[~valid] = np.inf
+    # what float32 cannot tell apart: its grain of the squares the
+    # distance is a difference of
+    grain = (np.asarray(x, np.float64) ** 2).sum(axis=0) \
+        + (np.asarray(c, np.float64) ** 2).sum(axis=1)[want.argmin(axis=0)]
+    cols = np.arange(want.shape[1])
+
+    def worst(nearest):
+        idx, d2 = jax.jit(lambda a, b: nearest(a, b, valid))(x, c)
+        idx = np.asarray(idx)
+        return (np.abs(np.asarray(d2, np.float64) - want.min(axis=0))
+                / grain).max(), \
+            ((want[idx, cols] - want.min(axis=0)) / grain).max()
+
+    if what == "float32-grain":
+        gap, excess = worst(clustering._nearest)
+        held_gap, held_excess = worst(_highest_nearest)
+        assert held_gap < 2e-6 and held_excess < 2e-6   # the bound it met
+        assert gap < 2e-6 and excess < 2e-6
+    elif what == "first-parts-control":
+        gap, excess = worst(_first_parts_nearest)
+        assert gap > 1e-4, "one part of three is no float32 product"
+    elif what == "ties":
+        # centers that coincide to the bit score the same to the bit: the
+        # lower index takes their rows, and under the other tie rule the
+        # higher takes exactly those rows and no other
+        once = np.asarray(jax.jit(
+            lambda a, b: clustering._nearest(a, b, valid))(x, c)[0])
+        doubled = np.unique(once)[:5]
+        twice = jnp.concatenate([c, c[doubled]])
+        also = None if valid is None else np.concatenate(
+            [valid, np.ones(5, bool)])
+        first = np.asarray(jax.jit(
+            lambda a, b: clustering._nearest(a, b, also))(x, twice)[0])
+        np.testing.assert_array_equal(first, once)
+        monkeypatch.setattr(
+            clustering, "_first_min", lambda score: (
+                score.shape[0] - 1 - jnp.argmin(score[::-1], axis=0)
+            ).astype(jnp.int32))
+        last = np.asarray(jax.jit(
+            lambda a, b: clustering._nearest(a, b, also))(x, twice)[0])
+        theirs = np.isin(once, doubled)
+        assert theirs.any()
+        np.testing.assert_array_equal(last[~theirs], once[~theirs])
+        np.testing.assert_array_equal(
+            last[theirs], k + np.searchsorted(doubled, once[theirs]))
+    elif what == "invalid":
+        dead = np.ones(k, bool)
+        dead[::3] = False
+        idx, _ = jax.jit(lambda a, b: clustering._nearest(a, b, dead))(x, c)
+        assert dead[np.asarray(idx)].all()
+        masked = np.where(dead[:, None], _float64_distances(x, c), np.inf)
+        picked = masked[np.asarray(idx), cols]
+        assert ((picked - masked.min(axis=0)) / grain).max() < 2e-6
+    else:
+        # the benchmark's control reaches every operand: a rounded
+        # operand's second and third parts are zero, and what is left is
+        # the one-part product, to the bit where it is summed over the
+        # same contraction (zeros in the five other groups) and to a sum's
+        # order where it is the plain product over d
+        rounded = lambda a: jax.lax.reduce_precision(a, 8, 7)
+        for part in clustering._three_bfloat16(rounded(x))[1:]:
+            assert not np.asarray(part, np.float32).any()
+        monkeypatch.setattr(clustering, "_product_operand", rounded)
+        got = jax.jit(lambda a, b: clustering._nearest(a, b, valid))(x, c)
+
+        def one_part(a):
+            return [rounded(a).astype(jnp.bfloat16),
+                    jnp.zeros(a.shape, jnp.bfloat16),
+                    jnp.zeros(a.shape, jnp.bfloat16)]
+        monkeypatch.setattr(clustering, "_three_bfloat16", one_part)
+        lone = jax.jit(lambda a, b: clustering._nearest(a, b, valid))(x, c)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(lone[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(lone[1]))
+        plain = jax.jit(lambda a, b: _first_parts_nearest(
+            rounded(a), rounded(b), valid))(x, c)
+        assert (np.abs(np.asarray(got[1], np.float64)
+                       - np.asarray(plain[1], np.float64))
+                / grain).max() < 2e-6
+        gap, _ = worst(lambda a, b, v: _first_parts_nearest(
+            rounded(a), rounded(b), v))
+        assert gap > 1e-4
+
+
 def test_flagged_finds_the_places_in_order():
     rng = np.random.default_rng(2)
     for n, slots in ((5000, 64), (1024, 8), (300, 400), (7, 3)):

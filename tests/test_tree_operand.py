@@ -562,7 +562,14 @@ def test_the_clustering_fit_holds_no_rows_by_k_array_at_the_cells_width(
     k = 1000, two rounds of k-means||) over 262,144 rows in blocks of 8,192,
     compiled for the chip: the seeding, the Lloyd loop and the cost are
     there, the largest arrays are the table and a block's (k, block) tile,
-    and nothing has rows x k elements (at the cell's size: 27 GB). The
+    and nothing has rows x k elements (at the cell's size: 27 GB). Every
+    distance product (the Lloyd step's, the cost pass's, the two seeding
+    rounds' and the candidates' own) is ONE bfloat16 product over a
+    contraction of 6d = 252 with a float32 output, `_nearest`'s six parts'
+    products stacked (ISSUE 48): no float32 product over a contraction of
+    d is left with a (centers, block) output, the compiler still writes
+    no such tile (the temporaries stay far under 1 GiB), and it writes no
+    (6d, block) operand out either (`_stacked_rows`). The
     compile goes here, where the others are: one worker loads libtpu."""
     from sml_tpu.ml import clustering
     rows, d, k, block = 262_144, 42, 1000, 8192
@@ -597,3 +604,28 @@ def test_the_clustering_fit_holds_no_rows_by_k_array_at_the_cells_width(
     assert max(sizes.values()) <= max(d * rows, (128 + 2 * slots) * block)
     assert not [s for s, n in sizes.items() if n >= rows * k]
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the distance products: (centers, 6d) x (6d, block), bfloat16 in and
+    # float32 out, the Lloyd step's and the cost pass's against k centers,
+    # a seeding round's against its slots, the candidates' own against k
+    distance = {(k, block): 0, (slots, block): 0, (k, 128 + 2 * slots): 0}
+    for computation in re.split(r"\n(?=\S)", hlo):
+        shape_of = dict(re.findall(
+            r"(%[\w.\-]+) = (\w+\[[0-9,]*\])", computation))
+        for out, lhs, rhs in re.findall(
+                r"= (\w+\[[0-9,]*\])\S* convolution\((%[\w.\-]+), "
+                r"(%[\w.\-]+)\)", computation):
+            kind, dims = out.rstrip("]").split("[")
+            dims = tuple(int(x) for x in dims.split(","))
+            if dims not in distance:
+                continue
+            assert kind == "f32", out
+            assert {shape_of[lhs], shape_of[rhs]} == {
+                f"bf16[{dims[0]},{6 * d}]", f"bf16[{6 * d},{dims[1]}]"}, \
+                (out, shape_of[lhs], shape_of[rhs])
+            distance[dims] += 1
+    assert distance == {(k, block): 2, (slots, block): 2,
+                        (k, 128 + 2 * slots): 1}, distance
+    # the block's side is built inside the product's fusion: nothing
+    # writes a (6d, block) array out before it
+    assert not re.search(
+        rf"= bf16\[{6 * d},{block}\]\S* concatenate\(", hlo)
