@@ -728,36 +728,80 @@ def _sliced_draw(n: int, data_width: int, draw, axes=None):
     return jax.lax.dynamic_slice(full, (idx * n,), (n,))
 
 
+#: rows of the operand `_tree_operand` writes at a time: a block's bins
+#: broadcast over the bin ids (`F·B × block` bytes, 117 MB at 28 columns of
+#: 256 bins) is all the build holds beside the operand itself
+_OPERAND_BLOCK_ROWS = 1 << 14
+
+
+def _operand_blocks(rows: int) -> int:
+    """Blocks `_tree_operand` walks to build the operand of `rows` rows (a
+    device's): whole ones in its loop, and the remainder where a small
+    table's rows do not divide."""
+    return -(-int(rows) // min(max(int(rows), 1), _OPERAND_BLOCK_ROWS))
+
+
 def _tree_operand(binned_c, n_bins: int, hist_dtype, barrier: bool = True):
     """The histogram operand of every tree program, built ONCE a
-    dispatch: `(binned, B1t)` = the compact bins widened to int32 and
-    their one-hot, `(F·B, n)`, pre-transposed for the histogram dot (a
-    `.T` at the dot would re-materialize a gigabyte transpose every
-    level of every tree). It is STORED in `_operand_dtype(hist_dtype)`:
-    int8 on the chip, where the dot widens it to bf16 as it reads (one
-    byte an element from HBM and not two: 1.09 GB a dot at 1.7 M rows ×
-    640 columns); the float32 one-hot itself elsewhere.
+    dispatch: `(binned, B1t)` = the compact bins widened to int32 (what
+    `tree.route` reads) and their one-hot, `(F·B, n)`, laid out as the
+    histogram dot reads it (a `.T` at the dot would re-materialize a
+    multi-gigabyte transpose every level of every tree). It is STORED in
+    `_operand_dtype(hist_dtype)`: int8 on the chip, where the dot widens
+    it to bf16 as it reads (one byte an element from HBM and not two:
+    1.09 GB a dot at 1.7 M rows × 640 columns, 6.11 GB at 852 k rows ×
+    7,168); the float32 one-hot itself elsewhere.
 
-    The `optimization_barrier` is what keeps "once" true. The one-hot is
-    loop-invariant and cheap to express (broadcast, compare, convert), and
-    XLA:TPU's fusible-sinking pass moves exactly such producers INTO a
-    while loop that consumes them, trading recomputation for live memory
-    (the body's name then ends `…sunk`): though written outside the
-    `lax.scan` over rounds it is then rebuilt every round (as bf16, 4.4 GB
-    read and 2.2 GB written at 1.7 M rows × 640 columns, longer than the
-    round's four histogram dots took: PERF.md §6, PR 27). Behind the
+    It is built BY ROW BLOCKS, in the layout it is kept in: a loop over
+    blocks of `_OPERAND_BLOCK_ROWS` rows compares the block's bins, as
+    narrow as they were staged and feature-major, `(F, 1, block)`,
+    against the bin ids `(1, B, 1)`, and writes the `(F·B, block)` slab
+    in place (`dynamic_update_slice` along the row axis). Nothing
+    table-wide exists beside the operand: `jax.nn.one_hot` over the whole
+    table widened the bins to int32 and XLA:TPU wrote that broadcast out,
+    `s32[n, F, B]`, four times the operand (4.36 GB at 1.7 M rows × 640
+    columns; 24.4 GB at xgboost's 256 bins on 28 columns, which a v5e
+    cannot hold), before comparing and transposing it. The same compare
+    written as ONE pass over the table still writes a table-wide
+    broadcast (one byte an element: the compiler moves the reshape above
+    the compare and materializes what feeds it), so the loop it is:
+    docs/KERNELS.md, "The histogram operand".
+
+    The `optimization_barrier` is what keeps "once" true. The operand is
+    loop-invariant, and XLA:TPU's fusible-sinking pass moves cheap
+    loop-invariant producers INTO a while loop that consumes them, trading
+    recomputation for live memory (the body's name then ends `…sunk`):
+    the one-hot of PRs 27-48, though written outside the `lax.scan` over
+    rounds, was then rebuilt every round (PERF.md §6, PR 27). Behind the
     barrier it is an opaque buffer the loop carries as an operand; the
     barrier is the identity on its value. `barrier=False` is for a
     program with NO loop to sink into (`_build_tree_program`): there it
-    could only change how a backend fuses the one-hot into its consumers."""
+    could only change how a backend fuses the operand into its consumers."""
     with jax.named_scope("tree.operand"):
         # compact uint8/uint16 bins widen ON-DEVICE (a fused VPU cast over
         # the 4x-smaller staged matrix), never on the host/H2D path
         binned = binned_c.astype(jnp.int32)
-        n, F = binned.shape
-        B1t = jax.nn.one_hot(binned, n_bins,
-                             dtype=_operand_dtype(hist_dtype)) \
-            .reshape(n, F * n_bins).T
+        n, F = binned_c.shape
+        dtype = _operand_dtype(hist_dtype)
+        ids = jnp.arange(n_bins, dtype=binned_c.dtype)
+        bt = binned_c.T
+        block = min(n, _OPERAND_BLOCK_ROWS)
+
+        def slab(cols):
+            return (cols[:, None, :] == ids[None, :, None]).astype(dtype) \
+                .reshape(F * n_bins, cols.shape[1])
+
+        def write(i, out):
+            cols = jax.lax.dynamic_slice_in_dim(bt, i * block, block, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, slab(cols), i * block, axis=1)
+
+        B1t = jax.lax.fori_loop(0, n // block, write,
+                                jax.lax.empty((F * n_bins, n), dtype))
+        if n % block:
+            tail = n - n % block
+            B1t = jax.lax.dynamic_update_slice_in_dim(
+                B1t, slab(bt[:, tail:]), tail, axis=1)
         return binned, (jax.lax.optimization_barrier(B1t) if barrier
                         else B1t)
 
@@ -784,8 +828,12 @@ def ops_in_loop_bodies(hlo_text: str, scope: str = "tree.operand") -> list:
     calls = {c: {m for ln in lines for m in called.findall(
                  ln.split(" = ", 1)[1].split(", metadata=")[0]) if m in comps}
              for c, lines in comps.items()}
+    held = re.compile(r'op_name="[^"]*' + re.escape(scope))
+    # (a loop that is itself the scope's, such as `_tree_operand`'s walk
+    # over its row blocks, is not a loop the scope was sunk INTO: where it
+    # stands in another loop's body, its own line says so below)
     todo = [m for lines in comps.values() for ln in lines
-            if re.search(r"\swhile\(", ln)
+            if re.search(r"\swhile\(", ln) and not held.search(ln)
             for m in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
     inside = set()
     while todo:
@@ -793,9 +841,11 @@ def ops_in_loop_bodies(hlo_text: str, scope: str = "tree.operand") -> list:
         if c in comps and c not in inside:
             inside.add(c)
             todo.extend(calls[c])
-    held = re.compile(r'op_name="[^"]*' + re.escape(scope))
+    # (a constant computes nothing: the compiler shares one scalar zero
+    # among every user, under the name of whichever it met first)
     return [ln.split(" = ", 1)[0].strip().removeprefix("ROOT ")
-            for c in sorted(inside) for ln in comps[c] if held.search(ln)]
+            for c in sorted(inside) for ln in comps[c] if held.search(ln)
+            and not re.search(r"\sconstant\(", ln.split(", metadata=")[0])]
 
 
 def _ensemble_pieces(es: EnsembleSpec, data_width: int = 1,
@@ -979,6 +1029,7 @@ def _boost_rounds(binned_dev, y_dev, mask_dev, es: EnsembleSpec, seed: int,
                 "args": _prewarm.arg_specs(binned_dev, y_dev, mask_dev,
                                            margin)})
             PROFILER.count("tree.fit_dispatch")
+            _count_operand(es.tree, binned_dev.shape[0])
             with PROFILER.span("fit.dispatch"):
                 margin, packs = _compiled_chunk(es, c)(
                     binned_dev, y_dev, mask_dev, margin, rng, jnp.int32(t))
@@ -1156,13 +1207,25 @@ def _onehot_bytes(spec: TreeSpec, rows: int) -> int:
     the duration of a tree-fit dispatch (every tree program shape,
     fit_tree included). Dispatch-long BY CONSTRUCTION: `_tree_operand`
     builds it once and its optimization_barrier makes it a buffer the
-    loop over rounds carries (left to itself the compiler rebuilds it
-    every round: as many bytes alive, a round at a time). While it is
-    built the int32 broadcast it is compared from (4 bytes an element,
-    four times the chip's one-byte operand) is alive beside it; that is
-    not charged here (PERF.md §3, `memory_peak_bytes`)."""
+    loop over rounds carries. While it is built a block's broadcast is
+    alive beside it and no more (`_OPERAND_BLOCK_ROWS` rows × F × bins
+    bytes), so this is what a fit's programs ask of the chip beyond the
+    histogram dots' own operands (PERF.md §3, `memory_peak_bytes`)."""
     return int(rows) * spec.n_features * spec.n_bins \
         * np.dtype(_operand_dtype(_hist_dtype())).itemsize
+
+
+def _count_operand(spec: TreeSpec, rows: int, table_rows: int = None,
+                   mesh=None) -> None:
+    """A dispatch's operand in the recorder's counters, beside
+    `tree.fit_dispatch`: `tree.operand.bytes` += the stored operand's bytes
+    over all devices (`_onehot_bytes` of all the dispatch's `rows`),
+    `tree.operand.blocks` += the row blocks ONE device's loop walks to
+    build it (of `table_rows`, one table's, where the dispatch stacks
+    folds or trials: a block is then a block of every stacked table)."""
+    PROFILER.count("tree.operand.bytes", _onehot_bytes(spec, rows))
+    PROFILER.count("tree.operand.blocks", _operand_blocks(
+        int(rows if table_rows is None else table_rows) // _data_width(mesh)))
 
 
 def _run_and_read(compiled, *args):
@@ -1200,6 +1263,7 @@ def _fit_ensemble_on_device(binned_dev, y_dev, mask_dev, es: EnsembleSpec,
         "es": _es_meta(es),
         "args": _prewarm.arg_specs(binned_dev, y_dev, mask_dev)})
     PROFILER.count("tree.fit_dispatch")
+    _count_operand(es.tree, binned_dev.shape[0])
     with transient_hbm("hist_onehot",
                        _onehot_bytes(es.tree, binned_dev.shape[0])):
         packs, base = _run_and_read(compiled, binned_dev, y_dev, mask_dev,
@@ -1306,6 +1370,7 @@ def fit_ensembles_folds(bst, yst, mst, es: EnsembleSpec, seed: int = 0):
             transient_hbm("hist_onehot",
                           _onehot_bytes(es.tree, fo * n_pad)):
         PROFILER.count("tree.fit_dispatch")
+        _count_operand(es.tree, fo * n_pad, n_pad)
         packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rng)
     return [(_unpack_trees(packs[k]), float(bases[k])) for k in range(fo)]
 
@@ -1550,6 +1615,8 @@ def fit_ensembles_trials(bst, yst, mst, es: EnsembleSpec, rngs,
             transient_hbm("hist_onehot",
                           _onehot_bytes(es.tree, e_pad * n_pad)):
         PROFILER.count("tree.fit_dispatch")
+        _count_operand(es.tree, e_pad * n_pad, n_pad,
+                       tmesh if tdim > 1 else mesh)
         packs, bases = _run_and_read(compiled, b_dev, y_dev, m_dev, rngs,
                                      *dyns)
     return packs[:E], bases[:E]
@@ -1699,6 +1766,7 @@ def fit_tree(binned_dev, grad_dev, hess_dev, weight_dev, spec: TreeSpec,
     if feat_key is None:
         feat_key = jax.random.key_data(jax.random.PRNGKey(rng))
     PROFILER.count("tree.fit_dispatch")
+    _count_operand(spec, binned_dev.shape[0])
     with transient_hbm("hist_onehot",
                        _onehot_bytes(spec, binned_dev.shape[0])):
         out = compiled(binned_dev, grad_dev, hess_dev, weight_dev, feat_key)

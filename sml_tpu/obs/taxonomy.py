@@ -153,6 +153,11 @@ COUNTERS = {
     "compile.program.*",  # per-name program-cache-miss counts
     "tree.fit_dispatch",  # device launches of tree-fit programs (the
                           # grid-fused CV dispatch-count contract)
+    # the histogram operand a tree-fit dispatch builds
+    # (tree_impl._tree_operand, counted by _count_operand beside
+    # tree.fit_dispatch): the stored one-hot's bytes over all devices / the
+    # row blocks one device's loop walks to write it
+    "tree.operand.bytes", "tree.operand.blocks",
     # the layout a tree fit staged its bin matrix on
     # (tree_impl.stage_tree_data): fit.shards += devices that hold a shard,
     # fit.shard_rows_max += rows (padding included) on the fullest. Read as

@@ -47,7 +47,14 @@ class _XgboostParams:
         self._declareParam("n_estimators", default=100, doc="boosting rounds")
         self._declareParam("learning_rate", default=0.3, doc="eta")
         self._declareParam("max_depth", default=6, doc="tree depth")
-        self._declareParam("max_bins", default=256, doc="histogram bins")
+        self._declareParam(
+            "max_bins", default=256,
+            doc="histogram bins a column (xgboost's own default, max_bin). "
+                "A fit holds columns x max_bins x padded rows bytes on each "
+                "chip for its one dispatch (the one-hot histogram operand, "
+                "tree_impl._tree_operand, built by row blocks): the default "
+                "fits a v5e up to about 10 GB of it, 28 columns x 850 k "
+                "rows or 10 columns x 4 M rows a chip")
         self._declareParam("reg_lambda", default=1.0, doc="L2 on leaf weights")
         self._declareParam("gamma", default=0.0, doc="min split loss")
         self._declareParam("subsample", default=1.0, doc="row subsample per round")

@@ -1,22 +1,28 @@
-"""The histogram operand is built once a dispatch (ISSUE 27) and stored at
-one byte an element on the chip (ISSUE 41).
+"""The histogram operand is built once a dispatch (ISSUE 27), stored at
+one byte an element on the chip (ISSUE 41) and written by row blocks, with
+nothing table-wide beside it (ISSUE 50).
 
 `tree_impl._tree_operand` is the one place that widens the bins and builds
 the one-hot `B1t`, and it ends in an `optimization_barrier`: without
-it XLA:TPU's fusible sinking rebuilds the one-hot inside the loop over
-rounds. `tree_impl._operand_dtype` is the one place that says what it is
-stored as: int8 where the histogram dot multiplies in bf16 (the chip), the
-dot's own type elsewhere. Four things are held here, none of which needs the
-chip:
+it XLA:TPU's fusible sinking rebuilt the one-hot of ISSUES 27-48 inside the
+loop over rounds. `tree_impl._operand_dtype` is the one place that says what
+it is stored as: int8 where the histogram dot multiplies in bf16 (the chip),
+the dot's own type elsewhere. Five things are held here, none of which needs
+the chip:
 
   (a) the jaxpr of every looping program has the barrier once, outside the
-      scan, and the histogram dot reads it as a scan constant;
+      scan over rounds, and the histogram dot reads it as a scan constant;
   (b) compiled for a described v5e chip, no `tree.operand` instruction is
-      inside the while loop, the loop carries the one-hot as ONE int8
+      inside the loop over rounds, that loop carries the one-hot as ONE int8
       operand, and no widened copy of it exists anywhere in the program;
   (c) the barrier is the identity: the fitted packs are bit-equal without it;
   (d) so is the stored type: the packs are bit-equal with the operand stored
-      as int8 and as the histogram's own type.
+      as int8 and as the histogram's own type;
+  (e) so is the build: the packs are bit-equal with the operand built the
+      way it was until ISSUE 50 (`jax.nn.one_hot` over the whole table and a
+      transpose, kept HERE as `_whole_table_operand`), and compiled at the
+      sizes of the benchmark's tree cells no program holds an array of rows
+      x columns x bins elements but the operand itself.
 """
 
 import re
@@ -71,6 +77,22 @@ def _program(which: str, es):
         (True, True, True) + (False,) * 7
 
 
+def _whole_table_operand(binned_c, n_bins: int, hist_dtype,
+                         barrier: bool = True):
+    """`tree_impl._tree_operand` as it was until ISSUE 50: `jax.nn.one_hot`
+    of the whole table's bins widened to int32, reshaped and transposed.
+    The control of (e), and what XLA:TPU sinks into the loop over rounds
+    where nothing bars it."""
+    with jax.named_scope("tree.operand"):
+        binned = binned_c.astype(jnp.int32)
+        n, width = binned.shape
+        B1t = jax.nn.one_hot(
+            binned, n_bins, dtype=tree_impl._operand_dtype(hist_dtype)) \
+            .reshape(n, width * n_bins).T
+        return binned, (jax.lax.optimization_barrier(B1t) if barrier
+                        else B1t)
+
+
 def _cpu_mesh():
     return Mesh(np.array(jax.devices()[:1]), (D,))
 
@@ -113,7 +135,10 @@ def test_one_barrier_outside_the_scan_feeds_the_histogram_dot(which, boosting):
     assert (b1t.aval.shape, b1t.aval.dtype) == (
         (F * B, 64), tree_impl._operand_dtype(tree_impl._hist_dtype()))
 
-    scans = [e for e, _ in eqns if e.primitive.name == "scan"]
+    # (the operand's own walk over its row blocks is a scan too, of its
+    # scope; the loop over rounds is the other one)
+    scans = [e for e, _ in eqns if e.primitive.name == "scan"
+             and "tree.operand" not in str(e.source_info.name_stack)]
     assert len(scans) == 1
     scan = scans[0]
     body = scan.params["jaxpr"].jaxpr
@@ -145,7 +170,9 @@ def test_the_single_tree_program_builds_it_the_same_way_without_a_barrier():
     mapped, _ = _sharded(program, args, (True, True, True, True, False),
                          _cpu_mesh())
     eqns = [e for e, _ in _walk(jax.make_jaxpr(mapped)(*args).jaxpr)]
-    names = [e.primitive.name for e in eqns]
+    names = [e.primitive.name for e in eqns
+             if "tree.operand" not in str(e.source_info.name_stack)
+             or e.primitive.name != "scan"]     # (its walk over row blocks)
     assert "optimization_barrier" not in names and "scan" not in names
     assert any("tree.operand" in str(e.source_info.name_stack)
                and e.outvars[0].aval.shape == (F * B, 64)
@@ -292,40 +319,110 @@ def test_compiled_for_v5e_the_operand_is_outside_the_loop(one_chip_mesh,
     assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand") == []
     # the loop over rounds (the forest's Poisson draw is a loop too)
     carried = [ln.split(" while(")[0] for ln in hlo.splitlines()
-               if re.search(r"\swhile\(", ln)]
+               if re.search(r"\swhile\(", ln) and "tree.operand" not in ln]
     assert sum(c.count(f"s8[{F * B},{AOT_ROWS}]") for c in carried) == 1, \
         "the loop over rounds carries the one-hot as ONE int8 operand"
     assert _wider_copies(hlo, F * B, AOT_ROWS) == []
 
 
 def test_the_check_sees_an_operand_that_was_sunk(one_chip_mesh, monkeypatch):
-    """The control: without the barrier libtpu 0.0.34 sinks the one-hot into
-    the loop, and `ops_in_loop_bodies` says so. Should a later compiler stop
-    sinking, this test fails and the barrier can be reconsidered."""
+    """The control: without the barrier libtpu 0.0.34 sinks a one-hot
+    written as one expression over the table (the build of ISSUES 27-48)
+    into the loop, and `ops_in_loop_bodies` says so. Should a later compiler
+    stop sinking, this test fails and the barrier can be reconsidered."""
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    monkeypatch.setattr(tree_impl, "_tree_operand", _whole_table_operand)
     hlo = _compiled_for(one_chip_mesh, _es(True, n_trees=3))
     assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand")
+
+
+#: the benchmark's tree cells at their own shapes (padded rows, columns,
+#: bins, depth, rounds; cell 3 is cell 1's shapes a chip)
+CELLS = {
+    "ml11_xgb.fit": dict(rows=1_703_936, feats=10, bins=64, depth=4,
+                         trees=100, boosting=True, loss="squared"),
+    "ml07_rf.fit": dict(rows=1_703_936, feats=10, bins=40, depth=5,
+                        trees=10, boosting=False, loss="squared",
+                        feature_k=3),
+    "xgb_higgs.fit_boost_logistic": dict(
+        rows=851_968, feats=28, bins=256, depth=8, trees=24, boosting=True,
+        loss="logistic"),
+}
+_cell_programs = {}
+
+
+def _cell_compiled(mesh, cell: str):
+    """(compiled program, its optimized HLO) of a tree cell's fit at the
+    cell's own shapes, compiled once a module."""
+    if cell not in _cell_programs:
+        c = CELLS[cell]
+        spec = tree_impl.TreeSpec(
+            max_depth=c["depth"], n_bins=c["bins"], n_features=c["feats"],
+            feature_k=c.get("feature_k", c["feats"]), min_instances=1,
+            min_info_gain=0.0, reg_lambda=1.0 if c["boosting"] else 0.0,
+            gamma=0.0)
+        es = tree_impl.EnsembleSpec(
+            tree=spec, n_trees=c["trees"], loss=c["loss"],
+            boosting=c["boosting"], bootstrap=not c["boosting"],
+            subsample=1.0, step_size=0.1)
+        compiled = _compile_for(mesh, es, rows=c["rows"])
+        _cell_programs[cell] = (compiled, compiled.as_text())
+    return _cell_programs[cell]
 
 
 def test_the_boosted_fit_holds_a_one_byte_operand_at_the_cells_size(
         one_chip_mesh):
     """`ml11_xgb.fit`'s program at its own shapes (1,703,936 padded rows x
     10 features, 64 bins, depth 4, 100 rounds), compiled for the chip:
-    5.58 GB of temporaries with the one-hot resident as s8[640, rows]
-    (6.67 GB with it in bf16, ISSUE 41), and no wider copy of it."""
+    1.44 GB of temporaries with the one-hot resident as s8[640, rows] and
+    built by row blocks (5.58 GB with the table-wide int32 broadcast beside
+    it until ISSUE 50, 6.67 GB with it in bf16 until ISSUE 41), and no wider
+    copy of it."""
     rows, feats, bins = 1_703_936, 10, 64
-    spec = tree_impl.TreeSpec(
-        max_depth=4, n_bins=bins, n_features=feats, feature_k=feats,
-        min_instances=1, min_info_gain=0.0, reg_lambda=1.0, gamma=0.0)
-    es = tree_impl.EnsembleSpec(
-        tree=spec, n_trees=100, loss="squared", boosting=True,
-        bootstrap=False, subsample=1.0, step_size=0.1)
-    compiled = _compile_for(one_chip_mesh, es, rows=rows)
+    compiled, hlo = _cell_compiled(one_chip_mesh, "ml11_xgb.fit")
     temporaries = compiled.memory_analysis().temp_size_in_bytes
-    assert temporaries < 5.8e9, f"{temporaries / 1e9:.2f} GB of temporaries"
-    hlo = compiled.as_text()
+    assert temporaries < 1.6e9, f"{temporaries / 1e9:.2f} GB of temporaries"
     assert f"s8[{feats * bins},{rows}]" in hlo
     assert _wider_copies(hlo, feats * bins, rows) == []
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_a_tree_cells_program_holds_nothing_table_wide_but_the_operand(
+        one_chip_mesh, cell):
+    """(e) Each tree cell's fit compiled for the chip at the cell's own
+    shapes: the operand is s8[columns x bins, rows], no instruction of its
+    scope runs inside the loop over rounds, that loop carries it once, and
+    NO other array of the program has rows x columns x bins elements: not
+    the `s32[rows, columns, bins]` broadcast `jax.nn.one_hot` was compared
+    from (4.36 GB of cells 1-3's 5.58 GB; 24.4 GB at the new cell's 256
+    bins), not the `pred` it gave, not a one-byte broadcast of the bins
+    (what the same compare written as one pass over the table leaves).
+    The new cell's arguments and temporaries fit the chip with room: under
+    12 GiB of its 16."""
+    c = CELLS[cell]
+    rows, width = c["rows"], c["feats"] * c["bins"]
+    compiled, hlo = _cell_compiled(one_chip_mesh, cell)
+    assert f"s8[{width},{rows}]" in hlo
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.hist")
+    assert tree_impl.ops_in_loop_bodies(hlo, "tree.operand") == []
+    loops = [ln for ln in hlo.splitlines() if re.search(r"\swhile\(", ln)]
+    own = [ln for ln in loops if "tree.operand" in ln]
+    assert len(own) == 1, "the operand's walk over its row blocks"
+    assert sum(ln.split(" while(")[0].count(f"s8[{width},{rows}]")
+               for ln in loops if ln not in own) == 1
+    table_wide = {
+        f"{kind}[{dims}]"
+        for kind, dims in re.findall(r"\b([a-z]+[0-9]*)\[([0-9,]+)\]", hlo)
+        if np.prod([int(x) for x in dims.split(",")]) >= rows * width}
+    assert table_wide and all(a.startswith("s8[") for a in table_wide), \
+        table_wide
+    block = tree_impl._OPERAND_BLOCK_ROWS
+    assert f"[{c['feats']},{c['bins']},{block}]" in hlo, \
+        "a block's bins against the bin ids"
+    memory = compiled.memory_analysis()
+    held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert held < rows * width + (2 << 30), f"{held / 1e9:.2f} GB"
+    assert held < 12 << 30
 
 
 def test_the_fused_logistic_fit_fits_a_v5e_at_the_cells_size(one_chip_mesh):
@@ -553,6 +650,59 @@ def test_fitted_packs_are_bit_equal_with_the_operand_stored_as_int8(
     assert (as_hist[0][:, 0] >= 0).any(), "the trees split"
     np.testing.assert_array_equal(as_hist[0], as_int8[0])
     assert as_hist[1] == as_int8[1]
+
+
+# ------------------------------------------ (e) and so is the build
+@pytest.mark.parametrize("bins", [40, 64, 256])
+@pytest.mark.parametrize("boosting", [True, False], ids=["boosted", "bagged"])
+def test_fitted_packs_are_bit_equal_to_the_whole_table_builds(
+        boosting, bins, monkeypatch):
+    """The operand written by row blocks (two whole blocks of 200 rows and
+    a remainder of 112 here) is the operand `jax.nn.one_hot` of the whole
+    table gave, element for element: the program fits the very trees."""
+    width = 5
+    spec = tree_impl.TreeSpec(
+        max_depth=3, n_bins=bins, n_features=width,
+        feature_k=width if boosting else 2, min_instances=1,
+        min_info_gain=0.0, reg_lambda=1.0 if boosting else 0.0, gamma=0.0)
+    es = tree_impl.EnsembleSpec(
+        tree=spec, n_trees=3, loss="logistic" if boosting else "squared",
+        boosting=boosting, bootstrap=not boosting, subsample=1.0,
+        step_size=0.3)
+    rng = np.random.default_rng(bins)
+    binned = rng.integers(0, bins, size=(512, width)).astype(np.uint8)
+    y = ((binned[:, 0] > bins // 2) ^ (binned[:, 1] < bins // 3)) \
+        .astype(np.float32)
+    data = (binned, y, np.ones(512, np.float32),
+            np.asarray(jax.random.key_data(jax.random.PRNGKey(1))))
+
+    def fit():
+        program = tree_impl._make_ensemble_program(es, 1, (D,), 0)
+        mapped, _ = _sharded(program, data, (True, True, True, False),
+                             _cpu_mesh())
+        packs, base = jax.jit(mapped)(*data)
+        return np.asarray(packs), float(base), \
+            str(jax.make_jaxpr(mapped)(*data))
+
+    monkeypatch.setattr(tree_impl, "_OPERAND_BLOCK_ROWS", 200)
+    by_blocks = fit()
+    assert "dynamic_update_slice" in by_blocks[2]
+    assert f"[512,{width},{bins}]" not in by_blocks[2]
+    monkeypatch.setattr(tree_impl, "_tree_operand", _whole_table_operand)
+    whole = fit()
+    assert f"[512,{width},{bins}]" in whole[2] \
+        and "dynamic_update_slice" not in whole[2], \
+        "the patch reaches the operand"
+    assert (whole[0][:, 0] >= 0).any(), "the trees split"
+    np.testing.assert_array_equal(by_blocks[0], whole[0])
+    assert by_blocks[1] == whole[1]
+
+
+@pytest.mark.parametrize("rows,blocks", [
+    (64, 1), (1 << 14, 1), ((1 << 14) + 1, 2), (851_968, 52),
+    (1_703_936, 104)])
+def test_operand_blocks_counts_whole_blocks_and_a_remainder(rows, blocks):
+    assert tree_impl._operand_blocks(rows) == blocks
 
 
 # ----------------------------------- the clustering's blocked Lloyd step
