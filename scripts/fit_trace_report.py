@@ -17,13 +17,15 @@ first), then, from the same process's recorder ring and the run's
    the window: anchor-placed start/end minus profiler-recorded start/end, and
    the end of the last device operation of the fit minus the end of its
    `fit.device_wait` (both on the profiler's clock);
-2. the phases of each fit of the window, from the spans that share its
-   trace id, children beside their parents (`fit.quantize.*`, the
+2. the phases of each fit of the window, from the program's own record of
+   it (`obs.fit_records()`: the spans that share its trace id, summed by
+   name), children beside their parents (`fit.quantize.*`, the
    `fit.featurize.*` children, the staging steps `stage.key` / `.pad` / `.put`)
    and what a span notes in numbers as `<name> [<note>]` (the process's
    `cpu_s`, the slowest column job's `longest_s`, a staging
    step's `bytes`, `copied`, `hit`, and `stage.pad [warm share]`: the share
-   of a fit's pad steps written into the pad pool's warm pages): fits 1-3
+   of a fit's pad steps written into the pad pool's warm pages), with the
+   collector's pauses (and a slow fit's memory at its close): fits 1-3
    against the rest (which phase is still warming);
 3. the one-clock check extended to the transfer: for every `fit.stage` of
    the window, the end of the fit's last host-to-device event on the trace's
@@ -185,41 +187,44 @@ def put_against_transfer(profile, placed, window):
     return out
 
 
-def fits_by_phase(events, since_s: float):
+def fits_by_phase(records, since_s: float):
     """One row a fit whose root `fit` span started after `since_s` (the
-    recorder's clock): seconds by span name, the root's own remainder as
-    `(unattributed)`; every child (of `fit.quantize`, `fit.featurize`,
-    `fit.stage`) kept beside its parent, under its own name, and what a
-    span notes in numbers beside it as `<name> [<note>]`."""
-    spans = [e for e in events if e.kind == "span" and "trace" in e.args]
-    roots = sorted((e for e in spans if e.name == "fit"
-                    and e.args.get("parent") is None and e.ts >= since_s),
-                   key=lambda e: e.ts)
+    recorder's clock): a formatting of `obs.fit_records()`, the program's
+    own per-fit form of the span totals (`sml_tpu/obs/_fits.py`). Seconds by
+    span name, every child (of `fit.quantize`, `fit.featurize`,
+    `fit.stage`) beside its parent under its own name, what a span notes in
+    numbers beside it as `<name> [<note>]`, and the record's own facts:
+    `(unattributed)` (the root less the seven phases the benchmark reports:
+    `fit.host.unattributed_s`; until PR 52 this script took the root less
+    its direct children, which counted a program's span whole), `(gc)` the
+    collector's pauses inside the fit, and for a slow fit `(rss bytes)` /
+    `(available bytes)` at its close."""
     rows = []
-    for root in roots:
-        mine = [e for e in spans if e.args["trace"] == root.args["trace"]]
-        row = {"fit": root.dur}
-        direct = 0.0
-        pads = sum(1 for e in mine if "warm" in e.args)
-        for e in mine:
+    for record in records:
+        if record["t0"] < since_s:
+            continue
+        spans = record["spans"]
+        row = {"fit": record["wall_s"]}
+        pads = sum(e["n"] for e in spans.values() if "warm" in e)
+        for name, entry in spans.items():
             # what a span notes beside its seconds: the process's CPU
             # seconds (`CPU_SPANS`), the slowest job and, for a staging
             # step, its bytes, how often it had to copy the caller's
             # array or found it cached and, of the pads, the share written
             # into warm pages
             for note in ("cpu_s", "longest_s", "bytes", "copied", "hit"):
-                if note in e.args:
-                    key = f"{e.name} [{note}]"
-                    row[key] = row.get(key, 0.0) + e.args[note]
-            if e.args.get("warm"):
-                key = f"{e.name} [warm share]"
-                row[key] = row.get(key, 0.0) + 1.0 / pads
-            if e is root:
-                continue
-            row[e.name] = row.get(e.name, 0.0) + e.dur
-            if e.args.get("parent") == root.args["span"]:
-                direct += e.dur
-        row["(unattributed)"] = root.dur - direct
+                if note in entry:
+                    row[f"{name} [{note}]"] = entry[note]
+            if entry.get("warm"):
+                row[f"{name} [warm share]"] = entry["warm"] / pads
+            if name != "fit":
+                row[name] = entry["wall_s"]
+        row["(unattributed)"] = record["phases"]["fit.host.unattributed_s"]
+        row["(gc)"] = record["gc_s"]
+        for key, label in (("rss_bytes", "(rss bytes)"),
+                           ("mem_available_bytes", "(available bytes)")):
+            if key in record:
+                row[label] = record[key]
         rows.append(row)
     return rows
 
@@ -322,7 +327,7 @@ def main() -> int:
     recorder = obs.RECORDER
     # the recorder's clock starts at its epoch; the anchor is on perf_counter
     offset = recorder.epoch_unix() - (wallclock() - now())
-    rows = fits_by_phase(recorder.events(), seen["anchor_s"] - offset)
+    rows = fits_by_phase(obs.fit_records(), seen["anchor_s"] - offset)
     report = {
         "cell": args.workload, "seed": args.seed,
         "trace_file_bytes": os.path.getsize(path),

@@ -33,13 +33,14 @@ from ..project import Project
 # receiver name -> {method -> (arg index of the NAME, taxonomy kind)}
 TARGETS = {
     "PROFILER": {"span": (0, "span"), "count": (0, "count")},
+    # `total`: a running total with no ring event (Recorder.total)
     "RECORDER": {"emit": (1, "emit"), "counter": (0, "counter"),
-                 "gauge": (0, "gauge")},
+                 "total": (0, "counter"), "gauge": (0, "gauge")},
     "_OBS": {"emit": (1, "emit"), "counter": (0, "counter"),
-             "gauge": (0, "gauge")},
+             "total": (0, "counter"), "gauge": (0, "gauge")},
     # the recorder as obs/'s own classes hold it (`self._rec`)
     "_rec": {"emit": (1, "emit"), "counter": (0, "counter"),
-             "gauge": (0, "gauge")},
+             "total": (0, "counter"), "gauge": (0, "gauge")},
     # streaming-metrics histograms (obs/_metrics.py): observed names are
     # part of the same taxonomy (METRICS_NAMES, kind "observe")
     "METRICS": {"observe": (0, "observe")},

@@ -40,6 +40,14 @@ built on ONE structured event bus:
   its audit-predicted wall (floor `sml.obs.stallMillis`) is flagged
   with all-thread stack snapshots, surfaced as the `inflight` block of
   `engine_health()`.
+- `fit_records()` (`_fits`): a record for every root fit — wall and CPU
+  seconds by span name and by the benchmark's eight host phases, the
+  collector's pauses (a slow fit's also the resident and the available
+  memory) — the per-fit form
+  of the `span_s.*` totals; the root `fit` carries a watchdog ticket
+  whose expectation is its shape's own median, and a fit a quarter and
+  0.1 s over it leaves a `fit.slow` event and ONE WARNING line that
+  names the phase.
 - `dump_blackbox` / `install_blackbox` (`blackbox`): black-box
   postmortem bundles (ring + metrics + audit + ledger + in-flight
   tickets + stacks + conf) on unhandled exception, hard stall, or
@@ -62,7 +70,7 @@ import threading
 from typing import Dict, Optional
 
 from ..conf import GLOBAL_CONF
-from . import _audit, _context, _ledger
+from . import _audit, _context, _fits, _ledger
 from . import drift as drift  # noqa: F401 — re-exported subsystem
 from ._audit import records as audit_records, report as audit_report
 from ._context import TraceContext, activate as activate_trace, \
@@ -85,7 +93,7 @@ __all__ = ["RECORDER", "Event", "LEDGER", "METRICS", "SKEW", "INGEST_SKEW",
            "LogHistogram", "merge_snapshots", "export_chrome_trace",
            "audit_report", "audit_records", "memory_report",
            "engine_metrics", "engine_health", "straggler_report",
-           "skew_report_from_trace", "reset",
+           "skew_report_from_trace", "reset", "fit_records",
            "enabled", "note_compile", "autolog_fit"]
 
 
@@ -93,9 +101,16 @@ def enabled() -> bool:
     return RECORDER.enabled
 
 
+def fit_records():
+    """A record for every root fit that ended, the newest 256, oldest
+    first (`obs/_fits.py`: wall and CPU seconds by span name and by the
+    benchmark's eight phases, the collector's pauses)."""
+    return RECORDER.fit_records()
+
+
 def reset() -> None:
-    """Drop recorded events, audit records, metric histograms, skew
-    attributions, watchdog statistics, and re-arm HBM peaks (live ledger
+    """Drop recorded events, fit records, audit records, metric histograms,
+    skew attributions, watchdog statistics, and re-arm HBM peaks (live ledger
     bytes and OPEN watchdog tickets persist — they describe real cache
     residency / real in-flight work)."""
     RECORDER.reset()
@@ -314,15 +329,24 @@ def _fit_root(estimator, df):
     the fit then belongs to). Every `PROFILER.span` below is a child
     unit, so the recorded spans share the `trace` id and name their
     `parent` (docs/OBSERVABILITY.md "The span tree of a fit"). `rows`
-    only where the frame is materialized already: counting costs nothing."""
+    only where the frame is materialized already: counting costs nothing.
+    Round the span, `_fits`: a watchdog ticket with the fit's own
+    expectation while it runs, its record and verdict when it has ended."""
     from ..utils.profiler import PROFILER
     parts = getattr(df, "_parts", None)
+    rows = None if parts is None else sum(len(p) for p in parts)
     opened = open_trace() if current_trace() is None else None
-    with activate_trace(opened), \
-            PROFILER.span("fit", estimator=type(estimator).__name__,
-                          rows=None if parts is None
-                          else sum(len(p) for p in parts)):
-        yield
+    with activate_trace(opened):
+        state = _fits.open_fit(estimator, rows)
+        root = None
+        try:
+            with PROFILER.span("fit", estimator=type(estimator).__name__,
+                               rows=rows):
+                ended = current_trace()
+                yield
+            root = ended
+        finally:
+            _fits.close_fit(state, root)
 
 
 @contextlib.contextmanager

@@ -14,6 +14,7 @@ no lock, no allocation, no conf lookup.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -70,6 +71,46 @@ def event_record(ev: Event) -> Dict[str, object]:
 #: tests/test_obs.py).
 _MAX_TIDS = 512
 
+#: fit records kept (`keep_fit`): the newest, in a deque beside the ring
+_MAX_FIT_RECORDS = 256
+
+#: a collection of this many seconds or more, or of generation 2, lands a
+#: span `gc.pause`; every shorter one is in `gc.pause_s` / `gc.collections`
+#: alone (generation 0 runs hundreds of times a fit)
+_GC_SPAN_S = 1e-3
+
+
+class _GcPauses:
+    """The `gc.callbacks` hook: two clock reads a collection, summed into
+    `seconds` / `collections`; a pause worth a span waits in `waiting` for
+    the recorder's next `emit`. It takes no lock and emits nothing: a
+    collection may start on a thread that is inside `emit`, under the
+    recorder's lock. The interpreter runs one collection at a time and
+    both callbacks inside it, so these attributes have ONE writer, this
+    call; a `reset()` moves the recorder's baseline, not these."""
+
+    def __init__(self) -> None:
+        self.t0: Optional[float] = None
+        self.seconds = 0.0
+        self.collections = 0
+        self.waiting: deque = deque()
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self.t0 = time.perf_counter()
+            return
+        t0, self.t0 = self.t0, None
+        if t0 is None:      # hooked between a collection's two callbacks
+            return
+        dt = time.perf_counter() - t0
+        self.seconds += dt
+        self.collections += 1
+        if dt >= _GC_SPAN_S or info["generation"] == 2:
+            self.waiting.append(
+                (t0, dt, threading.get_ident(),
+                 {"generation": info["generation"],
+                  "collected": info["collected"]}))
+
 
 class Recorder:
     def __init__(self) -> None:
@@ -88,8 +129,12 @@ class Recorder:
         self._sink_max = max(int(GLOBAL_CONF.getInt("sml.obs.sinkMaxBytes")),
                              0)
         self.dropped = 0
+        self._fits: deque = deque(maxlen=_MAX_FIT_RECORDS)
+        self._gc = _GcPauses()
+        self._gc_base = (0.0, 0)    # the hook's counts at the last reset()
         # plain attribute, NOT a property: the disabled-path cost per event
         self.enabled: bool = GLOBAL_CONF.getBool("sml.obs.enabled")
+        self._sync_gc_hook()
 
     # ------------------------------------------------------------- config
     def reconfigure(self) -> None:
@@ -110,6 +155,7 @@ class Recorder:
             self._sink_max = max(
                 int(GLOBAL_CONF.getInt("sml.obs.sinkMaxBytes")), 0)
         self.enabled = GLOBAL_CONF.getBool("sml.obs.enabled")
+        self._sync_gc_hook()
 
     def note_process(self, **facts: float) -> None:
         """Facts of the PROCESS, not of a recorder epoch, as gauges
@@ -142,27 +188,34 @@ class Recorder:
         at = (ts if ts is not None else time.perf_counter()) - self._epoch
         ident = threading.get_ident()
         with self._lock:
-            # tid assignment under the lock: two threads' first emits must
-            # not share a lane (a counter read outside it is not unique)
-            tid = self._tids.get(ident)
-            if tid is None:
-                tid = self._claim_tid_locked(ident)
-            ev = Event(ts=max(at, 0.0), kind=kind, name=name, dur=dur,
-                       tid=tid, args=args or {})
-            if len(self._ring) == self._ring.maxlen:
-                self.dropped += 1
-            self._ring.append(ev)
-            if kind == "span":
-                totals, busy, calls = self._totals, "span_s." + name, \
-                    "span_n." + name
-                totals[busy] = totals.get(busy, 0.0) + (dur or 0.0)
-                totals[calls] = totals.get(calls, 0.0) + 1.0
-                if args and "cpu_s" in args:
-                    cpu = "span_cpu_s." + name
-                    totals[cpu] = totals.get(cpu, 0.0) + args["cpu_s"]
-            sink = self._ensure_sink()
-            if sink is not None:  # under the lock: lines must not interleave
-                self._write_sink(ev, sink)
+            if self._gc.waiting:
+                self._land_gc_locked()
+            self._append_locked(at, kind, name, dur, ident, args or {})
+
+    def _append_locked(self, at: float, kind: str, name: str,
+                       dur: Optional[float], ident: int,
+                       args: Dict[str, object]) -> None:
+        # tid assignment under the lock: two threads' first emits must
+        # not share a lane (a counter read outside it is not unique)
+        tid = self._tids.get(ident)
+        if tid is None:
+            tid = self._claim_tid_locked(ident)
+        ev = Event(ts=max(at, 0.0), kind=kind, name=name, dur=dur,
+                   tid=tid, args=args)
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+        self._ring.append(ev)
+        if kind == "span":
+            totals, busy, calls = self._totals, "span_s." + name, \
+                "span_n." + name
+            totals[busy] = totals.get(busy, 0.0) + (dur or 0.0)
+            totals[calls] = totals.get(calls, 0.0) + 1.0
+            if "cpu_s" in args:
+                cpu = "span_cpu_s." + name
+                totals[cpu] = totals.get(cpu, 0.0) + args["cpu_s"]
+        sink = self._ensure_sink()
+        if sink is not None:  # under the lock: lines must not interleave
+            self._write_sink(ev, sink)
 
     def _claim_tid_locked(self, ident: int) -> int:
         """Dense lane id for a newly-seen thread. At the _MAX_TIDS bound,
@@ -200,6 +253,16 @@ class Recorder:
             self._totals[name] = total
         self.emit("counter", name, args={"total": total, "inc": inc})
 
+    def total(self, name: str, inc: float = 1.0) -> None:
+        """Add to a running total with NO ring event: what is counted
+        once a fit or once a watchdog wait and read as a delta between
+        two `counters()` snapshots, where an event each would say
+        nothing the fit's record does not."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._totals[name] = self._totals.get(name, 0.0) + inc
+
     def gauge(self, name: str, value: float) -> None:
         """Point-in-time gauge (HBM ledger live bytes): the recorded
         total IS the current value, not a sum."""
@@ -216,6 +279,64 @@ class Recorder:
             return
         self.emit("span", name, dur=dur, ts=t0,
                   args={k: v for k, v in meta.items() if v is not None})
+
+    # --------------------------------------------------------- fit records
+    def keep_fit(self, record: Dict[str, object]) -> None:
+        """Keep one fit's record (`obs/_fits.py`), the newest
+        `_MAX_FIT_RECORDS` of them: beside the ring, not in it."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._fits.append(record)
+
+    def fit_records(self) -> List[Dict[str, object]]:
+        with self._lock:
+            return list(self._fits)
+
+    def trace_spans(self, trace_id: int, since: float) -> List[Event]:
+        """The span events of one trace that ended at or after `since` (an
+        absolute perf_counter stamp), newest first: a walk of the ring
+        backwards under the lock, until an event that ended before
+        `since` (events are appended as they end, so a fit's are the
+        ring's newest 40 or so; the ring is not copied)."""
+        at = since - self._epoch
+        out: List[Event] = []
+        with self._lock:
+            if self._gc.waiting:
+                self._land_gc_locked()
+            for ev in reversed(self._ring):
+                if ev.ts + (ev.dur or 0.0) < at:
+                    break
+                if ev.kind == "span" and ev.args.get("trace") == trace_id:
+                    out.append(ev)
+        return out
+
+    # ------------------------------------------------------ the collector
+    def _sync_gc_hook(self) -> None:
+        """The `gc.callbacks` hook is in the list while the recorder is on
+        and out of it while it is off (off: nothing runs a collection)."""
+        hooked = self._gc in gc.callbacks
+        if self.enabled and not hooked:
+            gc.callbacks.append(self._gc)
+        elif hooked and not self.enabled:
+            gc.callbacks.remove(self._gc)
+
+    def _land_gc_locked(self) -> None:
+        """The pauses that wait become `gc.pause` spans, each on the lane
+        of the thread it ran on: before the next event, so the ring stays
+        in the order things ended."""
+        waiting = self._gc.waiting
+        while waiting:
+            t0, dt, ident, args = waiting.popleft()
+            self._append_locked(t0 - self._epoch, "span", "gc.pause", dt,
+                                ident, args)
+
+    def gc_totals(self) -> tuple:
+        """(seconds, collections) of the collector's pauses since the last
+        `reset()`: what `counters()` reports as `gc.pause_s` and
+        `gc.collections`, without the copy."""
+        seconds, collections = self._gc_base
+        return self._gc.seconds - seconds, self._gc.collections - collections
 
     # --------------------------------------------------------------- sink
     def _sink_header_locked(self, sink) -> None:
@@ -271,26 +392,39 @@ class Recorder:
     # ------------------------------------------------------------ reading
     def events(self) -> List[Event]:
         with self._lock:
+            if self._gc.waiting:
+                self._land_gc_locked()
             return list(self._ring)
 
     def counters(self) -> Dict[str, float]:
-        """The running totals and the `process.*` gauges (`note_process`).
-        Off, the recorder answers with nothing at all, as its contract
-        has been (`tests/test_obs.py`): the gauges too."""
+        """The running totals, the collector's pauses (`gc.pause_s`,
+        `gc.collections`: `_GcPauses`) and the `process.*` gauges
+        (`note_process`). Off, the recorder answers with nothing at all,
+        as its contract has been (`tests/test_obs.py`): the gauges too."""
+        seconds, collections = self.gc_totals()
         with self._lock:
-            return dict(self._totals, **self._process) if self.enabled \
-                else dict(self._totals)
+            if not self.enabled:
+                return dict(self._totals)
+            out = dict(self._totals, **self._process)
+            if collections:
+                out["gc.pause_s"] = seconds
+                out["gc.collections"] = float(collections)
+            return out
 
     def reset(self) -> None:
-        """Drop all events/totals and re-zero the epoch (enabled state,
-        sink configuration and the `process.*` gauges survive: those are
-        facts of the process, not of an epoch). An OPEN sink gets a fresh
+        """Drop all events, totals and fit records and re-zero the epoch
+        (enabled state, sink configuration and the `process.*` gauges
+        survive: those are facts of the process, not of an epoch; the
+        collector's counts restart from here). An OPEN sink gets a fresh
         header line: its previous epoch_unix anchor no longer describes
         the re-zeroed timeline, and a postmortem reader re-anchors at the
         newest header above each line."""
         with self._lock:
+            self._land_gc_locked()      # into the ring that is dropped
             self._ring.clear()
             self._totals.clear()
+            self._fits.clear()
+            self._gc_base = (self._gc.seconds, self._gc.collections)
             self.dropped = 0
             self._epoch = time.perf_counter()
             if self._sink is not None:

@@ -18,12 +18,21 @@ the in-flight half of the story:
 - a daemon thread flags any ticket whose elapsed time exceeds
   `sml.obs.stallFactor x` its expected (audit-predicted) time, floored
   at `sml.obs.stallMillis` — predicted-slow work is NOT a stall, only
-  work that broke its own prediction is;
+  work that broke its own prediction is; a caller that knows better
+  brings its own threshold (`open(..., threshold_s=)`: the root `fit`'s
+  ticket, whose expectation is its shape's own median, `obs/_fits.py`);
 - a flagged ticket emits a `stall.detected` event carrying the ticket
   (name, kind, elapsed, expected, trace id) plus an ALL-THREAD stack
   snapshot (`sys._current_frames`) — the "where is everyone" picture a
-  postmortem needs, taken while the hang is live; `stall.resolved`
-  closes the story if the operation eventually completes;
+  postmortem needs, taken while the hang is live — and, for a ticket
+  with a trace, the spans of that trace closed since it opened (`closed`:
+  how far the work got); `stall.resolved` closes the story if the
+  operation eventually completes;
+- the loop times ITSELF: how much later than asked each `wait` returned,
+  summed beyond `_LATE_S` into the total `watchdog.late_s` and noted on a
+  stall event as `late_s` (the lateness while that ticket was open): a
+  watchdog on time beside a late thread says that thread was blocked, a
+  late watchdog says the process, or the machine, stood still;
 - `report()` surfaces the in-flight table as the `inflight` block of
   `obs.engine_health()` / `ServingEndpoint.health_report()`, and
   `on_stall` hooks let the blackbox (obs/blackbox.py) auto-dump a
@@ -67,6 +76,9 @@ _MAX_REPORT_TICKETS = 32
 
 _POLL_IDLE_S = 0.25
 _POLL_MIN_S = 0.01
+#: a `wait` that returns this much later than asked counts as lateness of
+#: the loop (`watchdog.late_s`): under it, it is the scheduler's jitter
+_LATE_S = 0.05
 
 
 def all_thread_stacks(limit: int = _MAX_STACK_THREADS) -> Dict[str, List[str]]:
@@ -96,23 +108,33 @@ class Watchdog:
         self._wake = threading.Event()
         self._on_stall: List[Callable[[dict], None]] = []
         self.flagged_total = 0
+        #: seconds the loop's waits returned late (`_loop`), since reset()
+        self.late_s = 0.0
 
     # ------------------------------------------------------------- tickets
     def open(self, kind: str, name: str, *,
              expected_s: Optional[float] = None,
+             threshold_s: Optional[float] = None,
              trace: Optional[object] = None,
              thread: Optional[str] = None) -> Optional[int]:
         """Register one in-flight operation; returns the ticket id (None
         with the recorder disabled — the one-attribute-load path).
         `expected_s` is the audit-predicted wall for this operation (None
-        = no prediction; only the stallMillis floor applies). `trace`
-        accepts a TraceContext or a raw trace id."""
+        = no prediction; only the stallMillis floor applies).
+        `threshold_s` is the caller's own threshold in place of the
+        conf's factor and floor: such a ticket is no HARD stall (the
+        blackbox's dump keeps to those flagged at the conf's floor).
+        `trace` accepts a TraceContext or a raw trace id."""
         if not self._rec.enabled:
             return None
-        factor = max(float(GLOBAL_CONF.get("sml.obs.stallFactor")), 1.0)
-        floor = max(int(GLOBAL_CONF.getInt("sml.obs.stallMillis")), 1) / 1e3
-        threshold = max(factor * expected_s, floor) if expected_s \
-            else floor
+        if threshold_s is None:
+            factor = max(float(GLOBAL_CONF.get("sml.obs.stallFactor")), 1.0)
+            floor = max(int(GLOBAL_CONF.getInt("sml.obs.stallMillis")),
+                        1) / 1e3
+            threshold = max(factor * expected_s, floor) if expected_s \
+                else floor
+        else:
+            threshold = threshold_s
         trace_id = getattr(trace, "trace_id", trace)
         ticket = {
             "id": next(self._seq),
@@ -124,6 +146,8 @@ class Watchdog:
             "trace": trace_id,
             "thread": thread or threading.current_thread().name,
             "flagged": False,
+            "hard": threshold_s is None,
+            "late0": self.late_s,
         }
         with self._lock:
             self._tickets[ticket["id"]] = ticket
@@ -175,9 +199,18 @@ class Watchdog:
 
     def _loop(self) -> None:
         while True:
-            self._wake.wait(self._poll_s())
+            asked = self._poll_s()
+            before = time.perf_counter()
+            woken = self._wake.wait(asked)
             self._wake.clear()
             now = time.perf_counter()
+            late = now - before - asked
+            if not woken and late > _LATE_S:
+                # the loop's own lateness: nothing it waits on but the
+                # clock, so what held it held the process
+                with self._lock:
+                    self.late_s += late
+                self._rec.total("watchdog.late_s", late)
             stalled: List[dict] = []
             with self._lock:
                 for t in self._tickets.values():
@@ -189,13 +222,20 @@ class Watchdog:
             for t in stalled:
                 # the snapshot is taken while the hang is LIVE — the
                 # whole point; outside the lock, stacks can be slow
-                self._rec.emit("stall", "stall.detected", args={
+                args = {
                     "name": t["name"], "kind": t["kind"],
                     "elapsed_s": round(now - t["t0"], 4),
                     "expected_s": t["expected_s"],
                     "threshold_s": round(t["threshold_s"], 4),
                     "trace": t["trace"], "thread": t["thread"],
-                    "stacks": all_thread_stacks()})
+                    "late_s": round(max(self.late_s - t["late0"], 0.0), 4),
+                    "stacks": all_thread_stacks()}
+                if t["trace"] is not None:
+                    # how far the work got: "0.41 s into fit.quantize"
+                    args["closed"] = [
+                        [ev.name, round(ev.dur or 0.0, 6)] for ev in
+                        reversed(self._rec.trace_spans(t["trace"], t["t0"]))]
+                self._rec.emit("stall", "stall.detected", args=args)
                 self._rec.counter("stall.flagged")
                 for hook in list(self._on_stall):
                     try:
@@ -218,6 +258,7 @@ class Watchdog:
         tickets.sort(key=lambda t: t["t0"])
         for t in tickets:
             t["elapsed_s"] = round(now - t.pop("t0"), 4)
+            t.pop("late0")
             t["expected_s"] = (round(t["expected_s"], 4)
                                if t["expected_s"] else None)
             t["threshold_s"] = round(t["threshold_s"], 4)
@@ -234,12 +275,13 @@ class Watchdog:
         }
 
     def reset(self) -> None:
-        """Drop the flagged-total statistic (open tickets are LIVE state
-        — they describe real in-flight work and are never dropped). The
-        flagger thread increments `flagged_total` under `_lock`; an
+        """Drop the flagged-total and lateness statistics (open tickets
+        are LIVE state — they describe real in-flight work and are never
+        dropped). The flagger thread writes both under `_lock`; an
         unguarded reset racing it would resurrect the dropped count."""
         with self._lock:
             self.flagged_total = 0
+            self.late_s = 0.0
 
 
 WATCHDOG = Watchdog()

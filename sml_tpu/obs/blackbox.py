@@ -23,6 +23,9 @@ fatal — the dump path must work in a dying process):
       events.jsonl    the ring, one event per line (sink line shape,
                       header line first) — replayable into a Chrome
                       trace by scripts/blackbox_view.py WITHOUT jax
+      fits.jsonl      the newest fit records (`obs.fit_records()`), one
+                      per line: the per-fit phases the ring may have
+                      rolled past
       metrics.json    METRICS snapshot (incl. exemplars), SLO, skew
       audit.json      dispatch audit records + the rendered report
       ledger.json     HBM ledger snapshot
@@ -130,6 +133,14 @@ def dump_blackbox(reason: str = "manual", exc=None,
     except Exception:
         pass
 
+    # ---- fits.jsonl: the fit records kept beside the ring -------------
+    try:
+        with open(os.path.join(bundle, "fits.jsonl"), "w") as f:
+            for record in RECORDER.fit_records():
+                f.write(json.dumps(record, default=str) + "\n")
+    except Exception:
+        pass
+
     # ---- MANIFEST.json ------------------------------------------------
     import platform
     from ..version import __version__
@@ -197,7 +208,11 @@ def dump_blackbox(reason: str = "manual", exc=None,
 def _stall_hook(ticket: dict) -> None:
     """Once-per-process auto-dump on the FIRST hard stall (every later
     stall is in the first bundle's ring anyway; a stall storm must not
-    fill the disk with bundles)."""
+    fill the disk with bundles). A ticket flagged at its caller's own
+    threshold (a root `fit` a quarter over its shape's median) is a slow
+    fit, not a hard stall: its story is the `fit.slow` event."""
+    if not ticket.get("hard", True):
+        return
     with _lock:
         if _state["stall_dumped"]:
             return

@@ -58,6 +58,12 @@ SPANS = {
     "stage.*",
     # serving layer: one coalesced device dispatch of the micro-batcher
     "serve.batch",
+    # the interpreter's collector (obs/_recorder.py `_GcPauses`, a
+    # `gc.callbacks` hook while the recorder is on): gc.pause, one span a
+    # collection of 1 ms or more or of generation 2 (`generation`,
+    # `collected`), on the thread it ran on; shorter ones are in the totals
+    # gc.pause_s / gc.collections alone
+    "gc.*",
     # per-device straggler attribution (obs/_skew.py): skew.compute /
     # skew.wait lanes rendered on the trace exporter's per-device process
     "skew.*",
@@ -66,14 +72,35 @@ SPANS = {
     "ingest.*",
 }
 
+#: the host phases of a fit, by the benchmark metric that reports each: the
+#: same name lists as `benchmark/layer_metrics/_fit_spans.PHASES` (the
+#: program cannot import the benchmark; `tests/test_fit_records.py` holds
+#: the two equal). A fit's record (`obs/_fits.py`) makes its `phases` from
+#: them; with `fit.host.unattributed_s` they sum to the root span `fit`
+FIT_PHASES = {
+    "fit.host.featurize_s": ("fit.collect", "fit.prep", "fit.featurize"),
+    "fit.host.quantize_s": ("fit.quantize",),
+    "fit.host.stage_s": ("fit.stage",),
+    "fit.host.dispatch_s": ("fit.dispatch",),
+    "fit.host.device_wait_s": ("fit.device_wait",),
+    "fit.host.readback_s": ("fit.readback", "fit.unpack"),
+    "fit.host.observe_s": ("fit.baseline",),
+}
+
 #: spans that read the PROCESS's CPU seconds at their two ends while the
-#: recorder is on (`Profiler.span`): the one host phase whose total a
-#: reader takes. Process-wide on purpose: the phase's work is on the column
-#: plan's pool, so `span_cpu_s.<name>` over `span_s.<name>` is the cores it
-#: kept busy. A name is added with the metric that reads it (a read is a
-#: system call, and the clock ticks in 10 ms steps on the chip's host:
-#: nothing for a span of a few milliseconds)
-CPU_SPANS = frozenset({"fit.featurize"})
+#: recorder is on (`Profiler.span`): the root, the spans the phases are made
+#: of and the three parts of `unattributed_s` that have spans; not their
+#: children, not `stage.*`. Wall seconds cannot say why a phase was slow:
+#: 5 s of wall and 0.05 s of CPU was not running, 5 s of both was working.
+#: Process-wide on purpose: a pooled phase's work is on the pool's threads,
+#: so `span_cpu_s.<name>` over `span_s.<name>` is the cores it kept busy,
+#: and `fit.device_wait`'s is what the runtime's threads burn while the
+#: host thread sleeps. A read is a system call (6 us on the chip's host,
+#: about 30 a fit) and the clock ticks in 10 ms steps there: nothing for a
+#: span of a few milliseconds, plenty for a stall
+CPU_SPANS = frozenset(
+    {"fit", "fit.summary", "fit.cv.folds", "fit.cv.eval"}
+    | {name for names in FIT_PHASES.values() for name in names})
 
 COUNTERS = {
     # running totals the recorder keeps for EVERY span name (no call
@@ -84,6 +111,20 @@ COUNTERS = {
     # and for the spans of `CPU_SPANS` alone: CPU seconds (user + system,
     # every thread) of the process between the span's two ends
     "span_cpu_s.*",
+    # the collector's pauses, every collection of every generation (no ring
+    # event: generation 0 runs hundreds of times a fit): gc.pause_s seconds
+    # between a collection's two callbacks / gc.collections how many
+    "gc.*",
+    # a fit's record (obs/_fits.py), added when the root `fit` closes with
+    # no ring event: fit.gc_s the collector's pauses inside root fits (the
+    # untimed splits' are in gc.pause_s and not here) / fit.slow fits whose
+    # wall passed their shape's median by a quarter and by 0.1 s /
+    # fit.slow.excess_s their seconds over that median
+    "fit.gc_s", "fit.slow", "fit.slow.excess_s",
+    # the watchdog's own lateness (obs/_watchdog.py `_loop`): seconds its
+    # `wait` returned later than asked, summed beyond 50 ms a wait: the
+    # whole process, or the machine, stood still
+    "watchdog.late_s",
     # stall watchdog (obs/_watchdog.py): flagged in-flight tickets
     "stall.*",
     # black-box postmortem (obs/blackbox.py): bundles written
@@ -315,6 +356,9 @@ EVENTS = {
     "dispatch.*",         # dispatch.host / dispatch.device
     "featurize.plan.declined",  # why a fit did not take the column plan
                           # (args: reason), beside the counter of that name
+    "fit.slow",           # a fit's verdict when its root closes (obs/_fits.py):
+                          # the record, its shape's median, the phases by
+                          # their excess over their medians, largest first
     "cache.*",            # cache.evict / ...
     "collective.*",       # collective.psum / ...
     "compile.*",          # compile.trace / compile.cache_dir
@@ -390,6 +434,9 @@ METRICS_NAMES = {
     "dispatch.*",         # dispatch.host_ms / dispatch.device_ms: measured
                           # walls of routed programs (fed by the audit's
                           # attach path)
+    "fit.wall_ms",        # a root fit's wall, one observation a record,
+                          # exemplar = the fit's trace id: the slowest
+                          # fit's trace through engine_health()["metrics"]
     "load.*",             # open-loop harness latencies, SCHEDULED-arrival
                           # -> result (queueing charged to the system, not
                           # hidden in the client): load.request_ms plus the

@@ -106,12 +106,13 @@ class Profiler:
         the active context inside the block, so spans nest as they ran.
         The span is also a `jax.profiler.TraceAnnotation`, so a profiler
         trace shows it on the host plane above the device ops it caused.
-        A span the taxonomy lists under `CPU_SPANS` (`fit.featurize`)
-        also reads the PROCESS's CPU seconds at its two ends while the
-        recorder is on (`time.process_time`: a system call, 6 us on the
-        chip's host): the delta rides its event as `cpu_s` and the
-        recorder's running total `span_cpu_s.<name>`. Process-wide on
-        purpose: a pooled phase's work is on the pool's threads.
+        A span the taxonomy lists under `CPU_SPANS` (the root `fit` and
+        the spans its host phases are made of) also reads the PROCESS's
+        CPU seconds at its two ends while the recorder is on
+        (`time.process_time`: a system call, 6 us on the chip's host):
+        the delta rides its event as `cpu_s` and the recorder's running
+        total `span_cpu_s.<name>`. Process-wide on purpose: a pooled
+        phase's work is on the pool's threads.
         For spans carrying a dispatch `route`, it registers a
         stall-watchdog ticket (expected wall = the audit's prediction for
         this thread's pending decision) and feeds the measured wall time

@@ -454,6 +454,34 @@ def test_disabled_recorder_costs_one_attribute_load():
     per_ex = (time.perf_counter() - t0) / n
     assert per_ex < 20e-6, f"{per_ex * 1e6:.2f}us per disabled exemplar"
     assert obs.METRICS.histogram("serve.request_ms") is None
+    # a fit's record (PR 52): off, a running total and a kept record are
+    # no-ops behind the same attribute load, a root fit leaves no record,
+    # reads no clock of the process's and opens no ticket, and the
+    # collector's hook is not in the interpreter's list: nothing runs
+    import gc
+    t0 = time.perf_counter()
+    for _ in range(n):
+        obs.RECORDER.total("fit.gc_s", 0.5)
+    per_total = (time.perf_counter() - t0) / n
+    assert per_total < 20e-6, f"{per_total * 1e6:.2f}us per disabled total"
+    obs.RECORDER.keep_fit({"trace": 1})
+    assert obs.fit_records() == [] and obs.RECORDER.counters() == {}
+    assert obs.RECORDER._gc not in gc.callbacks
+    from sml_tpu.ml import Pipeline
+    estimator = Pipeline(stages=[])
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with obs.autolog_fit(estimator, None):
+            pass
+    per_fit = (time.perf_counter() - t0) / n
+    assert per_fit < 30e-6, f"{per_fit * 1e6:.2f}us per disabled root fit"
+    assert obs.fit_records() == [] and obs.RECORDER.events() == []
+    t0 = time.perf_counter()
+    for _ in range(n):
+        obs.WATCHDOG.open("fit", "Pipeline", expected_s=1.0, threshold_s=1.25)
+    per_open = (time.perf_counter() - t0) / n
+    assert per_open < 20e-6, f"{per_open * 1e6:.2f}us per disabled fit ticket"
+    assert obs.WATCHDOG.report()["open"] == 0
 
 
 # -------------------------------------------------------- profiler reset fix
